@@ -1,7 +1,6 @@
 """The reference's four guides (plus its single-node baseline and the
 bandwidth study they were all built for), as library entry points."""
 
-from .. import _jax_compat  # noqa: F401  (jax API shims, must load first)
 from . import (  # noqa: F401
     bandwidth_study,
     bare_init,

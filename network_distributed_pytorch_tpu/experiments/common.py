@@ -239,6 +239,8 @@ def train_loop(
                     batch = next(batches, None)
                 if batch is None:
                     break
+                if logger.batch_devices is None:
+                    logger.batch_devices = _shard_devices(batch)
                 if audit_pending:
                     # must precede the first execution: donate_argnums
                     # invalidates the state buffers the lowering would need
@@ -544,16 +546,69 @@ def evaluate_text_classifier(model, params, split, batch_size: int = 64) -> floa
     return correct / max(total, 1)
 
 
+def _shard_devices(tree: Any) -> list:
+    """Ids of the devices holding addressable shards of ``tree``'s first
+    array leaf (``[]`` for host arrays or an empty tree)."""
+    for leaf in jax.tree_util.tree_leaves(tree):
+        shards = getattr(leaf, "addressable_shards", None)
+        if shards is not None:
+            return sorted({shard.device.id for shard in shards})
+    return []
+
+
+def device_fields(reducer: Any = None, attn_impl: Optional[str] = None) -> Dict:
+    """What this process actually ran on, for every run summary: the device
+    as jax reports it, whether Pallas kernels compiled or were interpreted,
+    the kernel choices ``"auto"`` resolved to against that backend
+    (``attn_impl`` is the model's configured value — a deterministic
+    forward runs what it resolves to; ``orthogonalize_impl`` /
+    ``compress_impl`` are read back off the constructed reducer), and which
+    host tier fed the data. With these on the record a run that found no
+    chip cannot be read as one that used it."""
+    from ..native.build import host_data_tier
+    from ..observe.memory import all_device_memory_stats
+    from ..ops import pallas_interpret
+    from ..ops.flash_attention import resolve_attn_impl
+
+    devices = jax.devices()
+    out = {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "n_devices": len(devices),
+        "pallas_interpret": pallas_interpret(),
+        "host_data_tier": host_data_tier(),
+        "device_memory": all_device_memory_stats(),
+    }
+    if attn_impl is not None:
+        out["attn_impl"] = resolve_attn_impl(attn_impl)
+    for knob in ("orthogonalize_impl", "compress_impl"):
+        if getattr(reducer, knob, None) is not None:
+            out[knob] = getattr(reducer, knob)
+    return out
+
+
 def summarize(
     name: str,
     logger: MetricsLogger,
     extra: Optional[Dict] = None,
     perplexity: bool = False,
+    reducer: Any = None,
+    attn_impl: Optional[str] = None,
+    state: Optional[TrainState] = None,
 ) -> Dict:
-    """Summary dict for an experiment run. ``perplexity=True`` (LM
-    experiments) adds ``final_perplexity = exp(final_loss)``, None-safe for
-    resumed-already-complete runs with zero recorded steps."""
-    out = {"experiment": name, **logger.summary()}
+    """Summary dict for an experiment run, always carrying
+    :func:`device_fields`. ``perplexity=True`` (LM experiments) adds
+    ``final_perplexity = exp(final_loss)``, None-safe for
+    resumed-already-complete runs with zero recorded steps. ``state`` (the
+    final TrainState) adds ``placement``: which devices hold shards of the
+    params, the per-worker error memories and the batches the loop fed."""
+    out = {"experiment": name, **logger.summary(), **device_fields(reducer, attn_impl)}
+    if state is not None:
+        out["placement"] = {
+            "params": _shard_devices(state.params),
+            "memories": _shard_devices(state.memories),
+            "batch": logger.batch_devices,
+        }
     if perplexity:
         import math
 

@@ -202,7 +202,6 @@ def run(
     extra = {
         "preset": preset,
         "real_data": is_real,
-        "num_devices": mesh.size,
         "sync_every": sync_every,
         "fragments": fragments,
         "reducer": reducer,

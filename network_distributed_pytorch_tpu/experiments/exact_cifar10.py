@@ -321,8 +321,7 @@ def run(
     finally:
         telemetry.close()
     extra = {
-        "preset": preset, "real_data": is_real, "num_devices": mesh.size,
-        "strategy": strategy,
+        "preset": preset, "real_data": is_real, "strategy": strategy,
     }
     if adaptive:
         extra["final_rung"] = controller.rung.name

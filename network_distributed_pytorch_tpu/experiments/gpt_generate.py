@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from ..models.gpt import decode_tokens, generate, gpt_prefill, gpt_small, gpt_tiny
 from ..utils.config import ExperimentConfig
+from .common import device_fields
 
 
 def run(
@@ -109,7 +110,5 @@ def run(
         "decode_ms_per_token": decode_ms_per_token,
         "decode_time_unreliable": decode_unreliable,
         "sample_head": [int(t) for t in out[0, :8]],
-        "device": getattr(
-            jax.devices()[0], "device_kind", jax.devices()[0].platform
-        ),
+        **device_fields(attn_impl=model.config.attn_impl),
     }

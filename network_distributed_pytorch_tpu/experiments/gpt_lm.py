@@ -79,8 +79,9 @@ def run(
         ),
         "exact": ExactReducer,
     }
+    reducer_obj = reducers[reducer]()
     step = make_train_step(
-        stateless_loss(loss_fn), reducers[reducer](), params,
+        stateless_loss(loss_fn), reducer_obj, params,
         learning_rate=config.learning_rate, momentum=config.momentum,
         algorithm="ef_momentum" if reducer == "powersgd" else "sgd",
         mesh=mesh, donate_state=False,
@@ -102,4 +103,7 @@ def run(
             "reducer": reducer, "vocab": vocab, "seq_len": seq_len,
         },
         perplexity=True,
+        reducer=reducer_obj,
+        attn_impl=model.config.attn_impl,
+        state=state,
     )

@@ -107,7 +107,6 @@ def run(
     extra = {
         "preset": preset,
         "real_data": is_real,
-        "num_devices": mesh.size,
         "reducer_rank": config.reducer_rank,
     }
     if eval_after:
@@ -117,4 +116,6 @@ def run(
         extra["eval_accuracy"] = evaluate_image_classifier(
             model, state.params, step.eval_model_state(state)["batch_stats"], test_x, test_y
         )
-    return summarize("powersgd_cifar10", logger, extra)
+    return summarize(
+        "powersgd_cifar10", logger, extra, reducer=reducer, state=state
+    )

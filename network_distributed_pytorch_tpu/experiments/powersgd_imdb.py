@@ -26,6 +26,7 @@ from ..utils.losses import cross_entropy_loss
 from .common import (
     accum_batch_sharding,
     accumulated_batches,
+    powersgd_reducer_kwargs,
     summarize,
     train_loop,
 )
@@ -52,17 +53,14 @@ def run(
     if not config.global_batch_size:
         config.global_batch_size = 16 * mesh.size  # total_batch = 16 * size
 
-    if preset == "full":
-        model = distilbert_base(
-            num_labels=2, dtype=jnp.dtype(config.compute_dtype), remat=remat
-        )
-        vocab = model.config.vocab_size
-    else:
-        model = distilbert_tiny(
-            num_labels=2, dtype=jnp.dtype(config.compute_dtype), remat=remat
-        )
-        vocab = model.config.vocab_size
-        max_len = min(max_len, model.config.max_position_embeddings)
+    make = distilbert_base if preset == "full" else distilbert_tiny
+    model = make(
+        num_labels=2, dtype=jnp.dtype(config.compute_dtype), remat=remat,
+        # None = keep the model default ("auto": flash on TPU, einsum off)
+        **({} if config.attn_impl is None else {"attn_impl": config.attn_impl}),
+    )
+    vocab = model.config.vocab_size
+    max_len = min(max_len, model.config.max_position_embeddings)
 
     train_split, _val_split, is_real = prepare_imdb(
         data_dir=data_dir, tokenizer=tokenizer, max_len=max_len,
@@ -96,6 +94,7 @@ def run(
         compression_rank=config.reducer_rank,
         reuse_query=config.reuse_query,
         matricize="last",
+        **powersgd_reducer_kwargs(config),
     )
     step = make_train_step(
         loss_fn,
@@ -115,18 +114,35 @@ def run(
         arrays, config, max_steps_per_epoch=max_steps_per_epoch,
         keys=("input_ids", "attention_mask", "labels"),
     )
-    state, logger = train_loop(
-        step, state, batches, config.training_epochs,
-        rank=config.process_id, log_every=config.log_every,
-        batch_sharding=accum_batch_sharding(mesh, config.accum_steps),
-    )
+    from ..observe import audit_from_config, telemetry_from_config
+
+    telemetry = telemetry_from_config(config)
+    try:
+        state, logger = train_loop(
+            step, state, batches, config.training_epochs,
+            rank=config.process_id, log_every=config.log_every,
+            batch_sharding=accum_batch_sharding(mesh, config.accum_steps),
+            telemetry=telemetry,
+            trace_dir=config.trace_dir,
+            audit=audit_from_config(config),
+            run_name="powersgd_imdb",
+        )
+    finally:
+        telemetry.close()
     return summarize(
         "powersgd_imdb",
         logger,
         {
             "preset": preset,
             "real_data": is_real,
-            "num_devices": mesh.size,
             "reducer_rank": config.reducer_rank,
+            "model": {
+                k: getattr(model.config, k)
+                for k in ("n_layers", "dim", "n_heads", "hidden_dim", "vocab_size")
+            },
+            "seq_len": max_len,
         },
+        reducer=reducer,
+        attn_impl=model.config.attn_impl,
+        state=state,
     )

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from ..models.gpt import gpt_small, gpt_tiny
 from ..utils.config import ExperimentConfig
+from .common import device_fields
 
 
 def run(
@@ -209,9 +210,7 @@ def run(
             "live_requests_total": registry.get_counter(
                 "live_serving_requests_total", state="finished"
             ),
-            "device": getattr(
-                jax.devices()[0], "device_kind", jax.devices()[0].platform
-            ),
+            **device_fields(attn_impl=model.config.attn_impl),
             **mode,
         }
         if engine == "paged":

@@ -18,6 +18,7 @@ from __future__ import annotations
 import argparse
 import sys
 
+from . import hostenv
 from .experiments import (
     bandwidth_study,
     bare_init,
@@ -54,6 +55,11 @@ EXPERIMENTS = {
     "gpt_generate": gpt_generate.run,
     "serve_gpt": serve_gpt.run,
 }
+
+# experiments whose ranks share no collective (serve_gpt ranks share only the
+# file spool): no jax.distributed rendezvous, and the only ones a supervisor
+# may run as several one-chip workers on one TPU host
+RENDEZVOUS_FREE = ("bare_init", "serve_gpt")
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -557,6 +563,30 @@ def _supervise(args, argv) -> dict:
 
     base = worker_argv_base(argv)
 
+    # one process per chip: N>1 workers on a TPU host would each open every
+    # chip (make_mesh() takes all visible devices) and all but the first
+    # would die or hang on a busy chip. The parent counts chips from their
+    # device nodes — it must never initialise a backend itself — and pins
+    # each worker to
+    # its own chip, which only rendezvous-free experiments can use: a
+    # training job drives all chips of a host from ONE process.
+    chips = hostenv.local_tpu_chips() if args.num_processes > 1 else 0
+    if chips and args.experiment not in RENDEZVOUS_FREE:
+        raise SystemExit(
+            f"launch: refusing --supervise --num-processes"
+            f" {args.num_processes} for {args.experiment!r} on a host with"
+            f" {chips} TPU chip(s): a chip belongs to one process, and one"
+            " process already shards a training step over every local chip."
+            " Use --num-processes 1 here (N>1 is for one process per HOST,"
+            f" or for {', '.join(RENDEZVOUS_FREE)} workers pinned one per"
+            " chip)."
+        )
+    if chips and args.num_processes > chips:
+        raise SystemExit(
+            f"launch: refusing --num-processes {args.num_processes}: this"
+            f" host has {chips} TPU chip(s) and each worker needs its own"
+        )
+
     def argv_for_rank(rank: int, world: int, incarnation: int) -> list:
         return [
             sys.executable, "-m", "network_distributed_pytorch_tpu.launch",
@@ -593,6 +623,7 @@ def _supervise(args, argv) -> dict:
             telemetry=telemetry,
             log_dir=args.worker_log_dir,
             run_dir=args.run_dir,
+            pin_chips=bool(chips),
         )
         if args.run_dir:
             telemetry.emit(
@@ -641,6 +672,9 @@ def main(argv=None) -> dict:
         raise ValueError("--mesh-shape requires --supervise")
     if args.supervise:
         return _supervise(args, argv if argv is not None else sys.argv[1:])
+    # worker path only (the supervising parent stays off jax config): one
+    # persistent compile cache, placeable through JAX_COMPILATION_CACHE_DIR
+    hostenv.configure_compile_cache()
     if args.run_dir:
         # a worker rank of a run-dir launch: derive this rank's event shard,
         # and make sure the run env is present so telemetry_for_run leads
@@ -733,9 +767,7 @@ def main(argv=None) -> dict:
     # serve_gpt ranks share only the file spool — no collectives, and a
     # rendezvous would couple the fleet's fate to its slowest/dead rank,
     # exactly what the elastic spool exists to avoid
-    if args.num_processes > 1 and args.experiment not in (
-        "bare_init", "serve_gpt"
-    ):
+    if args.num_processes > 1 and args.experiment not in RENDEZVOUS_FREE:
         initialize_distributed(
             DistributedConfig(
                 process_id=cfg.process_id,

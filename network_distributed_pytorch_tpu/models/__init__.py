@@ -5,7 +5,6 @@ HuggingFace DistilBERT); this package provides TPU-native equivalents plus the
 small models the test tier needs.
 """
 
-from .. import _jax_compat  # noqa: F401  (jax API shims, must load first)
 from .mlp import MLP  # noqa: F401
 from .cnn import SmallCNN  # noqa: F401
 from .resnet import ResNet, resnet18, resnet50, resnet152  # noqa: F401

@@ -122,11 +122,11 @@ class MultiHeadSelfAttention(nn.Module):
                 )
             ctx = impls[cfg.seq_impl](q, k, v, cfg.seq_axis, mask=mask)
         elif attn_impl == "flash":
-            from ..ops.flash_attention import flash_attention
+            from ..ops import flash_attention, pallas_interpret
 
             ctx = flash_attention(
                 q, k, v, mask=mask.astype(jnp.float32),
-                interpret=jax.default_backend() != "tpu",
+                interpret=pallas_interpret(),
             )
         else:
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(head_dim).astype(cfg.dtype)
@@ -207,11 +207,17 @@ class DistilBertForSequenceClassification(nn.Module):
         return logits.astype(jnp.float32)
 
 
-def distilbert_base(num_labels: int = 2, dtype=jnp.float32, remat: bool = False) -> DistilBertForSequenceClassification:
+def distilbert_base(
+    num_labels: int = 2, dtype=jnp.float32, remat: bool = False,
+    attn_impl: str = "auto",
+) -> DistilBertForSequenceClassification:
     """distilbert-base-uncased shape (the reference's checkpoint,
     ``ddp_powersgd_distillBERT_IMDb/ddp_init.py:150``)."""
     return DistilBertForSequenceClassification(
-        DistilBertConfig(num_labels=num_labels, dtype=dtype, remat=remat)
+        DistilBertConfig(
+            num_labels=num_labels, dtype=dtype, remat=remat,
+            attn_impl=attn_impl,
+        )
     )
 
 
@@ -238,7 +244,10 @@ def distilbert_wide(num_labels: int = 2, dtype=jnp.float32, remat: bool = False)
     )
 
 
-def distilbert_tiny(num_labels: int = 2, dtype=jnp.float32, remat: bool = False) -> DistilBertForSequenceClassification:
+def distilbert_tiny(
+    num_labels: int = 2, dtype=jnp.float32, remat: bool = False,
+    attn_impl: str = "auto",
+) -> DistilBertForSequenceClassification:
     """Test-tier configuration (SURVEY §4: 'DistilBERT-shaped toy transformer')."""
     return DistilBertForSequenceClassification(
         DistilBertConfig(
@@ -251,5 +260,6 @@ def distilbert_tiny(num_labels: int = 2, dtype=jnp.float32, remat: bool = False)
             num_labels=num_labels,
             dtype=dtype,
             remat=remat,
+            attn_impl=attn_impl,
         )
     )

@@ -126,11 +126,10 @@ class CausalSelfAttention(nn.Module):
                 )
             ctx = impls[cfg.seq_impl](q, k, v, cfg.seq_axis, causal=True)
         elif attn_impl == "flash":
-            from ..ops.flash_attention import flash_attention
+            from ..ops import flash_attention, pallas_interpret
 
             ctx = flash_attention(
-                q, k, v, causal=True,
-                interpret=jax.default_backend() != "tpu",
+                q, k, v, causal=True, interpret=pallas_interpret(),
             )
         else:
             t = x.shape[1]

@@ -17,6 +17,7 @@ _SRC = os.path.join(os.path.dirname(__file__), "dataloader.cpp")
 _BUILD_DIR = os.path.join(os.path.dirname(__file__), "_build")
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
+_load_refused = ""  # why the numpy tier is feeding this process, if it is
 
 
 # Portable flags on purpose: -march=native would bake host ISA into a .so
@@ -50,14 +51,16 @@ def _compile(so_path: str) -> None:
 
 def load_library() -> Optional[ctypes.CDLL]:
     """The loaded native library, building it if needed; None when disabled
-    (``NDP_TPU_NO_NATIVE=1``) or the toolchain/build is unavailable."""
-    global _lib, _load_attempted
+    (``NDP_TPU_NO_NATIVE=1``) or the toolchain/build is unavailable — the
+    callers then run their numpy tier, and :func:`host_data_tier` says so."""
+    global _lib, _load_attempted, _load_refused
     if _lib is not None:
         return _lib
     if _load_attempted:
         return None
     _load_attempted = True
     if os.environ.get("NDP_TPU_NO_NATIVE") == "1":
+        _load_refused = "NDP_TPU_NO_NATIVE=1"
         return None
     try:
         so = _so_path()
@@ -66,13 +69,25 @@ def load_library() -> Optional[ctypes.CDLL]:
         lib = ctypes.CDLL(so)
         _declare(lib)
         _lib = lib
-    except (OSError, subprocess.CalledProcessError):
+    except (OSError, subprocess.CalledProcessError) as e:
+        _load_refused = f"{type(e).__name__}: {e}"[:200]
         return None
     return _lib
 
 
 def native_available() -> bool:
     return load_library() is not None
+
+
+def host_data_tier() -> str:
+    """Which host data tier has fed this process so far, for run summaries:
+    ``"native"`` (the C++ library loaded), ``"numpy (<why>)"`` (a load was
+    refused and the numpy fallbacks ran), or ``"numpy (native not
+    requested)"`` when nothing asked for the library. Never triggers a
+    build itself."""
+    if _lib is not None:
+        return "native"
+    return f"numpy ({_load_refused or 'native not requested'})"
 
 
 def _declare(lib: ctypes.CDLL) -> None:

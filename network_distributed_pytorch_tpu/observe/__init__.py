@@ -58,7 +58,7 @@ that loop as a first-class subsystem instead of scattered fragments:
   (``artifacts/fidelity_frontier.json``) joining loss against cumulative
   ledger bytes per fallback-ladder rung.
 - :mod:`observe.memory`    — the device-memory plane: the compile-time
-  HBM footprint audit (``_jax_compat.compiled_memory`` joined onto
+  HBM footprint audit (``memory.compiled_memory`` joined onto
   ``CompileEvent``), the live ``device.memory_stats()`` sampler emitting
   typed ``MemoryEvent`` records, and the OOM post-mortem builder behind
   ``artifacts/oom_report.json``.

@@ -144,15 +144,14 @@ class CompileEvent(Event):
     # device-cost extension (observe.mfu): per-step FLOPs/bytes recorded at
     # compile time so a jax-free report can join them with measured step
     # times. ``flops_source`` says where the count came from —
-    # "cost_analysis" (XLA's own model via _jax_compat.compiled_cost) or
+    # "cost_analysis" (XLA's own model via observe.ledger.compiled_cost) or
     # "analytic" (the model's hand count). All None when unknown.
     flops_per_step: Optional[float] = None
     bytes_accessed_per_step: Optional[float] = None
     flops_source: Optional[str] = None
     device_kind: Optional[str] = None
     peak_flops_per_s: Optional[float] = None
-    # compile-time HBM footprint (observe.memory via
-    # _jax_compat.compiled_memory): XLA's buffer-assignment split for the
+    # compile-time HBM footprint (observe.memory.compiled_memory): XLA's buffer-assignment split for the
     # compiled executable — exact per-executable, the predicted side of the
     # report's predicted-vs-measured memory join. All None when the backend
     # exposes no memory_analysis (the join then marks prediction

@@ -247,23 +247,33 @@ def _overlap_extract(report: Dict) -> Dict:
     return {k: report[k] for k in keys if k in report}
 
 
+def compiled_cost(compiled) -> Optional[Dict[str, float]]:
+    """XLA's cost model for a ``jax.stages.Compiled`` as a flat
+    ``{metric: float}`` dict (``"flops"``, ``"bytes accessed"``, ...), or
+    ``None`` when it reports no flops — callers then use the analytic
+    count."""
+    cost = compiled.cost_analysis()
+    if not cost:
+        return None
+    out = {
+        k: float(v) for k, v in cost.items() if isinstance(v, (int, float))
+    }
+    return out if out.get("flops") else None
+
+
 def device_cost_fields(compiled, analytic_flops: Optional[float] = None) -> Dict:
     """The ``CompileEvent`` device-cost extension for an AOT executable:
-    XLA's own per-execution cost model when the backend provides one
-    (``_jax_compat.compiled_cost``), else the caller's analytic FLOPs
-    count, plus the device identity the peak-FLOPs table is keyed on.
-    Returns kwargs for ``CompileEvent`` (possibly just ``device_kind``
-    when neither source knows a FLOPs number)."""
+    XLA's own per-execution cost model when it reports flops
+    (:func:`compiled_cost`), else the caller's analytic FLOPs count, plus
+    the device identity the peak-FLOPs table is keyed on. Returns kwargs
+    for ``CompileEvent`` (just ``device_kind`` when neither source knows a
+    FLOPs number)."""
     import jax
 
-    from .._jax_compat import compiled_cost
     from .mfu import peak_flops
 
-    try:
-        dev = jax.devices()[0]
-        device_kind, platform = dev.device_kind, dev.platform
-    except Exception:
-        device_kind, platform = "", ""
+    dev = jax.devices()[0]
+    device_kind, platform = dev.device_kind, dev.platform
     cost = compiled_cost(compiled) if compiled is not None else None
     if cost is not None:
         flops, source = cost["flops"], "cost_analysis"

@@ -8,7 +8,7 @@ discipline the cost model uses:
 - **Compile-time footprint audit** (:func:`memory_footprint_fields`):
   XLA's per-executable buffer-assignment split
   (argument/output/temp/generated-code bytes) via
-  ``_jax_compat.compiled_memory``, attached to the
+  :func:`compiled_memory`, attached to the
   :class:`observe.events.CompileEvent` next to the FLOPs fields so every
   jitted step publishes its predicted peak. This side is EXACT per
   executable (see DESIGN.md guarantee classes).
@@ -58,6 +58,25 @@ FOOTPRINT_FIELDS = (
 )
 
 
+def compiled_memory(compiled) -> Optional[Dict[str, float]]:
+    """XLA's buffer-assignment split for a ``jax.stages.Compiled``:
+    ``{"argument_bytes", "output_bytes", "temp_bytes",
+    "generated_code_bytes"}`` floats read off ``memory_analysis()``'s
+    ``*_size_in_bytes`` attributes, or ``None`` when there is no
+    executable or the backend returns no analysis."""
+    if compiled is None:
+        return None
+    mem = compiled.memory_analysis()
+    if mem is None:
+        return None
+    out = {}
+    for field in FOOTPRINT_FIELDS:
+        value = getattr(mem, field.replace("_bytes", "_size_in_bytes"), None)
+        if isinstance(value, (int, float)):
+            out[field] = float(value)
+    return out or None
+
+
 def memory_footprint_fields(compiled) -> Dict:
     """CompileEvent kwargs for the compile-time HBM footprint of a
     ``jax.stages.Compiled`` — the predicted side of the memory join.
@@ -65,19 +84,13 @@ def memory_footprint_fields(compiled) -> Dict:
     ``peak_hbm_bytes`` is the split's sum: XLA's buffer assignment
     accounts arguments, outputs, temps, and generated code separately,
     and their total is the executable's device-memory high water.
-    Empty dict (NOT None) when the backend exposes no
-    ``memory_analysis`` so callers can always ``**`` it.
+    Empty dict (NOT None) when there is no analysis, so callers can
+    always ``**`` it.
     """
-    from .._jax_compat import compiled_memory
-
-    mem = compiled_memory(compiled)
-    if not mem:
+    out = compiled_memory(compiled)
+    if not out:
         return {}
-    out = {
-        name: mem[name] for name in FOOTPRINT_FIELDS if mem.get(name) is not None
-    }
-    if out:
-        out["peak_hbm_bytes"] = sum(out.values())
+    out["peak_hbm_bytes"] = sum(out.values())
     return out
 
 
@@ -104,6 +117,21 @@ def device_memory_stats(device=None) -> Optional[Dict]:
         if isinstance(stats.get(name), (int, float))
     }
     return out or None
+
+
+def all_device_memory_stats() -> List[Dict]:
+    """:func:`device_memory_stats` for EVERY local device, each row tagged
+    with its device ``id`` — the sampler and the OOM report read device 0
+    only, which cannot show whether a multi-chip run put anything on the
+    other chips. Empty where the backend has no ``memory_stats`` (CPU)."""
+    import jax
+
+    rows = []
+    for device in jax.local_devices():
+        stats = device_memory_stats(device)
+        if stats:
+            rows.append({"id": device.id, **stats})
+    return rows
 
 
 class MemorySampler:

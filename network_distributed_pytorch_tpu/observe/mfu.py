@@ -13,7 +13,7 @@ there is exactly one provenance for the numbers the gate compares.
 **The FLOPs join.** At compile time the trainer records per-step FLOPs on
 its :class:`observe.events.CompileEvent` — XLA's own
 ``compiled.cost_analysis()`` when the backend provides it
-(``_jax_compat.compiled_cost``), the analytic model count otherwise, the
+(``observe.ledger.compiled_cost``), the analytic model count otherwise, the
 ``flops_source`` field says which. At report time
 :func:`mfu_from_compile_records` joins those recorded counts with the
 measured steady-state step time: ``MFU = flops_per_step / step_time /
@@ -78,23 +78,37 @@ COMM_EXPOSED_THRESHOLD = 0.5
 STEADY_STATE = "steady-state"
 
 
-def _table_lookup(table: Dict[str, float], device_kind: str, platform: str) -> float:
-    if platform and platform != "tpu":
-        return 0.0
+def _table_lookup(
+    table: Dict[str, float], device_kind: str, platform: Optional[str]
+) -> float:
     kind = (device_kind or "").lower()
+    if platform is None:
+        # report time: a run log records the kind only, and every TPU's
+        # device_kind says "TPU" (the toy probe's "toy-sim" does not)
+        platform = "tpu" if "tpu" in kind else ""
+    if platform != "tpu":
+        return 0.0
     for key in sorted(table, key=len, reverse=True):
         if key in kind:
             return table[key]
-    return 0.0
+    raise ValueError(
+        f"TPU device_kind {device_kind!r} is not in the observe.mfu peak"
+        " tables; add its published peak there (a device that is not in"
+        " the table is an error, not a default)"
+    )
 
 
-def peak_flops(device_kind: str, platform: str = "tpu") -> float:
-    """Peak bf16 FLOP/s for the device kind, or 0.0 when unknown (CPU)."""
+def peak_flops(device_kind: str, platform: Optional[str] = None) -> float:
+    """Peak bf16 FLOP/s for a TPU device kind; 0.0 off-TPU (a CPU has no
+    entry and gets no MFU); raises for a TPU kind the table lacks.
+    ``platform`` is jax's ``device.platform`` where a device is at hand;
+    None reads it off the kind."""
     return _table_lookup(PEAK_BF16_FLOPS, device_kind, platform)
 
 
-def hbm_bandwidth(device_kind: str, platform: str = "tpu") -> float:
-    """HBM bytes/s for the device kind, or 0.0 when unknown."""
+def hbm_bandwidth(device_kind: str, platform: Optional[str] = None) -> float:
+    """HBM bytes/s for a TPU device kind; same contract as
+    :func:`peak_flops`."""
     return _table_lookup(HBM_BYTES_PER_S, device_kind, platform)
 
 
@@ -158,7 +172,7 @@ def mfu_event(
     n_steps: int = 0,
     flops_source: str = "analytic",
     device_kind: str = "",
-    platform: str = "tpu",
+    platform: Optional[str] = None,
     peak_flops_per_s: Optional[float] = None,
     bytes_accessed_per_step: Optional[float] = None,
     hbm_bytes_per_s_: Optional[float] = None,

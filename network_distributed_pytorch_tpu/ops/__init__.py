@@ -1,7 +1,7 @@
 """TPU ops: Gram-Schmidt orthogonalization (XLA fori_loop + Pallas variants)
 and Pallas flash attention."""
 
-from .. import _jax_compat  # noqa: F401  (jax API shims, must load first)
+from ._backend import pallas_interpret  # noqa: F401
 from .orthogonalize import orthogonalize  # noqa: F401
 from .flash_attention import flash_attention  # noqa: F401
 from .paged import (  # noqa: F401

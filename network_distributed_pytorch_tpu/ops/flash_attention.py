@@ -27,7 +27,6 @@ runs in interpret mode (the test path), on TPU it compiles with Mosaic.
 from __future__ import annotations
 
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
@@ -35,17 +34,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU lowering)
 
-# pre-varying-types jax has no vma on avals (shard_map check_rep=False does
-# no replication tracking), so out_shape structs must not mention it there
-_STRUCT_HAS_VMA = (
-    "vma" in inspect.signature(jax.ShapeDtypeStruct.__init__).parameters
-)
-
-
-def _out_struct(shape, dtype, vma):
-    if _STRUCT_HAS_VMA:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+from ._backend import pallas_interpret
 
 _NEG_INF = float(-1e30)  # finite stand-in: -inf breaks the m-correction math
 _LSE_EMPTY = float(1e30)  # lse for fully-masked rows: exp(s - 1e30) == 0
@@ -69,7 +58,7 @@ def resolve_attn_impl(attn_impl: str) -> str:
     untouched (tests pin both engines regardless of backend).
     """
     if attn_impl == "auto":
-        return "flash" if jax.default_backend() == "tpu" else "einsum"
+        return "einsum" if pallas_interpret() else "flash"
     return attn_impl
 
 
@@ -100,17 +89,18 @@ def _flash_kernel(
 
     def body(j, carry):
         m, l, acc = carry
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        ks = pl.multiple_of(j * block_k, block_k)
+        k_blk = k_ref[0, pl.ds(ks, block_k), :].astype(jnp.float32)
+        v_blk = v_ref[0, pl.ds(ks, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (block_q, block_k)
-        mask_blk = mask_ref[0, pl.ds(j * block_k, block_k)]
-        valid = jnp.broadcast_to(
-            (mask_blk > _MASK_PAD)[None, :], (block_q, block_k)
-        )
-        s = s + mask_blk[None, :]
+        # the mask arrives as (1, T/block_k, block_k): K block j is ROW j,
+        # a dynamic sublane index — Mosaic has no dynamic lane slicing
+        mask_blk = mask_ref[0, pl.ds(j, 1), :]  # (1, block_k)
+        valid = jnp.broadcast_to(mask_blk > _MASK_PAD, (block_q, block_k))
+        s = s + mask_blk
         if causal:
             q_pos = qi * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0
@@ -140,7 +130,7 @@ def _flash_kernel(
     m, l, acc = lax.fori_loop(0, hi, body, (m0, l0, acc0))
     o_ref[0] = (acc / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
     lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-37)), _LSE_EMPTY)
-    lse_ref[0] = lse[:, 0]
+    lse_ref[0] = lse.reshape(1, block_q)
 
 
 def _causal_bias(t_q: int, block_k: int, k_start, dtype=jnp.float32):
@@ -240,6 +230,13 @@ def flash_attention(
     if mask is None:
         mask = jnp.zeros((b, t), jnp.float32)
     mask = mask.astype(jnp.float32)
+    # inside shard_map the mask must vary over the mesh as q does: the
+    # backward's dmask does (it is built from do), and a custom_vjp
+    # cotangent has to have its primal's type — a mask made here (or shared
+    # by all workers) would otherwise be invariant
+    missing = tuple(jax.typeof(qf).vma - jax.typeof(mask).vma)
+    if missing:
+        mask = lax.pcast(mask, missing, to="varying")
 
     kernel = functools.partial(
         _flash_kernel, block_q, block_k, t, causal, scale
@@ -250,8 +247,11 @@ def flash_attention(
         # over the mesh — exactly as the union of its operands do
         vma = frozenset()
         for operand in (qf, kf, vf, mask):
-            vma = vma | getattr(jax.typeof(operand), "vma", frozenset())
-        return pl.pallas_call(
+            vma = vma | jax.typeof(operand).vma
+        # TPU block shapes need their last two dims (8, 128)-divisible or
+        # equal to the array's: the mask rides as (B, T/block_k, block_k)
+        # and the lse as (B*H, 1, T), never as 2-D rows of width T
+        out, lse = pl.pallas_call(
             kernel,
             grid=(b * h, t // block_q),
             in_specs=[
@@ -259,18 +259,21 @@ def flash_attention(
                 pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0)),
                 pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0)),
                 # mask is per-batch: integer-divide the (b*h) grid row
-                pl.BlockSpec((1, t), lambda bh, qi: (bh // h, 0)),
+                pl.BlockSpec(
+                    (1, t // block_k, block_k), lambda bh, qi: (bh // h, 0, 0)
+                ),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-                pl.BlockSpec((1, block_q), lambda bh, qi: (bh, qi)),
+                pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)),
             ],
             out_shape=[
-                _out_struct((b * h, t, d), q.dtype, vma),
-                _out_struct((b * h, t), jnp.float32, vma),
+                jax.ShapeDtypeStruct((b * h, t, d), q.dtype, vma=vma),
+                jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32, vma=vma),
             ],
             interpret=interpret,
-        )(qf, kf, vf, mask)
+        )(qf, kf, vf, mask.reshape(b, t // block_k, block_k))
+        return out, lse.reshape(b * h, t)
 
     @jax.custom_vjp
     def attn(qf, kf, vf, mask):

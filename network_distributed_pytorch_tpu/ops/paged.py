@@ -24,8 +24,6 @@ them onto the row's LAST real block).
 
 from __future__ import annotations
 
-from .. import _jax_compat  # noqa: F401  (jax API shims, must load first)
-
 import jax.numpy as jnp
 
 

@@ -53,7 +53,10 @@ def orthogonalize_pallas(
     mt = matrix.T  # (r, n): lanes = n
     out = pl.pallas_call(
         functools.partial(_gram_schmidt_kernel, r, eps),
-        out_shape=jax.ShapeDtypeStruct((r, n), matrix.dtype),
+        # inside shard_map the output varies over the mesh as the input does
+        out_shape=jax.ShapeDtypeStruct(
+            (r, n), matrix.dtype, vma=jax.typeof(matrix).vma
+        ),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=interpret,

@@ -35,7 +35,6 @@ like ``ops.flash_attention``.
 from __future__ import annotations
 
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
@@ -43,17 +42,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU lowering)
 
-# pre-varying-types jax has no vma on avals (shard_map check_rep=False does
-# no replication tracking), so out_shape structs must not mention it there
-_STRUCT_HAS_VMA = (
-    "vma" in inspect.signature(jax.ShapeDtypeStruct.__init__).parameters
-)
-
-
 def _out_struct(shape, dtype, vma):
-    if _STRUCT_HAS_VMA:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _vma_union(*operands):
@@ -62,7 +52,7 @@ def _vma_union(*operands):
     vma = frozenset()
     for op in operands:
         if op is not None:
-            vma = vma | getattr(jax.typeof(op), "vma", frozenset())
+            vma = vma | jax.typeof(op).vma
     return vma
 
 

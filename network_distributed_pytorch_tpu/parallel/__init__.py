@@ -1,6 +1,5 @@
 """Parallelism layers: mesh (L1), comm (L2), packing, reducers (L3), trainer (L4)."""
 
-from .. import _jax_compat  # noqa: F401  (jax API shims, must load first)
 from .mesh import (  # noqa: F401
     DATA_AXIS,
     DistributedConfig,

@@ -217,7 +217,8 @@ def make_fsdp_train_step(
         # the full flat buffer is the per-device pieces re-concatenated.
         # The fence chains chunk j's payload to chunk j-1's gathered result
         # (and, transposed, chunk j's cotangent to chunk j-1's scattered
-        # gradient — _jax_compat registers the barrier's AD rules), which
+        # gradient — the barrier is linear, so jax differentiates through
+        # it), which
         # pins the pipeline in BOTH directions.
         pieces, prev = [], None
         for start, end in chunk_bounds(shard.shape[0], comm_chunks):

@@ -53,6 +53,7 @@ from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops import pallas_interpret
 from ..ops.orthogonalize import orthogonalize
 from .comm import (
     all_reduce_mean,
@@ -459,9 +460,9 @@ class PowerSGDReducer:
         # gradients' own dtype (the reference's fp32 behavior).
         self.compression_dtype = jnp.dtype(compression_dtype) if compression_dtype else None
         # off-TPU the Pallas kernels run in interpret mode (the test path)
-        self._interpret = jax.default_backend() != "tpu"
+        self._interpret = pallas_interpret()
         if orthogonalize_impl == "auto":
-            orthogonalize_impl = "pallas" if not self._interpret else "xla"
+            orthogonalize_impl = "xla" if self._interpret else "pallas"
         self.orthogonalize_impl = orthogonalize_impl
         self.compress_impl = compress_impl
         if orthogonalize_impl == "pallas":

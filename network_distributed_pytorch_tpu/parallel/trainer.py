@@ -33,7 +33,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .comm import all_reduce_mean
 from .mesh import DATA_AXIS
@@ -396,6 +396,9 @@ class CompiledStep(NamedTuple):
     # stamped into the audit's CompileEvent so the offline cost model
     # (observe.costmodel) can identify WHICH config a run executed
     comm_config: Optional[Dict] = None
+    # the carry's NamedShardings over ``mesh`` (one per TrainState field) —
+    # ``fn``'s out_shardings, so also how ``init_state`` places it
+    state_shardings: Any = None
 
     def __call__(self, state, batch):
         return self.fn(state, batch)
@@ -407,10 +410,22 @@ class CompiledStep(NamedTuple):
     def init_state(self, params: PyTree, model_state: PyTree = None) -> TrainState:
         """Build a correctly-shaped TrainState for this step (adds the
         per-worker leading axis on error memories and model_state in the
-        distributed case)."""
-        return init_train_state(
+        distributed case), placed over the mesh with the step's own
+        shardings: the first call then starts where every later call does —
+        no host-to-device copy of the carry inside the first step, one
+        dispatch signature from the first call on.
+
+        Over a mesh that spans other processes the state stays on the host:
+        placing it there is ``data.multihost.global_state_from_host``'s job
+        (each process materialises only its own shards, no cross-process
+        traffic), and that seam takes host values."""
+        state = init_train_state(
             params, self.reducer, model_state, self.num_devices, self.optimizer
         )
+        if self.state_shardings is None or self.mesh.is_multi_process:
+            return state
+        # one sharding per TrainState field: a pytree prefix of the state
+        return jax.device_put(state, self.state_shardings)
 
     def eval_model_state(self, state: TrainState, reduce: str = "mean") -> PyTree:
         """Eval-ready model_state: the single-process step carries it plain;
@@ -419,6 +434,29 @@ class CompiledStep(NamedTuple):
         if self.mesh is None:
             return state.model_state
         return collapse_per_worker(state.model_state, reduce)
+
+
+def _jit_step(
+    sharded, mesh: Mesh, state_specs: TrainState, batch_spec: PartitionSpec,
+    donate_state: bool,
+):
+    """jit a shard_mapped ``(state, batch) -> (state, out)`` step with its
+    shardings pinned to the shard_map's own specs over ``mesh``; returns
+    ``(fn, state_shardings)``. Left to infer them, jit keys the program on
+    however each caller happened to place the carry — a host-built or
+    restored state compiled a second program on the second call — and hands
+    a ``P(axis)`` output back as ``P()`` when the axis has one device, so on
+    one chip the carry never came back as it went in. Pinned, there is one
+    program, what goes in is what comes out, and an abstract ``lower`` of
+    unplaced shapes is the program that runs."""
+    state_shardings = TrainState(*(NamedSharding(mesh, s) for s in state_specs))
+    fn = jax.jit(
+        sharded,
+        donate_argnums=(0,) if donate_state else (),
+        in_shardings=(state_shardings, NamedSharding(mesh, batch_spec)),
+        out_shardings=(state_shardings, NamedSharding(mesh, PartitionSpec())),
+    )
+    return fn, state_shardings
 
 
 def make_scanned_train_fn(
@@ -501,7 +539,9 @@ def make_scanned_train_fn(
         in_specs=(state_specs, batch_spec),
         out_specs=(state_specs, PartitionSpec()),
     )
-    fn = jax.jit(sharded, donate_argnums=(0,) if donate_state else ())
+    fn, state_shardings = _jit_step(
+        sharded, mesh, state_specs, batch_spec, donate_state
+    )
     bits = _reducer_bits(reducer, params_template, mesh.size) + LOSS_SYNC_BITS
     return CompiledStep(
         fn,
@@ -514,6 +554,7 @@ def make_scanned_train_fn(
             loss_fn, reducer, mesh, axis_name, accum_steps
         ),
         comm_config=reducer_comm_config(reducer),
+        state_shardings=state_shardings,
     )
 
 
@@ -767,7 +808,9 @@ def make_train_step(
         in_specs=(state_specs, batch_spec),
         out_specs=(state_specs, PartitionSpec()),
     )
-    fn = jax.jit(sharded, donate_argnums=(0,) if donate_state else ())
+    fn, state_shardings = _jit_step(
+        sharded, mesh, state_specs, batch_spec, donate_state
+    )
     bits = _reducer_bits(reducer, params_template, mesh.size) + LOSS_SYNC_BITS
     return CompiledStep(
         fn,
@@ -780,4 +823,5 @@ def make_train_step(
             loss_fn, reducer, mesh, axis_name, accum_steps
         ),
         comm_config=reducer_comm_config(reducer),
+        state_shardings=state_shardings,
     )

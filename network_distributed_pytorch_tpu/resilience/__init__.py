@@ -133,7 +133,6 @@ from .supervisor import (  # noqa: F401
     Supervisor,
     SupervisorConfig,
     SupervisorResult,
-    device_ranks_from_env,
     incarnation_from_env,
     mesh_from_env,
     plan_mesh,

@@ -20,7 +20,7 @@ Fault kinds and where they bite:
                        linearly over the window (skewed shard sizes after a
                        bad re-split: the rank falls progressively behind)
 ``step_transient``     the step raises a transient ``RuntimeError`` at the
-                       reducer boundary (a preemption blip / tunnel hiccup)
+                       reducer boundary (a preemption blip / runtime hiccup)
 ``step_nan``           the step reports a NaN loss (gradient burst) without
                        advancing state
 ``ckpt_torn``          the checkpoint just written loses its commit marker

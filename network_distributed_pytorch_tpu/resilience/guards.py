@@ -4,7 +4,7 @@ chaos plan injects.
 Two wrappers, both opt-in from ``resilient_train_loop``:
 
 - :class:`GuardedStep` — retries a step whose execution raised a transient
-  ``RuntimeError`` (preemption blip, tunnel hiccup, injected
+  ``RuntimeError`` (preemption blip, runtime hiccup, injected
   ``ChaosTransientError``) and rejects a step whose loss came back
   non-finite (NaN gradient burst) WITHOUT advancing state, re-running it
   instead. Requires the wrapped step to have been built with

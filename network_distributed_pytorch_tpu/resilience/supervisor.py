@@ -57,6 +57,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
+from ..hostenv import one_chip_env  # jax-free, like this module
+
 # environment contract with workers (read via :func:`incarnation_from_env`)
 ENV_INCARNATION = "RESILIENCE_INCARNATION"
 ENV_RANK = "RESILIENCE_RANK"
@@ -68,7 +70,9 @@ ENV_MESH = "RESILIENCE_MESH"
 # worker rank r of a scheduled job sits on fleet chip device_ranks[r].
 # Exported only when the supervisor was constructed with a device grant —
 # an exclusive-ownership launch (the pre-fleet default) omits it and
-# workers assume chips 0..W-1.
+# worker rank r sits on chip r. Informational for the worker: on a TPU
+# host the PARENT applies the lease, by starting the worker with
+# hostenv.one_chip_env (Supervisor.pin_chips).
 ENV_DEVICE_RANKS = "RESILIENCE_DEVICE_RANKS"
 
 
@@ -94,21 +98,6 @@ def mesh_from_env() -> Optional[Dict[str, int]]:
     if not isinstance(axes, dict):
         return None
     return {str(k): int(v) for k, v in axes.items()}
-
-
-def device_ranks_from_env() -> Optional[List[int]]:
-    """The fleet chip ranks this worker's job was granted, or None for an
-    exclusive-ownership launch (workers then assume chips 0..W-1)."""
-    raw = os.environ.get(ENV_DEVICE_RANKS)
-    if not raw:
-        return None
-    try:
-        ranks = json.loads(raw)
-    except ValueError:
-        return None
-    if not isinstance(ranks, list):
-        return None
-    return [int(r) for r in ranks]
 
 
 def _divisors(n: int) -> List[int]:
@@ -244,6 +233,7 @@ class Supervisor:
         run_dir: Optional[str] = None,
         run_id: Optional[str] = None,
         device_ranks: Optional[List[int]] = None,
+        pin_chips: bool = False,
     ):
         self.argv_for_rank = argv_for_rank
         self.world_size = world_size
@@ -263,6 +253,10 @@ class Supervisor:
                 f" world_size={world_size}"
             )
         self.device_ranks = list(device_ranks) if device_ranks else None
+        # one process per chip: export the env that makes worker rank r open
+        # only its own TPU chip (device_ranks[r], or chip r under exclusive
+        # ownership). Set by launchers that found chips on this host.
+        self.pin_chips = pin_chips
         # fleet preemption: request_preempt() arms this from the scheduler
         # thread; the run loop observes it and drains gracefully. Plain
         # attribute assignment is the synchronization (GIL-atomic), and the
@@ -333,6 +327,10 @@ class Supervisor:
             env[ENV_MESH] = json.dumps(self.mesh)
         if self.device_ranks is not None:
             env[ENV_DEVICE_RANKS] = json.dumps(self.device_ranks)
+        if self.pin_chips:
+            env.update(one_chip_env(
+                self.device_ranks[rank] if self.device_ranks else rank
+            ))
         if self._manifest is not None:
             from ..observe import runlog
 

@@ -13,7 +13,7 @@ immediately). This module adds the detection machinery the reference lacks
   the callback's job is to REPORT (structured banner, flight-recorder dump)
   and decide (e.g. ``os._exit`` for a supervisor restart).
 - :func:`retry_transient` — bounded retry for transient runtime errors
-  (preemption blips, tunnel hiccups) with exponential backoff.
+  (preemption blips, runtime hiccups) with exponential backoff.
 - :class:`HeartbeatMonitor` — file-based liveness over a shared filesystem,
   the same substrate as the reference's ``file://`` rendezvous
   (``ddp_guide/ddp_init.py:41``): each process beats its own file; any

@@ -45,6 +45,9 @@ class MetricsLogger:
     log_every: int = 0  # 0 = silent per-step
     records: List[StepRecord] = field(default_factory=list)
     telemetry: Optional[Telemetry] = None
+    # device ids holding shards of the first batch the loop fed (set by
+    # experiments.common.train_loop; reported under the summary's placement)
+    batch_devices: Optional[List[int]] = None
     _epoch_losses: List[float] = field(default_factory=list)
     _step: int = 0
     _bits: int = 0
@@ -113,6 +116,7 @@ class MetricsLogger:
             "first_loss": self.records[0].loss if self.records else None,
             "final_loss": self.records[-1].loss if self.records else None,
             "mean_step_time_s": sum(times) / len(times) if times else None,
+            "bits_per_step": self.bits_per_step,
             "bits_communicated": self._bits,
             "bytes_communicated": self._bits // 8,
         }

@@ -68,17 +68,10 @@ def _round_number(path: str) -> Optional[int]:
 
 
 def _platform_of(doc: Dict) -> Optional[str]:
-    """Device provenance of a round's summary, mirroring gate.py's
-    resolution order plus the bench attestation block."""
-    p = gate._platform_of(doc)
-    if p is not None:
-        return p
-    ev = doc.get("tpu_evidence")
-    if isinstance(ev, dict):
-        dev = ev.get("device")
-        if isinstance(dev, str) and dev.strip():
-            return dev.strip().lower()
-    return None
+    """Device provenance of a round's summary: gate.py's resolution order
+    and nothing else — a record that does not name the device it ran on has
+    none (an older chip record stapled to the line is not provenance)."""
+    return gate._platform_of(doc)
 
 
 def load_round(path: str) -> Optional[Dict]:

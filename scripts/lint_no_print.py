@@ -39,7 +39,6 @@ ALLOWED = {os.path.join("observe", "sinks.py")}
 SCRIPT_ALLOWED = {
     "accuracy_study.py",
     "bandwidth_artifact.py",
-    "tpu_evidence.py",
 }
 
 # the sanctioned wall-clock call sites inside observe/ (everything else

@@ -88,8 +88,8 @@ def test_fence_preserves_values():
 
 
 def test_fence_is_transparent_to_grad():
-    # the _jax_compat AD rules: chunked FSDP gathers differentiate through
-    # the barrier, so grad(f ∘ fence) must equal grad(f)
+    # chunked FSDP gathers differentiate through the barrier, so
+    # grad(f ∘ fence) must equal grad(f)
     def f(x):
         return jnp.sum(fence(x) ** 2)
 

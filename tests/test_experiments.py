@@ -69,6 +69,12 @@ def test_powersgd_imdb(devices):
         max_steps_per_epoch=2,
     )
     assert out["steps"] == 2 and np.isfinite(out["final_loss"])
+    # the fields themselves are pinned in the fast tier
+    # (test_trainer.py::test_summary_names_what_ran); here: this experiment
+    # hands its reducer, model and final state to ``summarize``
+    assert (out["attn_impl"], out["orthogonalize_impl"]) == ("einsum", "xla")
+    assert out["bytes_communicated"] * 8 == 2 * out["bits_per_step"]
+    assert out["placement"]["memories"] == list(range(8))
 
 
 def test_imdb_baseline_single_node(devices):

@@ -1,8 +1,7 @@
 """Memory observatory: footprint shim, sampler, headroom, OOM, gate.
 
 Unit coverage for the device-memory plane (``observe/memory.py`` and its
-shims): the ``compiled_memory`` normalization across the result shapes
-different jaxlibs return (attrs / dict / list / raising), the
+readers): ``compiled_memory``'s read of ``memory_analysis()``, the
 MemorySampler's one-way CPU no-op (probe once, disable forever, zero log
 lines), the EWMA headroom detector's warn/critical ladder and its
 silent-drop of limitless samples, the live plane's memory gauges, the
@@ -21,7 +20,6 @@ import sys
 
 import pytest
 
-from network_distributed_pytorch_tpu._jax_compat import compiled_memory
 from network_distributed_pytorch_tpu.observe import MemoryEvent, Telemetry
 from network_distributed_pytorch_tpu.observe.health import (
     DetectorConfig,
@@ -32,6 +30,7 @@ from network_distributed_pytorch_tpu.observe.live import (
     ingest_record,
 )
 from network_distributed_pytorch_tpu.observe.memory import (
+    compiled_memory,
     MemorySampler,
     build_oom_report,
     device_memory_stats,
@@ -82,7 +81,7 @@ def _telemetry():
 
 
 # ---------------------------------------------------------------------------
-# compiled_memory: one shim over every result shape jaxlib has shipped
+# compiled_memory: the memory_analysis() read
 # ---------------------------------------------------------------------------
 
 
@@ -96,8 +95,6 @@ class _AttrsAnalysis:
 def _compiled(result):
     class _Compiled:
         def memory_analysis(self):
-            if isinstance(result, Exception):
-                raise result
             return result
 
     return _Compiled()
@@ -113,26 +110,9 @@ def test_compiled_memory_attrs_shape():
     }
 
 
-def test_compiled_memory_dict_shapes_with_and_without_suffix():
-    long = compiled_memory(
-        _compiled({"argument_size_in_bytes": 7, "temp_size_in_bytes": 3})
-    )
-    short = compiled_memory(_compiled({"argument_bytes": 7, "temp_bytes": 3}))
-    assert long == short == {"argument_bytes": 7.0, "temp_bytes": 3.0}
-
-
-def test_compiled_memory_list_shape_takes_first_element():
-    out = compiled_memory(_compiled([_AttrsAnalysis(), _AttrsAnalysis()]))
-    assert out["argument_bytes"] == 100.0
-    assert compiled_memory(_compiled([])) is None
-
-
-def test_compiled_memory_raising_backend_is_none_not_crash():
-    assert compiled_memory(_compiled(RuntimeError("no stats here"))) is None
-    assert compiled_memory(object()) is None  # no memory_analysis at all
-    # numeric garbage / unknown keys yield None, not a partial dict
-    assert compiled_memory(_compiled({"argument_bytes": "big"})) is None
-    assert compiled_memory(_compiled({"unrelated": 1.0})) is None
+def test_compiled_memory_without_analysis_is_none():
+    assert compiled_memory(None) is None  # no executable
+    assert compiled_memory(_compiled(None)) is None  # backend reports none
 
 
 def test_footprint_fields_sum_to_peak_and_splat_safely():
@@ -142,9 +122,9 @@ def test_footprint_fields_sum_to_peak_and_splat_safely():
         "argument_bytes", "output_bytes", "temp_bytes",
         "generated_code_bytes", "peak_hbm_bytes",
     }
-    # degraded backends give {} (never None) so callers can always **
+    # no analysis gives {} (never None) so callers can always **
     assert memory_footprint_fields(None) == {}
-    assert memory_footprint_fields(_compiled(RuntimeError("x"))) == {}
+    assert memory_footprint_fields(_compiled(None)) == {}
 
 
 def test_real_compiled_step_footprint_matches_shim():

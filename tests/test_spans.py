@@ -2,7 +2,7 @@
 
 Units: the nested host span API (``observe.spans``), the per-phase
 MFU/roofline accounting (``observe.mfu``), the ``cost_analysis`` compat
-shim (``_jax_compat.compiled_cost``), and report.py's span aggregation +
+read (``observe.ledger.compiled_cost``), and report.py's span aggregation +
 Chrome-trace export — all jax-free.
 
 End-to-end: ``scripts/run_probe.py`` spawns the REAL 2-rank supervised toy
@@ -22,7 +22,7 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-from network_distributed_pytorch_tpu._jax_compat import compiled_cost  # noqa: E402
+from network_distributed_pytorch_tpu.observe.ledger import compiled_cost  # noqa: E402
 from network_distributed_pytorch_tpu.observe import mfu, spans  # noqa: E402
 from network_distributed_pytorch_tpu.observe.sinks import MemorySink  # noqa: E402
 from network_distributed_pytorch_tpu.observe.telemetry import Telemetry  # noqa: E402
@@ -143,7 +143,10 @@ def test_peak_flops_table_lookup():
     assert mfu.peak_flops("TPU v5p") == 459e12
     # longest-match: "v5 lite" must not resolve via the bare "v5" entry
     assert mfu.peak_flops("tpu v5 litepod-8") == 197e12
-    assert mfu.peak_flops("TPU v99") == 0.0  # unknown kind
+    with pytest.raises(ValueError, match="v99"):  # unknown TPU kind: an
+        mfu.peak_flops("TPU v99")  # error, never a silent 0.0 peak
+    with pytest.raises(ValueError, match="v99"):
+        mfu.hbm_bandwidth("TPU v99")
     assert mfu.peak_flops("cpu", platform="cpu") == 0.0  # non-TPU platform
     assert mfu.hbm_bandwidth("TPU v4") == 1228e9
 
@@ -194,32 +197,25 @@ def test_mfu_from_compile_records_joins_and_dedupes():
 
 
 # ---------------------------------------------------------------------------
-# _jax_compat.compiled_cost: the cost_analysis shim
+# observe.ledger.compiled_cost: the cost_analysis read
 
 
 class _FakeCompiled:
-    def __init__(self, result=None, raises=False):
+    def __init__(self, result=None):
         self._result = result
-        self._raises = raises
 
     def cost_analysis(self):
-        if self._raises:
-            raise NotImplementedError("unsupported backend")
         return self._result
 
 
-def test_compiled_cost_normalizes_both_jaxlib_shapes():
+def test_compiled_cost_keeps_numeric_metrics():
     cost = {"flops": 123.0, "bytes accessed": 456.0, "utilization": "n/a"}
-    # jaxlib <= 0.4.x returns [dict]; newer returns the dict directly
-    assert compiled_cost(_FakeCompiled([dict(cost)])) == {
+    assert compiled_cost(_FakeCompiled(cost)) == {
         "flops": 123.0, "bytes accessed": 456.0
     }
-    assert compiled_cost(_FakeCompiled(dict(cost)))["flops"] == 123.0
 
 
-def test_compiled_cost_graceful_none():
-    assert compiled_cost(_FakeCompiled(raises=True)) is None
-    assert compiled_cost(_FakeCompiled([])) is None
+def test_compiled_cost_without_flops_is_none():
     assert compiled_cost(_FakeCompiled(None)) is None
     # a cost dict with no flops is useless for MFU: normalized to None
     assert compiled_cost(_FakeCompiled({"bytes accessed": 9.0})) is None

@@ -232,3 +232,69 @@ def test_collapse_per_worker_is_host_side(devices):
     assert isinstance(first["bn"], np.ndarray)
     np.testing.assert_allclose(mean["bn"], stats.mean(axis=0))
     np.testing.assert_allclose(first["bn"], stats[0])
+
+
+@pytest.mark.parametrize("n_devices", [8, 1])
+def test_carry_goes_in_as_it_comes_out(devices, n_devices):
+    """``init_state`` places the carry with the shardings the step hands
+    back, so the second call is the first call's program: one jit entry
+    after three calls. The one-device mesh is the one-chip case — left to
+    itself jit returns ``P('data')`` over a one-device axis as ``P()`` and
+    the second call dispatched through the slow path again."""
+    from network_distributed_pytorch_tpu.data import global_batch_from_local
+
+    mesh = make_mesh(devices=devices[:n_devices])
+    params, loss_fn = _cnn_setup()
+    step = make_train_step(
+        loss_fn, PowerSGDReducer(random_seed=0, compression_rank=2), params,
+        learning_rate=0.05, algorithm="ef_momentum", mesh=mesh,
+    )
+    state = step.init_state(params)
+    placed = [leaf.sharding for leaf in jax.tree_util.tree_leaves(state)]
+    assert {d for s in placed for d in s.device_set} == set(mesh.devices.flat)
+    for i in range(3):
+        x, y = _synthetic_batch(jax.random.PRNGKey(i), n=8 * n_devices)
+        state, _ = step(
+            state, global_batch_from_local((np.asarray(x), np.asarray(y)), mesh)
+        )
+    assert [leaf.sharding for leaf in jax.tree_util.tree_leaves(state)] == placed
+    assert step.fn._cache_size() == 1
+
+
+def test_summary_names_what_ran(devices):
+    """Every run summary says what the run actually had under it, so a
+    record taken without a chip cannot be read as a chip run: the device as
+    jax reports it, the kernels ``"auto"`` resolved to against it, who fed
+    the data, where the carry and the batches sat, and the wire bytes."""
+    from network_distributed_pytorch_tpu.experiments.common import (
+        summarize,
+        train_loop,
+    )
+
+    params, loss_fn = _cnn_setup()
+    reducer = PowerSGDReducer(random_seed=0, compression_rank=2)
+    step = make_train_step(
+        loss_fn, reducer, params, learning_rate=0.05,
+        algorithm="ef_momentum", mesh=make_mesh(),
+    )
+
+    def batches(epoch):
+        for i in range(2):
+            x, y = _synthetic_batch(jax.random.PRNGKey(i))
+            yield np.asarray(x), np.asarray(y)
+
+    state, logger = train_loop(step, step.init_state(params), batches, epochs=1)
+    out = summarize("t", logger, reducer=reducer, attn_impl="auto", state=state)
+    assert (out["platform"], out["device_kind"], out["n_devices"]) == (
+        "cpu", devices[0].device_kind, 8
+    )
+    assert out["pallas_interpret"] is True
+    assert (out["attn_impl"], out["orthogonalize_impl"]) == ("einsum", "xla")
+    assert out["host_data_tier"].startswith(("native", "numpy ("))
+    assert out["device_memory"] == []  # the CPU allocator reports nothing
+    assert out["steps"] == 2
+    assert out["bytes_communicated"] * 8 == 2 * out["bits_per_step"] > 0
+    everywhere = list(range(8))
+    assert out["placement"] == {
+        "params": everywhere, "memories": everywhere, "batch": everywhere
+    }
