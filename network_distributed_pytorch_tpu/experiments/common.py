@@ -30,6 +30,7 @@ def accumulated_batches(
     import jax.numpy as jnp
 
     from ..data import iterate_batches
+    from ..observe.spans import span
 
     k = config.accum_steps
     if k < 1:
@@ -69,7 +70,11 @@ def accumulated_batches(
                 batch = tuple(
                     a.reshape((k, a.shape[0] // k) + a.shape[1:]) for a in batch
                 )
-            batch = tuple(jnp.asarray(a) for a in batch)
+            # the copy to device 0, on the consumer's thread: like the
+            # loader's assemble it runs inside the loop's next(), so it
+            # nests under data_load
+            with span("data_load/to_device"):
+                batch = tuple(jnp.asarray(a) for a in batch)
             yield dict(zip(keys, batch)) if keys else batch
 
     return gen

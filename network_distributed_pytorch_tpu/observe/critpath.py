@@ -2,12 +2,13 @@
 
 The span plane is strictly per-rank; the wire ledger says every rank
 synchronizes at each step's collectives. Stitching the two gives a
-per-step causal graph: each rank runs its local chain of leaf spans
-(data_load -> compute -> collective-wait -> ...), and the step's
+per-step causal graph: each rank runs its local chain of spans, each
+charged its self time (data_load -> compute -> collective-wait -> ...),
+and the step's
 collective is a synchronization edge joining all participants — no rank's
 step completes before the slowest rank reaches the join. The longest
 weighted path through that graph therefore runs entirely along ONE rank's
-timeline (the rank with the largest summed leaf-span time), which makes
+timeline (the rank with the largest summed span self time), which makes
 the critical path computable in closed form per step, and the interesting
 output is the BLAME: which rank gated the step, which of its phases
 carried the gap, and — when the gating phase is collective-wait — which
@@ -40,7 +41,7 @@ PHASES = (PHASE_DATA, PHASE_COMPUTE, PHASE_COMM)
 
 
 def phase_of(span_name: str) -> str:
-    """Map a leaf span name onto the three-way phase taxonomy: anything
+    """Map a span name onto the three-way phase taxonomy: anything
     carrying ``data_load`` is the input pipeline, anything carrying
     ``comm`` is exposed collective wait, and the rest (compute,
     checkpoint, eval) charges the compute lane."""
@@ -52,12 +53,15 @@ def phase_of(span_name: str) -> str:
     return PHASE_COMPUTE
 
 
-def _leaf_spans_by_step_rank(
+def _self_spans_by_step_rank(
     events: List[Dict],
 ) -> Dict[int, Dict[int, List[Dict]]]:
-    """{step: {rank: [leaf span records]}}. Container spans (any span
-    another span names as parent within the same (step, rank) group) are
-    dropped so nested trees don't double-charge their children."""
+    """{step: {rank: [span records]}}, each charged its SELF time:
+    ``dur_s`` less the spans of the same (step, rank) group that name it
+    as parent. A leaf keeps its whole duration; a container keeps what no
+    child covers (the loop's ``data_load`` less its assemble / to_device /
+    stage children is the wait for the producer), so nested trees neither
+    double-charge their children nor drop the time between them."""
     grouped: Dict[Tuple[int, int], List[Dict]] = {}
     for e in events:
         if e.get("event") != "span":
@@ -70,11 +74,15 @@ def _leaf_spans_by_step_rank(
         grouped.setdefault((int(step), int(rank)), []).append(e)
     out: Dict[int, Dict[int, List[Dict]]] = {}
     for (step, rank), spans in grouped.items():
-        parents = {
-            s.get("parent_id") for s in spans if s.get("parent_id") is not None
-        }
-        leaves = [s for s in spans if s.get("span_id") not in parents]
-        out.setdefault(step, {})[rank] = leaves or spans
+        covered: Dict = {}
+        for s in spans:
+            parent = s.get("parent_id")
+            if parent is not None:
+                covered[parent] = covered.get(parent, 0.0) + float(s["dur_s"])
+        out.setdefault(step, {})[rank] = [
+            dict(s, dur_s=max(float(s["dur_s"]) - covered.get(s.get("span_id"), 0.0), 0.0))
+            for s in spans
+        ]
     return out
 
 
@@ -131,7 +139,7 @@ def analyze(events: List[Dict], world_size: int) -> Optional[Dict]:
     shares by rank and by phase, the top gating edge, and the gate's
     scalar ``comm_share`` — the share of summed critical-path seconds the
     gating ranks spent in collective-wait (lower is better)."""
-    by_step = _leaf_spans_by_step_rank(events)
+    by_step = _self_spans_by_step_rank(events)
     verdicts: List[CritPathEvent] = []
     for step in sorted(by_step):
         per_rank = {
@@ -183,13 +191,13 @@ def analyze(events: List[Dict], world_size: int) -> Optional[Dict]:
 def comm_waits_by_edge(
     events: List[Dict], world_size: int
 ) -> Dict[Tuple[int, int], List[float]]:
-    """Per-ring-edge exposed-wait samples: rank r's collective-wait leaf
+    """Per-ring-edge exposed-wait samples: rank r's collective-wait
     spans charged to its outgoing edge. The live plane's per-edge detector
     and the fabric matrix share this charging rule."""
     bw = _load_utils_module("bandwidth")
     edges = {src: (src, dst) for src, dst in bw.ring_neighbors(world_size)}
     out: Dict[Tuple[int, int], List[float]] = {}
-    for step_group in _leaf_spans_by_step_rank(events).values():
+    for step_group in _self_spans_by_step_rank(events).values():
         for rank, spans in step_group.items():
             if rank not in edges:
                 continue

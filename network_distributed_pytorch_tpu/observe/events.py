@@ -332,7 +332,10 @@ class SpanEvent(Event):
     is the nesting level, which is what lets ``scripts/report.py
     --trace-out`` render the spans as a nested Perfetto flamegraph without
     re-deriving containment. Silent on stdout — a span per step would drown
-    the banners."""
+    the banners.
+
+    ``step`` is the span's own or, where it was opened without one, its
+    parent's."""
 
     KIND: ClassVar[str] = "span"
 
@@ -343,6 +346,15 @@ class SpanEvent(Event):
     dur_s: float
     step: Optional[int] = None
     rank: Optional[int] = None
+
+    def record(self) -> Dict:
+        # written out: the loop emits seven of these per step, and the
+        # base class's walk over dataclasses.fields() cost 2 us each
+        return {
+            "event": "span", "name": self.name, "span_id": self.span_id,
+            "parent_id": self.parent_id, "depth": self.depth,
+            "dur_s": self.dur_s, "step": self.step, "rank": self.rank,
+        }
 
 
 @dataclass

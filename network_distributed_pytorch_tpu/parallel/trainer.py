@@ -321,27 +321,30 @@ def make_step_fn(
                 reducer_state, delta, memories, _ = reducer.reduce(
                     state.reducer_state, send, axis_name
                 )
-            delta = clip_by_global_norm(delta)
-            # (Algo 2 lines 12-13)
-            params, momenta = ef_momentum_update(
-                state.params, state.momenta, delta, learning_rate, momentum
-            )
-        elif algorithm == "optax":
-            reducer_state, delta, memories, _ = reducer.reduce(
-                state.reducer_state, grads, axis_name
-            )
-            delta = clip_by_global_norm(delta)
-            import optax
-
-            updates, momenta = optimizer.update(delta, state.momenta, state.params)
-            params = optax.apply_updates(state.params, updates)
         else:
-            # exact-DDP path: allreduce-mean the raw gradients
+            # exact-DDP and optax paths: allreduce-mean the raw gradients
             reducer_state, delta, memories, _ = reducer.reduce(
                 state.reducer_state, grads, axis_name
             )
+
+        # the optimizer, every branch of it under one scope: the clip and the
+        # parameter/momentum update (the third of the per-layer split of a
+        # training step: forward/backward, exchange, optimizer)
+        with jax.named_scope("step.update"):
             delta = clip_by_global_norm(delta)
-            if algorithm == "sgd":
+            if algorithm == "ef_momentum":
+                # (Algo 2 lines 12-13)
+                params, momenta = ef_momentum_update(
+                    state.params, state.momenta, delta, learning_rate, momentum
+                )
+            elif algorithm == "optax":
+                import optax
+
+                updates, momenta = optimizer.update(
+                    delta, state.momenta, state.params
+                )
+                params = optax.apply_updates(state.params, updates)
+            elif algorithm == "sgd":
                 params, momenta = sgd_momentum_update(
                     state.params, state.momenta, delta, learning_rate, momentum
                 )

@@ -150,6 +150,59 @@ def test_device_prefetch_preserves_trajectory():
     assert outs[0][1] == outs[1][1]
 
 
+def test_train_loop_data_load_tree_shares_its_step():
+    """Everything the loop does to fetch a batch is a span under that
+    step's ``data_load`` and carries its step: the numpy gather, the copy to
+    device 0 that ``accumulated_batches`` makes, and the staging."""
+    import jax.numpy as jnp
+
+    from network_distributed_pytorch_tpu.experiments.common import (
+        accumulated_batches,
+        train_loop,
+    )
+    from network_distributed_pytorch_tpu.observe.sinks import MemorySink
+    from network_distributed_pytorch_tpu.observe.telemetry import Telemetry
+    from network_distributed_pytorch_tpu.parallel import ExactReducer, make_mesh
+    from network_distributed_pytorch_tpu.parallel.trainer import (
+        make_train_step,
+        stateless_loss,
+    )
+    from network_distributed_pytorch_tpu.utils.config import ExperimentConfig
+
+    rng = np.random.RandomState(0)
+    x = rng.randn(64, 8).astype(np.float32)
+    y = (x @ rng.randn(8, 1).astype(np.float32))[:, 0]
+    params = {"w": jnp.zeros((8,))}
+    loss = stateless_loss(
+        lambda p, b: ((b["x"] @ p["w"] - b["y"]) ** 2).mean()
+    )
+    step = make_train_step(
+        loss, ExactReducer(), params, 0.05, mesh=make_mesh(),
+        algorithm="sgd_plain", donate_state=False,
+    )
+    config = ExperimentConfig(global_batch_size=16, seed=7)
+    # dict batches keep the numpy loader: its assemble span is in the tree
+    batches = accumulated_batches((x, y), config, keys=("x", "y"))
+    sink = MemorySink()
+    train_loop(
+        step, step.init_state(params), batches, epochs=1, log_every=0,
+        prefetch=2, telemetry=Telemetry([sink]),
+    )
+    spans = sink.of_kind("span")
+    loads = {r["span_id"]: r for r in spans if r["name"] == "data_load"}
+    assert sorted(r["step"] for r in loads.values()) == [0, 1, 2, 3, 4]
+    children = [r for r in spans if r["name"].startswith("data_load/")]
+    assert {r["name"] for r in children} == {
+        "data_load/assemble", "data_load/to_device", "data_load/stage"
+    }
+    to_device = [r for r in children if r["name"] == "data_load/to_device"]
+    assert len(to_device) == 4  # one per batch of the epoch
+    for r in children:
+        assert r["step"] == loads[r["parent_id"]]["step"]
+    # the first fetch fills the prefetch ring: three batches under step 0
+    assert sum(1 for r in to_device if r["step"] == 0) == 3
+
+
 def test_cifar10_bin_format_matches_pickle(tmp_path):
     """The SAME dataset written as cifar-10-batches-bin (native decoder) and
     cifar-10-batches-py (pickle) loads to identical arrays."""

@@ -134,6 +134,57 @@ def test_span_emits_even_when_body_raises():
     assert spans.current_span_id() is None  # the stack unwound
 
 
+def test_span_event_record_is_the_base_class_record():
+    """``SpanEvent.record()`` is written out by hand for speed: it must stay
+    what ``Event.record()`` would build, field for field and in order, or a
+    field added to the dataclass goes missing from the run log."""
+    from network_distributed_pytorch_tpu.observe.events import Event, SpanEvent
+
+    event = SpanEvent("step/compute", 7, 3, 1, 0.25, step=4, rank=2)
+    assert event.record() == Event.record(event)
+    assert list(event.record()) == list(Event.record(event))
+    telemetry, sink = _mem_telemetry()
+    with spans.span("outer", telemetry=telemetry, step=4):
+        pass
+    assert set(sink.of_kind("span")[0]) - {"ts", "ts_mono"} == set(event.record())
+
+
+@pytest.mark.parametrize(
+    "child_step,expected",
+    [(None, "parent's"), (99, 99)],
+    ids=["child_without_step_takes_its_parents", "child_with_step_keeps_its_own"],
+)
+def test_span_step_inheritance_is_per_thread(child_step, expected):
+    telemetry, sink = _mem_telemetry()
+    barrier = threading.Barrier(2, timeout=5.0)
+
+    def loop(step):
+        with spans.span(f"load{step}", telemetry=telemetry, step=step):
+            barrier.wait()  # both parents are open at once
+            with spans.span(f"load{step}/child", telemetry=telemetry, step=child_step):
+                with spans.span(f"load{step}/child/leaf", telemetry=telemetry):
+                    pass
+            barrier.wait()
+
+    threads = [threading.Thread(target=loop, args=(step,)) for step in (3, 4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(5.0)
+        assert not t.is_alive()
+    by_name = {r["name"]: r for r in sink.of_kind("span")}
+    assert len(by_name) == 6
+    for step in (3, 4):
+        want = step if expected == "parent's" else expected
+        assert by_name[f"load{step}"]["step"] == step
+        assert by_name[f"load{step}/child"]["step"] == want
+        assert by_name[f"load{step}/child/leaf"]["step"] == want  # through two levels
+    # outside any span there is nothing to inherit
+    with spans.span("alone", telemetry=telemetry):
+        pass
+    assert sink.of_kind("span")[-1]["step"] is None
+
+
 # ---------------------------------------------------------------------------
 # observe.mfu: peak tables, roofline classification, event construction
 
@@ -279,6 +330,75 @@ def test_chrome_trace_backdates_spans_and_names_processes():
     }
     assert names == {-1: "supervisor", 0: "rank 0", 1: "rank 1"}
     assert report.chrome_trace([])["traceEvents"] == []
+
+
+def _loop_records(rank, stepped_children):
+    """One step of the loop's span tree as a shard's records: ``data_load``
+    (4 ms, 1 ms of it waiting) over assemble and stage, then ``step`` over
+    compute and comm. ``stepped_children=False`` is a log written before
+    PR 24: no step on the spans below ``data_load``."""
+    base, t = 10 * (rank + 1), 100.0
+
+    def rec(name, span_id, parent, depth, close, dur, step):
+        out = {
+            "event": "span", "name": name, "span_id": base + span_id,
+            "parent_id": None if parent is None else base + parent,
+            "depth": depth, "dur_s": dur, "step": step, "rank": rank,
+            "ts": 1000.0 + close, "ts_mono": t + close,
+        }
+        return out
+
+    child_step = 0 if stepped_children else None
+    return [
+        {"event": "marker", "kind": "run_start", "incarnation": 0,
+         "ts": 1000.0, "ts_mono": t},
+        rec("data_load/assemble", 2, 1, 1, 0.002, 0.002, child_step),
+        rec("data_load/stage", 3, 1, 1, 0.003, 0.001, child_step),
+        rec("data_load", 1, None, 0, 0.004, 0.004, 0),
+        rec("step/compute", 5, 4, 1, 0.010, 0.006, 0),
+        rec("step/comm", 6, 4, 1, 0.012, 0.002, 0),
+        rec("step", 4, None, 0, 0.012, 0.008, 0),
+    ]
+
+
+@pytest.mark.parametrize("stepped_children", [False, True], ids=["old_run_log", "new_run_log"])
+def test_span_consumers_read_logs_with_and_without_stepped_children(tmp_path, stepped_children):
+    """A run log from before child spans took their parent's step still
+    loads, and every reader of span records (the merge, the report's span
+    summary and Chrome trace, the critical path) gives the same answer on
+    both: a container and its stepped children count once."""
+    from network_distributed_pytorch_tpu.observe import critpath, runlog
+
+    report = _load_script("report")
+    m = runlog.new_manifest("spans", world_size=2)
+    for rank in (0, 1):
+        m.record_spawn(rank=rank, incarnation=0, world_size=2, spawned_unix=1000.0)
+        with open(runlog.shard_path(str(tmp_path), rank), "w") as f:
+            for record in _loop_records(rank, stepped_children):
+                f.write(json.dumps(record) + "\n")
+    m.save(str(tmp_path))
+    merged = runlog.merge_run(str(tmp_path))
+    recs = [e for e in merged.events if e.get("event") == "span"]
+    assert len(recs) == 12 and all("t_run" in e for e in recs)
+
+    summary = report.span_summary(merged.events)
+    assert summary["by_name"]["data_load"]["total_s"] == pytest.approx(0.008)
+    assert summary["by_name"]["data_load/stage"]["count"] == 2
+    slices = [e for e in report.chrome_trace(merged.events)["traceEvents"] if e.get("ph") == "X"]
+    assert len(slices) == 12
+    assert {e["args"].get("step") for e in slices if e["name"] == "data_load/stage"} == {
+        0 if stepped_children else None
+    }
+
+    crit = critpath.analyze(merged.events, world_size=2)
+    assert crit["n_steps"] == 1
+    ev = crit["events"][0]
+    # the whole data_load, once: as a leaf (old) or as its children plus
+    # the millisecond none of them covers (new)
+    assert ev["data_s"] == pytest.approx(0.004)
+    assert ev["compute_s"] == pytest.approx(0.006)
+    assert ev["comm_s"] == pytest.approx(0.002)
+    assert ev["path_s"] == pytest.approx(0.012)
 
 
 # ---------------------------------------------------------------------------
