@@ -14,10 +14,20 @@ block it holds. Causal mode prunes K blocks strictly above the diagonal via
 the loop bound (not just masking).
 
 Training: the kernel is wrapped in a ``custom_vjp``. The forward also emits
-the per-row log-sum-exp; the backward recomputes attention block-by-block
-(a ``lax.scan`` over K blocks — the standard flash backward recurrence
-``dS = P ∘ (dO·Vᵀ − D)``), so the score matrix is never materialized on the
-backward pass either.
+the per-row log-sum-exp; the backward is a second Pallas kernel
+(``_flash_bwd_kernel``, named ``flash_attention_bwd`` in the compiled
+program) that recomputes P = exp(S − lse) tile by tile and runs the standard
+flash recurrence ``dS = P ∘ (dO·Vᵀ − D)``, dV = Pᵀ·dO, dQ = dS·K, dK = dSᵀ·Q
+with S, P, dP and dS never leaving VMEM: one grid step per (batch, head)
+holds q, k, v and dO whole, loops K blocks outside and Q blocks inside
+(causal: Q blocks before the K block are skipped by the loop bound), and
+emits dq, dk, dv and the additive mask's cotangent per head (summed over
+heads outside). Only D = rowsum(dO ∘ O) is an XLA reduction. Every shape
+takes this path, on TPU and in interpret mode alike; the tile edge is the
+largest of 512/256/128 that divides T (else the forward's blocks, which is
+T itself below 128). Its reach is VMEM: seven (T, D) operands held whole,
+which it asks Mosaic for beyond the 16 MiB default — T = 16384 at D = 128 in
+bf16 compiles, as far as the forward's own whole-K/V residency goes.
 
 Correctness is pinned against naive einsum attention (padding masks, causal,
 both, and grads) in ``tests/test_flash_attention.py``; on CPU the kernel
@@ -32,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU lowering)
+from jax.experimental.pallas import tpu as pltpu
 
 from ._backend import pallas_interpret
 
@@ -133,64 +143,185 @@ def _flash_kernel(
     lse_ref[0] = lse.reshape(1, block_q)
 
 
-def _causal_bias(t_q: int, block_k: int, k_start, dtype=jnp.float32):
-    q_pos = lax.broadcasted_iota(jnp.int32, (t_q, block_k), 0)
-    k_pos = k_start + lax.broadcasted_iota(jnp.int32, (t_q, block_k), 1)
-    return jnp.where(q_pos >= k_pos, 0.0, _NEG_INF).astype(dtype)
+def _flash_bwd_kernel(
+    block_q: int,
+    block_k: int,
+    t: int,
+    causal: bool,
+    scale: float,
+    q_ref,
+    k_ref,
+    v_ref,
+    do_ref,
+    lse_ref,
+    delta_ref,
+    mask_ref,
+    dq_ref,
+    dk_ref,
+    dv_ref,
+    dmask_ref,
+    dqt_acc,
+):
+    """The whole backward of one (batch, head): q, k, v, do sit in VMEM, a
+    loop over K blocks holds dK/dV of its block while the loop inside it
+    walks the Q blocks — so s, p, dp and ds live and die as
+    (block_k, block_q) tiles and each of the five products runs once.
+
+    The tiles are TRANSPOSED, keys on sublanes and queries on lanes: lse and
+    D then broadcast as the lane-dense rows they arrive as, and no product
+    needs a score-sized operand turned — dQ gathers as dQᵀ = Kᵀ·dS in an
+    fp32 VMEM scratch and is turned once, at (block_q, d), on the way out.
+    Products take their operands in the input dtype and accumulate in fp32;
+    exp, the row terms and every accumulator are fp32."""
+    n_q, n_k = t // block_q, t // block_k
+    nt = (((1,), (1,)), ((), ()))  # A·Bᵀ
+    tn = (((0,), (0,)), ((), ()))  # Aᵀ·B
+    tile = (block_k, block_q)
+    dqt_acc[...] = jnp.zeros_like(dqt_acc)
+
+    def k_block(j, _):
+        ks = pl.multiple_of(j * block_k, block_k)
+        k_blk = k_ref[0, pl.ds(ks, block_k), :]
+        v_blk = v_ref[0, pl.ds(ks, block_k), :]
+        # the mask arrives with its keys on lanes, (1, block_k); this tile
+        # wants them on sublanes: the diagonal of the row broadcast down a
+        # square (exact, any block size, once per K block)
+        diagonal = lax.broadcasted_iota(
+            jnp.int32, (block_k, block_k), 0
+        ) == lax.broadcasted_iota(jnp.int32, (block_k, block_k), 1)
+        mask_col = jnp.sum(
+            jnp.where(diagonal, mask_ref[0, pl.ds(j, 1), :], 0.0),
+            axis=1, keepdims=True,
+        )  # (block_k, 1)
+        key_ok = jnp.broadcast_to(mask_col > _MASK_PAD, tile)
+
+        def q_block(i, carry):
+            dk, dv, dmask = carry
+            qs = pl.multiple_of(i * block_q, block_q)
+            q_blk = q_ref[0, pl.ds(qs, block_q), :]
+            do_blk = do_ref[0, pl.ds(qs, block_q), :]
+            s = jax.lax.dot_general(
+                k_blk, q_blk, nt, preferred_element_type=jnp.float32
+            ) * scale + mask_col
+            valid = key_ok
+            if causal:
+                k_pos = ks + lax.broadcasted_iota(jnp.int32, tile, 0)
+                q_pos = qs + lax.broadcasted_iota(jnp.int32, tile, 1)
+                valid = valid & (q_pos >= k_pos)
+            # invalid entries are force-excluded by the flag, as in the
+            # forward; an all-padded row has lse == _LSE_EMPTY and p == 0
+            p = jnp.where(valid, jnp.exp(s - lse_ref[0, pl.ds(i, 1), :]), 0.0)
+            dv = dv + jnp.dot(
+                p.astype(do_blk.dtype), do_blk,
+                preferred_element_type=jnp.float32,
+            )
+            dp = jax.lax.dot_general(
+                v_blk, do_blk, nt, preferred_element_type=jnp.float32
+            )
+            ds = p * (dp - delta_ref[0, pl.ds(i, 1), :])
+            ds_in = ds.astype(q_blk.dtype)
+            dk = dk + jnp.dot(ds_in, q_blk, preferred_element_type=jnp.float32)
+            dqt_acc[i] += jax.lax.dot_general(
+                k_blk, ds_in, tn, preferred_element_type=jnp.float32
+            )  # (d, block_q)
+            # the mask enters s additively: its cotangent is ds summed over
+            # the query rows (here) and over the heads (outside)
+            return dk, dv, dmask + jnp.sum(ds, axis=1, keepdims=True)
+
+        zero = jnp.zeros((block_k, k_blk.shape[-1]), jnp.float32)
+        # causal: Q blocks that end before this K block starts see none of it
+        lo = lax.div(j * block_k, block_q) if causal else 0
+        dk, dv, dmask = lax.fori_loop(
+            lo, n_q, q_block, (zero, zero, jnp.zeros((block_k, 1), jnp.float32))
+        )
+        dk_ref[0, pl.ds(ks, block_k), :] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0, pl.ds(ks, block_k), :] = dv.astype(dv_ref.dtype)
+        dmask_ref[0, pl.ds(j, 1), :] = dmask.reshape(1, block_k)
+        return 0
+
+    lax.fori_loop(0, n_k, k_block, 0)
+
+    def turn_dq(i, _):
+        qs = pl.multiple_of(i * block_q, block_q)
+        dq_ref[0, pl.ds(qs, block_q), :] = (dqt_acc[i].T * scale).astype(
+            dq_ref.dtype
+        )
+        return 0
+
+    lax.fori_loop(0, n_q, turn_dq, 0)
 
 
-def _flash_bwd_chunked(scale, causal, block_k, q, k, v, mask, out, lse, do):
-    """Standard flash backward, one K block at a time (lax.scan): recompute
-    P = exp(S − lse), then dV = Pᵀ dO, dS = P ∘ (dO Vᵀ − D), dQ += dS·K,
-    dK = dSᵀ Q — the (T, T) score matrix never exists. Shapes are the folded
-    (BH, T, D); mask is (B, T) shared over heads."""
+# VMEM a TPU kernel may use unasked (Mosaic's scoped default) and the most
+# _flash_bwd asks for: under the 128 MiB of a v5e/v6e core
+_VMEM_DEFAULT = 16 * 2**20
+_VMEM_MOST = 100 * 2**20
+
+
+def _flash_bwd(
+    scale, causal, h, block_q, block_k, interpret, q, k, v, mask, out, lse, do
+):
+    """Flash backward as one Pallas kernel (``_flash_bwd_kernel``): p is
+    recomputed from the saved lse, dV = Pᵀ dO, dS = P ∘ (dO Vᵀ − D),
+    dQ = dS·K, dK = dSᵀ Q, and no score-sized array reaches HBM. Shapes are
+    the folded (B*H, T, D); mask is (B, T), shared over heads; returns
+    (dq, dk, dv, dmask)."""
     bh, t, d = q.shape
-    b = mask.shape[0]
-    h = bh // b
-    q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
-    do32 = do.astype(jnp.float32)
-    D = jnp.sum(do32 * out.astype(jnp.float32), axis=-1)  # (BH, T)
-
-    def block(carry, j):
-        dq_acc, dmask_acc = carry
-        ks = j * block_k
-        k_blk = lax.dynamic_slice_in_dim(k32, ks, block_k, 1)  # (BH, bk, d)
-        v_blk = lax.dynamic_slice_in_dim(v32, ks, block_k, 1)
-        m_blk = lax.dynamic_slice_in_dim(mask, ks, block_k, 1)  # (B, bk)
-        s = (
-            jnp.einsum("zqd,zkd->zqk", q32, k_blk) * scale
-            + jnp.repeat(m_blk, h, axis=0)[:, None, :]
-        )
-        if causal:
-            s = s + _causal_bias(t, block_k, ks)[None]
-        p = jnp.exp(s - lse[:, :, None])  # (BH, T, bk)
-        # force-exclude padded keys (mask ≤ _MASK_PAD) instead of relying on
-        # exp underflow — mirrors the forward kernel's validity flag
-        p = jnp.where(
-            jnp.repeat(m_blk > _MASK_PAD, h, axis=0)[:, None, :], p, 0.0
-        )
-        dp = jnp.einsum("zqd,zkd->zqk", do32, v_blk)
-        ds = p * (dp - D[:, :, None])
-        dq_acc = dq_acc + jnp.einsum("zqk,zkd->zqd", ds, k_blk) * scale
-        dk_blk = jnp.einsum("zqk,zqd->zkd", ds, q32) * scale
-        dv_blk = jnp.einsum("zqk,zqd->zkd", p, do32)
-        # mask enters s additively, shared over heads and q rows
-        dmask_blk = jnp.sum(ds.reshape(b, h, t, block_k), axis=(1, 2))
-        dmask_acc = lax.dynamic_update_slice_in_dim(dmask_acc, dmask_blk, ks, 1)
-        return (dq_acc, dmask_acc), (dk_blk, dv_blk)
-
-    # the dmask accumulator must carry the inputs' device-variance (e.g. a
-    # data mesh axis) or the scan carry types mismatch under shard_map; a
-    # zero "tint" derived from do carries it
-    tint = (do32 * 0).sum()
-    (dq, dmask), (dks, dvs) = lax.scan(
-        block,
-        (jnp.zeros_like(q32), jnp.zeros_like(mask) + tint),
-        jnp.arange(t // block_k),
+    b = bh // h
+    # D = rowsum(dO ∘ O), the softmax backward's row term: (B*H, T) fp32
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    # inside shard_map the outputs vary over the mesh as the operands do
+    vma = frozenset()
+    for operand in (q, k, v, mask, do):
+        vma = vma | jax.typeof(operand).vma
+    whole = pl.BlockSpec((1, t, d), lambda i: (i, 0, 0))
+    # per-row scalars ride as (blocks, block): block i is sublane row i of a
+    # lane-dense array (no dynamic lane slicing on TPU) — lse and D by Q
+    # block, the mask and its cotangent by K block
+    q_rows = (t // block_q, block_q)
+    k_rows = (t // block_k, block_k)
+    # what the kernel keeps in VMEM: seven (T, D) operands, double-buffered
+    # and padded to 128 lanes, the dQᵀ scratch and a few fp32 tiles
+    resident = (
+        14 * t * max(d, 128) * q.dtype.itemsize
+        + 4 * t * d
+        + 8 * 4 * block_q * block_k
     )
-    dk = jnp.moveaxis(dks, 0, 1).reshape(bh, t, d)
-    dv = jnp.moveaxis(dvs, 0, 1).reshape(bh, t, d)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), dmask
+    dq, dk, dv, dmask = pl.pallas_call(
+        functools.partial(
+            _flash_bwd_kernel, block_q, block_k, t, causal, scale
+        ),
+        grid=(bh,),
+        in_specs=[
+            whole, whole, whole, whole,
+            pl.BlockSpec((1,) + q_rows, lambda i: (i, 0, 0)),
+            pl.BlockSpec((1,) + q_rows, lambda i: (i, 0, 0)),
+            # mask is per-batch: integer-divide the (b*h) grid row
+            pl.BlockSpec((1,) + k_rows, lambda i: (i // h, 0, 0)),
+        ],
+        out_specs=[
+            whole, whole, whole,
+            pl.BlockSpec((1,) + k_rows, lambda i: (i, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t, d), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t, d), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t, d), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh,) + k_rows, jnp.float32, vma=vma),
+        ],
+        scratch_shapes=[pltpu.VMEM((t // block_q, d, block_q), jnp.float32)],
+        # long sequences only: the cells' shapes stay under the default
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=min(resident, _VMEM_MOST)
+        ) if resident > _VMEM_DEFAULT else None,
+        interpret=interpret,
+        name="flash_attention_bwd",
+    )(
+        q, k, v, do,
+        lse.reshape((bh,) + q_rows),
+        delta.reshape((bh,) + q_rows),
+        mask.reshape((b,) + k_rows),
+    )
+    return dq, dk, dv, dmask.reshape(b, h, t).sum(axis=1)
 
 
 @functools.partial(
@@ -285,9 +416,14 @@ def flash_attention(
         return out, (qf, kf, vf, mask, out, lse)
 
     def attn_bwd(res, do):
-        qf, kf, vf, mask, out, lse = res
-        return _flash_bwd_chunked(
-            scale, causal, block_k, qf, kf, vf, mask, out, lse, do
+        # on the chip a tile's fixed cost outweighs what it holds (PERF.md
+        # §6, PR 25: at T=512 one 512x512 tile takes half the time of
+        # sixteen 128x128), so the backward takes the largest edge that
+        # divides T, and the forward's blocks where none does
+        edge = next((e for e in (512, 256, 128) if t % e == 0), None)
+        return _flash_bwd(
+            scale, causal, h, edge or block_q, edge or block_k, interpret,
+            *res, do,
         )
 
     attn.defvjp(attn_fwd, attn_bwd)

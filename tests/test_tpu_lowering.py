@@ -118,6 +118,46 @@ def test_flash_attention_compiles_with_mosaic(v5e_devices, shape, dtype, causal,
         jax.jit(fn).lower(*args).compile()
 
 
+# the benchmark's two per-chip attention shapes (imdb_psgd16_b16 and _x4:
+# 48x512; imdb_psgd16_b128: 128x512; both padded) and GPT-2-small's causal one
+FLASH_BWD_CASES = [
+    pytest.param((48, 512, 12, 64), False, True, id="imdb-48x512"),
+    pytest.param((128, 512, 12, 64), False, True, id="imdb-128x512"),
+    pytest.param((8, 1024, 12, 64), True, False, id="gpt-1024-causal"),
+]
+
+
+@pytest.mark.parametrize("shape,causal,masked", FLASH_BWD_CASES)
+def test_flash_backward_is_a_kernel_in_the_compiled_program(
+    v5e_devices, record_property, shape, causal, masked
+):
+    """The counter that says the mechanism engaged is structural: in the
+    program XLA compiled for the chip the whole attention backward is one
+    ``tpu_custom_call`` with a name of its own (``breakdown`` rows are named
+    after it), beside the forward's; no ``while`` is left of the K-block
+    scan it replaced, and no array is larger than q — the scan's
+    (B*H, T, block_k) score tensors are what the kernel keeps in VMEM."""
+    import re
+
+    b, t, h, d = shape
+    args, fns = _flash_fns(shape, jnp.bfloat16, causal, masked)
+    args = [_on(v5e_devices[0], a) for a in args]
+    hlo = jax.jit(fns["grad"]).lower(*args).compile().as_text()
+    kernels = re.findall(
+        r"(%[\w.]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo
+    )
+    record_property("tpu_custom_calls", " ".join(kernels))
+    assert len(kernels) == 2, kernels
+    assert sum(k.startswith("%flash_attention_bwd") for k in kernels) == 1, kernels
+    assert not re.search(r"\bwhile\(", hlo)
+    sizes = {
+        dims: int(np.prod([int(n) for n in dims.split(",")]))
+        for dims in re.findall(r"\b(?:bf16|f32|s32|pred)\[([\d,]+)\]", hlo)
+    }
+    too_large = {dims for dims, n in sizes.items() if n > b * h * t * d}
+    assert not too_large, too_large
+
+
 @pytest.mark.parametrize("shape", ORTHOGONALIZE_SHAPES, ids=str)
 def test_pallas_orthogonalize_compiles_with_mosaic(v5e_devices, shape):
     arg = _on(v5e_devices[0], jax.ShapeDtypeStruct(shape, jnp.float32))
