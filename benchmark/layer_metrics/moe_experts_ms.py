@@ -1,0 +1,9 @@
+"""Device self time per step under the scope ``moe.experts``, forward,
+recomputation and backward (see ``scoped.py``), in milliseconds."""
+
+from .scoped import scope_seconds
+
+
+def read(run):
+    seconds = scope_seconds(run, "moe.experts")
+    return None if seconds is None else 1e3 * seconds

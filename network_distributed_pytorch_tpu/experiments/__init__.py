@@ -15,4 +15,5 @@ from . import (  # noqa: F401
     imdb_baseline,
     powersgd_cifar10,
     powersgd_imdb,
+    powersgd_nemotron,
 )

@@ -13,7 +13,7 @@ from typing import Any, Callable, Dict, Iterator, Optional, Tuple
 import flax.linen as nn
 import jax
 
-from ..parallel.trainer import CompiledStep, TrainState
+from ..parallel.trainer import STEP_COUNTERS, CompiledStep, TrainState
 from ..utils.metrics import MetricsLogger
 
 
@@ -216,6 +216,7 @@ def train_loop(
     logger = MetricsLogger(
         bits_per_step=step.bits_per_step, log_every=log_every, telemetry=telemetry
     )
+    has_counters = STEP_COUNTERS in (getattr(state, "model_state", None) or {})
     memory_sampler = None
     fidelity_tracker = None
     if health_every > 0 and telemetry is not None:
@@ -278,8 +279,17 @@ def train_loop(
                         state, loss = step(state, batch)
                     # the device_get blocks until the step (and its
                     # collectives) retires: host-visible step tail
-                    with span("step/loss_sync", step=logger._step):
-                        loss = jax.device_get(loss)
+                    with span("step/loss_sync", step=logger._step) as sync:
+                        if has_counters:
+                            # the step's own counters ride the same fetch
+                            loss, counted = jax.device_get(
+                                (loss, state.model_state[STEP_COUNTERS])
+                            )
+                            sync.counters = jax.tree_util.tree_map(
+                                lambda a: a.tolist(), counted
+                            )
+                        else:
+                            loss = jax.device_get(loss)
                 logger.end_step(epoch, loss)
                 steps_done += 1
                 if (

@@ -33,6 +33,7 @@ from .experiments import (
     imdb_baseline,
     powersgd_cifar10,
     powersgd_imdb,
+    powersgd_nemotron,
     serve_gpt,
 )
 from .observe import RawEvent, StreamJsonSink, Telemetry
@@ -45,6 +46,7 @@ EXPERIMENTS = {
     "diloco_cifar10": diloco_cifar10.run,
     "powersgd_cifar10": powersgd_cifar10.run,
     "powersgd_imdb": powersgd_imdb.run,
+    "powersgd_nemotron": powersgd_nemotron.run,
     "imdb_baseline": imdb_baseline.run,
     "bandwidth_study": bandwidth_study.run,
     "gpt_lm": gpt_lm.run,
@@ -821,6 +823,9 @@ def main(argv=None) -> dict:
                       spec_k=args.spec_k if args.spec_k is not None else 0)
     elif args.experiment == "bandwidth_study":
         kwargs.update(preset=args.preset)
+    elif args.experiment == "powersgd_nemotron":
+        kwargs.update(preset=args.preset,
+                      max_steps_per_epoch=args.max_steps_per_epoch)
     elif args.experiment in ("gpt_lm", "gpt_pp", "gpt_sp", "gpt_tp", "gpt_moe"):
         kwargs.update(preset=args.preset, max_steps_per_epoch=args.max_steps_per_epoch)
         if args.experiment == "gpt_lm":

@@ -33,3 +33,9 @@ from .gpt import (  # noqa: F401
     stack_gpt_layer_params,
     unstack_gpt_layer_params,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig,
+    NemotronHLM,
+    nemotron_h_tiny,
+    next_token_lm_loss,
+)

@@ -335,7 +335,8 @@ class SpanEvent(Event):
     the banners.
 
     ``step`` is the span's own or, where it was opened without one, its
-    parent's."""
+    parent's. ``counters`` is what the region counted, where its body said
+    (the step's ``step_counters`` on ``step/loss_sync``), else None."""
 
     KIND: ClassVar[str] = "span"
 
@@ -346,6 +347,7 @@ class SpanEvent(Event):
     dur_s: float
     step: Optional[int] = None
     rank: Optional[int] = None
+    counters: Optional[Dict] = None
 
     def record(self) -> Dict:
         # written out: the loop emits seven of these per step, and the
@@ -354,6 +356,7 @@ class SpanEvent(Event):
             "event": "span", "name": self.name, "span_id": self.span_id,
             "parent_id": self.parent_id, "depth": self.depth,
             "dur_s": self.dur_s, "step": self.step, "rank": self.rank,
+            "counters": self.counters,
         }
 
 

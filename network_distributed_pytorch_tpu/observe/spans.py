@@ -124,7 +124,7 @@ class span:
 
     __slots__ = (
         "name", "recorder", "step", "rank", "span_id", "parent_id", "depth",
-        "stack", "annotation", "t0",
+        "stack", "annotation", "t0", "counters",
     )
 
     def __init__(
@@ -138,8 +138,11 @@ class span:
         self.recorder = telemetry
         self.step = step
         self.rank = rank
+        # what the region counted, set by the body (``with span(..) as s:
+        # s.counters = {..}``): JSON-ready, emitted with the span
+        self.counters = None
 
-    def __enter__(self) -> None:
+    def __enter__(self) -> "span":
         if self.recorder is None:
             self.recorder = _AMBIENT
         stack = self.stack = _stack()
@@ -156,6 +159,7 @@ class span:
         if self.annotation is not None:
             self.annotation.__enter__()
         self.t0 = time.monotonic()
+        return self
 
     def __exit__(self, *exc) -> None:
         dur = time.monotonic() - self.t0
@@ -170,5 +174,6 @@ class span:
             SpanEvent(
                 self.name, self.span_id, self.parent_id, self.depth, dur,
                 self.step, self.rank if self.rank is not None else _default_rank(),
+                self.counters,
             )
         )

@@ -1,7 +1,23 @@
-"""Expert parallelism: Switch-style top-1 MoE with all-to-all token dispatch.
+"""Expert parallelism: two routed mixture-of-experts layers.
 
-Beyond-parity capability (SURVEY §2.3: EP/MoE absent from the reference).
-TPU-native design:
+Which layer is which:
+
+- :func:`switch_moe` — Switch/GShard routing over an ``expert`` mesh axis:
+  softmax scores, top-1 (or top-k priority) dispatch through one-hot
+  ``(T, E, C)`` masks, a fixed per-expert ``capacity`` whose overflow DROPS
+  tokens, two ``lax.all_to_all`` hops, the Switch auxiliary loss. The router
+  is exactly as wide as the experts the mesh holds. Used by ``gpt_moe``.
+- :func:`held_experts_moe` — the layer of one expert-parallel rank of a
+  larger deployment: it is told which expert ids it ``held``s, routes over
+  ALL of the router's experts (sigmoid scores, top-k, renormalised, scaled),
+  sorts the assignments that land on its own experts and computes their part
+  of the result with grouped products (every block of sorted rows belongs to
+  one expert: batched matmuls over blocks). No capacity and no drops, no
+  array of size tokens x experts x capacity; what absent experts would add
+  is left out (another rank's part). Used by ``models/nemotron_h``.
+
+``switch_moe``, in detail (beyond-parity capability, SURVEY §2.3: EP/MoE
+absent from the reference). TPU-native design:
 
 - experts live on an ``expert`` mesh axis: device i holds only its
   ``E/N`` experts' parameters (stacked expert params sharded on the leading
@@ -24,10 +40,11 @@ and expert shard axis).
 
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 PyTree = Any
@@ -159,3 +176,154 @@ def stacked_expert_params(params_per_expert: list[PyTree]) -> PyTree:
     """Stack E per-expert pytrees with a leading expert axis — shard it over
     the ``expert`` mesh axis."""
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *params_per_expert)
+
+
+def relu_squared(h: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(h))
+
+
+def held_experts_moe(
+    x: jax.Array,               # (T, D) tokens, compute dtype
+    router_in: jax.Array,       # (T, D) what the router scores (fp32 where the caller has it)
+    router_kernel: jax.Array,   # (D, E) over ALL experts of the model
+    select_bias: jax.Array,     # (E,) added to the scores for SELECTION only
+    w_in: jax.Array,            # (len(held), D, F) this rank's experts, stacked
+    w_out: jax.Array,           # (len(held), F, D)
+    held: Sequence[int],        # the expert ids that live here, in stacking order
+    top_k: int,
+    scaling: float = 1.0,
+    axis_name: Optional[str] = None,
+    block_rows: int = 512,
+) -> tuple[jax.Array, Dict[str, jax.Array]]:
+    """This rank's part of a dropless top-k expert layer.
+
+    Scores are ``sigmoid(router_in @ router_kernel)`` in fp32 at full
+    precision (a top-k is discrete: a score rounded to bf16 picks other
+    experts); each token takes the ``top_k`` largest of ``score +
+    select_bias`` and weights them ``scaling * score_i / sum_topk score``.
+    Of those T*top_k assignments the ones on a ``held`` expert are sorted by
+    expert and computed as grouped products, ``w_out_e . relu(w_in_e . x)^2``:
+    each expert's rows are laid out from a multiple of ``block_rows``, so
+    every block of rows belongs to one expert and the two products are
+    batched matmuls over blocks, each block with its expert's weights; the
+    weighted rows are added back into their tokens.
+
+    The work follows the assignments that landed here. They are taken in
+    chunks of T rows (the expected load is T*top_k*len(held)/E): the first
+    chunk runs outside any loop and nearly always holds them all; a heavier
+    load goes on chunk after chunk (``lax.cond`` into a ``lax.scan``, a chunk
+    past the last assignment skipped) up to T*min(top_k, len(held)) rows —
+    every assignment there can be — so none is ever dropped, and no array
+    has a (tokens, experts, capacity) shape.
+
+    Returns ``(out, counters)``: ``out`` (T, D) in ``x``'s dtype, and
+    int32 counters of this call — ``held`` (len(held),) assignments per held
+    expert, ``absent`` assignments on experts that live elsewhere, ``dropped``
+    assignments on held experts that were not computed (always 0; counted
+    from the rows the chunks covered, not assumed).
+
+    ``axis_name`` is where the exchange between ranks would ride; only the
+    one-rank layer (``None``) exists.
+    """
+    if axis_name is not None:
+        raise NotImplementedError("held_experts_moe has no exchange over a mesh axis yet")
+    t, d = x.shape
+    e = router_kernel.shape[1]
+    n_held = len(held)
+    assert w_in.shape[0] == w_out.shape[0] == n_held and 1 <= top_k <= e
+    f32, i32 = jnp.float32, jnp.int32
+
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(
+            router_in.astype(f32), router_kernel.astype(f32), precision=lax.Precision.HIGHEST
+        )
+        scores = jax.nn.sigmoid(logits)  # (T, E)
+        _, chosen = lax.top_k(scores + select_bias.astype(f32), top_k)  # (T, K)
+        picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        weights = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
+        # expert id -> its slot here; every absent expert shares the slot past the last
+        slot_of = np.full((e,), n_held, np.int32)
+        slot_of[list(held)] = np.arange(n_held)
+        slots = jnp.asarray(slot_of)[chosen].reshape(-1)  # (T*K,)
+        order = jnp.argsort(slots, stable=True)  # held experts' assignments first, by expert
+        counts = jnp.zeros((n_held + 1,), i32).at[slots].add(1)
+        group_sizes, absent = counts[:n_held], counts[n_held]
+        group_ends = jnp.cumsum(group_sizes)
+        landed = group_ends[-1]
+
+    # chunks of the sorted assignments: T rows each, in whole blocks. The expected load is
+    # T*top_k*len(held)/E, well under T for a rank that holds a share of the experts, but
+    # skewed traffic (Zipf token ids route by id) was seen at 1.5x it in one run of nine
+    most = t * min(top_k, n_held)
+    rows = -(-min(t, most) // block_rows) * block_rows
+    n_chunks = -(-most // rows)
+    n_blocks = rows // block_rows + n_held  # each expert may end on a part-filled block
+    padded = n_blocks * block_rows
+    pad_to = lambda v, fill: jnp.pad(v[:most], (0, n_chunks * rows - most), constant_values=fill)
+    sorted_slots = pad_to(slots[order], n_held)
+    sorted_tokens = pad_to(order // top_k, 0)
+    sorted_weights = pad_to(weights.reshape(-1)[order], 0.0)
+
+    def chunk(first, x, w_in, w_out, sorted_weights):
+        """Rows [first, first + rows) of the sorted assignments -> their
+        part of the output (T, D) and how many of them were live."""
+        at = first + jnp.arange(rows)
+        live = at < landed
+        slot = jnp.where(live, lax.dynamic_slice(sorted_slots, (first,), (rows,)), 0)
+        starts = jnp.clip(group_ends - group_sizes, first, first + rows)
+        blocks_of = -(-(jnp.clip(group_ends, first, first + rows) - starts) // block_rows)
+        block_ends = jnp.cumsum(blocks_of)
+        # where each assignment's row goes: its expert's first block, then its rank there
+        to = jnp.where(live, (block_ends - blocks_of)[slot] * block_rows + at - starts[slot], padded)
+        row_token = jnp.full((padded,), t, i32).at[to].set(
+            lax.dynamic_slice(sorted_tokens, (first,), (rows,)), mode="drop"
+        )
+        row_weight = jnp.zeros((padded,), f32).at[to].set(
+            lax.dynamic_slice(sorted_weights, (first,), (rows,)), mode="drop"
+        )
+        block_expert = jnp.minimum(
+            jnp.searchsorted(block_ends, jnp.arange(n_blocks), side="right"), n_held - 1
+        )
+        # a block's weights by a one-hot product: a gather whose transpose is no scatter
+        pick = (block_expert[:, None] == jnp.arange(n_held)).astype(x.dtype)
+        block_in = jnp.einsum("ne,edf->ndf", pick, w_in)
+        block_out = jnp.einsum("ne,efd->nfd", pick, w_out)
+        # an empty row's token is T, out of range: it reads zeros and adds nowhere
+        rows_in = x.at[row_token].get(mode="fill", fill_value=0).reshape(n_blocks, block_rows, d)
+        hidden = jnp.einsum("nbd,ndf->nbf", rows_in, block_in, preferred_element_type=f32)
+        hidden = relu_squared(hidden).astype(x.dtype)
+        part = jnp.einsum("nbf,nfd->nbd", hidden, block_out, preferred_element_type=f32)
+        part = part.reshape(padded, d) * row_weight[:, None]
+        out = jnp.zeros((t, d), f32).at[row_token].add(part, mode="drop")
+        return out, jnp.sum(live.astype(i32))
+
+    def later_chunks(*operands):
+        """The rare, heavy load: chunk after chunk until the assignments end.
+        A chunk past them is skipped; each is recomputed in the backward pass
+        so that the scan keeps one chunk's intermediates, not all."""
+        # the checkpoint goes round the cond: inside a branch, what it keeps
+        # for the backward pass (the weights) would leave the cond as outputs
+        # and be stacked once per chunk
+        @jax.checkpoint
+        def maybe_chunk(first):
+            return lax.cond(first < landed, lambda: chunk(first, *operands), nothing)
+
+        def next_chunk(carry, first):
+            return jax.tree_util.tree_map(jnp.add, carry, maybe_chunk(first)), None
+
+        return lax.scan(next_chunk, nothing(), jnp.arange(1, n_chunks) * rows)[0]
+
+    def nothing(*_):
+        zeros = jnp.zeros((t, d), f32), jnp.zeros((), i32)
+        varying = tuple(jax.typeof(x).vma)  # inside shard_map fresh zeros are invariant
+        return tuple(lax.pcast(z, varying, to="varying") for z in zeros) if varying else zeros
+
+    with jax.named_scope("moe.experts"):
+        operands = (x, w_in.astype(x.dtype), w_out.astype(x.dtype), sorted_weights)
+        # the first chunk nearly always holds every assignment: it runs outside any loop
+        out, computed = chunk(0, *operands)
+        if n_chunks > 1:
+            more, more_computed = lax.cond(landed > rows, later_chunks, nothing, *operands)
+            out, computed = out + more, computed + more_computed
+    counters = {"held": group_sizes, "absent": absent, "dropped": landed - computed}
+    return out.astype(x.dtype), counters

@@ -48,6 +48,15 @@ PyTree = Any
 # (``CompiledStep.eval_model_state``). Stateless models pass {} through.
 LossFn = Callable[[PyTree, PyTree, Any], Tuple[jax.Array, PyTree]]
 
+# The one key of ``model_state`` that holds COUNTERS of the step just run
+# rather than state it carries on: small integer arrays the loss function
+# writes anew every step (an expert layer's assignments per expert, its
+# drops). They ride the carry like batch-norm statistics, per worker and at
+# no wire cost, and ``experiments.common.train_loop`` fetches them with the
+# loss and puts them on that step's ``step/loss_sync`` span. A model_state
+# without the key is untouched by all of this.
+STEP_COUNTERS = "step_counters"
+
 # The one non-reducer collective in the distributed step: the scalar loss is
 # pmean'd for reporting (f32[] all-reduce = 4 bytes = 32 bits). Included in
 # ``bits_per_step`` so the analytic model reconciles byte-exactly with the
