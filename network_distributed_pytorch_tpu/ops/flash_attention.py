@@ -2,10 +2,25 @@
 
 Why a kernel: XLA's attention materializes (or at best tiles) the (T, T)
 score matrix through HBM; flash attention never builds it. Each grid program
-owns one Q block held in VMEM, streams K/V blocks through VMEM, and keeps the
-flash-style running (max, normalizer, accumulator) in registers/VMEM across
-the whole K loop — one HBM read per operand, one write of the output, all
-matmuls on the MXU at (block_q × d) × (d × block_k) tile shapes.
+owns one Q block, holds K and V of its (batch, head) whole in VMEM, walks
+them in K blocks, and keeps the flash-style running (max, normalizer,
+accumulator) on chip across the whole K loop — one HBM read per operand, one
+write of the output.
+
+The forward (``_flash_kernel``) feeds the MXU as the backward does. Its two
+products, S = K·Qᵀ and Oᵀ = Vᵀ·P, take their operands in the input dtype and
+accumulate in fp32 (bf16 models run bf16 products, fp32 inputs fp32 ones);
+the scale meets the fp32 scores after the product, and P is cast to V's
+dtype for the second. The running max, exp, the normaliser (summed from the
+fp32 P), the accumulator, the validity flags, the lse and the final division
+are fp32. Tiles are transposed, keys on sublanes and queries on lanes, so
+the row statistics and the lse are lane-dense rows and Oᵀ is turned once, at
+(D, block_q), on the way out. The tile edge is ``tile_edge(T)``, the largest
+of 512/256/128 that divides T (else one block of at most 128), the same the
+backward takes; explicit ``block_q``/``block_k`` win in both directions. Its
+reach is VMEM: K and V held whole, double-buffered, beside the tile's fp32
+temporaries, which it asks Mosaic for beyond the 16 MiB default — T = 16384
+at D = 128 in bf16 compiles.
 
 The online-softmax recurrence is the same one the framework's ring and
 Ulysses schedules use (``parallel.sequence``); this kernel is the
@@ -23,11 +38,10 @@ holds q, k, v and dO whole, loops K blocks outside and Q blocks inside
 (causal: Q blocks before the K block are skipped by the loop bound), and
 emits dq, dk, dv and the additive mask's cotangent per head (summed over
 heads outside). Only D = rowsum(dO ∘ O) is an XLA reduction. Every shape
-takes this path, on TPU and in interpret mode alike; the tile edge is the
-largest of 512/256/128 that divides T (else the forward's blocks, which is
-T itself below 128). Its reach is VMEM: seven (T, D) operands held whole,
-which it asks Mosaic for beyond the 16 MiB default — T = 16384 at D = 128 in
-bf16 compiles, as far as the forward's own whole-K/V residency goes.
+takes this path, on TPU and in interpret mode alike, on the forward's
+tiles. Its reach is VMEM: seven (T, D) operands held whole, which it asks
+Mosaic for beyond the 16 MiB default — T = 16384 at D = 128 in bf16
+compiles, as far as the forward's own whole-K/V residency goes.
 
 Correctness is pinned against naive einsum attention (padding masks, causal,
 both, and grads) in ``tests/test_flash_attention.py``; on CPU the kernel
@@ -72,6 +86,17 @@ def resolve_attn_impl(attn_impl: str) -> str:
     return attn_impl
 
 
+def _keys_on_sublanes(row):
+    """A (1, block_k) row of per-key values as the (block_k, 1) column a
+    transposed tile wants: the diagonal of the row broadcast down a square
+    (exact, any block size)."""
+    n = row.shape[-1]
+    diagonal = lax.broadcasted_iota(
+        jnp.int32, (n, n), 0
+    ) == lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return jnp.sum(jnp.where(diagonal, row, 0.0), axis=1, keepdims=True)
+
+
 def _flash_kernel(
     block_q: int,
     block_k: int,
@@ -85,9 +110,20 @@ def _flash_kernel(
     o_ref,
     lse_ref,
 ):
+    """One Q block against every K block it can see, fed to the MXU as the
+    backward feeds it: both products take their operands in the input dtype
+    and accumulate in fp32, the scale meets the fp32 scores after the
+    product, and the tiles are TRANSPOSED, keys on sublanes and queries on
+    lanes — the running max, normaliser and lse are lane-dense rows, the
+    softmax reduces down sublanes, and the output gathers as Oᵀ = Vᵀ·P,
+    turned once at (d, block_q) on the way out. The running max, exp,
+    normaliser, accumulator, validity flags and lse are fp32."""
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale  # (block_q, d)
+    q = q_ref[0]  # (block_q, d)
     d = q.shape[-1]
+    nt = (((1,), (1,)), ((), ()))  # A·Bᵀ
+    tn = (((0,), (0,)), ((), ()))  # Aᵀ·B
+    tile = (block_k, block_q)
 
     n_blocks = t // block_k
     if causal:
@@ -98,49 +134,44 @@ def _flash_kernel(
         hi = n_blocks
 
     def body(j, carry):
-        m, l, acc = carry
+        m, l, acc = carry  # (1, block_q), (1, block_q), (d, block_q)
         ks = pl.multiple_of(j * block_k, block_k)
-        k_blk = k_ref[0, pl.ds(ks, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(ks, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (block_q, block_k)
+        k_blk = k_ref[0, pl.ds(ks, block_k), :]
+        v_blk = v_ref[0, pl.ds(ks, block_k), :]
         # the mask arrives as (1, T/block_k, block_k): K block j is ROW j,
-        # a dynamic sublane index — Mosaic has no dynamic lane slicing
-        mask_blk = mask_ref[0, pl.ds(j, 1), :]  # (1, block_k)
-        valid = jnp.broadcast_to(mask_blk > _MASK_PAD, (block_q, block_k))
-        s = s + mask_blk
+        # a dynamic sublane index — Mosaic has no dynamic lane slicing —
+        # with its keys on lanes; this tile wants them on sublanes
+        mask_col = _keys_on_sublanes(mask_ref[0, pl.ds(j, 1), :])
+        s = jax.lax.dot_general(
+            k_blk, q, nt, preferred_element_type=jnp.float32
+        ) * scale + mask_col
+        valid = jnp.broadcast_to(mask_col > _MASK_PAD, tile)
         if causal:
-            q_pos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = j * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
+            k_pos = ks + lax.broadcasted_iota(jnp.int32, tile, 0)
+            q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, tile, 1)
             valid = valid & (q_pos >= k_pos)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
 
         # invalid (padding / causal-pruned) entries are force-excluded by
         # the validity flag — never by hoping exp underflows (see _MASK_PAD)
-        blk_max = jnp.max(jnp.where(valid, s, _NEG_INF), axis=-1, keepdims=True)
+        blk_max = jnp.max(jnp.where(valid, s, _NEG_INF), axis=0, keepdims=True)
         new_m = jnp.maximum(m, blk_max)
         correction = jnp.exp(m - new_m)
         p = jnp.where(valid, jnp.exp(s - new_m), 0.0)
-        l = l * correction + jnp.sum(p, axis=-1, keepdims=True)
+        l = l * correction + jnp.sum(p, axis=0, keepdims=True)
         acc = acc * correction + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
+            v_blk, p.astype(v_blk.dtype), tn,
             preferred_element_type=jnp.float32,
-        )
+        )  # (d, block_q)
         return new_m, l, acc
 
-    m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
+    m0 = jnp.full((1, block_q), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((1, block_q), jnp.float32)
+    acc0 = jnp.zeros((d, block_q), jnp.float32)
     m, l, acc = lax.fori_loop(0, hi, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
-    lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-37)), _LSE_EMPTY)
-    lse_ref[0] = lse.reshape(1, block_q)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-37)).T.astype(o_ref.dtype)
+    lse_ref[0] = jnp.where(
+        l > 0, m + jnp.log(jnp.maximum(l, 1e-37)), _LSE_EMPTY
+    )
 
 
 def _flash_bwd_kernel(
@@ -184,15 +215,8 @@ def _flash_bwd_kernel(
         k_blk = k_ref[0, pl.ds(ks, block_k), :]
         v_blk = v_ref[0, pl.ds(ks, block_k), :]
         # the mask arrives with its keys on lanes, (1, block_k); this tile
-        # wants them on sublanes: the diagonal of the row broadcast down a
-        # square (exact, any block size, once per K block)
-        diagonal = lax.broadcasted_iota(
-            jnp.int32, (block_k, block_k), 0
-        ) == lax.broadcasted_iota(jnp.int32, (block_k, block_k), 1)
-        mask_col = jnp.sum(
-            jnp.where(diagonal, mask_ref[0, pl.ds(j, 1), :], 0.0),
-            axis=1, keepdims=True,
-        )  # (block_k, 1)
+        # wants them on sublanes
+        mask_col = _keys_on_sublanes(mask_ref[0, pl.ds(j, 1), :])
         key_ok = jnp.broadcast_to(mask_col > _MASK_PAD, tile)
 
         def q_block(i, carry):
@@ -252,9 +276,26 @@ def _flash_bwd_kernel(
 
 
 # VMEM a TPU kernel may use unasked (Mosaic's scoped default) and the most
-# _flash_bwd asks for: under the 128 MiB of a v5e/v6e core
+# either kernel asks for: under the 128 MiB of a v5e/v6e core
 _VMEM_DEFAULT = 16 * 2**20
 _VMEM_MOST = 100 * 2**20
+
+
+def _vmem_params(resident: int):
+    """Ask Mosaic for ``resident`` bytes of VMEM where that passes its
+    default — long sequences only: the cells' imdb shapes stay under it."""
+    if resident <= _VMEM_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=min(resident, _VMEM_MOST))
+
+
+def tile_edge(t: int) -> int:
+    """The edge of the square score tile both kernels walk a sequence of
+    ``t`` with. On the chip a tile's fixed cost outweighs what it holds
+    (PERF.md §6, PR 25: at T=512 one 512x512 tile takes half the time of
+    sixteen 128x128), so it is the largest of 512/256/128 that divides T,
+    and one block of at most 128 where none does."""
+    return next((e for e in (512, 256, 128) if t % e == 0), min(128, t))
 
 
 def _flash_bwd(
@@ -309,10 +350,7 @@ def _flash_bwd(
             jax.ShapeDtypeStruct((bh,) + k_rows, jnp.float32, vma=vma),
         ],
         scratch_shapes=[pltpu.VMEM((t // block_q, d, block_q), jnp.float32)],
-        # long sequences only: the cells' shapes stay under the default
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=min(resident, _VMEM_MOST)
-        ) if resident > _VMEM_DEFAULT else None,
+        compiler_params=_vmem_params(resident),
         interpret=interpret,
         name="flash_attention_bwd",
     )(
@@ -334,8 +372,8 @@ def flash_attention(
     v: jax.Array,
     mask: jax.Array = None,
     causal: bool = False,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int = None,
+    block_k: int = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Exact attention without materializing the score matrix.
@@ -343,12 +381,14 @@ def flash_attention(
     q/k/v: (B, T, H, D) — the package's layout everywhere else.
     mask: optional (B, T) additive key mask (0 = attend, very negative =
     padding), the same convention as ``parallel.sequence``.
+    block_q/block_k: the score tile of both kernels; ``None`` is
+    ``tile_edge(T)``.
     Differentiable (custom VJP, blockwise backward). Returns (B, T, H, D)
     in q's dtype.
     """
     b, t, h, d = q.shape
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
+    block_q = min(block_q or tile_edge(t), t)
+    block_k = min(block_k or tile_edge(t), t)
     assert t % block_q == 0 and t % block_k == 0, (
         f"T={t} must divide into blocks ({block_q}, {block_k}); pad the"
         " sequence (and mask the pads) first"
@@ -371,6 +411,13 @@ def flash_attention(
 
     kernel = functools.partial(
         _flash_kernel, block_q, block_k, t, causal, scale
+    )
+
+    # what the kernel keeps in VMEM: K and V whole and the Q and O blocks,
+    # double-buffered and padded to 128 lanes, and a few fp32 tiles
+    resident = (
+        4 * (t + block_q) * max(d, 128) * q.dtype.itemsize
+        + 8 * 4 * block_q * block_k
     )
 
     def call_kernel(qf, kf, vf, mask):
@@ -402,6 +449,7 @@ def flash_attention(
                 jax.ShapeDtypeStruct((b * h, t, d), q.dtype, vma=vma),
                 jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32, vma=vma),
             ],
+            compiler_params=_vmem_params(resident),
             interpret=interpret,
         )(qf, kf, vf, mask.reshape(b, t // block_k, block_k))
         return out, lse.reshape(b * h, t)
@@ -416,14 +464,8 @@ def flash_attention(
         return out, (qf, kf, vf, mask, out, lse)
 
     def attn_bwd(res, do):
-        # on the chip a tile's fixed cost outweighs what it holds (PERF.md
-        # §6, PR 25: at T=512 one 512x512 tile takes half the time of
-        # sixteen 128x128), so the backward takes the largest edge that
-        # divides T, and the forward's blocks where none does
-        edge = next((e for e in (512, 256, 128) if t % e == 0), None)
         return _flash_bwd(
-            scale, causal, h, edge or block_q, edge or block_k, interpret,
-            *res, do,
+            scale, causal, h, block_q, block_k, interpret, *res, do
         )
 
     attn.defvjp(attn_fwd, attn_bwd)

@@ -1,7 +1,8 @@
 """Pallas flash attention (interpret mode on CPU) vs naive einsum attention:
 plain, padding-masked, causal, and causal+masked; bf16 inputs; the GPT
-attn_impl="flash" path; and the backward kernel's dq, dk, dv and dmask at
-the kernel's real block size, alone and inside ``shard_map``."""
+attn_impl="flash" path; the forward kernel and the backward kernel's dq,
+dk, dv and dmask at the tiles the chip runs (``tile_edge``), alone and
+inside ``shard_map``."""
 
 import functools
 
@@ -10,7 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from network_distributed_pytorch_tpu.ops.flash_attention import flash_attention
+from network_distributed_pytorch_tpu.ops.flash_attention import (
+    flash_attention,
+    tile_edge,
+)
 
 B, T, H, D = 2, 32, 4, 16
 
@@ -162,6 +166,85 @@ def test_flash_gradients_match_naive(devices, causal):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(e), rtol=5e-4, atol=5e-4
         )
+
+
+# --- the forward kernel at the tiles the chip runs (default blocks) -----------
+
+FWD_KINDS = ("full", "mask", "causal", "causal+mask")
+FWD_CASES = [
+    pytest.param(t, kind, dtype, id=f"T{t}-{kind}-{dtype.__name__}")
+    for t in (96, 128, 512, 640)  # 96: one block under 128; 640: 5x5 tiles of 128
+    for kind in FWD_KINDS
+    for dtype in (jnp.float32, jnp.bfloat16)
+]
+
+
+@pytest.mark.parametrize("t,kind,dtype", FWD_CASES)
+def test_flash_forward_kernel_matches_naive(devices, t, kind, dtype):
+    """The forward with its default blocks against naive fp32 attention on
+    the same inputs. bf16 inputs are held to the backward's 1.5% of the
+    largest entry: q, k, p and v go into their products as bf16 (fp32
+    accumulation) and the output is rounded to bf16 once. The masked cases
+    carry a padded tail that splits a block and one fully padded sequence,
+    which must come out exactly zero."""
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    q, k, v = (jax.random.normal(key, (BWD_B, t, BWD_H, BWD_D), dtype) for key in ks)
+    mask, live = None, np.ones(BWD_B, bool)
+    if "mask" in kind:
+        m = np.zeros((BWD_B, t), np.float32)
+        m[0, :] = np.finfo(np.float32).min  # what DistilBertEncoder emits
+        m[1, t - t // 4 - 3:] = -1e30
+        mask, live[0] = jnp.asarray(m), False
+    out = flash_attention(q, k, v, mask=mask, causal="causal" in kind, interpret=True)
+    want = _naive(q, k, v, mask=mask, causal="causal" in kind)
+    assert out.dtype == dtype and out.shape == q.shape
+    got, want = np.asarray(out, np.float32), np.asarray(want)
+    # naive softmax spreads an all-padded row evenly; the kernel gives nothing
+    assert np.all(got[~live] == 0.0)
+    tol = 1.5e-2 if dtype == jnp.bfloat16 else 2e-5
+    assert np.all(np.isfinite(got))
+    assert np.abs(got[live] - want[live]).max() <= tol * np.abs(want[live]).max()
+
+
+def _kernel_blocks(jaxpr):
+    """{kernel name: the block shape of each operand and output} of every
+    ``pallas_call`` in a jaxpr; the forward kernel has no name."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = [
+                    tuple(dim.block_size for dim in m.block_shape)
+                    for m in eqn.params["grid_mapping"].block_mappings
+                ]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
+@pytest.mark.parametrize(
+    "t,edge", [(512, 512), (1024, 512), (768, 256), (640, 128), (96, 96)]
+)
+def test_tile_edge_is_shared_by_forward_and_backward(devices, t, edge):
+    """One rule gives the tile from T, and both kernels are built on it:
+    the forward walks Q in blocks of that edge and emits the lse so, the
+    backward takes the lse as rows of that edge and the mask too."""
+    assert tile_edge(t) == edge
+    q = jax.ShapeDtypeStruct((1, t, 2, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, interpret=True).astype(jnp.float32).sum()
+
+    kernels = _kernel_blocks(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
+    assert sorted(kernels, key=str) == [None, "flash_attention_bwd"]
+    q_block, _, _, mask_rows, _, lse_block = kernels[None]
+    assert (q_block, lse_block) == ((1, edge, 64), (1, 1, edge))
+    assert mask_rows == (1, t // edge, edge)
+    _, _, _, _, lse_rows, _, mask_rows = kernels["flash_attention_bwd"][:7]
+    assert lse_rows == mask_rows == (1, t // edge, edge)
 
 
 # --- the backward kernel at the tile sizes the chip runs (T up to 512) -------

@@ -31,13 +31,17 @@ from network_distributed_pytorch_tpu.ops.pallas_orthogonalize import (
 
 # (b, t, h, d), dtype, causal, padded mask — chip_smoke's DistilBERT-base
 # attention (bf16 and fp32), GPT-2-small at its context, gpt_lm's full
-# preset (t=64), and a serve prefill length that is no multiple of 128
+# preset (t=64), a serve prefill length that is no multiple of 128, and the
+# benchmark cells' own: imdb_psgd16_b16 (one 512x512 tile a head) and
+# nemotron_psgd16_t8k (512x512 tiles, K and V whole: the VMEM request)
 FLASH_CASES = [
     pytest.param((16, 256, 12, 64), jnp.bfloat16, False, True, id="distilbert-bf16"),
     pytest.param((16, 256, 12, 64), jnp.float32, False, True, id="distilbert-fp32"),
     pytest.param((8, 1024, 12, 64), jnp.bfloat16, True, False, id="gpt-1024-causal"),
     pytest.param((8, 64, 12, 64), jnp.float32, True, False, id="gpt_lm-64-causal"),
     pytest.param((2, 96, 12, 64), jnp.float32, True, False, id="prefill-96-causal"),
+    pytest.param((48, 512, 12, 64), jnp.bfloat16, False, True, id="imdb-48x512"),
+    pytest.param((1, 8192, 32, 128), jnp.bfloat16, True, False, id="nemotron-8192-causal"),
 ]
 # P-factor shapes: DistilBERT-base at rank 16 (chip_smoke), GPT-2 at rank 4
 ORTHOGONALIZE_SHAPES = [
