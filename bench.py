@@ -664,9 +664,9 @@ def _phase_overlap() -> dict:
     """Comm/compute schedule evidence for the PowerSGD step, from the
     scheduled v5e executable (SURVEY §5 set 'assert via profile' as the bar
     for replacing the reference's async-handle overlap,
-    ``reducer.py:131-168``). Two findings from the post-optimization HLO,
-    persisted as ``OVERLAP.json``: (a) async ``*-start``/``*-done``
-    collective windows and the compute scheduled inside them
+    ``reducer.py:131-168``). Two findings from the post-optimization HLO:
+    (a) async ``*-start``/``*-done`` collective windows and the compute
+    scheduled inside them
     (``utils.overlap``); (b) what the all-reduce combiner did to the 4
     logical collectives (P, rank-1, Q, loss) — on v5e it MERGES the rank-1
     payload into the Q all-reduce, i.e. the separate collective the
@@ -675,13 +675,7 @@ def _phase_overlap() -> dict:
     ``n_async_collectives`` is reported as observed and has been 0 — we do
     NOT claim collectives overlap compute. Unless already on a ≥2-chip
     mesh, the step is compiled against an 8-chip v5e topology AOT — the
-    schedule IS the evidence, no execution needed.
-
-    A third finding (Round-6): the SAME workload compiled with
-    ``comm_chunks=4`` — per-chunk collectives, their async windows or
-    textual interleaving with compute fusions, and the byte-exact
-    reconciliation of the per-chunk ledger against the compiled HLO —
-    lands under the ``chunked`` key of ``OVERLAP.json``."""
+    schedule IS the evidence, no execution needed."""
     import jax
     import jax.numpy as jnp
 
@@ -697,13 +691,11 @@ def _phase_overlap() -> dict:
     small = _small_preset()
     mesh = make_mesh()
     target_mesh = mesh
-    topology_note = "attached TPU devices"
     if mesh.size < 2:
         from jax.experimental import topologies
 
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x4")
         target_mesh = make_mesh(devices=topo.devices)
-        topology_note = "AOT v5e:2x4 topology (no execution)"
 
     model = _make_model(jnp.bfloat16, small)
     variables = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)), train=True)
@@ -727,7 +719,7 @@ def _phase_overlap() -> dict:
     # scheduled HLO; option sets are tried most-specific first, and an
     # executable with no async windows still yields the combiner evidence
     lowered = step.fn.lower(state_abs, batch_abs)
-    compiled_exe, flags_used, opts_used, last_opt_err = None, None, None, None
+    compiled_exe, last_opt_err = None, None
     for opts in (
         {
             "xla_tpu_enable_latency_hiding_scheduler": "true",
@@ -741,8 +733,6 @@ def _phase_overlap() -> dict:
             compiled_exe = (
                 lowered.compile(compiler_options=opts) if opts else lowered.compile()
             )
-            flags_used = sorted(opts) if opts else []
-            opts_used = opts
             break
         except Exception as opt_err:  # noqa: BLE001 — try the next set
             last_opt_err = opt_err
@@ -751,94 +741,14 @@ def _phase_overlap() -> dict:
 
     hlo = hlo_text_of_compiled(compiled_exe)
     rep = overlap_report(hlo)
-    rep["compiler_flags"] = flags_used
     aud = collective_summary(hlo)
-    rep["compiled_collectives"] = {
-        "count": aud["count"],
-        "by_kind": aud["by_kind"],
-        "ops": [
-            {
-                "kind": o.kind,
-                "dtype": o.dtype,
-                "shapes": [list(s) for s in o.shape],
-                "payload_bytes": o.payload_bytes,
-            }
-            for o in aud["ops"]
-        ],
-    }
-    # P, rank-1, Q, loss — reducer.py:126-147 + the loss pmean
-    rep["logical_collectives"] = 4
-    rep["combiner_merged"] = aud["count"] < 4
-    rep["workload"] = "powersgd_r4_" + ("resnet18" if small else "resnet50")
-    rep["compiled_for"] = topology_note
-    # Round-6 chunked-pipeline evidence (DESIGN.md): the SAME workload with
-    # comm_chunks=4 — the schedule must show either async windows with
-    # compute inside them or the chunk collectives textually interleaved
-    # with compute fusions, and the per-chunk ledger must reconcile
-    # byte-exactly against the compiled HLO. Best-effort: a failure here
-    # must not cost the phase its monolithic evidence.
-    try:
-        chunks = max(2, int(os.environ.get("BENCH_COMM_CHUNKS", "4")))
-        cstep = make_train_step(
-            loss_fn,
-            PowerSGDReducer(
-                random_seed=714, compression_rank=4, matricize="last",
-                comm_chunks=chunks,
-            ),
-            variables["params"], learning_rate=0.001, momentum=0.9,
-            algorithm="ef_momentum", mesh=target_mesh, donate_state=False,
-        )
-        clowered = cstep.fn.lower(state_abs, batch_abs)
-        cexe = (
-            clowered.compile(compiler_options=opts_used)
-            if opts_used else clowered.compile()
-        )
-        chlo = hlo_text_of_compiled(cexe)
-        crep = overlap_report(chlo)
-        rec = cstep.ledger.reconcile(chlo)
-        rep["chunked"] = {
-            "comm_chunks": chunks,
-            "ledger_collectives": sum(e.count for e in cstep.ledger.entries),
-            "ledger_bytes": cstep.ledger.total_bytes(),
-            "hlo_collectives": rec["hlo_collective_count"],
-            "hlo_bytes": rec["hlo_bytes"],
-            "ledger_exact": rec["exact"],
-            "n_async_collectives": crep["n_async_collectives"],
-            "n_overlapped": crep["n_overlapped"],
-            "collectives": crep["collectives"],
-            "n_sync_collectives": crep["n_sync_collectives"],
-            "n_sync_gaps_with_compute": crep["n_sync_gaps_with_compute"],
-            "sync_interleaved": crep["sync_interleaved"],
-            "sync_collectives": crep["sync_collectives"],
-            "collective_emitters": crep["collective_emitters"],
-        }
-    except Exception as e:  # noqa: BLE001 — chunked evidence is additive
-        rep["chunked"] = {"error": f"{type(e).__name__}: {e}"[:300]}
-    # an AOT-topology schedule is attached-device-independent — say so
-    # rather than stamping whatever chip happened to be attached
-    rep["device"] = (
-        "AOT (schedule is attached-device-independent)"
-        if target_mesh is not mesh
-        else _phase_probe()["device"]
-    )
-    with open(os.path.join(HERE, "OVERLAP.json"), "w") as f:
-        json.dump(rep, f, indent=1)
     summary = {
         "n_async_collectives": rep["n_async_collectives"],
         "n_overlapped": rep["n_overlapped"],
         "compiled_collectives": aud["count"],
-        "combiner_merged": rep["combiner_merged"],
+        # P, rank-1, Q, loss — reducer.py:126-147 + the loss pmean
+        "combiner_merged": aud["count"] < 4,
     }
-    if "error" not in rep["chunked"]:
-        summary["chunked"] = {
-            k: rep["chunked"][k]
-            for k in (
-                "comm_chunks", "hlo_collectives", "ledger_exact",
-                "n_overlapped", "n_sync_gaps_with_compute", "sync_interleaved",
-            )
-        }
-    else:
-        summary["chunked"] = rep["chunked"]
     return {"overlap": summary}
 
 

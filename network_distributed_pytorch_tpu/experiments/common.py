@@ -80,40 +80,20 @@ def accumulated_batches(
     return gen
 
 
-def reducer_comm_kwargs(config) -> Dict[str, Any]:
-    """The chunked-reduction knobs every reducer constructor shares
-    (``parallel.comm.chunked_all_reduce_mean``): pass as ``**kwargs`` so an
-    experiment's reducer follows ``config.comm_chunks``/``comm_strategy``
-    without each entry point re-spelling the plumbing. Empty when chunking
-    is off, keeping reducer constructors at their historical signature."""
-    if config.comm_chunks is None:
-        return {}
-    return {
-        "comm_chunks": config.comm_chunks,
-        "comm_strategy": config.comm_strategy,
-    }
-
-
 def exact_reducer_kwargs(config) -> Dict[str, Any]:
-    """``ExactReducer`` constructor kwargs from config: the shared chunking
-    knobs plus the DDP-style backward-order bucket target
-    (``config.bucket_bytes`` → ``bucket_bytes``)."""
-    kw = reducer_comm_kwargs(config)
-    if getattr(config, "bucket_bytes", None) is not None:
-        kw["bucket_bytes"] = config.bucket_bytes
-    return kw
+    """``ExactReducer`` constructor kwargs from config: the DDP-style
+    backward-order bucket target (``config.bucket_bytes`` →
+    ``bucket_bytes``)."""
+    if getattr(config, "bucket_bytes", None) is None:
+        return {}
+    return {"bucket_bytes": config.bucket_bytes}
 
 
 def powersgd_reducer_kwargs(config) -> Dict[str, Any]:
-    """``PowerSGDReducer`` constructor kwargs from config: the shared
-    chunking knobs plus the kernel-implementation overrides
-    (``compress_impl`` for the fused Pallas compress pipeline,
-    ``orthogonalize_impl`` for the Gram-Schmidt — "auto" resolves to the
+    """``PowerSGDReducer`` constructor kwargs from config: the Gram-Schmidt
+    implementation override (``orthogonalize_impl`` — "auto" resolves to the
     Pallas kernel on TPU)."""
-    kw = reducer_comm_kwargs(config)
-    kw["compress_impl"] = getattr(config, "compress_impl", "xla")
-    kw["orthogonalize_impl"] = getattr(config, "orthogonalize_impl", "auto")
-    return kw
+    return {"orthogonalize_impl": getattr(config, "orthogonalize_impl", "auto")}
 
 
 def accum_batch_sharding(mesh, accum_steps: int):
@@ -576,10 +556,10 @@ def device_fields(reducer: Any = None, attn_impl: Optional[str] = None) -> Dict:
     as jax reports it, whether Pallas kernels compiled or were interpreted,
     the kernel choices ``"auto"`` resolved to against that backend
     (``attn_impl`` is the model's configured value — a deterministic
-    forward runs what it resolves to; ``orthogonalize_impl`` /
-    ``compress_impl`` are read back off the constructed reducer), and which
-    host tier fed the data. With these on the record a run that found no
-    chip cannot be read as one that used it."""
+    forward runs what it resolves to; ``orthogonalize_impl`` is read back
+    off the constructed reducer), and which host tier fed the data. With
+    these on the record a run that found no chip cannot be read as one that
+    used it."""
     from ..native.build import host_data_tier
     from ..observe.memory import all_device_memory_stats
     from ..ops import pallas_interpret
@@ -596,9 +576,8 @@ def device_fields(reducer: Any = None, attn_impl: Optional[str] = None) -> Dict:
     }
     if attn_impl is not None:
         out["attn_impl"] = resolve_attn_impl(attn_impl)
-    for knob in ("orthogonalize_impl", "compress_impl"):
-        if getattr(reducer, knob, None) is not None:
-            out[knob] = getattr(reducer, knob)
+    if getattr(reducer, "orthogonalize_impl", None) is not None:
+        out["orthogonalize_impl"] = reducer.orthogonalize_impl
     return out
 
 
@@ -663,10 +642,10 @@ def adaptive_train_loop(
 
     ``step_factory(overrides)`` builds a :class:`CompiledStep` for one
     fallback-ladder rung (overrides: ``reducer``, ``reducer_rank``,
-    ``comm_chunks``, ``comm_strategy``, ``sync_every``); it MUST use
+    ``sync_every``); it MUST use
     ``donate_state=False`` — both guards replay steps on their inputs.
     Around every step: a :class:`resilience.guards.CollectiveWatchdog`
-    fence hook arms per-chunk deadlines (registered FIRST, so the timer is
+    fence hook arms per-collective deadlines (registered FIRST, so the timer is
     running when an injected stall sleeps), the optional
     :class:`resilience.chaos.CommFaultInjector` is advanced host-side and
     registered as the second fence hook, and the step runs inside

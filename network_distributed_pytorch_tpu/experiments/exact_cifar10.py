@@ -64,7 +64,7 @@ def run(
     recovery guards.
 
     ``config.adaptive_comm`` switches to :func:`common.adaptive_train_loop`
-    instead: collective deadline watchdogs around every fenced chunk and
+    instead: collective deadline watchdogs around every fenced collective and
     the :class:`resilience.controller.FallbackController` walking the
     reducer fallback ladder at epoch boundaries (``config.chaos_plan``
     then drives the comm-layer faults in-process — no supervisor needed,
@@ -117,11 +117,6 @@ def run(
                 "checkpoint_dir requires strategy='ddp' (the FSDP carry"
                 " restores via restore_checkpoint_sharded, not this loop)"
             )
-        if config.comm_strategy != "interleave":
-            raise ValueError(
-                "strategy='fsdp' pipelines via chunked gathers; only"
-                " comm_strategy='interleave' applies"
-            )
         step = make_fsdp_train_step(
             loss_fn,
             params,
@@ -129,7 +124,6 @@ def run(
             momentum=config.momentum,
             algorithm="sgd",
             mesh=mesh,
-            comm_chunks=config.comm_chunks,
         )
     elif adaptive:
         from ..parallel import PowerSGDReducer
@@ -148,17 +142,9 @@ def run(
                         "reducer_rank", config.reducer_rank
                     ),
                     reuse_query=config.reuse_query,
-                    comm_chunks=overrides.get("comm_chunks", config.comm_chunks),
-                    comm_strategy=overrides.get(
-                        "comm_strategy", config.comm_strategy
-                    ),
                 )
             else:
                 reducer = ExactReducer(
-                    comm_chunks=overrides.get("comm_chunks", config.comm_chunks),
-                    comm_strategy=overrides.get(
-                        "comm_strategy", config.comm_strategy
-                    ),
                     bucket_bytes=overrides.get(
                         "bucket_bytes", config.bucket_bytes
                     ),

@@ -96,29 +96,11 @@ def build_parser() -> argparse.ArgumentParser:
              " (cifar/imdb experiments)",
     )
     p.add_argument(
-        "--comm-chunks", type=int, default=None,
-        help="split each packed reduction payload into K fenced, software-"
-             "pipelined collectives (cifar experiments; DESIGN.md Round-6)",
-    )
-    p.add_argument(
-        "--comm-strategy", choices=["interleave", "ring"], default=None,
-        help="chunk reduction engine: 'interleave' (per-chunk pmean, bitwise"
-             " == monolithic) or 'ring' (explicit ppermute ring schedule,"
-             " deterministic but reassociated)",
-    )
-    p.add_argument(
         "--bucket-bytes", type=int, default=None,
         help="bucketed backward overlap (cifar exact-DDP experiments): pack"
              " gradients into ~B-byte buckets in backward production order,"
              " one fenced collective each, so early buckets' wire time"
              " overlaps the rest of the backward (DESIGN.md: raw speed)",
-    )
-    p.add_argument(
-        "--compress-impl", choices=["xla", "pallas"], default=None,
-        help="PowerSGD compress pipeline: 'pallas' runs the fused kernels"
-             " (EF add + P=MQ; Gram-Schmidt + Q=M^T P; decompress +"
-             " residual — one HBM round-trip each per shape bucket);"
-             " interpret mode off-TPU",
     )
     p.add_argument(
         "--orthogonalize-impl", choices=["auto", "xla", "pallas"],
@@ -285,7 +267,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--adaptive-comm", action="store_true",
         help="exact_cifar10 (ddp) only: degraded-fabric survival — collective"
-             " deadline watchdogs around every fenced chunk plus the"
+             " deadline watchdogs around every fenced collective plus the"
              " closed-loop reducer fallback ladder (resilience.controller);"
              " --chaos-plan then drives comm-layer faults in-process, no"
              " checkpoint_dir needed",
@@ -430,10 +412,6 @@ def apply_plan(cfg: ExperimentConfig, args) -> None:
         cfg.plan_path = args.plan
         return
     best = costmodel.canonical_config((slot.get("best") or {}).get("config"))
-    if args.comm_chunks is None and best["comm_chunks"]:
-        cfg.comm_chunks = best["comm_chunks"]
-    if args.comm_strategy is None:
-        cfg.comm_strategy = best["comm_strategy"]
     if args.bucket_bytes is None and best["bucket_bytes"]:
         cfg.bucket_bytes = best["bucket_bytes"]
     if args.reducer_rank is None and best["reducer_rank"]:
@@ -474,14 +452,8 @@ def config_from_args(args) -> ExperimentConfig:
         cfg.accum_steps = args.accum_steps
     if args.max_grad_norm is not None:
         cfg.max_grad_norm = args.max_grad_norm
-    if args.comm_chunks is not None:
-        cfg.comm_chunks = args.comm_chunks
-    if args.comm_strategy is not None:
-        cfg.comm_strategy = args.comm_strategy
     if args.bucket_bytes is not None:
         cfg.bucket_bytes = args.bucket_bytes
-    if args.compress_impl is not None:
-        cfg.compress_impl = args.compress_impl
     if args.orthogonalize_impl is not None:
         cfg.orthogonalize_impl = args.orthogonalize_impl
     if args.attn_impl is not None:
@@ -716,17 +688,6 @@ def main(argv=None) -> dict:
         raise ValueError(
             f"--max-grad-norm is not supported by {args.experiment!r}"
             f" (supported: {', '.join(_ACCUM_OK)})"
-        )
-    _CHUNKS_OK = ("exact_cifar10", "powersgd_cifar10")
-    if cfg.comm_chunks is not None and args.experiment not in _CHUNKS_OK:
-        raise ValueError(
-            f"--comm-chunks is not supported by {args.experiment!r}"
-            f" (supported: {', '.join(_CHUNKS_OK)})"
-        )
-    if cfg.comm_strategy != "interleave" and args.experiment not in _CHUNKS_OK:
-        raise ValueError(
-            f"--comm-strategy is not supported by {args.experiment!r}"
-            f" (supported: {', '.join(_CHUNKS_OK)})"
         )
     if cfg.adaptive_comm and args.experiment != "exact_cifar10":
         raise ValueError(

@@ -27,9 +27,9 @@ The model, per (config, fabric):
 - **comm**: ring-allreduce wire time ``2(W-1)/W * bytes / beta(fabric)``
   (``utils.bandwidth.allreduce_time_s``'s model) discounted by the
   measured count-weighted ``exposed_fraction`` and by the config's
-  pipeline depth (chunked/bucketed collectives expose ~1/D of the wire
-  time), plus per-collective fabric latency that *grows* with depth — the
-  chunking tradeoff, priced.
+  pipeline depth (bucketed collectives expose ~1/D of the wire time), plus
+  per-collective fabric latency that *grows* with depth — the bucketing
+  tradeoff, priced.
 - **compression**: PowerSGD's compress-side compute,
   ``~6 * rank * n_elems`` FLOPs at the calibrated effective rate; payload
   bytes scale as ``rank * bytes_fraction_per_rank`` of the dense gradient
@@ -65,18 +65,13 @@ DEFAULT_BYTES_FRACTION_PER_RANK = 1.0 / 8.0
 # PowerSGD compress-side compute: ~2 GEMM passes (P = M^T Q, Q = M P) plus
 # the Gram-Schmidt, ~6 FLOPs per payload element per rank unit
 POWERSGD_FLOPS_PER_ELEM_PER_RANK = 6.0
-# modeled pipeline depth cap: beyond this, per-chunk latency dominates and
+# modeled pipeline depth cap: beyond this, per-bucket latency dominates and
 # the linear exposure discount stops being credible
 MAX_PIPELINE_DEPTH = 64
 # floor on the calibrated compute fraction of the measured step: the
 # subtraction path (step minus modeled comm) must not calibrate compute to
 # ~zero on a comm-dominated source run
 MIN_COMPUTE_FRACTION = 0.05
-
-KNOBS = (
-    "reducer", "reducer_rank", "comm_chunks", "comm_strategy",
-    "bucket_bytes", "sync_every", "outer_async", "sites",
-)
 
 # hierarchical pricing: the inner level reduces over the fast in-node
 # fabric, so it is priced on this scalar table entry and never on a
@@ -88,7 +83,9 @@ DEFAULT_SITES = 2
 def canonical_config(config: Optional[Dict], name: str = "") -> Dict:
     """Normalize a comm config (a fallback-ladder rung's overrides, a
     ``CompileEvent.comm_config``, or a plan entry) to the canonical knob
-    dict predictions and realized runs join on."""
+    dict predictions and realized runs join on. Keys outside it are dropped:
+    an older run record or plan still carries the retired ``comm_chunks``
+    and ``comm_strategy``."""
     config = config or {}
     reducer = str(config.get("reducer") or "exact").lower()
     if "powersgd" in reducer:
@@ -102,8 +99,6 @@ def canonical_config(config: Optional[Dict], name: str = "") -> Dict:
         "name": str(config.get("name") or name or ""),
         "reducer": reducer,
         "reducer_rank": int(rank) if rank else 0,
-        "comm_chunks": int(config.get("comm_chunks") or 0),
-        "comm_strategy": str(config.get("comm_strategy") or "interleave"),
         "bucket_bytes": int(config.get("bucket_bytes") or 0),
         "sync_every": max(1, int(config.get("sync_every") or 1)),
         # two-level knobs: meaningful only for reducer="hierarchical"
@@ -121,7 +116,6 @@ def config_key(config: Dict) -> str:
     c = canonical_config(config)
     key = (
         f"reducer={c['reducer']},rank={c['reducer_rank']},"
-        f"chunks={c['comm_chunks']},strategy={c['comm_strategy']},"
         f"bucket={c['bucket_bytes']},sync={c['sync_every']}"
     )
     if c["reducer"] == "hierarchical":
@@ -274,7 +268,7 @@ def predict(
 
     ``matrix`` is an optional measured per-edge fabric matrix
     (``observe.fabric`` / ``artifacts/fabric_matrix.json``). When present,
-    the ring terms price against the SLOWEST measured edge — every chunk
+    the ring terms price against the SLOWEST measured edge — every shard
     of a ring reduction traverses every link, so the worst link gates the
     whole collective — instead of the named fabric's scalar."""
     model = _load_utils_module("bandwidth").fabric_model(matrix)
@@ -298,15 +292,14 @@ def predict(
         wire_bytes = calib.dense_bytes
         n_coll = calib.n_collectives
 
-    # pipeline depth: chunked and bucketed configs decompose the payload
-    # into D fenced collectives; ~1/D of the wire time stays exposed, but
-    # every segment pays the fabric's latency
-    chunks = c["comm_chunks"] or 1
+    # pipeline depth: a bucketed config decomposes the payload into D
+    # fenced collectives; ~1/D of the wire time stays exposed, but every
+    # bucket pays the fabric's latency
     n_buckets = (
         max(1, math.ceil(wire_bytes / c["bucket_bytes"]))
         if c["bucket_bytes"] else 1
     )
-    depth = min(MAX_PIPELINE_DEPTH, max(chunks, n_buckets))
+    depth = min(MAX_PIPELINE_DEPTH, n_buckets)
 
     wire_s = (
         (2.0 * (w - 1) / w) * (wire_bytes / beta) if w > 1 and beta > 0 else 0.0
@@ -551,14 +544,12 @@ def ladder_configs(ladder=None) -> List[Dict]:
 
 def default_configs(calib: Optional[CostCalibration] = None) -> List[Dict]:
     """The planner's search space: every fallback-ladder rung plus the
-    chunk/bucket variants the ladder does not enumerate. Bucket targets
+    rank/bucket variants the ladder does not enumerate. Bucket targets
     derive from the calibrated dense payload so they stay meaningful at
     any model size."""
     configs = ladder_configs()
     seen = {config_key(c) for c in configs}
     extras: List[Dict] = [
-        {"name": "chunked-2", "comm_chunks": 2},
-        {"name": "ring-4", "comm_chunks": 4, "comm_strategy": "ring"},
         {"name": "compress-r2", "reducer": "powersgd", "reducer_rank": 2},
     ]
     if calib is not None and calib.dense_bytes > 0:

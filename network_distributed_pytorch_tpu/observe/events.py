@@ -162,8 +162,7 @@ class CompileEvent(Event):
     generated_code_bytes: Optional[float] = None
     peak_hbm_bytes: Optional[float] = None  # the split's sum (predicted peak)
     # the comm knobs the step was compiled with (``reducer``,
-    # ``reducer_rank``, ``comm_chunks``, ``comm_strategy``,
-    # ``bucket_bytes``) — what lets the offline cost model
+    # ``reducer_rank``, ``bucket_bytes``) — what lets the offline cost model
     # (:mod:`observe.costmodel`) identify WHICH config a run executed and
     # join its predictions against the measured step time
     comm_config: Dict = field(default_factory=dict)
@@ -433,7 +432,7 @@ class PolicyEvent(Event):
     that forced the move (deadline expiries, degraded steps, straggler
     flags, achieved-bandwidth collapse, or a sustained healthy streak);
     ``overrides`` is the new rung's knob dict (``reducer``,
-    ``comm_chunks``, ``comm_strategy``, ...) so the record alone is
+    ``reducer_rank``, ``sync_every``, ...) so the record alone is
     enough to reproduce the reconfiguration. ``predicted_bytes_per_step``
     is the NEW rung's static wire-ledger cost, ``realized_bytes_per_step``
     the measured cost at the OLD rung — the pair is the controller's

@@ -14,10 +14,8 @@ from .comm import (  # noqa: F401
     all_reduce_mean,
     all_gather,
     all_gather_replicated,
-    chunk_bounds,
-    chunked_all_reduce_mean,
     fence,
-    ring_all_reduce_mean,
+    tagged_all_reduce_mean,
 )
 from .packing import TensorPacker  # noqa: F401
 from .hierarchical import (  # noqa: F401

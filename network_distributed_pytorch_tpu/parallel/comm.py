@@ -16,30 +16,18 @@ number at runtime from tensor metadata). Like the reference, bits are counted
 per logical collective payload regardless of world size
 (``reducer.py:127,133,146`` increment unconditionally).
 
-Chunked, software-pipelined reduction (DESIGN.md Round-6): a monolithic
-blocking all-reduce serializes the whole wire time behind compute — the
-regime the paper's slow-network studies care about. :func:`chunk_bounds` +
-:func:`chunked_all_reduce_mean` split a flat payload into K chunks, issue
-one collective per chunk, and fence consecutive chunks with
-``lax.optimization_barrier`` so (a) XLA's all-reduce combiner cannot merge
-them back into one op and (b) the launch order is pinned — chunk *i*'s
-retire compute depends only on chunk *i*'s result, so the latency-hiding
-scheduler is free to run it while chunk *i+1* is on the wire. The default
-``"interleave"`` strategy reduces each chunk with ``pmean`` and is
-**bitwise identical** to the monolithic reduction (an all-reduce is
-elementwise; slicing commutes with it). The opt-in ``"ring"`` strategy
-(:func:`ring_all_reduce_mean`) spells the reduce-scatter/all-gather ring
-out as ``lax.ppermute`` stages — deterministic, but it reassociates the
-cross-worker sum (each shard is summed in a different rotation of rank
-order), so it is exact only on dyadic values and ~1 ulp off otherwise;
-see DESIGN.md Round-6 for why both exist.
+Every reducer payload reaches the wire through ONE entry,
+:func:`tagged_all_reduce_mean`: a ``pmean`` that carries the payload's ledger
+tag and, when host fence hooks are registered at trace time, is bracketed by
+their ``launch`` / ``retire`` callbacks (comm fault injection, collective
+deadline watchdogs). With no hook registered it traces to the bare ``pmean``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +38,7 @@ import numpy as np
 # topology-blind; the hierarchical reducer runs the SAME reducer code per
 # fabric level and needs the level visible in every fence-hook info dict
 # and ledger line. ``tag_scope("outer")`` prefixes every tag that
-# :func:`chunked_all_reduce_mean` burns into its callbacks while the scope
+# :func:`tagged_all_reduce_mean` burns into its callbacks while the scope
 # is active — at TRACE time, like the hook-presence gate, so the compiled
 # program carries "outer.powersgd.P" etc. and watchdogs/chaos injectors can
 # filter by level without the reducer knowing it was nested.
@@ -74,26 +62,25 @@ def scoped_tag(tag: str) -> str:
         return tag
     return ".".join(_TAG_SCOPE + [tag])
 
-# Host-side chunk fence hooks (degraded-fabric survival, DESIGN.md): a hook
-# is a plain Python callable invoked ON THE HOST at every chunk fence point
-# of :func:`chunked_all_reduce_mean` — once per device per execution, with
-# an info dict {tag, chunk, n_chunks, payload_bytes, phase, device_index}
-# where phase is "launch" (the chunk payload is about to ride its
-# collective) or "retire" (the reduced result is available). The insertion
-# is an ordered ``io_callback`` whose token is fenced into the dataflow, so
-# a sleeping hook genuinely delays the collective (comm fault injection)
-# and a timing hook genuinely brackets it (collective deadline watchdogs) —
-# while the callback itself issues NO collectives, leaving the wire ledger
-# byte-exact (schedule_smoke counts only collectives). Hooks are consulted
-# at TRACE time: with no hook registered the compiled graph is bit-for-bit
-# the pre-hook graph; registered hooks are late-bound (the host shim reads
-# the registry at call time), so the active hook set may change between
-# executions without recompiling.
+# Host-side fence hooks (degraded-fabric survival, DESIGN.md): a hook is a
+# plain Python callable invoked ON THE HOST on either side of every
+# :func:`tagged_all_reduce_mean` — once per device per execution, with an
+# info dict {tag, payload_bytes, phase, device_index} where phase is
+# "launch" (the payload is about to ride its collective) or "retire" (the
+# reduced result is available). The insertion is an ``io_callback`` whose
+# token is fenced into the dataflow, so a sleeping hook genuinely delays
+# the collective (comm fault injection) and a timing hook genuinely
+# brackets it (collective deadline watchdogs) — while the callback itself
+# issues NO collectives, leaving the wire ledger byte-exact. Hooks are
+# consulted at TRACE time: with no hook registered the compiled graph is
+# bit-for-bit the pre-hook graph; registered hooks are late-bound (the host
+# shim reads the registry at call time), so the active hook set may change
+# between executions without recompiling.
 _FENCE_HOOKS: List[Callable[[Dict], None]] = []
 
 
 def add_fence_hook(fn: Callable[[Dict], None]) -> None:
-    """Register a host-side chunk fence hook (see module note). Hooks run
+    """Register a host-side fence hook (see module note). Hooks run
     in registration order — register watchdogs BEFORE injectors so the
     deadline timer is armed when an injected stall starts sleeping."""
     _FENCE_HOOKS.append(fn)
@@ -113,14 +100,9 @@ def fence_hooks_active() -> bool:
     return bool(_FENCE_HOOKS)
 
 
-def _run_fence_hooks(
-    device_index, *, tag: str, chunk: int, n_chunks: int,
-    payload_bytes: int, phase: str
-):
+def _run_fence_hooks(device_index, *, tag: str, payload_bytes: int, phase: str):
     info = {
         "tag": tag,
-        "chunk": chunk,
-        "n_chunks": n_chunks,
         "payload_bytes": payload_bytes,
         "phase": phase,
         "device_index": int(device_index),
@@ -130,28 +112,26 @@ def _run_fence_hooks(
     return np.int32(0)
 
 
-def _chunk_callback(
-    carry: jax.Array, *, tag: str, chunk: int, n_chunks: int,
-    payload_bytes: int, phase: str, axis_name: Optional[str]
+def _fence_callback(
+    carry: jax.Array, *, tag: str, payload_bytes: int, phase: str,
+    axis_name: Optional[str]
 ) -> jax.Array:
-    """Fence a host callback into ``carry``'s dataflow at a chunk boundary:
-    the callback's token and the carried value pass through one
+    """Fence a host callback into ``carry``'s dataflow on one side of a
+    collective: the callback's token and the carried value pass through one
     ``optimization_barrier``, so XLA can neither hoist the collective above
     the callback nor sink the callback past the result.
 
     ``ordered=False`` deliberately: ordering comes from DATAFLOW, not the
     global token chain — each callback's token is fenced into its own
-    chunk's payload (launch) or the concatenated result (retire), and the
-    chunk pipeline itself is barrier-chained, so per-device callback order
-    follows the chunk schedule exactly. (``ordered=True`` also trips an
-    XLA sharding-propagation check on jaxlib 0.4.37 when the enclosing jit
-    carries explicit shardings: the ordering token becomes an extra entry
-    parameter the propagation vector doesn't cover.)"""
+    payload (launch) or result (retire), so per-device callback order
+    follows the collectives' data dependencies. (``ordered=True`` also trips
+    an XLA sharding-propagation check on jaxlib 0.4.37 when the enclosing
+    jit carries explicit shardings: the ordering token becomes an extra
+    entry parameter the propagation vector doesn't cover.)"""
     from jax.experimental import io_callback
 
     shim = functools.partial(
-        _run_fence_hooks, tag=tag, chunk=chunk, n_chunks=n_chunks,
-        payload_bytes=payload_bytes, phase=phase,
+        _run_fence_hooks, tag=tag, payload_bytes=payload_bytes, phase=phase,
     )
     token = io_callback(
         shim,
@@ -223,27 +203,6 @@ def all_gather_replicated(x: jax.Array, axis_name: Optional[str]) -> jax.Array:
     return all_gather_invariant(x, axis_name)
 
 
-def chunk_bounds(total: int, n_chunks: int) -> List[Tuple[int, int]]:
-    """Static ``(start, end)`` boundaries splitting ``total`` elements into
-    ``n_chunks`` balanced chunks (the first ``total % n_chunks`` chunks carry
-    one extra element, so the tail chunks are the ragged ones). ``n_chunks``
-    is clamped to ``[1, total]`` — every chunk is non-empty, and the chunk
-    count is exactly ``min(n_chunks, total)``. Pure Python: usable at trace
-    time and in ledger/bits bookkeeping alike."""
-    total = int(total)
-    if total <= 0:
-        return []
-    k = max(1, min(int(n_chunks), total))
-    base, rem = divmod(total, k)
-    bounds = []
-    start = 0
-    for i in range(k):
-        end = start + base + (1 if i < rem else 0)
-        bounds.append((start, end))
-        start = end
-    return bounds
-
-
 def bucket_assignments(
     sizes_bytes: List[int], bucket_bytes: int
 ) -> List[List[int]]:
@@ -260,7 +219,7 @@ def bucket_assignments(
     schedule.
 
     Pure Python over static sizes — usable at trace time and in
-    ledger/bits bookkeeping alike (like :func:`chunk_bounds`). Every bucket
+    ledger/bits bookkeeping alike. Every bucket
     is non-empty; indices *within* a bucket stay in ascending order so the
     per-bucket packer layout is deterministic. ``bucket_bytes`` clamps to
     >= 1 byte; a target at or above the total yields one bucket.
@@ -284,134 +243,36 @@ def fence(*values):
     """``lax.optimization_barrier`` over one or more pytrees: the returned
     values are identical but XLA may neither reorder computations across the
     barrier nor fuse ops on opposite sides of it. This is the pin that keeps
-    a decomposed chunk schedule decomposed — without it the all-reduce
-    combiner pass is free to re-merge the per-chunk collectives into the
-    monolithic op the decomposition exists to avoid (observed on v5e:
-    4 logical → 2 compiled collectives, OVERLAP.json round-5)."""
+    ``ExactReducer``'s bucket chain in launch order and a fence hook's
+    callback on its side of the collective."""
     if not values:
         return values
     out = jax.lax.optimization_barrier(values)
     return out[0] if len(values) == 1 else out
 
 
-def chunked_all_reduce_mean(
-    flat: jax.Array,
-    axis_name: Optional[str],
-    n_chunks: Optional[int],
-    strategy: str = "interleave",
-    tag: str = "payload",
+def tagged_all_reduce_mean(
+    flat: jax.Array, axis_name: Optional[str], tag: str = "payload"
 ) -> jax.Array:
-    """Software-pipelined chunked allreduce-mean of a flat buffer.
-
-    ``chunk_bounds`` splits ``flat`` into K chunks; each chunk rides its own
-    collective (``"interleave"`` → ``pmean`` per chunk, bitwise identical to
-    the monolithic reduction; ``"ring"`` → explicit ``ppermute``
-    reduce-scatter/all-gather, see :func:`ring_all_reduce_mean`). Chunk
-    *i*'s payload is fenced against chunk *i-1*'s **result** with
-    ``optimization_barrier``, which (a) stops the combiner from re-fusing
-    the pipeline and (b) orders the launches — while leaving the consumers
-    of chunk *i-1*'s result dependent only on that chunk, so the scheduler
-    overlaps their compute with chunk *i*'s wire time.
-
-    ``n_chunks=None`` (or a single-chunk split) degrades to the plain
-    monolithic path. Wire bytes are invariant in K: the chunk payloads are
-    a partition of the flat buffer.
+    """Allreduce-mean of one payload under its ledger ``tag`` (prefixed by
+    the active :func:`tag_scope`) — the single entry every reducer payload
+    takes to the wire.
 
     When fence hooks are registered at trace time (see
-    :func:`add_fence_hook`), every chunk launch and the final retire get an
-    ordered host callback fenced into the dataflow, tagged with ``tag`` —
-    on BOTH the chunked and the monolithic path, so comm faults and
-    deadline watchdogs bite even at the un-chunked baseline rung.
+    :func:`add_fence_hook`), the collective is bracketed by a ``launch`` and
+    a ``retire`` host callback fenced into the dataflow, so comm faults and
+    deadline watchdogs bite on every collective. With none registered this
+    is exactly :func:`all_reduce_mean`.
     """
-    assert strategy in ("interleave", "ring"), strategy
-    tag = scoped_tag(tag)
-    reduce_one = ring_all_reduce_mean if strategy == "ring" else all_reduce_mean
-    bounds = chunk_bounds(flat.size, n_chunks if n_chunks is not None else 1)
-    hooked = fence_hooks_active()
-    itemsize = flat.dtype.itemsize
-    total_bytes = int(flat.size) * itemsize
-    if len(bounds) <= 1:
-        if hooked:
-            flat = _chunk_callback(
-                flat, tag=tag, chunk=0, n_chunks=1,
-                payload_bytes=total_bytes, phase="launch",
-                axis_name=axis_name,
-            )
-        out = reduce_one(flat, axis_name)
-        if hooked:
-            out = _chunk_callback(
-                out, tag=tag, chunk=1, n_chunks=1,
-                payload_bytes=total_bytes, phase="retire",
-                axis_name=axis_name,
-            )
-        return out
-    prev = None
-    outs = []
-    k = len(bounds)
-    for idx, (start, end) in enumerate(bounds):
-        chunk = jax.lax.slice(flat, (start,), (end,))
-        if prev is not None:
-            chunk, prev = fence(chunk, prev)
-        if hooked:
-            chunk = _chunk_callback(
-                chunk, tag=tag, chunk=idx, n_chunks=k,
-                payload_bytes=(end - start) * itemsize, phase="launch",
-                axis_name=axis_name,
-            )
-        prev = reduce_one(chunk, axis_name)
-        outs.append(prev)
-    out = jnp.concatenate(outs)
-    if hooked:
-        out = _chunk_callback(
-            out, tag=tag, chunk=k, n_chunks=k,
-            payload_bytes=total_bytes, phase="retire",
-            axis_name=axis_name,
-        )
-    return out
-
-
-def ring_all_reduce_mean(x: jax.Array, axis_name: Optional[str]) -> jax.Array:
-    """Allreduce-mean spelled out as the classic bidirectional-bandwidth-
-    optimal ring: a ``ppermute`` reduce-scatter (W-1 rotations with
-    in-transit accumulation) followed by a ``ppermute`` all-gather (W-1 more
-    rotations), each stage data-dependent on the previous so the schedule IS
-    the ring. The payload is padded to ``W·ceil(n/W)`` and sliced back.
-
-    Determinism/exactness: every device applies the SAME rotation schedule,
-    so results are deterministic and identical across devices — but shard
-    *s* is summed in rank order ``s, s-1, …`` (a rotation of ``0…W-1`` that
-    differs per shard), which REASSOCIATES the floating-point sum relative
-    to ``pmean``. Exact on dyadic values (integers in float), within ~1 ulp
-    otherwise. The default chunk strategy is ``"interleave"`` for exactly
-    this reason; the ring is the explicit-schedule variant for meshes whose
-    native all-reduce underperforms (or for studying the schedule itself).
-
-    Identity when ``axis_name`` is None or the axis has a single worker.
-    """
-    if axis_name is None:
-        return x
-    world = axis_size(axis_name)
-    if world == 1 or x.size == 0:
-        return x
-    n = int(x.size)
-    shard = -(-n // world)  # ceil: per-device shard length
-    buf = jnp.pad(x.reshape(-1), (0, world * shard - n)).reshape(world, shard)
-    forward = [(j, (j + 1) % world) for j in range(world)]
-    i = jax.lax.axis_index(axis_name)
-    # reduce-scatter: at step t device i sends its running shard (i - t) and
-    # folds the received shard (i - t - 1) into its accumulator; after W-1
-    # steps shard (i + 1) % W is fully summed on device i
-    for t in range(world - 1):
-        send = jnp.take(buf, (i - t) % world, axis=0)
-        recv = jax.lax.ppermute(send, axis_name, forward)
-        buf = buf.at[(i - t - 1) % world].add(recv)
-    # all-gather: rotate the completed shard around the ring; at step t
-    # device i receives shard (i - t) % W, completed W-1 hops upstream
-    cur = jnp.take(buf, (i + 1) % world, axis=0)
-    for t in range(world - 1):
-        cur = jax.lax.ppermute(cur, axis_name, forward)
-        buf = buf.at[(i - t) % world].set(cur)
-    return (buf.reshape(-1)[:n] / world).astype(x.dtype).reshape(x.shape)
+    if not fence_hooks_active():
+        return all_reduce_mean(flat, axis_name)
+    callback = functools.partial(
+        _fence_callback, tag=scoped_tag(tag),
+        payload_bytes=int(flat.size) * flat.dtype.itemsize,
+        axis_name=axis_name,
+    )
+    out = all_reduce_mean(callback(flat, phase="launch"), axis_name)
+    return callback(out, phase="retire")
 
 
 def axis_size(axis_name: Optional[str]) -> int:

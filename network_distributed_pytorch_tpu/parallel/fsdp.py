@@ -28,13 +28,13 @@ like everything else (reference ``reducer.py:197-198`` analytic model).
 
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from .comm import all_reduce_mean, chunk_bounds, fence
+from .comm import all_reduce_mean
 from .mesh import DATA_AXIS
 from .trainer import LossFn
 
@@ -161,7 +161,6 @@ def make_fsdp_train_step(
     axis_name: str = DATA_AXIS,
     donate_state: bool = True,
     optimizer=None,
-    comm_chunks: Optional[int] = None,
 ) -> CompiledFSDPStep:
     """Compile the fully-sharded training step.
 
@@ -171,20 +170,10 @@ def make_fsdp_train_step(
     "sgd_nesterov", "optax"} with torch ``optim.SGD`` semantics (the exact-DDP
     trainer's optimizer, ``ddp_guide_cifar10/ddp_init.py:110``); elementwise
     optimizers apply shard-wise unchanged.
-
-    ``comm_chunks=K`` splits each leaf's parameter all-gather into up to K
-    fenced chunk gathers (``comm.chunk_bounds`` over the local shard) —
-    reverse-mode AD transposes each chunk gather into its OWN
-    ``psum_scatter``, so the ZeRO gradient reduce-scatter decomposes into
-    the same pipelined chunk schedule for free. Results are bitwise
-    identical to the monolithic step (gathers are data movement; each
-    chunk's scatter sums the same elements in the same rank order) and the
-    ledger bytes are K-invariant.
     """
     assert mesh is not None, "FSDP is inherently multi-device; pass a mesh"
     assert algorithm in ("sgd", "sgd_plain", "sgd_nesterov", "optax")
     assert (algorithm == "optax") == (optimizer is not None)
-    assert comm_chunks is None or comm_chunks >= 1
     world = int(mesh.shape[axis_name])
     templates = jax.tree_util.tree_map(
         lambda p: jax.ShapeDtypeStruct(jnp.shape(p), jnp.asarray(p).dtype),
@@ -209,25 +198,7 @@ def make_fsdp_train_step(
     def gather_full(shard, tmpl):
         # (chunk,) local shard -> full (…shape); AD transposes the tiled
         # all_gather into psum_scatter — the ZeRO gradient reduce-scatter.
-        if comm_chunks is None or len(chunk_bounds(shard.shape[0], comm_chunks)) <= 1:
-            flat = jax.lax.all_gather(shard, axis_name, tiled=True)
-            return flat[: tmpl.size].reshape(tmpl.shape)
-        # chunked: gather K fenced sub-ranges of the local shard; a tiled
-        # gather of piece j is (world · piece_j,) laid out per-device, so
-        # the full flat buffer is the per-device pieces re-concatenated.
-        # The fence chains chunk j's payload to chunk j-1's gathered result
-        # (and, transposed, chunk j's cotangent to chunk j-1's scattered
-        # gradient — the barrier is linear, so jax differentiates through
-        # it), which
-        # pins the pipeline in BOTH directions.
-        pieces, prev = [], None
-        for start, end in chunk_bounds(shard.shape[0], comm_chunks):
-            piece = jax.lax.slice(shard, (start,), (end,))
-            if prev is not None:
-                piece, prev = fence(piece, prev)
-            prev = jax.lax.all_gather(piece, axis_name, tiled=True)
-            pieces.append(prev.reshape(world, end - start))
-        flat = jnp.concatenate(pieces, axis=1).reshape(-1)
+        flat = jax.lax.all_gather(shard, axis_name, tiled=True)
         return flat[: tmpl.size].reshape(tmpl.shape)
 
     def step(state: FSDPState, batch):
@@ -329,12 +300,6 @@ def make_fsdp_train_step(
 
     from ..observe.ledger import LedgerEntry, WireLedger, loss_sync_entry
 
-    # collective count per direction: one per leaf, or per leaf-chunk when
-    # the gather is decomposed (payload bytes are K-invariant either way)
-    n_gathers = sum(
-        len(chunk_bounds(_chunk_size(int(t.size), world), comm_chunks or 1))
-        for t in leaves
-    )
     dtypes = {str(t.dtype) for t in leaves}
     dtype = dtypes.pop() if len(dtypes) == 1 else "mixed"
     ledger = WireLedger(
@@ -346,7 +311,7 @@ def make_fsdp_train_step(
                 axis=axis_name,
                 dtype=dtype,
                 payload_bytes=gather_bits // 8,
-                count=n_gathers,
+                count=len(leaves),
             ),
             LedgerEntry(
                 tag="fsdp.grad-scatter",
@@ -355,7 +320,7 @@ def make_fsdp_train_step(
                 axis=axis_name,
                 dtype=dtype,
                 payload_bytes=gather_bits // 8,
-                count=n_gathers,
+                count=len(leaves),
             ),
             loss_sync_entry(axis_name),
         ],

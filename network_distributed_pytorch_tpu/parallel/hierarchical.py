@@ -73,7 +73,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
-from .comm import chunked_all_reduce_mean, n_bits, tag_scope
+from .comm import n_bits, tag_scope, tagged_all_reduce_mean
 from .packing import TensorPacker
 
 PyTree = Any
@@ -83,7 +83,7 @@ AxisName = Union[str, Tuple[str, ...], None]
 def _packed_exact_mean(tree: PyTree, axis_name: str, tag: str) -> PyTree:
     """Exact allreduce-mean of a whole pytree as ONE packed collective
     (``TensorBuffer`` style — many tiny leaves cost one wire payload),
-    routed through :func:`~.comm.chunked_all_reduce_mean` so fence hooks
+    routed through :func:`~.comm.tagged_all_reduce_mean` so fence hooks
     (chaos faults, deadline watchdogs) and tag scoping apply. Bitwise
     identical to per-leaf ``pmean`` (an all-reduce is elementwise; packing
     is a permutation). Mixed-dtype trees fall back to one collective per
@@ -101,7 +101,7 @@ def _packed_exact_mean(tree: PyTree, axis_name: str, tag: str) -> PyTree:
         packer = TensorPacker.for_arrays(group)
         flat = packer.pack(group)
         gtag = f"{tag}.d{gi}" if multi else tag
-        reduced = chunked_all_reduce_mean(flat, axis_name, 1, tag=gtag)
+        reduced = tagged_all_reduce_mean(flat, axis_name, tag=gtag)
         for i, r in zip(idx, packer.unpack(reduced)):
             out[i] = r
     return jax.tree_util.tree_unflatten(treedef, out)
@@ -542,8 +542,7 @@ def make_hierarchical_train_fn(
             params, model_state, batch
         )
         # exact DDP over the fast fabric ONLY — the inner path issues no
-        # outer-axis collective (schedule_smoke pins this on the local
-        # round's HLO)
+        # outer-axis collective
         with tag_scope("inner"):
             grads = _packed_exact_mean(grads, inner_axis, tag="step_grads")
         if inner_algorithm == "optax":

@@ -21,17 +21,9 @@ Everything traces into one XLA computation under ``jit``/``shard_map``:
 - The reference's async rank-1 allreduce overlapped with orthogonalization
   (``reducer.py:131-137``) needs no handles here: the rank-1 ``pmean`` is
   issued in trace order between the P collective and the Gram-Schmidt, and
-  the compiler owns the schedule. What the compiled v5e executable actually
-  does (measured, ``OVERLAP.json``) is stronger than hiding the collective:
-  XLA's all-reduce **combiner merges the rank-1 payload into the Q
-  all-reduce** — the separate collective the reference could only overlap
-  is eliminated outright (4 logical → 2 compiled collectives) — and the
-  surviving all-reduces run as pipelined ICI ring transfers inside the TPU
-  collective emitter (``RotatedPincerShortEmitter/StrategyRing`` in the
-  op's backend_config) while the latency-hiding scheduler overlaps the
-  HBM DMA ``copy-start``/``copy-done`` windows with compute (hundreds of
-  windows, nearly all with compute inside — 475/490 on the ResNet-50
-  step — counted in the same artifact).
+  the compiler owns the schedule (on the chip XLA's all-reduce combiner
+  merges the rank-1 payload and the loss sync into the Q all-reduce: the
+  four-chip cell compiles two all-reduces for the ledger's four lines).
 - The shared-seed no-communication Q init (``reducer.py:36-41``: every worker
   seeds the same RNG, so Q is identical everywhere for free) becomes "same
   PRNGKey on every worker" — identical by construction.
@@ -58,23 +50,13 @@ from ..ops.orthogonalize import orthogonalize
 from .comm import (
     all_reduce_mean,
     bucket_assignments,
-    chunk_bounds,
-    chunked_all_reduce_mean,
     fence,
     n_bits,
+    tagged_all_reduce_mean,
 )
 from .packing import TensorPacker
 
 PyTree = Any
-
-
-def _n_chunk_collectives(total_size: int, comm_chunks: Optional[int]) -> int:
-    """How many collectives a flat payload of ``total_size`` elements costs
-    under the chunk engine (1 when chunking is off or the payload is empty
-    enough that ``chunk_bounds`` clamps)."""
-    if comm_chunks is None or total_size <= 0:
-        return 1
-    return len(chunk_bounds(total_size, comm_chunks))
 
 
 class ExactReducer:
@@ -89,15 +71,6 @@ class ExactReducer:
     the reference's one-collective-per-tensor structure (for the bandwidth
     study's latency-term comparison).
 
-    ``comm_chunks=K`` splits the packed flat buffer into K chunks riding K
-    fenced collectives (``comm.chunked_all_reduce_mean``): chunk *i*'s
-    unpack/astype retire compute overlaps chunk *i+1*'s wire time under the
-    latency-hiding scheduler. Bitwise identical to the monolithic path and
-    byte-invariant on the ledger (the chunks partition the same buffer).
-    ``comm_strategy="ring"`` swaps each chunk's pmean for the explicit
-    ``ppermute`` ring schedule (deterministic, reassociated — see
-    ``comm.ring_all_reduce_mean``).
-
     ``bucket_bytes=B`` is the DDP bucketed-backward-overlap structure
     (``comm.bucket_assignments``): leaves are assigned to ~B-byte buckets
     in REVERSE leaf order — gradient *production* order in the backward
@@ -105,38 +78,20 @@ class ExactReducer:
     collective's operands are ready as soon as the backward has produced
     that bucket's gradients. Consecutive bucket launches are fenced
     (``optimization_barrier``) to pin the DDP launch order and keep the
-    all-reduce combiner from re-merging the buckets; each bucket still
-    rides the chunked engine (``comm_chunks`` applies per bucket). An
-    all-reduce is elementwise, so partitioning the payload commutes with
-    it: the bucketed reduction is **bitwise identical** to the monolithic
-    one, and ledger bytes are invariant (the buckets partition the leaves).
+    all-reduce combiner from re-merging the buckets. An all-reduce is
+    elementwise, so partitioning the payload commutes with it: the bucketed
+    reduction is **bitwise identical** to the monolithic one, and ledger
+    bytes are invariant (the buckets partition the leaves).
     """
 
-    def __init__(
-        self,
-        packed: bool = True,
-        comm_chunks: Optional[int] = None,
-        comm_strategy: str = "interleave",
-        bucket_bytes: Optional[int] = None,
-    ):
-        assert comm_strategy in ("interleave", "ring"), comm_strategy
-        assert comm_chunks is None or comm_chunks >= 1
-        # chunking decomposes the ONE packed collective; the unpacked path
-        # is already per-tensor (the latency-study structure) and has no
-        # flat buffer to split
-        assert comm_chunks is None or packed, "comm_chunks requires packed=True"
-        # bucketing likewise re-partitions the packed payload
+    def __init__(self, packed: bool = True, bucket_bytes: Optional[int] = None):
+        # bucketing re-partitions the ONE packed payload; the unpacked path
+        # is already per-tensor (the latency-study structure)
         assert bucket_bytes is None or (packed and bucket_bytes >= 1), (
             "bucket_bytes requires packed=True"
         )
         self.packed = packed
-        self.comm_chunks = comm_chunks
-        self.comm_strategy = comm_strategy
         self.bucket_bytes = bucket_bytes
-
-    def _n_chunks(self, leaves) -> int:
-        total = sum(int(l.size) for l in leaves)
-        return _n_chunk_collectives(total, self.comm_chunks)
 
     def _buckets(self, leaves) -> List[List[int]]:
         """Leaf-index buckets in backward (production) order; one bucket
@@ -154,12 +109,7 @@ class ExactReducer:
         leaves = jax.tree_util.tree_leaves(grads_template)
         if not self.packed:
             return len(leaves)
-        return sum(
-            _n_chunk_collectives(
-                sum(int(leaves[i].size) for i in idxs), self.comm_chunks
-            )
-            for idxs in self._buckets(leaves)
-        )
+        return len(self._buckets(leaves))
 
     # named_scope: label the reduction's HLO so device traces attribute
     # collective/compress time to the reducer (pairs with the host-side
@@ -187,9 +137,8 @@ class ExactReducer:
                 flat = packer.pack(blk)
                 if prev is not None:
                     flat, prev = fence(flat, prev)
-                reduced = chunked_all_reduce_mean(
-                    flat, axis_name, self.comm_chunks, self.comm_strategy,
-                    tag=f"grads.b{bi}",
+                reduced = tagged_all_reduce_mean(
+                    flat, axis_name, tag=f"grads.b{bi}"
                 )
                 prev = reduced
                 bits += packer.bits()
@@ -198,14 +147,7 @@ class ExactReducer:
         elif self.packed:
             packer = TensorPacker.for_arrays(leaves)
             flat = packer.pack(leaves)
-            # always through the chunked engine: with comm_chunks=None this
-            # degrades to the identical monolithic pmean, but the shared
-            # path carries the fence-hook callbacks (comm fault injection /
-            # deadline watchdogs) even at the un-chunked baseline rung
-            reduced = chunked_all_reduce_mean(
-                flat, axis_name, self.comm_chunks, self.comm_strategy,
-                tag="grads",
-            )
+            reduced = tagged_all_reduce_mean(flat, axis_name, tag="grads")
             bits = packer.bits()
             out_leaves = [
                 o.astype(l.dtype) for o, l in zip(packer.unpack(reduced), leaves)
@@ -227,9 +169,8 @@ class ExactReducer:
         axis_name: Optional[str],
     ) -> Tuple[dict, PyTree, PyTree, int]:
         """Error-feedback entry point (``send = grads + memories`` then
-        :meth:`reduce`) — the uniform protocol the trainer calls so reducers
-        that CAN fuse the add (``PowerSGDReducer`` with
-        ``compress_impl="pallas"``) get the separated operands."""
+        :meth:`reduce`) — the protocol the trainer's ``ef_momentum`` step
+        calls, shared with ``PowerSGDReducer``."""
         send = jax.tree_util.tree_map(jnp.add, grads, memories)
         return self.reduce(state, send, axis_name)
 
@@ -312,10 +253,9 @@ class ExactReducer:
     def ledger_entries(self, grads_template: PyTree, axis: str = "", n_workers: int = 1):
         """Wire-ledger itemization of one exact reduction: the whole gradient
         as one flat-packed all-reduce (or, unpacked, one per-tensor all-reduce
-        batch; chunked, one all-reduce per chunk — the chunk payloads
-        partition the flat buffer, so ``payload_bytes`` is K-invariant;
-        bucketed, one entry per backward-order bucket tagged ``grads.b{i}``
-        — the buckets partition the leaves, so total bytes stay put).
+        batch; bucketed, one entry per backward-order bucket tagged
+        ``grads.b{i}`` — the buckets partition the leaves, so total bytes
+        stay put).
         Sums to ``reduce``'s analytic ``bits``."""
         from ..observe.ledger import LedgerEntry
 
@@ -323,7 +263,7 @@ class ExactReducer:
         if not leaves:
             return []
 
-        def _entry(tag, idxs, count):
+        def _entry(tag, idxs, count=1):
             dtypes = {str(leaves[i].dtype) for i in idxs}
             return LedgerEntry(
                 tag=tag,
@@ -339,23 +279,11 @@ class ExactReducer:
 
         if self.packed and self.bucket_bytes is not None:
             return [
-                _entry(
-                    f"grads.b{bi}",
-                    idxs,
-                    _n_chunk_collectives(
-                        sum(int(leaves[i].size) for i in idxs), self.comm_chunks
-                    ),
-                )
+                _entry(f"grads.b{bi}", idxs)
                 for bi, idxs in enumerate(self._buckets(leaves))
             ]
         all_idx = list(range(len(leaves)))
-        return [
-            _entry(
-                "grads",
-                all_idx,
-                self._n_chunks(leaves) if self.packed else len(leaves),
-            )
-        ]
+        return [_entry("grads", all_idx, 1 if self.packed else len(leaves))]
 
 
 class _MatrixMeta(NamedTuple):
@@ -395,30 +323,10 @@ class PowerSGDReducer:
     (HWIO conv kernels / (in, out) dense kernels put output features last).
     Both give the same (n+m)·r wire cost up to transposition.
 
-    ``comm_chunks=K`` runs every payload (P, Q, rank-1) through the fenced
-    chunk engine (``comm.chunked_all_reduce_mean``): each buffer splits into
-    up to K per-chunk collectives whose retire compute — unpacking and the
-    per-bucket Gram-Schmidt for P, the decompress matmuls for Q — depends
-    only on its own chunk, so it overlaps the later chunks' wire time.
-    Bitwise identical to the monolithic path; ledger bytes are K-invariant.
-    ``comm_strategy="ring"`` swaps each chunk's pmean for the explicit
-    ``ppermute`` ring (deterministic, reassociated).
-
     ``orthogonalize_impl="auto"`` (the default) resolves to the Pallas
     VMEM-resident Gram-Schmidt kernel on TPU and the XLA ``fori_loop``
     lowering elsewhere (DESIGN.md: the kernels exist so the TPU default
     should exercise them); explicit ``"xla"``/``"pallas"`` pin either.
-
-    ``compress_impl="pallas"`` (opt-in; default ``"xla"``) swaps the whole
-    per-bucket compress pipeline for the fused Pallas kernels of
-    ``ops.pallas_powersgd``: the error-feedback add + ``P = M·Q`` ride one
-    kernel, the Gram-Schmidt + ``Q = Mᵀ·P̂`` another (the factor stays in
-    VMEM between them, absorbing ``orthogonalize_impl``), and the
-    decompress + EF-residual a third — one HBM round-trip per shape bucket
-    per stage instead of ~5 separate XLA ops per matrix. Math is identical
-    up to fp32 MXU accumulation order (parity pinned in
-    ``tests/test_pallas_powersgd.py``); on CPU the kernels run in interpret
-    mode, so the fused path stays testable without a chip.
     """
 
     def __init__(
@@ -430,9 +338,6 @@ class PowerSGDReducer:
         matricize: str = "first",
         orthogonalize_impl: str = "auto",
         compression_dtype=None,
-        comm_chunks: Optional[int] = None,
-        comm_strategy: str = "interleave",
-        compress_impl: str = "xla",
     ):
         # The reference asserts n_power_iterations == 0 (reducer.py:30 — "0"
         # meaning the single fused iteration). Beyond parity, we support k
@@ -443,11 +348,6 @@ class PowerSGDReducer:
         assert n_power_iterations >= 0
         assert matricize in ("first", "last")
         assert orthogonalize_impl in ("auto", "xla", "pallas")
-        assert compress_impl in ("xla", "pallas")
-        assert comm_strategy in ("interleave", "ring"), comm_strategy
-        assert comm_chunks is None or comm_chunks >= 1
-        self.comm_chunks = comm_chunks
-        self.comm_strategy = comm_strategy
         self.n_power_iterations = n_power_iterations
         self.random_seed = random_seed
         self.reuse_query = reuse_query
@@ -459,12 +359,11 @@ class PowerSGDReducer:
         # argument the PowerSGD paper makes for rank truncation). None = the
         # gradients' own dtype (the reference's fp32 behavior).
         self.compression_dtype = jnp.dtype(compression_dtype) if compression_dtype else None
-        # off-TPU the Pallas kernels run in interpret mode (the test path)
+        # off-TPU the Pallas kernel runs in interpret mode (the test path)
         self._interpret = pallas_interpret()
         if orthogonalize_impl == "auto":
             orthogonalize_impl = "xla" if self._interpret else "pallas"
         self.orthogonalize_impl = orthogonalize_impl
-        self.compress_impl = compress_impl
         if orthogonalize_impl == "pallas":
             # VMEM-resident Gram-Schmidt TPU kernel (ops.pallas_orthogonalize)
             from ..ops.pallas_orthogonalize import orthogonalize_pallas
@@ -550,12 +449,9 @@ class PowerSGDReducer:
     def _reduce_flat(
         self, flat: jax.Array, axis_name: Optional[str], tag: str = "payload"
     ) -> jax.Array:
-        """One packed payload through the configured reduction engine —
-        unconditionally the chunked path (identical to the monolithic pmean
-        at ``comm_chunks=None``) so fence hooks cover every collective."""
-        return chunked_all_reduce_mean(
-            flat, axis_name, self.comm_chunks, self.comm_strategy, tag=tag
-        )
+        """One packed payload through the one collective entry, under the
+        ``reduce.collective`` scope the trace reduction reads."""
+        return tagged_all_reduce_mean(flat, axis_name, tag=tag)
 
     # ---- state -----------------------------------------------------------
 
@@ -590,7 +486,7 @@ class PowerSGDReducer:
         Step numbering follows the reference (``reducer.py:43-170``).
         """
         leaves, treedef = jax.tree_util.tree_flatten(send)
-        return self._reduce(state, leaves, None, treedef, axis_name)
+        return self._reduce(state, leaves, treedef, axis_name)
 
     @jax.named_scope("reduce.powersgd")
     def reduce_ef(
@@ -600,15 +496,14 @@ class PowerSGDReducer:
         memories: PyTree,
         axis_name: Optional[str],
     ) -> Tuple[PowerSGDState, PyTree, PyTree, int]:
-        """Error-feedback reduction with the add INSIDE the reducer:
-        mathematically ``reduce(state, grads + memories, axis_name)``, but
-        with ``compress_impl="pallas"`` the high-rank adds fuse into the
-        compress kernel's VMEM pass (``ops.pallas_powersgd``) — the summed
-        send matrix is never materialized as a separate XLA op."""
+        """Error-feedback reduction, ``reduce(state, grads + memories,
+        axis_name)`` with the add traced under this reducer's scope (the
+        trainer's ``ef_momentum`` entry)."""
         g_leaves, treedef = jax.tree_util.tree_flatten(grads)
         e_leaves = jax.tree_util.tree_leaves(memories)
         assert len(e_leaves) == len(g_leaves)
-        return self._reduce(state, g_leaves, e_leaves, treedef, axis_name)
+        leaves = [g + e for g, e in zip(g_leaves, e_leaves)]
+        return self._reduce(state, leaves, treedef, axis_name)
 
     def compression_error(
         self,
@@ -752,33 +647,10 @@ class PowerSGDReducer:
     def _reduce(
         self,
         state: PowerSGDState,
-        g_leaves: List[jax.Array],
-        e_leaves: Optional[List[jax.Array]],
+        leaves: List[jax.Array],
         treedef,
         axis_name: Optional[str],
     ) -> Tuple[PowerSGDState, PyTree, PyTree, int]:
-        fused = self.compress_impl == "pallas"
-        interp = self._interpret
-        if fused:
-            from ..ops.pallas_powersgd import (
-                fused_decompress_residual,
-                fused_ef_compress,
-                fused_orthogonalize_project,
-            )
-        # the leaves the rest of the pipeline sees are the SEND values
-        # (grads + error memory). On the fused path the high-rank adds
-        # happen inside the compress kernel instead; rank-1 leaves add here
-        # either way (their error memory is identically zero under the
-        # trainer contract, but reduce_ef keeps the general semantics).
-        if e_leaves is None:
-            leaves = list(g_leaves)
-        elif not fused:
-            leaves = [g + e for g, e in zip(g_leaves, e_leaves)]
-        else:
-            leaves = [
-                g if g.ndim > 1 else g + e
-                for g, e in zip(g_leaves, e_leaves)
-            ]
         rank1_idx, _ = self._split(leaves)
         metas = self._metas(leaves)
         p_packer, q_packer, rank1_packer = self._packers(leaves, metas)
@@ -798,33 +670,9 @@ class PowerSGDReducer:
                 for t, meta in enumerate(metas)
             ]
 
-        # Step 1/3 (fused): M = G + E and P = M·Q in ONE kernel pass per
-        # shape bucket — the EF add never round-trips HBM on its own. The
-        # kernel writes M back once because steps 6 and 8-9 re-read it.
-        first_ps: Optional[List[jax.Array]] = None
-        if fused and metas and e_leaves is not None:
-            matrices = [None] * len(metas)
-            first_ps = [None] * len(metas)
-            for poss in groups:
-                g_stack = jnp.stack([
-                    g_leaves[metas[p].leaf_index].reshape(metas[p].n, metas[p].m)
-                    for p in poss
-                ])
-                e_stack = jnp.stack([
-                    e_leaves[metas[p].leaf_index].reshape(metas[p].n, metas[p].m)
-                    for p in poss
-                ])
-                q_stack = jnp.stack([qs[p] for p in poss])
-                m_stack, p_stack = fused_ef_compress(
-                    g_stack, q_stack, e_stack, interpret=interp
-                )
-                for j, p in enumerate(poss):
-                    matrices[p] = m_stack[j]
-                    first_ps[p] = p_stack[j]
-        else:
-            matrices = [
-                leaves[meta.leaf_index].reshape(meta.n, meta.m) for meta in metas
-            ]
+        matrices = [
+            leaves[meta.leaf_index].reshape(meta.n, meta.m) for meta in metas
+        ]
 
         # Steps 3-7, run (1 + n_power_iterations) times: the reference's single
         # fused round (reducer.py:120-147), plus optional extra subspace
@@ -835,19 +683,10 @@ class PowerSGDReducer:
         ps: List[jax.Array] = []
         for it in range(1 + self.n_power_iterations):
             # Step 3: P <- M Q (reducer.py:120-123) — one batched matmul per
-            # distinct matrix shape (fused: the Pallas compress kernel; the
-            # EF-fused first round already produced its Ps above)
-            if it == 0 and first_ps is not None:
-                ps = first_ps
-            elif fused:
-                ps = self._grouped_map(
-                    lambda M, Q: fused_ef_compress(M, Q, interpret=interp)[1],
-                    groups, matrices, qs, out_len=len(metas),
-                )
-            else:
-                ps = self._grouped_map(
-                    lambda M, Q: M @ Q, groups, matrices, qs, out_len=len(metas)
-                )
+            # distinct matrix shape
+            ps = self._grouped_map(
+                lambda M, Q: M @ Q, groups, matrices, qs, out_len=len(metas)
+            )
 
             # Step 4: ALL_REDUCE_MEAN(P) — ONE collective for all Ps
             # (reducer.py:125-128)
@@ -875,39 +714,21 @@ class PowerSGDReducer:
                     for i, o in zip(rank1_idx, rank1_packer.unpack(rank1_reduced))
                 ]
 
-            # Steps 5-6: P_hat <- ORTHOGONALIZE(P), Q <- M^T P_hat
-            # (reducer.py:135-142). Fused: ONE kernel per shape bucket —
-            # the Gram-Schmidt result stays VMEM-resident through the
-            # Q = MᵀP̂ matmul (absorbing ops.pallas_orthogonalize).
-            if fused:
-                next_ps: List[jax.Array] = [None] * len(metas)
-                next_qs: List[jax.Array] = [None] * len(metas)
-                for poss in groups:
-                    p_stack = jnp.stack([ps[p] for p in poss])
-                    m_stack = jnp.stack([matrices[p] for p in poss])
-                    phat_stack, q_stack = fused_orthogonalize_project(
-                        p_stack, m_stack, interpret=interp
-                    )
-                    for j, p in enumerate(poss):
-                        next_ps[p] = phat_stack[j]
-                        next_qs[p] = q_stack[j]
-                ps, qs = next_ps, next_qs
-            else:
-                # Step 5: vmapped over each shape bucket (the standalone
-                # pallas GS kernel stays per-matrix: its grid is already
-                # the whole op)
-                if self._orthogonalize is orthogonalize:
-                    ps = self._grouped_map(
-                        jax.vmap(self._orthogonalize), groups, ps, out_len=len(metas)
-                    )
-                else:
-                    ps = [self._orthogonalize(p) for p in ps]
-
-                # Step 6: Q <- M^T P_hat (reducer.py:139-142)
-                qs = self._grouped_map(
-                    lambda M, Phat: jnp.einsum("gnm,gnr->gmr", M, Phat),
-                    groups, matrices, ps, out_len=len(metas),
+            # Step 5: P_hat <- ORTHOGONALIZE(P) (reducer.py:135-137), vmapped
+            # over each shape bucket (the pallas GS kernel stays per-matrix:
+            # its grid is already the whole op)
+            if self._orthogonalize is orthogonalize:
+                ps = self._grouped_map(
+                    jax.vmap(self._orthogonalize), groups, ps, out_len=len(metas)
                 )
+            else:
+                ps = [self._orthogonalize(p) for p in ps]
+
+            # Step 6: Q <- M^T P_hat (reducer.py:139-142)
+            qs = self._grouped_map(
+                lambda M, Phat: jnp.einsum("gnm,gnr->gmr", M, Phat),
+                groups, matrices, ps, out_len=len(metas),
+            )
 
             # Step 7: ALL_REDUCE_MEAN(Q) — ONE collective for all Qs
             # (reducer.py:144-147)
@@ -923,33 +744,16 @@ class PowerSGDReducer:
         # (reducer.py:157-163). Rank-1 error memory stays zero: the reference
         # never writes it (reducer.py only touches high-rank memories) and it
         # is zero-initialized in the trainer, so zeros_like is exact parity.
-        # Fused: one kernel per shape bucket computes the P·Qᵀ matmul AND
-        # the residual against the VMEM-resident send matrix M in the same
-        # pass (fp32 accumulation; M is `matrices`, i.e. G+E even when the
-        # add itself was kernel-fused).
         out_leaves = list(leaves)
         mem_leaves = [jnp.zeros_like(l) for l in leaves]
-        if fused and metas:
-            for poss in groups:
-                p_stack = jnp.stack([ps[p] for p in poss])
-                q_stack = jnp.stack([qs[p] for p in poss])
-                m_stack = jnp.stack([matrices[p] for p in poss])
-                out_stack, mem_stack = fused_decompress_residual(
-                    p_stack, q_stack, m_stack, interpret=interp
-                )
-                for j, pos in enumerate(poss):
-                    meta = metas[pos]
-                    out_leaves[meta.leaf_index] = out_stack[j].reshape(meta.shape)
-                    mem_leaves[meta.leaf_index] = mem_stack[j].reshape(meta.shape)
-        else:
-            approxes = self._grouped_map(
-                lambda P, Q: jnp.einsum("gnr,gmr->gnm", P, Q),
-                groups, ps, qs, out_len=len(metas),
-            )
-            for meta, approx in zip(metas, approxes):
-                approx = approx.reshape(meta.shape)
-                out_leaves[meta.leaf_index] = approx
-                mem_leaves[meta.leaf_index] = leaves[meta.leaf_index] - approx
+        approxes = self._grouped_map(
+            lambda P, Q: jnp.einsum("gnr,gmr->gnm", P, Q),
+            groups, ps, qs, out_len=len(metas),
+        )
+        for meta, approx in zip(metas, approxes):
+            approx = approx.reshape(meta.shape)
+            out_leaves[meta.leaf_index] = approx
+            mem_leaves[meta.leaf_index] = leaves[meta.leaf_index] - approx
         for i, reduced in zip(rank1_idx, rank1_out):
             out_leaves[i] = reduced
 
@@ -978,9 +782,7 @@ class PowerSGDReducer:
     def ledger_entries(self, grads_template: PyTree, axis: str = "", n_workers: int = 1):
         """Wire-ledger itemization of one compressed reduction: the P and Q
         factor all-reduces (one each per power-iteration round) and the
-        uncompressed rank-1 payload. With ``comm_chunks`` each payload's
-        ``count`` multiplies by its chunk count while ``payload_bytes`` stays
-        put (the chunks partition the buffer). Sums to :meth:`bits_per_step`."""
+        uncompressed rank-1 payload. Sums to :meth:`bits_per_step`."""
         from ..observe.ledger import LedgerEntry
 
         leaves = jax.tree_util.tree_leaves(grads_template)
@@ -994,7 +796,6 @@ class PowerSGDReducer:
             ("powersgd.rank1", rank1_packer, 1),
         ):
             if packer.bits():
-                chunks = _n_chunk_collectives(packer.total_size, self.comm_chunks)
                 entries.append(
                     LedgerEntry(
                         tag=tag,
@@ -1003,7 +804,7 @@ class PowerSGDReducer:
                         axis=axis,
                         dtype=str(packer.dtype),
                         payload_bytes=repeats * packer.bits() // 8,
-                        count=repeats * chunks,
+                        count=repeats,
                     )
                 )
         return entries

@@ -315,11 +315,9 @@ def make_step_fn(
 
         if algorithm == "ef_momentum":
             # (Algo 2 line 7) send = g + e  (ddp_init.py:156-157), via the
-            # reducer's error-feedback entry point when it has one: with
-            # the fused Pallas compress path the add happens in VMEM inside
-            # the compress kernel (ops.pallas_powersgd) instead of as a
-            # separate XLA op. Reducers without reduce_ef (the gather-family
-            # compressors) keep the explicit add.
+            # reducer's error-feedback entry point when it has one, so the
+            # add is traced under the reducer's own scope. Reducers without
+            # reduce_ef (the gather-family compressors) keep the explicit add.
             # (Algo 2 lines 8-11) compress → allreduce → decompress; e updated
             if hasattr(reducer, "reduce_ef"):
                 reducer_state, delta, memories, _ = reducer.reduce_ef(
@@ -722,8 +720,6 @@ def reducer_comm_config(reducer) -> Dict:
     cfg: Dict = {"reducer": type(reducer).__name__.lower()}
     for attr, key in (
         ("compression_rank", "reducer_rank"),
-        ("comm_chunks", "comm_chunks"),
-        ("comm_strategy", "comm_strategy"),
         ("bucket_bytes", "bucket_bytes"),
     ):
         v = getattr(reducer, attr, None)
@@ -758,12 +754,6 @@ def make_train_step(
     ``accum_steps > 1``: gradient accumulation (see :func:`make_step_fn`);
     batch leaves then carry a leading ``accum_steps`` axis ahead of the
     sharded batch axis.
-
-    Chunked pipelined reduction rides the REDUCER, not this builder:
-    construct it with ``comm_chunks=K`` (ExactReducer / PowerSGDReducer)
-    and the step's ledger itemizes the per-chunk collectives automatically
-    (``ledger_entries`` counts chunks; payload bytes and ``bits_per_step``
-    are K-invariant, so the ``step_ledger`` equality assert still pins them).
     """
     if mesh is None:
         body = make_step_fn(

@@ -24,8 +24,8 @@ operates them:
   bare kill), resumes from the newest committed checkpoint, and degrades
   to a shrunk world when a rank is permanently gone.
 - :mod:`resilience.controller` — the degraded-fabric policy loop: an
-  ordered fallback ladder over the comm knobs (chunking → ring schedule →
-  PowerSGD compression → widened sync period) walked down on degraded
+  ordered fallback ladder over the comm knobs (PowerSGD compression →
+  widened sync period → two-level reduction) walked down on degraded
   epoch verdicts and back up, with hysteresis, when the fabric recovers —
   every move a typed ``PolicyEvent``.
 - :mod:`resilience.reshard`    — what makes the degraded restart lossless:

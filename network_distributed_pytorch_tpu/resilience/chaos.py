@@ -34,11 +34,11 @@ Fault kinds and where they bite:
 ``proc_preempt``       a preemption notice: the worker SIGTERMs itself; an
                        installed ``guards.PreemptionGuard`` turns it into an
                        emergency committed checkpoint at the step boundary
-``comm_throttle``      the fabric degrades: every chunk collective pays a
+``comm_throttle``      the fabric degrades: every collective pays a
                        host-side sleep of ``payload_bytes / bytes_per_s``
                        (a mock line rate), injected at the comm fence hooks
 ``comm_stall``         ONE collective hangs past its deadline on the target
-                       rank (a dead link / stuck DMA): a single chunk
+                       rank (a dead link / stuck DMA): a single
                        launch sleeps ``stall_seconds``, then proceeds
 ``comm_flap``          a transient throttle that clears by itself after
                        ``clears_after`` steps — the flaky-link case the
@@ -505,14 +505,16 @@ class CommFaultInjector:
     on the host thread where telemetry is safe, while ``__call__`` — which
     runs inside the ordered io_callback, once per device per execution —
     only sleeps. Injection therefore delays the real collective (the
-    callback token is fenced into the chunk's dataflow) without adding a
+    callback token is fenced into the payload's dataflow) without adding a
     single byte to the wire ledger.
 
     Fault payload knobs: ``bytes_per_s`` (mock line rate, default 10GbE),
-    ``max_sleep_s`` (per-chunk sleep clamp, keeps a throttle under the
+    ``max_sleep_s`` (per-collective sleep clamp, keeps a throttle under the
     watchdog deadline), ``duration_steps`` / ``clears_after`` (throttle /
     flap lifetime in steps; a flap defaults to clearing after 3),
-    ``stall_seconds`` and ``chunk`` (which chunk launch hangs, once).
+    ``stall_seconds`` (the next collective launch hangs, once; a ``chunk``
+    key in a plan written before the chunk engine went is accepted and
+    ignored).
 
     Runs are single-controller per process: the hook filters on
     ``device_index == rank`` so a single-process multi-device test mesh
@@ -676,10 +678,7 @@ class CommFaultInjector:
                 ),
             }
         elif spec.kind == "comm_stall":
-            self._stall = {
-                "stall_seconds": float(p.get("stall_seconds", 1.0)),
-                "chunk": int(p.get("chunk", 0)),
-            }
+            self._stall = {"stall_seconds": float(p.get("stall_seconds", 1.0))}
 
     # -- the fence hook (io_callback thread, once per device) ---------------
     def __call__(self, info: Dict[str, Any]) -> None:
@@ -697,7 +696,7 @@ class CommFaultInjector:
             time.sleep(part["max_sleep_s"])
             return
         st = self._stall
-        if st is not None and info.get("chunk") == st["chunk"]:
+        if st is not None:
             self._stall = None  # one collective hangs, once
             time.sleep(st["stall_seconds"])
             return

@@ -8,10 +8,10 @@ the world. This module closes the loop: at every epoch boundary the
 by the training loop from the watchdog's counters and measured step
 times) and walks an explicit, ordered fallback ladder::
 
-    baseline -> chunked -> ring -> compress -> compress-low-rank -> localsgd
+    baseline -> compress -> compress-low-rank -> localsgd -> hierarchical
 
-Each rung is a named override dict over the comm knobs (``comm_chunks``,
-``comm_strategy``, ``reducer``, ``reducer_rank``, ``sync_every``); the
+Each rung is a named override dict over the comm knobs (``reducer``,
+``reducer_rank``, ``sync_every``, ``outer_async``); the
 loop recompiles ONCE per decision and carries the training state across
 the switch. Every transition emits a typed ``PolicyEvent`` with the
 trigger verdict, the rung before/after, and predicted-vs-realized
@@ -59,15 +59,11 @@ class Rung:
     overrides: Dict[str, Any] = field(default_factory=dict)
 
 
-# The ordered ladder the tentpole specifies: retune chunking first (free —
-# same bytes, better overlap), then the explicit ring schedule (same bytes,
-# no dependence on the native all-reduce), then PowerSGD compression
-# (bytes actually shrink; rank 4 then rank 1), then widen the sync period
-# (LocalSGD/DiLoCo-style — pays wire cost every ``sync_every`` steps).
+# The ordered ladder: the first descent is the first rung that changes the
+# bytes — PowerSGD compression (rank 4 then rank 1) — then widen the sync
+# period (LocalSGD/DiLoCo-style — pays wire cost every ``sync_every`` steps).
 DEFAULT_LADDER: List[Rung] = [
     Rung("baseline", {}),
-    Rung("chunked", {"comm_chunks": 4}),
-    Rung("ring", {"comm_chunks": 8, "comm_strategy": "ring"}),
     Rung("compress", {"reducer": "powersgd", "reducer_rank": 4}),
     Rung("compress-low-rank", {"reducer": "powersgd", "reducer_rank": 1}),
     Rung(
@@ -108,8 +104,8 @@ def ladder_from_plan(
     The controller's semantics are untouched — same hysteresis, one
     recompile per decision — only the ORDER it walks changes: under a
     planner-ordered ladder the first descent lands on the config the cost
-    model predicts cheapest for this fabric instead of blindly trying
-    chunking first. Rung names the plan does not rank keep their relative
+    model predicts cheapest for this fabric instead of the default order.
+    Rung names the plan does not rank keep their relative
     order after the ranked ones (the planner can only reorder what it
     priced); an unknown fabric or an empty ranking returns the ladder
     unchanged, so a stale plan can never brick a launch. ``max_rungs``

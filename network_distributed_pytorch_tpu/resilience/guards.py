@@ -36,7 +36,7 @@ And the degraded-fabric side (DESIGN.md):
   the wire ledger's bytes and the ``FABRICS_BYTES_PER_S`` model, floored
   by the measured collective p50 × a slack factor.
 - :class:`CollectiveWatchdog` — a fence hook (``parallel.comm``) arming a
-  ``StepWatchdog``-style monitor-thread timer around every fenced chunk;
+  ``StepWatchdog``-style monitor-thread timer around every fenced collective;
   expiry emits ``FailureEvent(kind="comm_deadline")`` and marks the
   attempt, never kills the process itself.
 - :class:`CommDeadlineGuard` — wraps the step OUTSIDE :class:`GuardedStep`
@@ -143,7 +143,7 @@ def derive_collective_deadline(
     The model is ``utils.bandwidth.allreduce_time_s`` (the ring lower
     bound at the fabric's ``FABRICS_BYTES_PER_S`` line rate) — optimistic
     by construction, hence the slack factor; the measured p50 of recent
-    fenced chunks keeps the deadline honest on hardware slower than the
+    fenced collectives keeps the deadline honest on hardware slower than the
     model (CPU test meshes most of all); the floor keeps tiny payloads
     from deriving microsecond hair-trigger deadlines."""
     # path-load so the supervisor-parent import path stays jax-free (the
@@ -358,14 +358,14 @@ class OuterSyncDriver:
 
 
 class CollectiveWatchdog:
-    """A deadline timer around every fenced chunk collective, driven as a
+    """A deadline timer around every fenced collective, driven as a
     ``parallel.comm`` fence hook.
 
     One monitor thread (the :class:`utils.failure.StepWatchdog` pattern:
     a ``Condition`` guarding a single monotonic deadline) watches the
-    currently-armed chunk. The hook arms on every ``launch`` with a
-    deadline from :func:`derive_collective_deadline` (per-chunk payload
-    bytes; measured p50 over the last ``history`` chunks as the floor) and
+    currently-armed collective. The hook arms on every ``launch`` with a
+    deadline from :func:`derive_collective_deadline` (the payload's bytes;
+    measured p50 over the last ``history`` collectives as the floor) and
     disarms on the next fence point — so the armed window brackets exactly
     one collective's wire time plus its retire compute. Expiry emits
     ``FailureEvent(kind="comm_deadline")`` from the monitor thread and
@@ -472,8 +472,7 @@ class CollectiveWatchdog:
         self._telemetry.emit(
             FailureEvent(
                 kind="comm_deadline",
-                label=f"{info.get('tag', '?')}"
-                      f"[{info.get('chunk', '?')}/{info.get('n_chunks', '?')}]",
+                label=str(info.get("tag", "?")),
                 message=(
                     f"collective exceeded deadline "
                     f"{info.get('deadline_s', 0.0):.3f}s "

@@ -40,16 +40,6 @@ class ExperimentConfig:
     log_every: int = 10
     accum_steps: int = 1  # gradient accumulation microbatches per step
     max_grad_norm: Optional[float] = None  # global-norm gradient clipping
-    # chunked software-pipelined reduction (parallel.comm, DESIGN.md
-    # Round-6): split each reducer payload into K fenced chunk collectives
-    # so chunk i's retire compute overlaps chunk i+1's wire time. None =
-    # today's monolithic collectives; worth trying on slow-interconnect
-    # (DCN / sub-ICI) meshes where wire time dominates the step.
-    comm_chunks: Optional[int] = None
-    # "interleave" (default; per-chunk pmean, bitwise identical to the
-    # monolithic path) or "ring" (explicit ppermute reduce-scatter/
-    # all-gather schedule — deterministic but reassociated, ~1 ulp)
-    comm_strategy: str = "interleave"
     # DDP-style backward-order gradient buckets for the exact reducer
     # (parallel.comm.bucket_assignments): target bytes per bucket; each
     # bucket's collective launches as soon as the backward pass has
@@ -59,9 +49,6 @@ class ExperimentConfig:
     # kernel implementation overrides (DESIGN.md "Raw speed"). "auto"
     # resolves per backend at construction: Pallas kernels on TPU, the XLA
     # reference lowerings on CPU (where Pallas would only run interpreted).
-    # compress_impl: "xla" | "pallas" — the fused PowerSGD compress
-    # pipeline (ops.pallas_powersgd); opt-in, never implied by "auto".
-    compress_impl: str = "xla"
     # orthogonalize_impl: "auto" | "xla" | "pallas" — PowerSGD Gram-Schmidt
     orthogonalize_impl: str = "auto"
     # attn_impl: None = keep each model's own default ("auto" → flash on
@@ -89,8 +76,8 @@ class ExperimentConfig:
     chaos_plan: Optional[str] = None
     # degraded-fabric survival (resilience.controller, DESIGN.md): run the
     # closed-loop fallback controller — collective deadline watchdogs
-    # around every fenced chunk plus the epoch-boundary reducer fallback
-    # ladder. exact_cifar10 ddp only.
+    # around every fenced collective plus the epoch-boundary reducer
+    # fallback ladder. exact_cifar10 ddp only.
     adaptive_comm: bool = False
     # the fabric whose FABRICS_BYTES_PER_S line rate models the collective
     # deadline budget (utils.bandwidth keys: "1GbE", "10GbE", "100GbE",

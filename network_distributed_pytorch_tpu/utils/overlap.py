@@ -12,7 +12,7 @@ order of the entry computation IS the execution order, and any instruction
 between a collective's ``-start`` and its ``-done`` runs inside the
 communication window.
 
-What the v5e schedule ACTUALLY shows (measured, ``OVERLAP.json``): the
+What the v5e schedule ACTUALLY shows (measured on the chip, round 5): the
 all-reduces compile as synchronous HLO ops whose async-ness lives inside
 the TPU collective emitter (``backend_config``'s
 ``RotatedPincerShortEmitter/StrategyRing`` — the op IS a pipelined ICI
@@ -23,13 +23,11 @@ async-collective-fusion form) are recognized too, classified by the
 wrapped collective. On CPU the backend emits synchronous collectives and
 no DMA windows, so the report honestly zeroes those fields.
 
-For the chunked pipelined schedules (``parallel.comm``, DESIGN.md Round-6)
-the report also attributes evidence to SPECIFIC collectives: every async
+The report also attributes evidence to SPECIFIC collectives: every async
 window carries the ``name`` of its start op, and synchronous collectives
 (the CPU backend, and any TPU op the emitter keeps synchronous) are listed
 in schedule order with the compute ops scheduled between each and the
-next — ``n_sync_gaps_with_compute > 0`` is the textual-interleave proof
-that the chunk collectives did not compile back into one blocking op.
+next (``n_sync_gaps_with_compute``).
 """
 
 from __future__ import annotations

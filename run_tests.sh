@@ -35,13 +35,6 @@ python scripts/lint_jax_free.py
 
 mkdir -p artifacts
 
-# Round-6 schedule smoke: AOT-compile (CPU, no execution) one chunked step
-# per reducer and assert the compiled collective count AND payload bytes
-# still match the wire ledger — the canary for an XLA upgrade (or a
-# comm.py edit) re-fusing the barrier-fenced chunk pipeline.
-env JAX_PLATFORMS=cpu \
-    XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python scripts/schedule_smoke.py
 # tests/ includes the resilience chaos suite (tests/test_chaos.py,
 # tests/test_supervisor.py): the fault-primitive and supervisor-mechanics
 # tests run in the fast tier (-m "not slow" compatible); the full chaos

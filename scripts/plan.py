@@ -4,7 +4,7 @@
 Front end of :mod:`observe.costmodel`. Calibrates the analytic cost model
 from a machine-readable run report (``scripts/report.py --run-dir`` /
 ``artifacts/run_report.json``, or directly from a ``--run-dir``), searches
-the comm-config space (fallback-ladder rungs plus chunk/bucket variants)
+the comm-config space (fallback-ladder rungs plus rank/bucket variants)
 across the requested fabrics, and writes:
 
 - ``--out`` (default ``artifacts/plan.json``): the tuned per-fabric plan —

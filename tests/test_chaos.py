@@ -9,6 +9,7 @@ selected, a bit-flip is caught by checksums at restore, and ``restore_latest``
 falls back to the previous good step with a telemetry trail.
 """
 
+import collections
 import json
 import os
 import signal
@@ -621,11 +622,10 @@ def test_restore_latest_empty_root(devices, tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def _info(phase="launch", chunk=0, n_chunks=1, payload=4096, device=0,
-          tag="grads"):
+def _info(phase="launch", payload=4096, device=0, tag="grads"):
     return {
-        "tag": tag, "chunk": chunk, "n_chunks": n_chunks,
-        "payload_bytes": payload, "phase": phase, "device_index": device,
+        "tag": tag, "payload_bytes": payload, "phase": phase,
+        "device_index": device,
     }
 
 
@@ -676,6 +676,8 @@ def test_comm_fault_injector_throttle_lifecycle():
 
 def test_comm_fault_injector_stall_fires_once():
     plan = ChaosPlan([
+        # "chunk": a plan written while payloads were split into chunk
+        # collectives still parses; the key is ignored
         FaultSpec(kind="comm_stall", step=0, payload={
             "stall_seconds": 0.05, "chunk": 1,
         }),
@@ -685,14 +687,14 @@ def test_comm_fault_injector_stall_fires_once():
     assert inj.stall_pending
     import time as _t
     t0 = _t.monotonic()
-    inj(_info(chunk=0))  # wrong chunk: no stall
+    inj(_info(phase="retire"))  # only a launch stalls
     assert _t.monotonic() - t0 < 0.02
     t0 = _t.monotonic()
-    inj(_info(chunk=1))
+    inj(_info())
     assert _t.monotonic() - t0 >= 0.04
     assert not inj.stall_pending  # one collective hangs, ONCE
     t0 = _t.monotonic()
-    inj(_info(chunk=1))
+    inj(_info())
     assert _t.monotonic() - t0 < 0.02
 
 
@@ -723,10 +725,10 @@ def test_collective_watchdog_expiry_and_epoch_counters():
         wd.note_step(False)
         # blown window: the retire never comes before the deadline
         wd.begin_attempt()
-        wd(_info(phase="launch", chunk=2, n_chunks=4))
+        wd(_info(phase="launch", tag="powersgd.P"))
         _t.sleep(0.15)
         assert wd.expired_this_attempt
-        assert wd.fired and wd.fired[-1]["chunk"] == 2
+        assert wd.fired and wd.fired[-1]["tag"] == "powersgd.P"
         # hooks from other devices never arm rank 0's timer
         wd.begin_attempt()
         wd(_info(phase="launch", device=3))
@@ -746,7 +748,7 @@ def test_collective_watchdog_expiry_and_epoch_counters():
         r for r in sink.records if r.get("kind") == "comm_deadline"
     ]
     assert len(deadline_events) == 1
-    assert "grads[2/4]" in deadline_events[0]["label"]
+    assert deadline_events[0]["label"] == "powersgd.P"
 
 
 class _ScriptedWatchdog:
@@ -819,51 +821,89 @@ def test_comm_deadline_guard_escalates_past_runtime_error_handlers():
     assert not issubclass(CommEscalationError, RuntimeError)
 
 
-def test_fence_hooks_preserve_bits_and_see_every_chunk(devices):
-    from jax.sharding import PartitionSpec as P
-
-    from network_distributed_pytorch_tpu.parallel import DATA_AXIS
-    from network_distributed_pytorch_tpu.parallel import comm
-    from network_distributed_pytorch_tpu.parallel.comm import (
-        chunked_all_reduce_mean,
+def _hooked_reducer(name):
+    """(reducer, mesh, the axes it reduces over) for one fence-hook case."""
+    from network_distributed_pytorch_tpu.parallel import (
+        ExactReducer,
+        HierarchicalReducer,
     )
 
-    mesh = make_mesh()
-    flat = jax.random.normal(jax.random.PRNGKey(0), (8, 531))
+    if name == "hierarchical":
+        mesh = make_mesh(axis_sizes=(2, 4), axis_names=("dcn", "ici"))
+        outer = PowerSGDReducer(random_seed=3, compression_rank=2)
+        return HierarchicalReducer(outer, mesh), mesh, ("dcn", "ici")
+    reducer = {
+        "exact": ExactReducer,
+        "exact-bucketed": lambda: ExactReducer(bucket_bytes=60),
+        "powersgd": lambda: PowerSGDReducer(random_seed=3, compression_rank=2),
+    }[name]()
+    return reducer, make_mesh(), "data"
 
-    def run(k):
-        def body(xs):
-            return chunked_all_reduce_mean(xs[0], DATA_AXIS, k, tag="t")[None]
 
-        return jax.jit(
-            jax.shard_map(
-                body, mesh=mesh, in_specs=P(DATA_AXIS), out_specs=P(DATA_AXIS)
-            )
-        )(flat)
+@pytest.mark.parametrize(
+    "name", ["exact", "exact-bucketed", "powersgd", "hierarchical"]
+)
+def test_fence_hooks_see_every_collective(devices, name):
+    """Every payload a reducer puts on the wire passes the fence hooks once
+    (a launch and a retire per ledger line, tag and bytes the ledger's own),
+    the callbacks stay outside the math (bitwise the unhooked results), and
+    with no hook registered the traced program carries no callback."""
+    from jax.sharding import PartitionSpec as P
 
-    baseline = np.asarray(run(3))
+    from network_distributed_pytorch_tpu.parallel import comm
+
+    reducer, mesh, axes = _hooked_reducer(name)
+    shapes = [(8, 3, 3, 3), (16, 8), (16,), (10, 16), (10,)]
+    sends = [
+        jax.random.normal(jax.random.PRNGKey(i), (8,) + shape)
+        for i, shape in enumerate(shapes)
+    ]
+    template = [s[0] for s in sends]
+    state = reducer.init(template)
+    spec = P(axes)
+
+    def body(state, *send):
+        _, out, mem, _ = reducer.reduce(state, [s[0] for s in send], axes)
+        return [o[None] for o in out], [m[None] for m in mem]
+
+    def build():
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=(P(),) + (spec,) * len(sends),
+            out_specs=([spec] * len(sends), [spec] * len(sends)),
+        ))
+
+    unhooked_jaxpr = str(jax.make_jaxpr(build())(state, *sends))
+    assert "callback" not in unhooked_jaxpr
+    baseline = build()(state, *sends)
     seen = []
     comm.add_fence_hook(seen.append)
     try:
         assert comm.fence_hooks_active()
-        hooked = np.asarray(run(3))
+        assert "callback" in str(jax.make_jaxpr(build())(state, *sends))
+        hooked = jax.block_until_ready(build()(state, *sends))
     finally:
         comm.remove_fence_hook(seen.append)
     assert not comm.fence_hooks_active()
-    # the callback is outside the math: bitwise identical results
-    np.testing.assert_array_equal(
-        baseline.view(np.uint32), hooked.view(np.uint32)
-    )
-    mine = [i for i in seen if i["device_index"] == 0]
-    launches = [i for i in mine if i["phase"] == "launch"]
-    retires = [i for i in mine if i["phase"] == "retire"]
-    # 3 chunk launches + the final retire, once per logical collective
-    assert [i["chunk"] for i in launches] == [0, 1, 2]
-    assert len(retires) == 1
-    itemsize = np.dtype(np.float32).itemsize
-    assert sum(i["payload_bytes"] for i in launches) == 531 * itemsize
-    assert retires[0]["payload_bytes"] == 531 * itemsize
-    assert all(i["tag"] == "t" and i["n_chunks"] == 3 for i in launches)
+    assert str(jax.make_jaxpr(build())(state, *sends)) == unhooked_jaxpr
+    for a, b in zip(
+        jax.tree_util.tree_leaves(baseline), jax.tree_util.tree_leaves(hooked)
+    ):
+        np.testing.assert_array_equal(
+            np.asarray(a).view(np.uint32), np.asarray(b).view(np.uint32)
+        )
+    entries = reducer.ledger_entries(template, axis="data")
+    for phase in ("launch", "retire"):
+        mine = [
+            i for i in seen if i["device_index"] == 0 and i["phase"] == phase
+        ]
+        assert {(i["tag"], i["payload_bytes"]) for i in mine} == {
+            (e.tag, e.payload_bytes) for e in entries
+        }
+        # once per collective: rank 0 along a collective's axis is one
+        # device of each group that reduces over it
+        assert collections.Counter(i["tag"] for i in mine) == {
+            e.tag: mesh.size // mesh.shape[e.axis] for e in entries
+        }
 
 
 # -- the e2e matrix: fault -> watchdog/controller -> documented recovery ----
@@ -885,16 +925,11 @@ def _adaptive_setup():
                 random_seed=7,
                 compression_rank=overrides.get("reducer_rank", 2),
                 matricize="last",
-                comm_chunks=overrides.get("comm_chunks"),
-                comm_strategy=overrides.get("comm_strategy", "interleave"),
             )
         else:
             from network_distributed_pytorch_tpu.parallel import ExactReducer
 
-            reducer = ExactReducer(
-                comm_chunks=overrides.get("comm_chunks"),
-                comm_strategy=overrides.get("comm_strategy", "interleave"),
-            )
+            reducer = ExactReducer()
         return make_train_step(
             stateless_loss(lf), reducer, params, learning_rate=0.05,
             momentum=0.9, algorithm="ef_momentum", mesh=mesh,

@@ -35,28 +35,47 @@ def _health(epoch=0, achieved=0.0, expiries=0, degraded=0, stragglers=0):
 def test_default_ladder_documented_order():
     names = [r.name for r in DEFAULT_LADDER]
     assert names == [
-        "baseline", "chunked", "ring", "compress", "compress-low-rank",
-        "localsgd", "hierarchical", "hierarchical-async",
+        "baseline", "compress", "compress-low-rank", "localsgd",
+        "hierarchical", "hierarchical-async",
     ]
-    # baseline overrides nothing; each compression rung names the reducer;
-    # the localsgd rung widens the sync period; the bottom two rungs go
+    # baseline overrides nothing; the first descent is the first rung that
+    # changes the bytes; each compression rung names the reducer; the
+    # localsgd rung widens the sync period; the bottom two rungs go
     # two-level (and finally async) — the geo-resilient end of the ladder
     assert DEFAULT_LADDER[0].overrides == {}
-    assert DEFAULT_LADDER[2].overrides["comm_strategy"] == "ring"
-    for rung in DEFAULT_LADDER[3:6]:
+    for rung in DEFAULT_LADDER[1:4]:
         assert rung.overrides["reducer"] == "powersgd"
-    assert DEFAULT_LADDER[4].overrides["reducer_rank"] < (
-        DEFAULT_LADDER[3].overrides["reducer_rank"]
+    assert DEFAULT_LADDER[2].overrides["reducer_rank"] < (
+        DEFAULT_LADDER[1].overrides["reducer_rank"]
     )
-    assert "sync_every" not in DEFAULT_LADDER[4].overrides
-    assert DEFAULT_LADDER[5].overrides["sync_every"] > 1
-    for rung in DEFAULT_LADDER[6:]:
+    assert "sync_every" not in DEFAULT_LADDER[2].overrides
+    assert DEFAULT_LADDER[3].overrides["sync_every"] > 1
+    for rung in DEFAULT_LADDER[4:]:
         assert rung.overrides["reducer"] == "hierarchical"
-    assert DEFAULT_LADDER[7].overrides.get("outer_async")
+    assert DEFAULT_LADDER[5].overrides.get("outer_async")
     assert (
-        DEFAULT_LADDER[7].overrides["sync_every"]
-        > DEFAULT_LADDER[6].overrides["sync_every"]
+        DEFAULT_LADDER[5].overrides["sync_every"]
+        > DEFAULT_LADDER[4].overrides["sync_every"]
     )
+
+
+@pytest.mark.parametrize("rung", ["baseline", "compress", "compress-low-rank"])
+def test_every_default_rung_builds_a_step(devices, rung):
+    """The rungs one flat CPU mesh can build, through the step factory the
+    adaptive loop is driven with: a rung's overrides are knobs a reducer
+    still takes, and the step it builds runs and prices its wire bytes."""
+    import numpy as np
+    from test_chaos import _adaptive_setup, _batches
+
+    step_factory, params = _adaptive_setup()
+    overrides = next(r.overrides for r in DEFAULT_LADDER if r.name == rung)
+    step = step_factory(dict(overrides))
+    expected = "powersgd" if overrides.get("reducer") == "powersgd" else "exact"
+    assert expected in step.comm_config["reducer"]
+    assert step.comm_config.get("reducer_rank") == overrides.get("reducer_rank")
+    _, loss = step(step.init_state(params), next(_batches(0)))
+    assert np.isfinite(float(loss))
+    assert step.ledger.total_bits() == step.bits_per_step > 0
 
 
 def test_ladder_validation():
@@ -150,8 +169,8 @@ def test_record_emits_policy_event_with_byte_claims():
     (e,) = events
     assert e["action"] == "descend"
     assert e["epoch"] == 5
-    assert e["rung_before"] == "baseline" and e["rung_after"] == "chunked"
-    assert e["overrides"] == {"comm_chunks": 4}
+    assert e["rung_before"] == "baseline" and e["rung_after"] == "compress"
+    assert e["overrides"] == {"reducer": "powersgd", "reducer_rank": 4}
     assert e["predicted_bytes_per_step"] == 1348.0
     assert e["realized_bytes_per_step"] == 4428.0
     assert e["rank"] == 3
@@ -160,11 +179,11 @@ def test_record_emits_policy_event_with_byte_claims():
 
 
 def test_custom_ladder_and_overrides_copying():
-    ladder = [Rung("a", {}), Rung("b", {"comm_chunks": 2})]
+    ladder = [Rung("a", {}), Rung("b", {"bucket_bytes": 2})]
     c = FallbackController(ladder=ladder, descend_after=1)
     d = c.observe(_health(expiries=1))
-    d.overrides["comm_chunks"] = 999  # mutating the decision's copy...
-    assert c.overrides == {"comm_chunks": 2}  # ...never reaches the rung
+    d.overrides["bucket_bytes"] = 999  # mutating the decision's copy...
+    assert c.overrides == {"bucket_bytes": 2}  # ...never reaches the rung
 
 
 # ---- collective-deadline derivation ----------------------------------------

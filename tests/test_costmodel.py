@@ -74,15 +74,34 @@ def _toy_report(**over):
 
 def test_canonical_config_normalizes_knobs():
     c = costmodel.canonical_config(
-        {"reducer": "PowerSGDReducer", "comm_chunks": None}, name="rung"
+        {"reducer": "PowerSGDReducer", "bucket_bytes": None}, name="rung"
     )
     assert c["reducer"] == "powersgd"
     assert c["reducer_rank"] == 1  # powersgd without a rank is rank-1
-    assert c["comm_chunks"] == 0 and c["bucket_bytes"] == 0
+    assert c["bucket_bytes"] == 0
     assert c["sync_every"] == 1
     assert c["name"] == "rung"
     # exact is the default family, whatever the class name looked like
     assert costmodel.canonical_config({})["reducer"] == "exact"
+
+
+def test_canonical_config_ignores_retired_knobs():
+    """A run record or plan written while payloads could be chunked still
+    carries ``comm_chunks`` / ``comm_strategy``: they are read and dropped,
+    so the record joins the config it would be today."""
+    old = {
+        "reducer": "powersgd", "reducer_rank": 4,
+        "comm_chunks": 4, "comm_strategy": "ring",
+    }
+    c = costmodel.canonical_config(old)
+    assert "comm_chunks" not in c and "comm_strategy" not in c
+    new = {"reducer": "powersgd", "reducer_rank": 4}
+    assert c == costmodel.canonical_config(new)
+    assert costmodel.config_key(old) == costmodel.config_key(new)
+    calib = costmodel.calibrate(_toy_report())
+    assert costmodel.predict(calib, old, "1GbE")["predicted_step_s"] == (
+        costmodel.predict(calib, new, "1GbE")["predicted_step_s"]
+    )
 
 
 def test_config_key_joins_on_knobs_not_names():
@@ -183,17 +202,6 @@ def test_predict_compression_shrinks_bytes_and_prices_compute():
         costmodel.POWERSGD_FLOPS_PER_ELEM_PER_RANK * (8 * MIB / 4.0)
     ) / calib.effective_flops_per_s
     assert p["compress_s"] == pytest.approx(expected_compress)
-
-
-def test_predict_chunks_trade_exposure_for_latency():
-    calib = costmodel.calibrate(_toy_report())
-    mono = costmodel.predict(calib, {}, "1GbE")
-    chunked = costmodel.predict(calib, {"comm_chunks": 4}, "1GbE")
-    assert chunked["pipeline_depth"] == 4
-    assert chunked["exposed_comm_s"] == pytest.approx(
-        mono["exposed_comm_s"] / 4
-    )
-    assert chunked["latency_s"] == pytest.approx(mono["latency_s"] * 4)
 
 
 def test_predict_bucket_bytes_sets_depth_and_caps():

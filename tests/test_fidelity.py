@@ -81,11 +81,10 @@ def _get(stats):
     "reducer",
     [
         ExactReducer(),
-        ExactReducer(comm_chunks=4),
         ExactReducer(bucket_bytes=512),
         ExactReducer(packed=False),
     ],
-    ids=["flat", "chunked", "bucketed", "unpacked"],
+    ids=["flat", "bucketed", "unpacked"],
 )
 def test_exact_compression_error_identically_zero(reducer):
     send = _template()

@@ -4,6 +4,7 @@ audit exposes the combiner's collective-count reduction."""
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from network_distributed_pytorch_tpu.models import SmallCNN
 from network_distributed_pytorch_tpu.parallel import (
@@ -65,6 +66,76 @@ def test_powersgd_hlo_payload_matches_analytic(devices):
     # Q depends on allreduced-P so at least 2 remain after the combiner
     # (how much the rest merge is toolchain-dependent)
     assert 2 <= s["by_kind"]["all-reduce"] <= 4
+
+
+def _vector_setup():
+    """A model of vectors only: PowerSGD has no matrix to compress, and the
+    whole gradient rides the rank-1 payload."""
+    params = {"scale": jnp.ones((24,)), "shift": jnp.zeros((24,))}
+
+    def lf(p, b):
+        x, y = b
+        pred = jnp.sum(x.reshape(x.shape[0], -1)[:, :24] * p["scale"] + p["shift"], -1)
+        return jnp.mean((pred - y) ** 2)
+
+    batch = (jnp.zeros((64, *IMG)), jnp.zeros((64,)))
+    return params, stateless_loss(lf), batch
+
+
+# reducer, algorithm, setup: every shape the ledger itemises
+AUDIT_CONFIGS = {
+    "exact-packed": (lambda: ExactReducer(), "sgd", _setup),
+    "exact-unpacked": (lambda: ExactReducer(packed=False), "sgd", _setup),
+    "exact-bucketed": (lambda: ExactReducer(bucket_bytes=60), "sgd", _setup),
+    "psgd-r1": (lambda: PowerSGDReducer(compression_rank=1), "ef_momentum", _setup),
+    "psgd-r4": (lambda: PowerSGDReducer(compression_rank=4), "ef_momentum", _setup),
+    "psgd-r4-bf16-wire": (
+        lambda: PowerSGDReducer(compression_rank=4, compression_dtype=jnp.bfloat16),
+        "ef_momentum", _setup,
+    ),
+    "psgd-r4-2-rounds": (
+        lambda: PowerSGDReducer(compression_rank=4, n_power_iterations=1),
+        "ef_momentum", _setup,
+    ),
+    "psgd-r4-matricize-last": (
+        lambda: PowerSGDReducer(compression_rank=4, matricize="last"),
+        "ef_momentum", _setup,
+    ),
+    "psgd-r4-fresh-query": (
+        lambda: PowerSGDReducer(compression_rank=4, reuse_query=False),
+        "ef_momentum", _setup,
+    ),
+    "psgd-vectors-only": (
+        lambda: PowerSGDReducer(compression_rank=4), "ef_momentum", _vector_setup,
+    ),
+}
+
+
+@pytest.mark.parametrize("n_workers", [2, 4, 8])
+@pytest.mark.parametrize("config", list(AUDIT_CONFIGS))
+def test_compiled_collectives_equal_ledger(devices, config, n_workers):
+    """The CPU twin of the four-chip cell's wire audit: the all-reduces in
+    the optimised HLO carry the ledger's bytes exactly, in no more ops than
+    the ledger has lines (the combiner may merge, nothing may be added)."""
+    make_reducer, algo, setup = AUDIT_CONFIGS[config]
+    params, loss_fn, batch = setup()
+    step = make_train_step(
+        loss_fn, make_reducer(), params, 0.05, 0.9, algo,
+        mesh=make_mesh(devices=devices[:n_workers]), donate_state=False,
+    )
+    txt = compiled_hlo_text(step.fn, step.init_state(params), batch)
+    audit = step.ledger.reconcile(txt)
+    # XLA:CPU widens a bf16 all-reduce to f32 (the chip does not): there the
+    # compiled bytes are the ledger's elements at four bytes each
+    widened = sum(
+        e.payload_bytes for e in step.ledger.entries if e.dtype == "bfloat16"
+    )
+    assert audit["delta_bytes"] == widened, audit
+    assert (widened > 0) == (config == "psgd-r4-bf16-wire")
+    assert set(audit["hlo_by_kind"]) == {"all-reduce"}, audit
+    assert step.ledger.total_bits() == step.bits_per_step
+    n_lines = sum(e.count for e in step.ledger.entries)
+    assert 1 <= audit["hlo_collective_count"] <= n_lines, (audit, n_lines)
 
 
 def test_full_step_with_batch_stats_no_unaccounted_collectives(devices):

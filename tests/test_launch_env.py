@@ -40,6 +40,21 @@ def test_config_from_args_overrides():
     assert cfg.training_epochs == 2
 
 
+@pytest.mark.parametrize(
+    "flag,value",
+    [("--comm-chunks", "4"), ("--comm-strategy", "ring"), ("--compress-impl", "pallas")],
+)
+def test_retired_flags_are_rejected(capsys, flag, value):
+    """The knobs of the chunked collective engine and the fused compress
+    kernels are gone: passing one is a usage error, never a silent no-op."""
+    from network_distributed_pytorch_tpu.launch import build_parser
+
+    with pytest.raises(SystemExit) as err:
+        build_parser().parse_args(["powersgd_cifar10", flag, value])
+    assert err.value.code == 2
+    assert f"unrecognized arguments: {flag}" in capsys.readouterr().err
+
+
 @pytest.mark.slow
 def test_cli_drives_experiment_end_to_end():
     """The L5 surface the reference launches with run_script.py: ONE

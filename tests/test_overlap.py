@@ -218,35 +218,3 @@ def test_overlap_report_sync_ignores_start_done_forms():
     assert rep["n_async_collectives"] == 1
     assert rep["n_sync_collectives"] == 0
     assert not rep["sync_interleaved"]
-
-
-def test_overlap_report_chunked_cpu_step_interleaves(devices):
-    """End-to-end Round-6 evidence on a REAL compiled chunked step: the CPU
-    backend keeps the K fenced chunk all-reduces separate, schedule order
-    interleaves them with retire compute, and every window carries a name."""
-    import jax.numpy as jnp
-
-    from network_distributed_pytorch_tpu.parallel import ExactReducer, make_mesh
-    from network_distributed_pytorch_tpu.parallel.trainer import (
-        make_train_step,
-        stateless_loss,
-    )
-    from network_distributed_pytorch_tpu.utils.hlo_audit import compiled_hlo_text
-
-    params = {"w": jnp.zeros((32, 16)), "b": jnp.zeros((16,))}
-    loss = stateless_loss(
-        lambda p, b: jnp.mean((b[0] @ p["w"] + p["b"] - b[1]) ** 2)
-    )
-    step = make_train_step(
-        loss, ExactReducer(comm_chunks=3), params, 0.05,
-        mesh=make_mesh(), donate_state=False,
-    )
-    state = step.init_state(params)
-    batch = (jnp.zeros((16, 32)), jnp.zeros((16, 16)))
-    rep = overlap_report(compiled_hlo_text(step.fn, state, batch))
-    # 3 grad chunks + the loss-sync pmean, all synchronous on CPU
-    assert rep["n_sync_collectives"] == 4
-    assert rep["n_async_collectives"] == 0
-    assert rep["sync_interleaved"]
-    assert rep["n_sync_gaps_with_compute"] >= 2
-    assert all(op["name"] for op in rep["sync_collectives"])

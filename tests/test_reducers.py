@@ -5,6 +5,7 @@ real 8-device shard_map/psum path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from network_distributed_pytorch_tpu.parallel import (
@@ -13,6 +14,7 @@ from network_distributed_pytorch_tpu.parallel import (
     PowerSGDReducer,
     make_mesh,
 )
+from network_distributed_pytorch_tpu.parallel.reducers import PowerSGDState
 from oracle_powersgd import powersgd_reduce_np
 
 W = 8
@@ -35,6 +37,27 @@ def _sends_per_worker(seed, n_workers=W):
         [np.asarray(l, dtype=np.float32) for l in _template_leaves(jax.random.PRNGKey(seed + w))]
         for w in range(n_workers)
     ]
+
+
+def _ragged_leaves(key):
+    """Uneven shape groups with vectors between them: three 100x37 twins in
+    ONE group, and a 5x3 and a 6x9 whose rank clips to min(n, m) below the
+    larger tested ranks."""
+    shapes = [(100, 37), (7,), (100, 37), (5, 3), (11,), (6, 9), (100, 37)]
+    return [
+        jax.random.normal(k, shape)
+        for k, shape in zip(jax.random.split(key, len(shapes)), shapes)
+    ]
+
+
+def _np_leaves(template_fn, seed):
+    return [np.asarray(l, np.float32) for l in template_fn(jax.random.PRNGKey(seed))]
+
+
+def _bits(x):
+    """uint bit-pattern view — equality here is BITWISE, not allclose."""
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32, 8: np.uint64}[x.dtype.itemsize])
 
 
 def _qs_from_state(reducer, state, template):
@@ -111,8 +134,6 @@ def test_powersgd_multiworker_golden_three_steps(devices):
     state = reducer.init(template)
 
     def f(q_memory, key, *send):
-        from network_distributed_pytorch_tpu.parallel.reducers import PowerSGDState
-
         send = [s[0] for s in send]
         st, out, mem, _ = reducer.reduce(PowerSGDState(q_memory, key), send, DATA_AXIS)
         return st.q_memory, st.key, [o[None] for o in out], [m[None] for m in mem]
@@ -146,8 +167,6 @@ def test_powersgd_multiworker_golden_three_steps(devices):
         qs = exp_qs  # oracle warm-start for next step
 
     # our carried q_memory must equal the oracle's final Qs
-    from network_distributed_pytorch_tpu.parallel.reducers import PowerSGDState
-
     final_qs = _qs_from_state(reducer, PowerSGDState(q_memory, key), template)
     for q, e in zip(final_qs, qs):
         np.testing.assert_allclose(q, e, rtol=2e-4, atol=1e-4)
@@ -330,3 +349,165 @@ def test_wide_distilbert_r16_compression_is_algorithmic():
     exact_bits = 32 * sum(int(np.prod(l.shape)) for l in leaves)
     psgd_bits = PowerSGDReducer(compression_rank=16).bits_per_step(leaves)
     assert exact_bits / psgd_bits >= 8.0
+
+
+# ---- the error-feedback entry the trainer calls, against the oracle --------
+
+
+@pytest.mark.parametrize("layout", [_template_leaves, _ragged_leaves], ids=["uniform", "ragged"])
+@pytest.mark.parametrize("rank", [1, 4, 8])
+def test_powersgd_matches_oracle(rank, layout):
+    """``reduce_ef`` (gradients and a nonzero error memory apart, as the
+    trainer hands them over) for r in {1, 4, 8}: batched shape groups,
+    uneven group tails and rank-clipped matrices give the oracle's out,
+    memory, next Q and bits."""
+    reducer = PowerSGDReducer(random_seed=17 + rank, compression_rank=rank)
+    grads = _np_leaves(layout, 29 + rank)
+    mems = [
+        np.zeros_like(m) if m.ndim <= 1 else 0.3 * m
+        for m in _np_leaves(layout, 41 + rank)
+    ]
+    template = [jnp.zeros_like(g) for g in grads]
+    state = reducer.init(template)
+    qs = _qs_from_state(reducer, state, template)
+    exp_out, exp_mems, exp_qs, exp_bits = powersgd_reduce_np(
+        [[g + m for g, m in zip(grads, mems)]], qs, rank
+    )
+    state2, out, mem, bits = reducer.reduce_ef(
+        state, [jnp.asarray(g) for g in grads], [jnp.asarray(m) for m in mems], None
+    )
+    assert bits == exp_bits == reducer.bits_per_step(template)
+    for got, want in zip(list(out) + list(mem), exp_out + exp_mems[0]):
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=1e-4)
+    for q, e in zip(_qs_from_state(reducer, state2, template), exp_qs):
+        np.testing.assert_allclose(q, e, rtol=2e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("rank", [1, 4, 8])
+@pytest.mark.parametrize("n_workers", [2, 4, 8])
+def test_powersgd_multiworker_matches_oracle(devices, n_workers, rank):
+    """Three steps on W workers with the warm-start Q and every worker's
+    error memory carried from step to step: where the P, Q and rank-1
+    all-reduces sit, and what each worker keeps, against the oracle."""
+    mesh = make_mesh(devices=devices[:n_workers])
+    reducer = PowerSGDReducer(random_seed=11, compression_rank=rank)
+    template = [jnp.zeros_like(l) for l in _template_leaves(jax.random.PRNGKey(0))]
+    n = len(template)
+    state = reducer.init(template)
+
+    def f(q_memory, key, grads, mems):
+        st, out, mem, _ = reducer.reduce_ef(
+            PowerSGDState(q_memory, key),
+            [g[0] for g in grads], [m[0] for m in mems], DATA_AXIS,
+        )
+        return st.q_memory, st.key, [o[None] for o in out], [m[None] for m in mem]
+
+    per_worker = [P(DATA_AXIS)] * n
+    shmap = jax.jit(
+        jax.shard_map(
+            f, mesh=mesh,
+            in_specs=(P(), P(), per_worker, per_worker),
+            out_specs=(P(), P(), per_worker, per_worker),
+        )
+    )
+    qs = _qs_from_state(reducer, state, template)
+    q_memory, key = state.q_memory, state.key
+    mems = [jnp.zeros((n_workers,) + t.shape) for t in template]
+    exp_mems = [[np.zeros(t.shape, np.float32) for t in template]] * n_workers
+    for step in range(3):
+        grads = _sends_per_worker(100 + 31 * step, n_workers)
+        sends = [[g + m for g, m in zip(gw, mw)] for gw, mw in zip(grads, exp_mems)]
+        exp_out, exp_mems, qs, _ = powersgd_reduce_np(sends, qs, rank)
+        stacked = [jnp.stack([jnp.asarray(w[i]) for w in grads]) for i in range(n)]
+        q_memory, key, out, mems = shmap(q_memory, key, stacked, mems)
+        for i in range(n):
+            for d in range(n_workers):
+                np.testing.assert_allclose(
+                    np.asarray(out[i])[d], exp_out[i], rtol=5e-4, atol=2e-4
+                )
+                np.testing.assert_allclose(
+                    np.asarray(mems[i])[d], exp_mems[d][i], rtol=5e-4, atol=2e-4
+                )
+    final_qs = _qs_from_state(reducer, PowerSGDState(q_memory, key), template)
+    for q, e in zip(final_qs, qs):
+        np.testing.assert_allclose(q, e, rtol=5e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("rank", [4, 8])
+def test_powersgd_bf16_wire_keeps_fp32_error_feedback(rank):
+    """A bf16 wire narrows P and Q only: the decompressed mean and the error
+    memory stay fp32, and ``out + memory == send`` to fp32 rounding — what
+    the wire's quantisation lost is in the memory, not gone."""
+    reducer = PowerSGDReducer(
+        random_seed=5, compression_rank=rank, compression_dtype=jnp.bfloat16
+    )
+    grads = [jnp.asarray(l) for l in _ragged_leaves(jax.random.PRNGKey(7))]
+    mems = [
+        jnp.zeros_like(l) if l.ndim <= 1 else 0.5 * l
+        for l in _ragged_leaves(jax.random.PRNGKey(8))
+    ]
+    state = reducer.init(grads)
+    assert state.q_memory.dtype == jnp.bfloat16
+    state2, out, mem, bits = reducer.reduce_ef(state, grads, mems, None)
+    assert state2.q_memory.dtype == jnp.bfloat16
+    assert 2 * bits == PowerSGDReducer(compression_rank=rank).bits_per_step(grads)
+    for g, e, o, m in zip(grads, mems, out, mem):
+        assert o.dtype == m.dtype == jnp.float32
+        if g.ndim > 1:
+            send = np.asarray(g) + np.asarray(e)
+            scale = max(np.abs(send).max(), np.abs(np.asarray(o)).max())
+            np.testing.assert_allclose(
+                np.asarray(o) + np.asarray(m), send, rtol=0, atol=4e-7 * scale
+            )
+            # the wire did quantise: the approximation is not the fp32 one
+            assert np.abs(np.asarray(m)).max() > 0
+
+
+# ---- bucketed backward overlap: bitwise identity --------------------------
+
+
+def _run_exact(reducer, stacked):
+    mesh = make_mesh()
+
+    def f(*send):
+        send = [s[0] for s in send]
+        _, out, _, _ = reducer.reduce({}, send, DATA_AXIS)
+        return tuple(o[None] for o in out)
+
+    return jax.jit(
+        jax.shard_map(
+            f, mesh=mesh,
+            in_specs=(P(DATA_AXIS),) * 5, out_specs=(P(DATA_AXIS),) * 5,
+        )
+    )(*stacked)
+
+
+@pytest.mark.parametrize("bucket_bytes", [10**9, 60])
+def test_bucketed_exact_bitwise_equals_monolithic(devices, bucket_bytes):
+    """One giant bucket (K=1) and 4 small buckets (K=4): partitioning the packed
+    payload commutes with the elementwise all-reduce, so the fenced bucket
+    chain is BITWISE the monolithic reduction."""
+    per_worker = [_template_leaves(jax.random.PRNGKey(50 + w)) for w in range(W)]
+    stacked = [jnp.stack([pw[i] for pw in per_worker]) for i in range(5)]
+    reducer = ExactReducer(bucket_bytes=bucket_bytes)
+    n_buckets = len(reducer._buckets([pw for pw in per_worker[0]]))
+    assert n_buckets == (1 if bucket_bytes == 10**9 else 4)
+    mono = _run_exact(ExactReducer(), stacked)
+    bucketed = _run_exact(reducer, stacked)
+    for a, b in zip(bucketed, mono):
+        np.testing.assert_array_equal(_bits(a), _bits(b))
+
+
+@pytest.mark.parametrize("bucket_bytes", [10**9, 60])
+def test_bucketed_ledger_bytes_invariant(bucket_bytes):
+    """The buckets partition the leaves: ledger bytes are invariant and the
+    entries itemize one backward-order bucket each."""
+    template = _template_leaves(jax.random.PRNGKey(0))
+    mono = ExactReducer()
+    bucketed = ExactReducer(bucket_bytes=bucket_bytes)
+    total = sum(e.payload_bytes * 1 for e in mono.ledger_entries(template))
+    entries = bucketed.ledger_entries(template)
+    assert sum(e.payload_bytes for e in entries) == total
+    assert [e.tag for e in entries] == [
+        f"grads.b{i}" for i in range(len(entries))
+    ]

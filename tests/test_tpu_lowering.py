@@ -13,10 +13,6 @@ serving experiments use:
 - and the reducer path the trainer takes on TPU,
   ``PowerSGDReducer(orthogonalize_impl="pallas")`` inside ``shard_map``, is
   stepped on the CPU mesh in interpret mode.
-
-The fused ``ops/pallas_powersgd.py`` kernels (opt-in ``compress_impl=
-"pallas"``, ROADMAP Speed 7) are pinned as strict xfails with the lowering
-error as the reason: no default may select them until they lower.
 """
 
 import jax
@@ -293,23 +289,23 @@ def test_pallas_orthogonalize_reducer_steps_inside_shard_map(devices):
     np.testing.assert_allclose(losses["pallas"], losses["xla"], rtol=1e-5)
 
 
-# --- the opt-in fused kernels do not lower for TPU at all -------------------
+# the distinct P factors of the benchmark's cells that no case above has at
+# its own shape: DistilBERT's three at rank 16 (31, 6 and 1 matrices), the
+# Nemotron mixer's in_proj, and ResNet-152's largest rank-4 group (36 convs)
+CELL_P_SHAPES = [(768, 16), (3072, 16), (30522, 16), (21504, 16), (2304, 4)]
 
 
-@pytest.mark.xfail(
-    strict=True,
-    raises=NotImplementedError,
-    reason="Unimplemented primitive in Pallas TPU lowering for KernelType.TC:"
-    " dynamic_slice (the in-kernel Gram-Schmidt slices a value, not a ref);"
-    " compress_impl='pallas' stays opt-in until it lowers — ROADMAP Speed 7",
-)
-def test_fused_orthogonalize_project_lowers_for_tpu():
-    from network_distributed_pytorch_tpu.ops.pallas_powersgd import (
-        fused_orthogonalize_project,
-    )
+@pytest.mark.parametrize("n,r", CELL_P_SHAPES)
+def test_gram_schmidt_lowers_at_cell_shapes(monkeypatch, n, r):
+    """What the reducer runs on the chip, at the shapes it runs it at: built
+    as there ("auto" resolving to the compiled Pallas Gram-Schmidt), its
+    orthogonalisation of an (n, r) factor lowers for TPU."""
+    from network_distributed_pytorch_tpu.parallel import PowerSGDReducer
 
-    p = jax.ShapeDtypeStruct((2, 768, 16), jnp.float32)
-    m = jax.ShapeDtypeStruct((2, 768, 768), jnp.float32)
-    jax.jit(fused_orthogonalize_project).trace(p, m).lower(
-        lowering_platforms=("tpu",)
-    )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    reducer = PowerSGDReducer(compression_rank=r, matricize="last")
+    assert (reducer.orthogonalize_impl, reducer._interpret) == ("pallas", False)
+    lowered = jax.jit(reducer._orthogonalize).trace(
+        jax.ShapeDtypeStruct((n, r), jnp.float32)
+    ).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
