@@ -21,6 +21,10 @@ the order.
   ``held_experts`` only and every rank the shared expert. Nothing is dropped.
 - **\\*** (``ops.flash_attention``): ``n_heads`` query heads over
   ``n_kv_heads`` key/value heads, causal, scale ``1/sqrt(head_dim)``, no bias.
+  The kernels take q, k, v as the projections emit them, ``(B, T, heads *
+  head_dim)``, a head of 128 lanes a block, and read the key/value head a
+  group shares in place: nothing is repeated or transposed on the way in or
+  out (the einsum path, off the TPU, repeats K and V).
 
 Parameters are fp32; ``dtype`` is what the products run in. The residual
 stream is carried in ``dtype`` (the published ``residual_in_fp32: false``).
@@ -203,12 +207,13 @@ class GroupedQueryAttention(nn.Module):
         v = _dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
         with jax.named_scope("attn.core"):
             # each key/value head serves n_heads // n_kv_heads query heads
-            k, v = (jnp.repeat(x, hq // hkv, axis=2) for x in (k, v))
             if resolve_attn_impl(cfg.attn_impl) == "flash":
                 from ..ops import flash_attention, pallas_interpret
 
+                # the kernels read the shared head in place
                 ctx = flash_attention(q, k, v, causal=True, interpret=pallas_interpret())
             else:
+                k, v = (jnp.repeat(x, hq // hkv, axis=2) for x in (k, v))
                 scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
                 scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores / np.sqrt(hd), -jnp.inf)
                 weights = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)

@@ -2,10 +2,32 @@
 
 Why a kernel: XLA's attention materializes (or at best tiles) the (T, T)
 score matrix through HBM; flash attention never builds it. Each grid program
-owns one Q block, holds K and V of its (batch, head) whole in VMEM, walks
-them in K blocks, and keeps the flash-style running (max, normalizer,
-accumulator) on chip across the whole K loop — one HBM read per operand, one
-write of the output.
+owns one Q block, holds K and V of its heads whole in VMEM, walks them in K
+blocks, and keeps the flash-style running (max, normalizer, accumulator) on
+chip across the whole K loop — one HBM read per operand, one write of the
+output.
+
+Layout: both kernels read and write the model's own ``(B, T, H·D)`` — what
+the q/k/v projections emit and the output projection consumes; ``(B, T, H,
+D)`` to it is a reshape of contiguous memory — so nothing is transposed,
+folded, repeated or re-laid round them. A grid step addresses its heads as a
+LANE BLOCK of the last axis through its ``BlockSpec``s, and a block's last
+dimension has to be a multiple of 128 lanes or the whole axis, so
+``heads_per_block(H, Hkv, D)`` gives the block from the shapes: D a multiple
+of 128, one head a block; D dividing 128 with H·D a multiple of 128, 128 // D
+heads a block (D = 64: a pair); H·D ≤ 128, every head in the one block. The
+heads of a block are walked inside one grid step, each picked out by
+zeroing the others' lanes of an operand: a product over the lanes (S = K·Qᵀ,
+dP = V·dOᵀ) is then that head's at the passes a 128-deep MXU takes either
+way, and a product that keeps the lanes (Oᵀ, dV, dK, dQᵀ) is stored 128
+lanes wide. Grouped K/V: ``k`` and ``v`` may bring ``Hkv`` heads, H a
+multiple; at D a multiple of 128 the index map reads head ``h // (H //
+Hkv)`` in place, dK and dV leave the backward a query head each and are
+summed over each group outside. Shapes no lane block serves (D = 96; three
+heads of 64; grouped heads narrower than 128) take the one fallback: heads
+folded into the batch, ``(B·H, T, D)``, one head the whole last axis, the
+same kernels, a shared K/V row and the mask found by integer division of the
+grid row.
 
 The forward (``_flash_kernel``) feeds the MXU as the backward does. Its two
 products, S = K·Qᵀ and Oᵀ = Vᵀ·P, take their operands in the input dtype and
@@ -15,12 +37,12 @@ dtype for the second. The running max, exp, the normaliser (summed from the
 fp32 P), the accumulator, the validity flags, the lse and the final division
 are fp32. Tiles are transposed, keys on sublanes and queries on lanes, so
 the row statistics and the lse are lane-dense rows and Oᵀ is turned once, at
-(D, block_q), on the way out. The tile edge is ``tile_edge(T)``, the largest
-of 512/256/128 that divides T (else one block of at most 128), the same the
-backward takes; explicit ``block_q``/``block_k`` win in both directions. Its
-reach is VMEM: K and V held whole, double-buffered, beside the tile's fp32
-temporaries, which it asks Mosaic for beyond the 16 MiB default — T = 16384
-at D = 128 in bf16 compiles.
+(lanes, block_q), on the way out. The tile edge is ``tile_edge(T)``, the
+largest of 512/256/128 that divides T (else one block of at most 128), the
+same the backward takes; explicit ``block_q``/``block_k`` win in both
+directions. Its reach is VMEM: K and V held whole, double-buffered, beside
+the tile's fp32 temporaries, which it asks Mosaic for beyond the 16 MiB
+default — T = 16384 at D = 128 in bf16 compiles.
 
 The online-softmax recurrence is the same one the framework's ring and
 Ulysses schedules use (``parallel.sequence``); this kernel is the
@@ -33,19 +55,21 @@ the per-row log-sum-exp; the backward is a second Pallas kernel
 (``_flash_bwd_kernel``, named ``flash_attention_bwd`` in the compiled
 program) that recomputes P = exp(S − lse) tile by tile and runs the standard
 flash recurrence ``dS = P ∘ (dO·Vᵀ − D)``, dV = Pᵀ·dO, dQ = dS·K, dK = dSᵀ·Q
-with S, P, dP and dS never leaving VMEM: one grid step per (batch, head)
-holds q, k, v and dO whole, loops K blocks outside and Q blocks inside
-(causal: Q blocks before the K block are skipped by the loop bound), and
-emits dq, dk, dv and the additive mask's cotangent per head (summed over
-heads outside). Only D = rowsum(dO ∘ O) is an XLA reduction. Every shape
-takes this path, on TPU and in interpret mode alike, on the forward's
-tiles. Its reach is VMEM: seven (T, D) operands held whole, which it asks
-Mosaic for beyond the 16 MiB default — T = 16384 at D = 128 in bf16
-compiles, as far as the forward's own whole-K/V residency goes.
+with S, P, dP and dS never leaving VMEM: one grid step per (sequence, lane
+block) holds q, k, v, o and dO whole, makes D = rowsum(dO ∘ O) a row a Q
+block first, loops K blocks outside and Q blocks inside (causal: Q blocks
+before the K block are skipped by the loop bound), and emits dq, dk, dv and
+the additive mask's cotangent per lane block (summed over the blocks
+outside). Every shape takes this path, on TPU and in interpret mode alike,
+on the forward's tiles. Its reach is VMEM: eight (T, lanes) operands held
+whole, which it asks Mosaic for beyond the 16 MiB default — T = 16384 at
+D = 128 in bf16 compiles, as far as the forward's own whole-K/V residency
+goes.
 
 Correctness is pinned against naive einsum attention (padding masks, causal,
-both, and grads) in ``tests/test_flash_attention.py``; on CPU the kernel
-runs in interpret mode (the test path), on TPU it compiles with Mosaic.
+both, grads, every lane block, the fold and grouped K/V) in
+``tests/test_flash_attention.py``; on CPU the kernel runs in interpret mode
+(the test path), on TPU it compiles with Mosaic.
 """
 
 from __future__ import annotations
@@ -97,12 +121,44 @@ def _keys_on_sublanes(row):
     return jnp.sum(jnp.where(diagonal, row, 0.0), axis=1, keepdims=True)
 
 
+def heads_per_block(h: int, hkv: int, d: int):
+    """How many heads of width ``d`` one lane block of the ``(B, T, H*d)``
+    layout holds, or None where no lane block serves the shape and the
+    heads are folded into the batch instead. A block's last dimension is a
+    multiple of 128 lanes or the whole axis: a head of 128 lanes or a
+    multiple is a block of its own (and ``hkv < h`` key/value heads are then
+    read in place, head ``i // (h // hkv)``); narrower heads that fill 128
+    lanes exactly go 128 // d to a block; heads that all fit in 128 lanes
+    are the one block."""
+    if d % 128 == 0:
+        return 1
+    if hkv != h:
+        return None
+    if h * d <= 128:
+        return h
+    if 128 % d == 0 and (h * d) % 128 == 0:
+        return 128 // d
+    return None
+
+
+def _only_head(x, heads: int, i: int, axis: int = -1):
+    """``x`` with every head's share of ``axis`` but head ``i``'s zeroed: a
+    product over that axis then sees head ``i`` alone, and one that keeps
+    the axis is zero outside it, so the heads of a block add up."""
+    if heads == 1:
+        return x
+    d = x.shape[axis] // heads
+    at = lax.broadcasted_iota(jnp.int32, x.shape, axis % x.ndim)
+    return jnp.where((at >= i * d) & (at < (i + 1) * d), x, jnp.zeros_like(x))
+
+
 def _flash_kernel(
     block_q: int,
     block_k: int,
     t: int,
     causal: bool,
     scale: float,
+    heads: int,
     q_ref,
     k_ref,
     v_ref,
@@ -110,17 +166,24 @@ def _flash_kernel(
     o_ref,
     lse_ref,
 ):
-    """One Q block against every K block it can see, fed to the MXU as the
-    backward feeds it: both products take their operands in the input dtype
-    and accumulate in fp32, the scale meets the fp32 scores after the
-    product, and the tiles are TRANSPOSED, keys on sublanes and queries on
-    lanes — the running max, normaliser and lse are lane-dense rows, the
-    softmax reduces down sublanes, and the output gathers as Oᵀ = Vᵀ·P,
-    turned once at (d, block_q) on the way out. The running max, exp,
-    normaliser, accumulator, validity flags and lse are fp32."""
-    qi = pl.program_id(1)
-    q = q_ref[0]  # (block_q, d)
-    d = q.shape[-1]
+    """One Q block of ``heads`` heads, side by side on the lanes, against
+    every K block it can see, fed to the MXU as the backward feeds it: both
+    products take their operands in the input dtype and accumulate in fp32,
+    the scale meets the fp32 scores after the product, and the tiles are
+    TRANSPOSED, keys on sublanes and queries on lanes — the running max,
+    normaliser and lse are lane-dense rows, the softmax reduces down
+    sublanes, and the output gathers as Oᵀ = Vᵀ·P, turned once at
+    (lanes, block_q) on the way out. The running max, exp, normaliser,
+    accumulator, validity flags and lse are fp32.
+
+    A head is picked out of the block by zeroing the others' lanes of Q:
+    S = K·Qᵀ over all the lanes is then that head's, at the passes of a
+    128-deep MXU either way; Vᵀ·P comes out for every lane of the block and
+    each head keeps its own rows."""
+    qi = pl.program_id(2)
+    q = q_ref[0]  # (block_q, lanes)
+    lanes = q.shape[-1]
+    q_heads = [_only_head(q, heads, i) for i in range(heads)]
     nt = (((1,), (1,)), ((), ()))  # A·Bᵀ
     tn = (((0,), (0,)), ((), ()))  # Aᵀ·B
     tile = (block_k, block_q)
@@ -134,7 +197,6 @@ def _flash_kernel(
         hi = n_blocks
 
     def body(j, carry):
-        m, l, acc = carry  # (1, block_q), (1, block_q), (d, block_q)
         ks = pl.multiple_of(j * block_k, block_k)
         k_blk = k_ref[0, pl.ds(ks, block_k), :]
         v_blk = v_ref[0, pl.ds(ks, block_k), :]
@@ -142,36 +204,51 @@ def _flash_kernel(
         # a dynamic sublane index — Mosaic has no dynamic lane slicing —
         # with its keys on lanes; this tile wants them on sublanes
         mask_col = _keys_on_sublanes(mask_ref[0, pl.ds(j, 1), :])
-        s = jax.lax.dot_general(
-            k_blk, q, nt, preferred_element_type=jnp.float32
-        ) * scale + mask_col
         valid = jnp.broadcast_to(mask_col > _MASK_PAD, tile)
         if causal:
             k_pos = ks + lax.broadcasted_iota(jnp.int32, tile, 0)
             q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, tile, 1)
             valid = valid & (q_pos >= k_pos)
 
-        # invalid (padding / causal-pruned) entries are force-excluded by
-        # the validity flag — never by hoping exp underflows (see _MASK_PAD)
-        blk_max = jnp.max(jnp.where(valid, s, _NEG_INF), axis=0, keepdims=True)
-        new_m = jnp.maximum(m, blk_max)
-        correction = jnp.exp(m - new_m)
-        p = jnp.where(valid, jnp.exp(s - new_m), 0.0)
-        l = l * correction + jnp.sum(p, axis=0, keepdims=True)
-        acc = acc * correction + jax.lax.dot_general(
-            v_blk, p.astype(v_blk.dtype), tn,
-            preferred_element_type=jnp.float32,
-        )  # (d, block_q)
-        return new_m, l, acc
+        def one_head(q, m, l, acc):
+            # m, l: (1, block_q); acc: (lanes, block_q)
+            s = jax.lax.dot_general(
+                k_blk, q, nt, preferred_element_type=jnp.float32
+            ) * scale + mask_col
+            # invalid (padding / causal-pruned) entries are force-excluded
+            # by the validity flag — never by hoping exp underflows (see
+            # _MASK_PAD)
+            blk_max = jnp.max(
+                jnp.where(valid, s, _NEG_INF), axis=0, keepdims=True
+            )
+            new_m = jnp.maximum(m, blk_max)
+            correction = jnp.exp(m - new_m)
+            p = jnp.where(valid, jnp.exp(s - new_m), 0.0)
+            l = l * correction + jnp.sum(p, axis=0, keepdims=True)
+            acc = acc * correction + jax.lax.dot_general(
+                v_blk, p.astype(v_blk.dtype), tn,
+                preferred_element_type=jnp.float32,
+            )
+            return new_m, l, acc
+
+        return tuple(
+            one_head(q, *state) for q, state in zip(q_heads, carry)
+        )
 
     m0 = jnp.full((1, block_q), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((1, block_q), jnp.float32)
-    acc0 = jnp.zeros((d, block_q), jnp.float32)
-    m, l, acc = lax.fori_loop(0, hi, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-37)).T.astype(o_ref.dtype)
-    lse_ref[0] = jnp.where(
-        l > 0, m + jnp.log(jnp.maximum(l, 1e-37)), _LSE_EMPTY
-    )
+    acc0 = jnp.zeros((lanes, block_q), jnp.float32)
+    done = lax.fori_loop(0, hi, body, ((m0, l0, acc0),) * heads)
+    for i, (m, l, _) in enumerate(done):
+        lse_ref[0, i] = jnp.where(
+            l > 0, m + jnp.log(jnp.maximum(l, 1e-37)), _LSE_EMPTY
+        )
+    # of Vᵀ·P's rows a head keeps its own
+    out_t = functools.reduce(jnp.add, [
+        _only_head(acc / jnp.maximum(l, 1e-37), heads, i, axis=0)
+        for i, (_, l, acc) in enumerate(done)
+    ])
+    o_ref[0] = out_t.T.astype(o_ref.dtype)
 
 
 def _flash_bwd_kernel(
@@ -180,39 +257,65 @@ def _flash_bwd_kernel(
     t: int,
     causal: bool,
     scale: float,
+    heads: int,
     q_ref,
     k_ref,
     v_ref,
+    o_ref,
     do_ref,
     lse_ref,
-    delta_ref,
     mask_ref,
     dq_ref,
     dk_ref,
     dv_ref,
     dmask_ref,
     dqt_acc,
+    delta_acc,
 ):
-    """The whole backward of one (batch, head): q, k, v, do sit in VMEM, a
-    loop over K blocks holds dK/dV of its block while the loop inside it
-    walks the Q blocks — so s, p, dp and ds live and die as
-    (block_k, block_q) tiles and each of the five products runs once.
+    """The whole backward of one lane block of ``heads`` heads of one
+    sequence: q, k, v, o, do sit in VMEM, a loop over K blocks holds dK/dV
+    of its block while the loop inside it walks the Q blocks — so s, p, dp
+    and ds live and die as (block_k, block_q) tiles and each of the five
+    products runs once a head.
 
     The tiles are TRANSPOSED, keys on sublanes and queries on lanes: lse and
-    D then broadcast as the lane-dense rows they arrive as, and no product
-    needs a score-sized operand turned — dQ gathers as dQᵀ = Kᵀ·dS in an
-    fp32 VMEM scratch and is turned once, at (block_q, d), on the way out.
-    Products take their operands in the input dtype and accumulate in fp32;
-    exp, the row terms and every accumulator are fp32."""
+    D then broadcast as lane-dense rows, and no product needs a score-sized
+    operand turned — dQ gathers as dQᵀ = Kᵀ·dS in an fp32 VMEM scratch and
+    is turned once, at (block_q, lanes), on the way out. D = rowsum(dO ∘ O)
+    is made here first, a row a Q block and head. Products take their
+    operands in the input dtype and accumulate in fp32; exp, the row terms
+    and every accumulator are fp32.
+
+    A head is picked out of the block by zeroing the others' lanes of its
+    Q, K and dO: the two products over the lanes (S, dP) are then that
+    head's, and the three that keep the lanes (dV, dK, dQᵀ) are zero
+    outside them, so the heads' add up in the block's accumulators."""
     n_q, n_k = t // block_q, t // block_k
     nt = (((1,), (1,)), ((), ()))  # A·Bᵀ
     tn = (((0,), (0,)), ((), ()))  # Aᵀ·B
     tile = (block_k, block_q)
+    lanes = q_ref.shape[-1]
     dqt_acc[...] = jnp.zeros_like(dqt_acc)
+
+    def row_terms(i, _):
+        qs = pl.multiple_of(i * block_q, block_q)
+        rows = pl.ds(qs, block_q)
+        prod_t = (
+            do_ref[0, rows, :].astype(jnp.float32)
+            * o_ref[0, rows, :].astype(jnp.float32)
+        ).T  # (lanes, block_q)
+        for h in range(heads):
+            delta_acc[h, pl.ds(i, 1), :] = jnp.sum(
+                _only_head(prod_t, heads, h, axis=0), axis=0, keepdims=True
+            )
+        return 0
+
+    lax.fori_loop(0, n_q, row_terms, 0)
 
     def k_block(j, _):
         ks = pl.multiple_of(j * block_k, block_k)
         k_blk = k_ref[0, pl.ds(ks, block_k), :]
+        k_heads = [_only_head(k_blk, heads, h) for h in range(heads)]
         v_blk = v_ref[0, pl.ds(ks, block_k), :]
         # the mask arrives with its keys on lanes, (1, block_k); this tile
         # wants them on sublanes
@@ -224,35 +327,44 @@ def _flash_bwd_kernel(
             qs = pl.multiple_of(i * block_q, block_q)
             q_blk = q_ref[0, pl.ds(qs, block_q), :]
             do_blk = do_ref[0, pl.ds(qs, block_q), :]
-            s = jax.lax.dot_general(
-                k_blk, q_blk, nt, preferred_element_type=jnp.float32
-            ) * scale + mask_col
             valid = key_ok
             if causal:
                 k_pos = ks + lax.broadcasted_iota(jnp.int32, tile, 0)
                 q_pos = qs + lax.broadcasted_iota(jnp.int32, tile, 1)
                 valid = valid & (q_pos >= k_pos)
-            # invalid entries are force-excluded by the flag, as in the
-            # forward; an all-padded row has lse == _LSE_EMPTY and p == 0
-            p = jnp.where(valid, jnp.exp(s - lse_ref[0, pl.ds(i, 1), :]), 0.0)
-            dv = dv + jnp.dot(
-                p.astype(do_blk.dtype), do_blk,
-                preferred_element_type=jnp.float32,
-            )
-            dp = jax.lax.dot_general(
-                v_blk, do_blk, nt, preferred_element_type=jnp.float32
-            )
-            ds = p * (dp - delta_ref[0, pl.ds(i, 1), :])
-            ds_in = ds.astype(q_blk.dtype)
-            dk = dk + jnp.dot(ds_in, q_blk, preferred_element_type=jnp.float32)
-            dqt_acc[i] += jax.lax.dot_general(
-                k_blk, ds_in, tn, preferred_element_type=jnp.float32
-            )  # (d, block_q)
-            # the mask enters s additively: its cotangent is ds summed over
-            # the query rows (here) and over the heads (outside)
-            return dk, dv, dmask + jnp.sum(ds, axis=1, keepdims=True)
+            for h in range(heads):
+                q_h = _only_head(q_blk, heads, h)
+                do_h = _only_head(do_blk, heads, h)
+                s = jax.lax.dot_general(
+                    k_heads[h], q_h, nt, preferred_element_type=jnp.float32
+                ) * scale + mask_col
+                # invalid entries are force-excluded by the flag, as in the
+                # forward; an all-padded row has lse == _LSE_EMPTY and p == 0
+                p = jnp.where(
+                    valid, jnp.exp(s - lse_ref[0, h, pl.ds(i, 1), :]), 0.0
+                )
+                dv = dv + jnp.dot(
+                    p.astype(do_h.dtype), do_h,
+                    preferred_element_type=jnp.float32,
+                )
+                dp = jax.lax.dot_general(
+                    v_blk, do_h, nt, preferred_element_type=jnp.float32
+                )
+                ds = p * (dp - delta_acc[h, pl.ds(i, 1), :])
+                ds_in = ds.astype(q_h.dtype)
+                dk = dk + jnp.dot(
+                    ds_in, q_h, preferred_element_type=jnp.float32
+                )
+                dqt_acc[i] += jax.lax.dot_general(
+                    k_heads[h], ds_in, tn, preferred_element_type=jnp.float32
+                )  # (lanes, block_q)
+                # the mask enters s additively: its cotangent is ds summed
+                # over the query rows and the block's heads (here) and over
+                # the head blocks (outside)
+                dmask = dmask + jnp.sum(ds, axis=1, keepdims=True)
+            return dk, dv, dmask
 
-        zero = jnp.zeros((block_k, k_blk.shape[-1]), jnp.float32)
+        zero = jnp.zeros((block_k, lanes), jnp.float32)
         # causal: Q blocks that end before this K block starts see none of it
         lo = lax.div(j * block_k, block_q) if causal else 0
         dk, dv, dmask = lax.fori_loop(
@@ -260,7 +372,7 @@ def _flash_bwd_kernel(
         )
         dk_ref[0, pl.ds(ks, block_k), :] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[0, pl.ds(ks, block_k), :] = dv.astype(dv_ref.dtype)
-        dmask_ref[0, pl.ds(j, 1), :] = dmask.reshape(1, block_k)
+        dmask_ref[0, 0, pl.ds(j, 1), :] = dmask.reshape(1, block_k)
         return 0
 
     lax.fori_loop(0, n_k, k_block, 0)
@@ -283,7 +395,8 @@ _VMEM_MOST = 100 * 2**20
 
 def _vmem_params(resident: int):
     """Ask Mosaic for ``resident`` bytes of VMEM where that passes its
-    default — long sequences only: the cells' imdb shapes stay under it."""
+    default: long sequences, and a block of two heads at 512x512 tiles,
+    whose fp32 temporaries are live side by side."""
     if resident <= _VMEM_DEFAULT:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=min(resident, _VMEM_MOST))
@@ -298,68 +411,155 @@ def tile_edge(t: int) -> int:
     return next((e for e in (512, 256, 128) if t % e == 0), min(128, t))
 
 
-def _flash_bwd(
-    scale, causal, h, block_q, block_k, interpret, q, k, v, mask, out, lse, do
-):
-    """Flash backward as one Pallas kernel (``_flash_bwd_kernel``): p is
-    recomputed from the saved lse, dV = Pᵀ dO, dS = P ∘ (dO Vᵀ − D),
-    dQ = dS·K, dK = dSᵀ Q, and no score-sized array reaches HBM. Shapes are
-    the folded (B*H, T, D); mask is (B, T), shared over heads; returns
-    (dq, dk, dv, dmask)."""
-    bh, t, d = q.shape
-    b = bh // h
-    # D = rowsum(dO ∘ O), the softmax backward's row term: (B*H, T) fp32
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    # inside shard_map the outputs vary over the mesh as the operands do
+def _vma(*operands):
+    """How a kernel's outputs vary over the mesh inside ``shard_map``:
+    ``pallas_call`` must declare it, and it is as the union of its operands
+    do."""
     vma = frozenset()
-    for operand in (q, k, v, mask, do):
+    for operand in operands:
         vma = vma | jax.typeof(operand).vma
-    whole = pl.BlockSpec((1, t, d), lambda i: (i, 0, 0))
+    return vma
+
+
+def _shares(q, k, mask):
+    """How many rows of q share one row of k/v, how many lane blocks of q
+    one of k/v, and how many rows of q one row of the mask: the integer
+    divisions of the kernels' index maps."""
+    return (
+        q.shape[0] // k.shape[0],
+        q.shape[2] // k.shape[2],
+        q.shape[0] // mask.shape[0],
+    )
+
+
+def _flash_fwd(
+    scale, causal, lanes, heads, block_q, block_k, interpret, q, k, v, mask
+):
+    """The forward kernel over the layout both kernels address: q
+    (N, T, Hq·D), k and v (Nkv, T, Hkv·D), mask (B, T); N is a multiple of
+    Nkv and of B, and Hq of Hkv. The grid is (row of q, lane block, Q
+    block), a lane block being ``lanes`` wide and ``heads`` heads; the
+    key/value block and the mask row a query block shares are found by
+    integer division in the index maps. Returns the output, as q, and the
+    lse, (N, Hq, 1, T)."""
+    n, t, width = q.shape
+    n_heads = width // lanes * heads
+    per_row, per_block, per_mask = _shares(q, k, mask)
+    vma = _vma(q, k, v, mask)
+    # what the kernel keeps in VMEM: K and V whole and the Q and O blocks,
+    # double-buffered and padded to 128 lanes, and a few fp32 tiles a head
+    resident = (
+        4 * (t + block_q) * max(lanes, 128) * q.dtype.itemsize
+        + heads * 8 * 4 * block_q * block_k
+    )
+    kv_block = pl.BlockSpec(
+        (1, t, lanes), lambda i, hb, qi: (i // per_row, 0, hb // per_block)
+    )
+    # TPU block shapes need their last two dims (8, 128)-divisible or equal
+    # to the array's: the mask rides as (B, T/block_k, block_k) and the lse
+    # as (N, Hq, 1, T), never as 2-D rows of width T
+    return pl.pallas_call(
+        functools.partial(
+            _flash_kernel, block_q, block_k, t, causal, scale, heads
+        ),
+        grid=(n, width // lanes, t // block_q),
+        in_specs=[
+            pl.BlockSpec((1, block_q, lanes), lambda i, hb, qi: (i, qi, hb)),
+            kv_block,
+            kv_block,
+            pl.BlockSpec(
+                (1, t // block_k, block_k),
+                lambda i, hb, qi: (i // per_mask, 0, 0),
+            ),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, lanes), lambda i, hb, qi: (i, qi, hb)),
+            pl.BlockSpec(
+                (1, heads, 1, block_q), lambda i, hb, qi: (i, hb, 0, qi)
+            ),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((n, n_heads, 1, t), jnp.float32, vma=vma),
+        ],
+        compiler_params=_vmem_params(resident),
+        interpret=interpret,
+    )(q, k, v, mask.reshape(-1, t // block_k, block_k))
+
+
+def _flash_bwd(
+    scale, causal, lanes, heads, block_q, block_k, interpret,
+    q, k, v, mask, out, lse, do,
+):
+    """Flash backward as one Pallas kernel (``_flash_bwd_kernel``) over the
+    forward's layout and lane blocks: p is recomputed from the saved lse,
+    dV = Pᵀ dO, dS = P ∘ (dO Vᵀ − D), dQ = dS·K, dK = dSᵀ Q, and no
+    score-sized array reaches HBM. dK and dV leave the kernel a query head
+    each and the heads that share a key/value head are summed here, as the
+    mask's cotangent is over every head; returns (dq, dk, dv, dmask)."""
+    n, t, width = q.shape
+    n_blocks = width // lanes
+    per_row, per_block, per_mask = _shares(q, k, mask)
+    vma = _vma(q, k, v, mask, do)
+    whole = pl.BlockSpec((1, t, lanes), lambda i, hb: (i, 0, hb))
+    kv_whole = pl.BlockSpec(
+        (1, t, lanes), lambda i, hb: (i // per_row, 0, hb // per_block)
+    )
     # per-row scalars ride as (blocks, block): block i is sublane row i of a
-    # lane-dense array (no dynamic lane slicing on TPU) — lse and D by Q
+    # lane-dense array (no dynamic lane slicing on TPU) — the lse by Q
     # block, the mask and its cotangent by K block
     q_rows = (t // block_q, block_q)
     k_rows = (t // block_k, block_k)
-    # what the kernel keeps in VMEM: seven (T, D) operands, double-buffered
-    # and padded to 128 lanes, the dQᵀ scratch and a few fp32 tiles
+    # what the kernel keeps in VMEM: eight (T, lanes) operands,
+    # double-buffered and padded to 128 lanes, the dQᵀ scratch and a few
+    # fp32 tiles a head
     resident = (
-        14 * t * max(d, 128) * q.dtype.itemsize
-        + 4 * t * d
-        + 8 * 4 * block_q * block_k
+        16 * t * max(lanes, 128) * q.dtype.itemsize
+        + 4 * t * lanes
+        + heads * 8 * 4 * block_q * block_k
     )
     dq, dk, dv, dmask = pl.pallas_call(
         functools.partial(
-            _flash_bwd_kernel, block_q, block_k, t, causal, scale
+            _flash_bwd_kernel, block_q, block_k, t, causal, scale, heads
         ),
-        grid=(bh,),
+        grid=(n, n_blocks),
         in_specs=[
-            whole, whole, whole, whole,
-            pl.BlockSpec((1,) + q_rows, lambda i: (i, 0, 0)),
-            pl.BlockSpec((1,) + q_rows, lambda i: (i, 0, 0)),
-            # mask is per-batch: integer-divide the (b*h) grid row
-            pl.BlockSpec((1,) + k_rows, lambda i: (i // h, 0, 0)),
+            whole, kv_whole, kv_whole, whole, whole,
+            pl.BlockSpec((1, heads) + q_rows, lambda i, hb: (i, hb, 0, 0)),
+            pl.BlockSpec((1,) + k_rows, lambda i, hb: (i // per_mask, 0, 0)),
         ],
         out_specs=[
             whole, whole, whole,
-            pl.BlockSpec((1,) + k_rows, lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1) + k_rows, lambda i, hb: (i, hb, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, t, d), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, t, d), v.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh,) + k_rows, jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
+            jax.ShapeDtypeStruct(q.shape, k.dtype, vma=vma),
+            jax.ShapeDtypeStruct(q.shape, v.dtype, vma=vma),
+            jax.ShapeDtypeStruct((n, n_blocks) + k_rows, jnp.float32, vma=vma),
         ],
-        scratch_shapes=[pltpu.VMEM((t // block_q, d, block_q), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((t // block_q, lanes, block_q), jnp.float32),
+            pltpu.VMEM((heads,) + q_rows, jnp.float32),
+        ],
         compiler_params=_vmem_params(resident),
         interpret=interpret,
         name="flash_attention_bwd",
     )(
-        q, k, v, do,
-        lse.reshape((bh,) + q_rows),
-        delta.reshape((bh,) + q_rows),
-        mask.reshape((b,) + k_rows),
+        q, k, v, out, do,
+        lse.reshape((n, -1) + q_rows),
+        mask.reshape((-1,) + k_rows),
     )
-    return dq, dk, dv, dmask.reshape(b, h, t).sum(axis=1)
+    if per_row * per_block > 1:
+        # the query heads of a group each brought a dK and a dV of the one
+        # key/value head they read
+        d = lanes // heads
+        shared = lambda x: x.reshape(
+            k.shape[0], per_row, t, k.shape[2] // d, per_block, d
+        ).sum(axis=(1, 4)).reshape(k.shape)
+        dk, dv = shared(dk), shared(dv)
+    dmask = dmask.reshape(mask.shape[0], per_mask * n_blocks, t).sum(axis=1)
+    return dq, dk, dv, dmask
 
 
 @functools.partial(
@@ -378,7 +578,9 @@ def flash_attention(
 ) -> jax.Array:
     """Exact attention without materializing the score matrix.
 
-    q/k/v: (B, T, H, D) — the package's layout everywhere else.
+    q: (B, T, H, D) — the package's layout everywhere else; k/v:
+    (B, T, Hkv, D) with H a multiple of Hkv: query head ``i`` reads
+    key/value head ``i // (H // Hkv)``.
     mask: optional (B, T) additive key mask (0 = attend, very negative =
     padding), the same convention as ``parallel.sequence``.
     block_q/block_k: the score tile of both kernels; ``None`` is
@@ -387,6 +589,12 @@ def flash_attention(
     in q's dtype.
     """
     b, t, h, d = q.shape
+    hkv = k.shape[2]
+    if h % hkv or v.shape != k.shape or k.shape != (b, t, hkv, d):
+        raise ValueError(
+            f"q {q.shape} needs k and v of one shape (B, T, Hkv, D) with H a"
+            f" multiple of Hkv; got {k.shape} and {v.shape}"
+        )
     block_q = min(block_q or tile_edge(t), t)
     block_k = min(block_k or tile_edge(t), t)
     assert t % block_q == 0 and t % block_k == 0, (
@@ -395,9 +603,18 @@ def flash_attention(
     )
     scale = 1.0 / float(d) ** 0.5
 
-    # (B, T, H, D) -> (B*H, T, D): one grid row per (batch, head)
-    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-    qf, kf, vf = fold(q), fold(k), fold(v)
+    heads = heads_per_block(h, hkv, d)
+    if heads is None:
+        # no lane block serves these heads: (B, T, H, D) -> (B*H, T, D),
+        # one row a (batch, head)
+        heads = 1
+        rows = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, t, d)
+        unrows = lambda x: x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    else:
+        # the model's own layout: heads side by side on the last axis, as
+        # the projections emit them
+        rows = lambda x: x.reshape(b, t, -1)
+        unrows = lambda x: x.reshape(b, t, h, d)
     if mask is None:
         mask = jnp.zeros((b, t), jnp.float32)
     mask = mask.astype(jnp.float32)
@@ -405,69 +622,22 @@ def flash_attention(
     # backward's dmask does (it is built from do), and a custom_vjp
     # cotangent has to have its primal's type — a mask made here (or shared
     # by all workers) would otherwise be invariant
-    missing = tuple(jax.typeof(qf).vma - jax.typeof(mask).vma)
+    missing = tuple(jax.typeof(q).vma - jax.typeof(mask).vma)
     if missing:
         mask = lax.pcast(mask, missing, to="varying")
 
-    kernel = functools.partial(
-        _flash_kernel, block_q, block_k, t, causal, scale
-    )
-
-    # what the kernel keeps in VMEM: K and V whole and the Q and O blocks,
-    # double-buffered and padded to 128 lanes, and a few fp32 tiles
-    resident = (
-        4 * (t + block_q) * max(d, 128) * q.dtype.itemsize
-        + 8 * 4 * block_q * block_k
-    )
-
-    def call_kernel(qf, kf, vf, mask):
-        # inside shard_map, pallas_call must declare how its outputs vary
-        # over the mesh — exactly as the union of its operands do
-        vma = frozenset()
-        for operand in (qf, kf, vf, mask):
-            vma = vma | jax.typeof(operand).vma
-        # TPU block shapes need their last two dims (8, 128)-divisible or
-        # equal to the array's: the mask rides as (B, T/block_k, block_k)
-        # and the lse as (B*H, 1, T), never as 2-D rows of width T
-        out, lse = pl.pallas_call(
-            kernel,
-            grid=(b * h, t // block_q),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-                pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0)),
-                pl.BlockSpec((1, t, d), lambda bh, qi: (bh, 0, 0)),
-                # mask is per-batch: integer-divide the (b*h) grid row
-                pl.BlockSpec(
-                    (1, t // block_k, block_k), lambda bh, qi: (bh // h, 0, 0)
-                ),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-                pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((b * h, t, d), q.dtype, vma=vma),
-                jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32, vma=vma),
-            ],
-            compiler_params=_vmem_params(resident),
-            interpret=interpret,
-        )(qf, kf, vf, mask.reshape(b, t // block_k, block_k))
-        return out, lse.reshape(b * h, t)
+    static = (scale, causal, heads * d, heads, block_q, block_k, interpret)
 
     @jax.custom_vjp
-    def attn(qf, kf, vf, mask):
-        out, _ = call_kernel(qf, kf, vf, mask)
-        return out
+    def attn(q, k, v, mask):
+        return _flash_fwd(*static, q, k, v, mask)[0]
 
-    def attn_fwd(qf, kf, vf, mask):
-        out, lse = call_kernel(qf, kf, vf, mask)
-        return out, (qf, kf, vf, mask, out, lse)
+    def attn_fwd(q, k, v, mask):
+        out, lse = _flash_fwd(*static, q, k, v, mask)
+        return out, (q, k, v, mask, out, lse)
 
     def attn_bwd(res, do):
-        return _flash_bwd(
-            scale, causal, h, block_q, block_k, interpret, *res, do
-        )
+        return _flash_bwd(*static, *res, do)
 
     attn.defvjp(attn_fwd, attn_bwd)
-    out = attn(qf, kf, vf, mask)
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return unrows(attn(rows(q), rows(k), rows(v), mask))

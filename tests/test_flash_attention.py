@@ -2,7 +2,9 @@
 plain, padding-masked, causal, and causal+masked; bf16 inputs; the GPT
 attn_impl="flash" path; the forward kernel and the backward kernel's dq,
 dk, dv and dmask at the tiles the chip runs (``tile_edge``), alone and
-inside ``shard_map``."""
+inside ``shard_map``; every lane block ``heads_per_block`` picks, the fold
+it falls back to and grouped key/value heads; and that nothing is turned or
+folded round the kernels where a lane block serves."""
 
 import functools
 
@@ -13,6 +15,7 @@ import pytest
 
 from network_distributed_pytorch_tpu.ops.flash_attention import (
     flash_attention,
+    heads_per_block,
     tile_edge,
 )
 
@@ -26,6 +29,8 @@ def _qkv(seed, dtype=jnp.float32):
 
 
 def _naive(q, k, v, mask=None, causal=False):
+    # fewer key/value heads: each serves a group of query heads
+    k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2) for x in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
     s = s / jnp.sqrt(q.shape[-1])
     if mask is not None:
@@ -206,23 +211,27 @@ def test_flash_forward_kernel_matches_naive(devices, t, kind, dtype):
     assert np.abs(got[live] - want[live]).max() <= tol * np.abs(want[live]).max()
 
 
+def _outside_the_kernels(jaxpr):
+    """Every equation of a jaxpr and of what it calls, the bodies of the
+    ``pallas_call``s left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _outside_the_kernels(sub)
+
+
 def _kernel_blocks(jaxpr):
     """{kernel name: the block shape of each operand and output} of every
     ``pallas_call`` in a jaxpr; the forward kernel has no name."""
-    found = {}
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found[eqn.params["name"]] = [
-                    tuple(dim.block_size for dim in m.block_shape)
-                    for m in eqn.params["grid_mapping"].block_mappings
-                ]
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jaxpr.jaxpr)
-    return found
+    return {
+        eqn.params["name"]: [
+            tuple(dim.block_size for dim in m.block_shape)
+            for m in eqn.params["grid_mapping"].block_mappings
+        ]
+        for eqn in _outside_the_kernels(jaxpr.jaxpr)
+        if eqn.primitive.name == "pallas_call"
+    }
 
 
 @pytest.mark.parametrize(
@@ -240,11 +249,13 @@ def test_tile_edge_is_shared_by_forward_and_backward(devices, t, edge):
 
     kernels = _kernel_blocks(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
     assert sorted(kernels, key=str) == [None, "flash_attention_bwd"]
+    # two heads of 64 are one block of 128 lanes
     q_block, _, _, mask_rows, _, lse_block = kernels[None]
-    assert (q_block, lse_block) == ((1, edge, 64), (1, 1, edge))
+    assert (q_block, lse_block) == ((1, edge, 128), (1, 2, 1, edge))
     assert mask_rows == (1, t // edge, edge)
-    _, _, _, _, lse_rows, _, mask_rows = kernels["flash_attention_bwd"][:7]
-    assert lse_rows == mask_rows == (1, t // edge, edge)
+    _, _, _, _, _, lse_rows, mask_rows = kernels["flash_attention_bwd"][:7]
+    assert lse_rows == (1, 2, t // edge, edge)
+    assert mask_rows == (1, t // edge, edge)
 
 
 # --- the backward kernel at the tile sizes the chip runs (T up to 512) -------
@@ -365,3 +376,93 @@ def test_flash_backward_kernel_inside_shard_map(devices, masked):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(e), rtol=2e-4, atol=2e-5
         )
+
+
+# --- lane blocks, the fold and grouped key/value heads -----------------------
+
+# (H, Hkv, D) -> heads a lane block: all of them where they fit 128 lanes,
+# 128 // D where they fill 128 exactly, one where D is a multiple of 128
+# (then fewer key/value heads are read in place), else None: the fold
+LANE_RULE = [
+    ((2, 2, 16), 2), ((4, 4, 64), 2), ((12, 12, 64), 2), ((2, 2, 128), 1),
+    ((4, 2, 128), 1), ((4, 1, 128), 1), ((32, 2, 128), 1), ((1, 1, 96), 1),
+    ((3, 3, 64), None), ((2, 2, 96), None), ((4, 2, 16), None),
+]
+
+
+@pytest.mark.parametrize("heads,per_block", LANE_RULE, ids=str)
+def test_heads_per_block(heads, per_block):
+    assert heads_per_block(*heads) == per_block
+
+
+LANE_T = 256  # 2x2 tiles of 128: both loops of both kernels turn
+LANE_CASES = [
+    pytest.param(heads, kind, dtype, id=f"H{heads[0]}kv{heads[1]}D{heads[2]}-{kind}-{dtype.__name__}")
+    for heads, _ in LANE_RULE
+    if heads[0] <= 12 and heads != (1, 1, 96)
+    for kind in ("padding", "causal", "both")
+    for dtype in (jnp.float32, jnp.bfloat16)
+]
+
+
+@pytest.mark.parametrize("heads,kind,dtype", LANE_CASES)
+def test_flash_lane_blocks_match_naive(devices, heads, kind, dtype):
+    """The output and all four cotangents against naive fp32 attention for
+    every way the kernels address heads: all in one lane block, pairs of 64,
+    one head of 128 a block, the fold where no lane block serves, and fewer
+    key/value heads than query heads (naive attention on repeated K/V, so
+    its dK and dV are summed over each group)."""
+    h, hkv, d = heads
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q, w = (jax.random.normal(key, (BWD_B, LANE_T, h, d), dtype) for key in ks[:2])
+    k, v = (jax.random.normal(key, (BWD_B, LANE_T, hkv, d), dtype) for key in ks[2:])
+    m = np.zeros((BWD_B, LANE_T), np.float32)
+    if kind != "causal":
+        m[1, LANE_T - LANE_T // 4 - 3:] = -1e30  # a padded tail that splits a block
+    mask, causal = jnp.asarray(m), kind != "padding"
+
+    def run(attend):
+        out, vjp = jax.vjp(
+            lambda q, k, v, mask: attend(q, k, v, mask=mask, causal=causal).astype(jnp.float32),
+            q, k, v, mask,
+        )
+        return (out,) + vjp(w.astype(jnp.float32))
+
+    flash = functools.partial(flash_attention, block_q=128, block_k=128, interpret=True)
+    tol = 1.5e-2 if dtype == jnp.bfloat16 else 2e-5
+    for name, a, e in zip(("out", "dq", "dk", "dv", "dmask"), run(flash), run(_naive)):
+        assert a.shape == e.shape and a.dtype == e.dtype, name
+        a, e = np.asarray(a, np.float32), np.asarray(e, np.float32)
+        assert np.all(np.isfinite(a)), name
+        assert np.abs(a - e).max() <= tol * np.abs(e).max(), name
+
+
+@pytest.mark.parametrize(
+    "b,t,h,hkv,d,causal",
+    [(48, 512, 12, 12, 64, False), (1, 8192, 32, 2, 128, True)],
+    ids=["imdb", "nemotron"],
+)
+def test_nothing_is_turned_or_folded_round_the_kernels(b, t, h, hkv, d, causal):
+    """At the cells' per-layer shapes the forward and the backward are the
+    two kernels on the model's own layout: no ``transpose`` outside them, no
+    (B*H, T, D) array, and K and V go in with the heads they have."""
+    q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, t, hkv, d), jnp.bfloat16)
+    # imdb's batches bring their padding mask, nemotron's are packed
+    args = (q, kv, kv) if causal else (q, kv, kv, jax.ShapeDtypeStruct((b, t), jnp.float32))
+
+    def loss(q, k, v, mask=None):
+        out = flash_attention(q, k, v, mask=mask, causal=causal)
+        return out.astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*args)
+    eqns = list(_outside_the_kernels(jaxpr.jaxpr))
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in kernels] == [None, "flash_attention_bwd"]
+    assert not [e for e in eqns if e.primitive.name == "transpose"]
+    shapes = {v.aval.shape for e in eqns for v in list(e.invars) + list(e.outvars)}
+    assert (b * h, t, d) not in shapes
+    for kernel in kernels:
+        assert [v.aval.shape for v in kernel.invars[:3]] == [
+            (b, t, h * d), (b, t, hkv * d), (b, t, hkv * d)
+        ]

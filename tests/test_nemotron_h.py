@@ -204,14 +204,30 @@ def test_the_layer_over_a_mesh_axis_is_not_built_yet():
 # ---- attention ---------------------------------------------------------------
 
 
-def test_grouped_query_attention_through_the_flash_kernel_at_head_dim_128():
-    """32-over-2 heads in the model is 4-over-2 here; head_dim 128 as
-    published; the kernel in interpret mode against materialised weights."""
-    cfg = dict(hidden_size=64, n_heads=4, n_kv_heads=2, head_dim=128)
+@pytest.mark.parametrize("n_kv_heads", [2, 1, 4])
+def test_grouped_query_attention_through_the_flash_kernel_at_head_dim_128(n_kv_heads):
+    """32-over-2 heads in the model is 4-over-2 here (and over 1, and over
+    4); head_dim 128 as published; the kernel in interpret mode, reading
+    each shared key/value head in place, against materialised weights on
+    repeated K/V."""
+    cfg = dict(hidden_size=64, n_heads=4, n_kv_heads=n_kv_heads, head_dim=128)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 256, 64))
     flash = GroupedQueryAttention(NemotronHConfig(attn_impl="flash", **cfg), 0.02)
     naive = GroupedQueryAttention(NemotronHConfig(attn_impl="einsum", **cfg), 0.02)
     params = naive.init(jax.random.PRNGKey(1), x)
+
+    def equations(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+    # K and V reach the kernel with the heads they have: nothing is repeated
+    traced = jax.make_jaxpr(flash.apply)(params, x).jaxpr
+    kernels = [e for e in equations(traced) if e.primitive.name == "pallas_call"]
+    assert [[v.aval.shape for v in e.invars[:3]] for e in kernels] == [
+        [(2, 256, 4 * 128)] + [(2, 256, n_kv_heads * 128)] * 2
+    ]
     np.testing.assert_allclose(flash.apply(params, x), naive.apply(params, x), rtol=2e-4, atol=2e-5)
     # causal: a later token leaves the earlier outputs alone
     moved = flash.apply(params, x.at[:, 200:].add(1.0))

@@ -25,11 +25,13 @@ from network_distributed_pytorch_tpu.ops.pallas_orthogonalize import (
     orthogonalize_pallas,
 )
 
-# (b, t, h, d), dtype, causal, padded mask — chip_smoke's DistilBERT-base
-# attention (bf16 and fp32), GPT-2-small at its context, gpt_lm's full
+# (b, t, h, d[, key/value heads]), dtype, causal, padded mask — chip_smoke's
+# DistilBERT-base attention (bf16 and fp32), GPT-2-small at its context, gpt_lm's full
 # preset (t=64), a serve prefill length that is no multiple of 128, and the
 # benchmark cells' own: imdb_psgd16_b16 (one 512x512 tile a head) and
-# nemotron_psgd16_t8k (512x512 tiles, K and V whole: the VMEM request)
+# nemotron_psgd16_t8k (512x512 tiles, K and V whole: the VMEM request; with
+# 32 key/value heads and with the model's 2, read in place), and a head
+# width no lane block serves (the fold)
 FLASH_CASES = [
     pytest.param((16, 256, 12, 64), jnp.bfloat16, False, True, id="distilbert-bf16"),
     pytest.param((16, 256, 12, 64), jnp.float32, False, True, id="distilbert-fp32"),
@@ -38,6 +40,8 @@ FLASH_CASES = [
     pytest.param((2, 96, 12, 64), jnp.float32, True, False, id="prefill-96-causal"),
     pytest.param((48, 512, 12, 64), jnp.bfloat16, False, True, id="imdb-48x512"),
     pytest.param((1, 8192, 32, 128), jnp.bfloat16, True, False, id="nemotron-8192-causal"),
+    pytest.param((1, 8192, 32, 128, 2), jnp.bfloat16, True, False, id="nemotron-8192-gqa"),
+    pytest.param((4, 512, 3, 64), jnp.bfloat16, False, True, id="fold-3x64"),
 ]
 # P-factor shapes: DistilBERT-base at rank 16 (chip_smoke), GPT-2 at rank 4
 ORTHOGONALIZE_SHAPES = [
@@ -47,8 +51,10 @@ ORTHOGONALIZE_SHAPES = [
 
 
 def _flash_fns(shape, dtype, causal, masked):
-    b, t, _, _ = shape
-    args = [jax.ShapeDtypeStruct(shape, dtype)] * 3
+    b, t, h, d = shape[:4]
+    hkv = shape[4] if len(shape) > 4 else h
+    kv = jax.ShapeDtypeStruct((b, t, hkv, d), dtype)
+    args = [jax.ShapeDtypeStruct(shape[:4], dtype), kv, kv]
     if masked:
         args.append(jax.ShapeDtypeStruct((b, t), jnp.float32))
 
