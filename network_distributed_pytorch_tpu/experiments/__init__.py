@@ -13,6 +13,7 @@ from . import (  # noqa: F401
     gpt_sp,
     gpt_tp,
     imdb_baseline,
+    powersgd_afmoe,
     powersgd_cifar10,
     powersgd_imdb,
     powersgd_nemotron,

@@ -13,11 +13,15 @@ on every step's ``step/loss_sync`` span.
 ``preset="small"`` is the test tier's model; ``"full"`` is the published
 widths at the seven-layer (one period), 8-of-128-experts, 16,384-row cut the benchmark
 runs (``benchmark/configs/nemotron3-nano-30b-a3b.json``).
+
+``train_lm`` is the experiment without its model: ``powersgd_afmoe.run``
+hands it another language model of the same calling convention (``(logits,
+counters)``, a config that names its ``expert_layers`` and ``held_experts``).
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +40,22 @@ from ..utils.config import ExperimentConfig
 from .common import accumulated_batches, powersgd_reducer_kwargs, summarize, train_loop
 
 
+def default_config() -> ExperimentConfig:
+    return ExperimentConfig(
+        training_epochs=1,
+        learning_rate=5e-5,
+        reducer_rank=16,
+        global_batch_size=0,  # train_lm sets it: one sequence per worker
+    )
+
+
+def model_kwargs(config: ExperimentConfig) -> Dict:
+    """What the experiment's config says of the model: its compute dtype
+    and, where set, its attention engine."""
+    attn = {} if config.attn_impl is None else {"attn_impl": config.attn_impl}
+    return {"dtype": jnp.dtype(config.compute_dtype), **attn}
+
+
 def run(
     config: Optional[ExperimentConfig] = None,
     preset: str = "small",
@@ -44,26 +64,46 @@ def run(
     pool_sequences: int = 64,
     max_steps_per_epoch: Optional[int] = None,
 ) -> Dict:
-    config = config or ExperimentConfig(
-        training_epochs=1,
-        learning_rate=5e-5,
-        reducer_rank=16,
-        global_batch_size=0,  # set below: one sequence per worker
-    )
-    mesh = mesh or make_mesh()
-    if not config.global_batch_size:
-        config.global_batch_size = mesh.size
-    dtype = jnp.dtype(config.compute_dtype)
-    attn = {} if config.attn_impl is None else {"attn_impl": config.attn_impl}
+    config = config or default_config()
     if preset == "full":
         model = NemotronHLM(NemotronHConfig(
             vocab_size=16384, pattern="MEMEM*E", held_experts=tuple(range(8)),
-            dtype=dtype, remat=True, **attn,
+            remat=True, **model_kwargs(config),
         ))
         seq_len = seq_len or 8192
     else:
-        model = nemotron_h_tiny(dtype=dtype, **attn)
+        model = nemotron_h_tiny(**model_kwargs(config))
         seq_len = seq_len or 64
+    c = model.config
+    return train_lm(
+        "powersgd_nemotron", model, config, mesh, seq_len, pool_sequences, max_steps_per_epoch,
+        {"preset": preset, "model": {
+            "pattern": c.pattern, "hidden_size": c.hidden_size, "held_experts": len(c.held_experts),
+            "n_routed_experts": c.n_routed_experts, "vocab_size": c.vocab_size,
+        }},
+    )
+
+
+def train_lm(
+    run_name: str,
+    model,
+    config: ExperimentConfig,
+    mesh,
+    seq_len: int,
+    pool_sequences: int,
+    max_steps_per_epoch: Optional[int],
+    summary: Dict,
+    collections_of: Optional[Callable] = None,
+) -> Dict:
+    """``model`` under PowerSGD through ``make_train_step`` and
+    ``train_loop`` on synthetic packed sequences; ``summary`` is what the
+    caller wants said of its model in the result. ``collections_of(params,
+    ids)`` gives the model's variable collections beside its parameters
+    (``powersgd_afmoe``'s balanced ``buffers``) from the ids of the pool's
+    first sequences; they ride ``model_state``."""
+    mesh = mesh or make_mesh()
+    if not config.global_batch_size:
+        config.global_batch_size = mesh.size
     vocab = model.config.vocab_size
 
     # synthetic packed sequences (no corpus ships with the repo): Zipf ids;
@@ -92,8 +132,9 @@ def run(
         algorithm="ef_momentum",
         mesh=mesh,
     )
+    collections = collections_of(params, ids[:4, :-1]) if collections_of else {}
     state = step.init_state(
-        params, model_state={STEP_COUNTERS: zero_counters(model.config)}
+        params, model_state={STEP_COUNTERS: zero_counters(model.config), **collections}
     )
     batches = accumulated_batches(
         [ids[:, :-1].copy(), ids[:, 1:].copy()], config,
@@ -109,24 +150,17 @@ def run(
             telemetry=telemetry,
             trace_dir=config.trace_dir,
             audit=audit_from_config(config),
-            run_name="powersgd_nemotron",
+            run_name=run_name,
         )
     finally:
         telemetry.close()
     counters = jax.device_get(state.model_state[STEP_COUNTERS])
     return summarize(
-        "powersgd_nemotron",
+        run_name,
         logger,
         {
-            "preset": preset,
+            **summary,
             "reducer_rank": config.reducer_rank,
-            "model": {
-                "pattern": model.config.pattern,
-                "hidden_size": model.config.hidden_size,
-                "held_experts": len(model.config.held_experts),
-                "n_routed_experts": model.config.n_routed_experts,
-                "vocab_size": vocab,
-            },
             "seq_len": seq_len,
             # the last step's counters, summed over workers and expert layers
             "last_step_assignments": {

@@ -31,6 +31,7 @@ from .experiments import (
     gpt_sp,
     gpt_tp,
     imdb_baseline,
+    powersgd_afmoe,
     powersgd_cifar10,
     powersgd_imdb,
     powersgd_nemotron,
@@ -47,6 +48,7 @@ EXPERIMENTS = {
     "powersgd_cifar10": powersgd_cifar10.run,
     "powersgd_imdb": powersgd_imdb.run,
     "powersgd_nemotron": powersgd_nemotron.run,
+    "powersgd_afmoe": powersgd_afmoe.run,
     "imdb_baseline": imdb_baseline.run,
     "bandwidth_study": bandwidth_study.run,
     "gpt_lm": gpt_lm.run,
@@ -784,7 +786,7 @@ def main(argv=None) -> dict:
                       spec_k=args.spec_k if args.spec_k is not None else 0)
     elif args.experiment == "bandwidth_study":
         kwargs.update(preset=args.preset)
-    elif args.experiment == "powersgd_nemotron":
+    elif args.experiment in ("powersgd_nemotron", "powersgd_afmoe"):
         kwargs.update(preset=args.preset,
                       max_steps_per_epoch=args.max_steps_per_epoch)
     elif args.experiment in ("gpt_lm", "gpt_pp", "gpt_sp", "gpt_tp", "gpt_moe"):

@@ -33,6 +33,7 @@ from .gpt import (  # noqa: F401
     stack_gpt_layer_params,
     unstack_gpt_layer_params,
 )
+from .afmoe import AfmoeConfig, AfmoeLM, afmoe_tiny  # noqa: F401
 from .nemotron_h import (  # noqa: F401
     NemotronHConfig,
     NemotronHLM,

@@ -1,0 +1,280 @@
+"""afmoe — Arcee's Trinity family (``model_type: afmoe``): sliding-window
+attention layers that carry rotary positions beside full-attention layers
+that carry none, gated attention, and gated experts; first-party flax.
+
+Follows HuggingFace's ``modeling_afmoe.py``. ``h = hidden_size``; every norm
+is an RMSNorm with a learned scale:
+
+- embedding: ``x = E[ids] * sqrt(h)`` (``mup_enabled``); after the last block
+  RMSNorm, then the untied head.
+- block, four norms: ``x <- x + N2(attn(N1(x)))``, then ``x <- x +
+  N4(ffn(N3(x)))`` (``input_layernorm``, ``post_attention_layernorm``,
+  ``pre_mlp_layernorm``, ``post_mlp_layernorm``).
+- attention (``ops.flash_attention``), ``n_heads`` query heads over
+  ``n_kv_heads`` key/value heads, no bias: ``q = RMSNorm_head(W_q u)``, ``k =
+  RMSNorm_head(W_k u)``, ``v = W_v u``, ``g = W_g u``; ``o = softmax(q k^T /
+  sqrt(head_dim)) v``; ``out = W_o (o * sigmoid(g))``. The layer's kind comes
+  from ``layer_types``: a ``sliding_attention`` layer turns q and k by the
+  rotary embedding (theta ``rope_theta``, the whole head, halves rotated as
+  HuggingFace's ``rotate_half``, angles in fp32) and lets query i see key j
+  iff ``0 <= i - j < sliding_window``; a ``full_attention`` layer applies NO
+  rotary embedding and is causal.
+- feed-forward: the first ``num_dense_layers`` layers a gated MLP of
+  ``dense_width``, ``W_d (silu(W_g u) * W_u u)``; the others routed experts
+  (``parallel.moe.held_experts_moe``): ``s = sigmoid(u W_r)`` in fp32 over
+  all ``n_routed_experts``, the ``experts_per_token`` largest of ``s +
+  expert_bias``, weights ``route_scale * s_i / sum_topk s``; every expert the
+  gated form at ``expert_width``; this rank computes the experts in
+  ``held_experts`` only and every rank the shared expert (``n_shared_experts
+  * expert_width`` wide). Nothing is dropped.
+- ``expert_bias`` is a buffer no gradient reaches (the ``buffers`` collection;
+  zeros where the caller brings none). A run in training keeps it where every
+  expert is chosen equally often; ``balanced_expert_bias`` puts it there for
+  weights that come from a seed and not from such a run.
+
+Parameters are fp32; ``dtype`` is what the products run in, and the residual
+stream is carried in it. The router, every norm, the rotary angles and the
+output gate's sigmoid compute in fp32. ``remat`` recomputes each block in the
+backward pass. ``RMSNorm``, the projections, the loss and the counters' tree
+are ``models/nemotron_h.py``'s: ``__call__`` returns ``(logits, counters)``
+as that model does, so ``next_token_lm_loss`` and ``zero_counters`` serve both.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from dataclasses import dataclass
+from typing import Any, Dict, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .nemotron_h import RMSNorm, _dense, _kernel, einsum_attention
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+BUFFERS = "buffers"  # the variable collection of what no gradient reaches: every expert_bias
+
+
+@dataclass(frozen=True)
+class AfmoeConfig:
+    vocab_size: int = 200192
+    hidden_size: int = 2048
+    layer_types: Tuple[str, ...] = (SLIDING, SLIDING, SLIDING, FULL) * 8
+    num_dense_layers: int = 2
+    norm_eps: float = 1e-5
+    mup_enabled: bool = True
+    # attention
+    n_heads: int = 32
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    sliding_window: int = 2048
+    rope_theta: float = 10000.0
+    attn_impl: str = "auto"  # "auto" (flash on TPU, einsum elsewhere) | "flash" | "einsum"
+    # feed-forward
+    dense_width: int = 6144
+    expert_width: int = 1024
+    n_shared_experts: int = 1
+    n_routed_experts: int = 128  # the router's width
+    held_experts: Tuple[int, ...] = tuple(range(128))  # the expert ids this rank computes
+    experts_per_token: int = 8
+    route_scale: float = 2.826
+    dtype: Any = jnp.float32
+    remat: bool = False
+    init_std: float = 0.02
+
+    def __post_init__(self):
+        unknown = set(self.layer_types) - {SLIDING, FULL}
+        if unknown or not self.layer_types:
+            raise ValueError(f"layer_types {self.layer_types!r}: {SLIDING} or {FULL} per layer")
+        if self.n_heads % self.n_kv_heads or self.head_dim % 2:
+            raise ValueError("heads must divide into their groups, and a head into two halves")
+
+    @property
+    def expert_layers(self) -> Tuple[int, ...]:
+        return tuple(range(self.num_dense_layers, len(self.layer_types)))
+
+
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """``x`` (B, T, H, D) in fp32, position t turned by the angles ``t *
+    theta^(-2i/D)``: ``x cos + rotate_half(x) sin``, the halves paired as
+    HuggingFace pairs them (i with i + D/2)."""
+    t, d = x.shape[1], x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]  # (T, D/2)
+    cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+class GatedMLP(nn.Module):
+    """``W_d (silu(W_g u) * W_u u)``: the leading dense layers and the shared expert."""
+
+    config: AfmoeConfig
+    width: int
+    out_std: float
+
+    @nn.compact
+    def __call__(self, u):
+        cfg = self.config
+        u = u.astype(cfg.dtype)
+        gate = _dense(cfg, self.width, cfg.init_std, "gate_proj")(u)
+        up = _dense(cfg, self.width, cfg.init_std, "up_proj")(u)
+        return _dense(cfg, cfg.hidden_size, self.out_std, "down_proj")(jax.nn.silu(gate) * up)
+
+
+def _balancing_bias(tokens32, router, top_k):
+    """The ``expert_bias`` under which every expert clears zero on ``top_k /
+    experts`` of these tokens: minus the score its (tokens * top_k /
+    experts)-th best token gives it. The ``top_k`` largest of ``s +
+    expert_bias`` then take each expert about equally often."""
+    scores = jax.nn.sigmoid(jnp.dot(tokens32, router, precision=jax.lax.Precision.HIGHEST))
+    n_tokens, n_experts = scores.shape
+    share = max(n_tokens * top_k // n_experts, 1)
+    return -jnp.sort(scores, axis=0)[n_tokens - share]
+
+
+class AfmoeExperts(nn.Module):
+    config: AfmoeConfig
+    out_std: float
+
+    @nn.compact
+    def __call__(self, u32):
+        from ..parallel.moe import held_experts_moe
+
+        cfg = self.config
+        d, f, held = cfg.hidden_size, cfg.expert_width, cfg.held_experts
+        router = self.param("router", _kernel(cfg.init_std), (d, cfg.n_routed_experts))
+        gate = self.param("experts_gate", _kernel(cfg.init_std), (len(held), d, f))
+        up = self.param("experts_up", _kernel(cfg.init_std), (len(held), d, f))
+        down = self.param("experts_down", _kernel(self.out_std), (len(held), f, d))
+        u = u32.astype(cfg.dtype)
+        bsz, t, _ = u.shape
+        tokens32 = u32.reshape(bsz * t, d)
+        expert_bias = jnp.zeros((cfg.n_routed_experts,), jnp.float32)
+        writable = self.is_mutable_collection(BUFFERS)  # init, or balanced_expert_bias's pass
+        if writable or self.has_variable(BUFFERS, "expert_bias"):
+            buffer = self.variable(BUFFERS, "expert_bias", lambda: expert_bias)
+            if writable and not self.is_initializing():  # found from this layer's own scores
+                buffer.value = _balancing_bias(tokens32, router, cfg.experts_per_token)
+            expert_bias = buffer.value
+        routed, counters = held_experts_moe(
+            u.reshape(bsz * t, d), tokens32, router, expert_bias,
+            up, down, held, cfg.experts_per_token, cfg.route_scale, w_gate=gate,
+        )
+        with jax.named_scope("moe.shared"):
+            shared = GatedMLP(cfg, cfg.n_shared_experts * f, self.out_std, name="shared")(u)
+        return routed.reshape(bsz, t, d) + shared, counters
+
+
+class AfmoeAttention(nn.Module):
+    config: AfmoeConfig
+    sliding: bool
+    out_std: float
+
+    @nn.compact
+    def __call__(self, u32):
+        from ..ops.flash_attention import resolve_attn_impl
+
+        cfg = self.config
+        hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        window = cfg.sliding_window if self.sliding else None
+        u = u32.astype(cfg.dtype)
+        bsz, t, _ = u.shape
+        q = _dense(cfg, hq * hd, cfg.init_std, "q_proj")(u).reshape(bsz, t, hq, hd)
+        k = _dense(cfg, hkv * hd, cfg.init_std, "k_proj")(u).reshape(bsz, t, hkv, hd)
+        v = _dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
+        gate = _dense(cfg, hq * hd, cfg.init_std, "gate_proj")(u)
+        with jax.named_scope("attn.rope"):
+            q = RMSNorm(cfg.norm_eps, name="q_norm")(q)
+            k = RMSNorm(cfg.norm_eps, name="k_norm")(k)
+            if self.sliding:  # the full layers carry no positions
+                q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
+            q, k = q.astype(cfg.dtype), k.astype(cfg.dtype)
+        with jax.named_scope("attn.window" if self.sliding else "attn.full"):
+            if resolve_attn_impl(cfg.attn_impl) == "flash":
+                from ..ops import flash_attention, pallas_interpret
+
+                ctx = flash_attention(
+                    q, k, v, causal=True, window=window, interpret=pallas_interpret()
+                )
+            else:
+                ctx = einsum_attention(q, k, v, window)
+        gated = ctx.reshape(bsz, t, hq * hd) * jax.nn.sigmoid(gate.astype(jnp.float32))
+        return _dense(cfg, cfg.hidden_size, self.out_std, "o_proj")(gated.astype(cfg.dtype))
+
+
+class AfmoeBlock(nn.Module):
+    config: AfmoeConfig
+    sliding: bool
+    dense: bool
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        norm = lambda name: RMSNorm(cfg.norm_eps, name=name)
+        # as nemotron_h's blocks: each output projection starts 1/sqrt(layers) smaller
+        out_std = cfg.init_std / np.sqrt(len(cfg.layer_types))
+        attended = AfmoeAttention(cfg, self.sliding, out_std, name="self_attn")(norm("input_layernorm")(x))
+        x = x + norm("post_attention_layernorm")(attended).astype(x.dtype)
+        normed, counters = norm("pre_mlp_layernorm")(x), {}
+        if self.dense:
+            out = GatedMLP(cfg, cfg.dense_width, out_std, name="mlp")(normed)
+        else:
+            out, counters = AfmoeExperts(cfg, out_std, name="mlp")(normed)
+        return x + norm("post_mlp_layernorm")(out).astype(x.dtype), counters
+
+
+class AfmoeLM(nn.Module):
+    config: AfmoeConfig
+
+    @nn.compact
+    def __call__(self, input_ids) -> Tuple[jax.Array, Dict[str, Dict[str, jax.Array]]]:
+        """``input_ids`` (B, T) -> fp32 logits (B, T, vocab) and the expert
+        layers' counters of this call."""
+        cfg = self.config
+        x = nn.Embed(
+            cfg.vocab_size, cfg.hidden_size, embedding_init=_kernel(cfg.init_std),
+            dtype=cfg.dtype, name="embed",
+        )(input_ids)
+        if cfg.mup_enabled:
+            x = x * jnp.asarray(np.sqrt(cfg.hidden_size), x.dtype)
+        block = nn.remat(AfmoeBlock) if cfg.remat else AfmoeBlock
+        counters = {}
+        for i, kind in enumerate(cfg.layer_types):
+            x, layer_counters = block(
+                cfg, kind == SLIDING, i < cfg.num_dense_layers, name=f"layer_{i}"
+            )(x)
+            if layer_counters:
+                counters[f"layer_{i}"] = layer_counters
+        x = RMSNorm(cfg.norm_eps, name="final_norm")(x).astype(cfg.dtype)
+        head = self.param("head", _kernel(cfg.init_std), (cfg.hidden_size, cfg.vocab_size))
+        logits = jnp.dot(x, head.astype(cfg.dtype), preferred_element_type=jnp.float32)
+        return logits, counters
+
+
+def balanced_expert_bias(model: AfmoeLM, params, input_ids) -> Dict:
+    """The ``buffers`` collection that balances ``params``' routing on
+    ``input_ids`` (B, T): one forward pass in which every expert layer takes
+    its ``expert_bias`` from its own scores (``_balancing_bias``) and routes
+    by it, so the layers behind it see what they will see in training."""
+    model = AfmoeLM(dataclasses.replace(model.config, remat=False))  # nothing to recompute
+    # the buffers alone leave the program: the head and its logits are never computed
+    return jax.jit(lambda p, ids: model.apply({"params": p}, ids, mutable=[BUFFERS])[1][BUFFERS])(
+        params, input_ids
+    )
+
+
+def afmoe_tiny(**overrides) -> AfmoeLM:
+    """The test tier's size: a leading dense layer, then sliding, full,
+    sliding, sliding with experts; 16 experts with 4 held; a window a quarter
+    of the sequences the tests use."""
+    base = dict(
+        vocab_size=256, hidden_size=64, layer_types=(SLIDING, SLIDING, FULL, SLIDING, SLIDING),
+        num_dense_layers=1, n_heads=4, n_kv_heads=2, head_dim=16, sliding_window=16,
+        dense_width=96, expert_width=32, n_routed_experts=16, held_experts=(0, 1, 2, 3),
+        experts_per_token=2,
+    )
+    base.update(overrides)
+    return AfmoeLM(AfmoeConfig(**base))

@@ -91,13 +91,18 @@ class NemotronHConfig:
     def d_inner(self) -> int:
         return self.mamba_heads * self.mamba_head_dim
 
+    @property
+    def expert_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, kind in enumerate(self.pattern) if kind == "E")
+
 
 def _kernel(std: float):
     return nn.initializers.normal(stddev=std)
 
 
-def _dense(cfg: "NemotronHConfig", width: int, std: float, name: str) -> nn.Dense:
-    """Every projection of the model: no bias, products in ``cfg.dtype``."""
+def _dense(cfg, width: int, std: float, name: str) -> nn.Dense:
+    """Every projection of the model (and of ``models/afmoe.py``): no bias,
+    products in ``cfg.dtype``."""
     return nn.Dense(width, use_bias=False, dtype=cfg.dtype, kernel_init=_kernel(std), name=name)
 
 
@@ -110,6 +115,19 @@ class RMSNorm(nn.Module):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
         x = x.astype(jnp.float32)
         return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps) * scale
+
+
+def einsum_attention(q, k, v, window: int = None):
+    """Causal grouped-query attention with the weights materialised, the
+    engine off the TPU: q (B, T, H, D), k and v (B, T, Hkv, D) repeated to H
+    heads; with ``window``, query i sees key j iff ``0 <= i - j < window``."""
+    t, hd = q.shape[1], q.shape[-1]
+    k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2) for x in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
+    behind = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]  # query - key
+    seen = (behind >= 0) & (behind < (window or t))
+    weights = jax.nn.softmax(jnp.where(seen, scores / np.sqrt(hd), -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", weights.astype(q.dtype), v)
 
 
 class Mamba2Mixer(nn.Module):
@@ -213,11 +231,7 @@ class GroupedQueryAttention(nn.Module):
                 # the kernels read the shared head in place
                 ctx = flash_attention(q, k, v, causal=True, interpret=pallas_interpret())
             else:
-                k, v = (jnp.repeat(x, hq // hkv, axis=2) for x in (k, v))
-                scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
-                scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores / np.sqrt(hd), -jnp.inf)
-                weights = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-                ctx = jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+                ctx = einsum_attention(q, k, v)
         return _dense(cfg, cfg.hidden_size, self.out_std, "o_proj")(ctx.reshape(bsz, t, hq * hd))
 
 
@@ -265,23 +279,28 @@ class NemotronHLM(nn.Module):
         return logits, counters
 
 
-def zero_counters(config: NemotronHConfig) -> Dict[str, Dict[str, jax.Array]]:
-    """The counters' tree before the first step: what ``init_state`` takes."""
+def zero_counters(config) -> Dict[str, Dict[str, jax.Array]]:
+    """The counters' tree before the first step: what ``init_state`` takes.
+    ``config`` names its ``expert_layers`` and ``held_experts`` (this model's
+    or ``models/afmoe.py``'s)."""
     zero = lambda *shape: jnp.zeros(shape, jnp.int32)
     return {
         f"layer_{i}": {"held": zero(len(config.held_experts)), "absent": zero(), "dropped": zero()}
-        for i, kind in enumerate(config.pattern) if kind == "E"
+        for i in config.expert_layers
     }
 
 
-def next_token_lm_loss(model: NemotronHLM):
+def next_token_lm_loss(model):
     """The trainer's loss function: mean next-token cross-entropy of fp32
     logits (``labels`` already shifted by the data), the expert layers'
-    counters handed on as model state under ``STEP_COUNTERS``."""
+    counters handed on as model state under ``STEP_COUNTERS``; whatever
+    else the model state holds is the model's other variable collections
+    (``models/afmoe.py``'s ``buffers``) and goes to it unchanged."""
     from ..parallel.trainer import STEP_COUNTERS
 
     def loss_fn(params, model_state, batch):
-        logits, counters = model.apply({"params": params}, batch["input_ids"])
+        others = {k: v for k, v in model_state.items() if k != STEP_COUNTERS}
+        logits, counters = model.apply({"params": params, **others}, batch["input_ids"])
         # logsumexp minus the label's logit: no (B, T, vocab) array of log-probabilities
         picked = jnp.take_along_axis(logits, batch["labels"][..., None], axis=-1)[..., 0]
         loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) - picked)

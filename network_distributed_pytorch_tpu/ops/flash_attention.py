@@ -48,7 +48,13 @@ The online-softmax recurrence is the same one the framework's ring and
 Ulysses schedules use (``parallel.sequence``); this kernel is the
 single-device / per-shard block engine, so a ring shard can run it on each
 block it holds. Causal mode prunes K blocks strictly above the diagonal via
-the loop bound (not just masking).
+the loop bound (not just masking). A sliding ``window`` (key j visible to
+query i iff 0 <= i - j < window) prunes the other side the same way: the
+forward starts its K loop at the first block the Q block's first row can
+still see, the backward ends its Q loop at the last block that can still see
+the K block, and the tiles on the band's two edges carry the position
+compare. The bounds follow the shapes; ``window=None`` (or one that covers
+the sequence) is the causal program, unchanged.
 
 Training: the kernel is wrapped in a ``custom_vjp``. The forward also emits
 the per-row log-sum-exp; the backward is a second Pallas kernel
@@ -67,7 +73,8 @@ D = 128 in bf16 compiles, as far as the forward's own whole-K/V residency
 goes.
 
 Correctness is pinned against naive einsum attention (padding masks, causal,
-both, grads, every lane block, the fold and grouped K/V) in
+both, windows under, at and across the tile edge, grads, every lane block,
+the fold and grouped K/V) in
 ``tests/test_flash_attention.py``; on CPU the kernel runs in interpret mode
 (the test path), on TPU it compiles with Mosaic.
 """
@@ -157,6 +164,7 @@ def _flash_kernel(
     block_k: int,
     t: int,
     causal: bool,
+    window: int,  # None: no window
     scale: float,
     heads: int,
     q_ref,
@@ -195,6 +203,10 @@ def _flash_kernel(
         hi = jnp.minimum(hi, n_blocks)
     else:
         hi = n_blocks
+    lo = 0
+    if window:
+        # K blocks that end before this Q block's first row's window starts
+        lo = lax.div(jnp.maximum(qi * block_q - (window - 1), 0), block_k)
 
     def body(j, carry):
         ks = pl.multiple_of(j * block_k, block_k)
@@ -209,6 +221,8 @@ def _flash_kernel(
             k_pos = ks + lax.broadcasted_iota(jnp.int32, tile, 0)
             q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, tile, 1)
             valid = valid & (q_pos >= k_pos)
+            if window:
+                valid = valid & (q_pos - k_pos < window)
 
         def one_head(q, m, l, acc):
             # m, l: (1, block_q); acc: (lanes, block_q)
@@ -238,7 +252,7 @@ def _flash_kernel(
     m0 = jnp.full((1, block_q), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((1, block_q), jnp.float32)
     acc0 = jnp.zeros((lanes, block_q), jnp.float32)
-    done = lax.fori_loop(0, hi, body, ((m0, l0, acc0),) * heads)
+    done = lax.fori_loop(lo, hi, body, ((m0, l0, acc0),) * heads)
     for i, (m, l, _) in enumerate(done):
         lse_ref[0, i] = jnp.where(
             l > 0, m + jnp.log(jnp.maximum(l, 1e-37)), _LSE_EMPTY
@@ -256,6 +270,7 @@ def _flash_bwd_kernel(
     block_k: int,
     t: int,
     causal: bool,
+    window: int,  # None: no window
     scale: float,
     heads: int,
     q_ref,
@@ -332,6 +347,8 @@ def _flash_bwd_kernel(
                 k_pos = ks + lax.broadcasted_iota(jnp.int32, tile, 0)
                 q_pos = qs + lax.broadcasted_iota(jnp.int32, tile, 1)
                 valid = valid & (q_pos >= k_pos)
+                if window:
+                    valid = valid & (q_pos - k_pos < window)
             for h in range(heads):
                 q_h = _only_head(q_blk, heads, h)
                 do_h = _only_head(do_blk, heads, h)
@@ -367,8 +384,13 @@ def _flash_bwd_kernel(
         zero = jnp.zeros((block_k, lanes), jnp.float32)
         # causal: Q blocks that end before this K block starts see none of it
         lo = lax.div(j * block_k, block_q) if causal else 0
+        hi = n_q
+        if window:
+            # Q blocks that start after this K block's last key's window ends
+            last = (j + 1) * block_k + window - 2  # the last row that sees it
+            hi = jnp.minimum(lax.div(last, block_q) + 1, n_q)
         dk, dv, dmask = lax.fori_loop(
-            lo, n_q, q_block, (zero, zero, jnp.zeros((block_k, 1), jnp.float32))
+            lo, hi, q_block, (zero, zero, jnp.zeros((block_k, 1), jnp.float32))
         )
         dk_ref[0, pl.ds(ks, block_k), :] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[0, pl.ds(ks, block_k), :] = dv.astype(dv_ref.dtype)
@@ -433,7 +455,8 @@ def _shares(q, k, mask):
 
 
 def _flash_fwd(
-    scale, causal, lanes, heads, block_q, block_k, interpret, q, k, v, mask
+    scale, causal, window, lanes, heads, block_q, block_k, interpret,
+    q, k, v, mask,
 ):
     """The forward kernel over the layout both kernels address: q
     (N, T, Hq·D), k and v (Nkv, T, Hkv·D), mask (B, T); N is a multiple of
@@ -460,7 +483,7 @@ def _flash_fwd(
     # as (N, Hq, 1, T), never as 2-D rows of width T
     return pl.pallas_call(
         functools.partial(
-            _flash_kernel, block_q, block_k, t, causal, scale, heads
+            _flash_kernel, block_q, block_k, t, causal, window, scale, heads
         ),
         grid=(n, width // lanes, t // block_q),
         in_specs=[
@@ -488,7 +511,7 @@ def _flash_fwd(
 
 
 def _flash_bwd(
-    scale, causal, lanes, heads, block_q, block_k, interpret,
+    scale, causal, window, lanes, heads, block_q, block_k, interpret,
     q, k, v, mask, out, lse, do,
 ):
     """Flash backward as one Pallas kernel (``_flash_bwd_kernel``) over the
@@ -520,7 +543,8 @@ def _flash_bwd(
     )
     dq, dk, dv, dmask = pl.pallas_call(
         functools.partial(
-            _flash_bwd_kernel, block_q, block_k, t, causal, scale, heads
+            _flash_bwd_kernel, block_q, block_k, t, causal, window, scale,
+            heads,
         ),
         grid=(n, n_blocks),
         in_specs=[
@@ -564,7 +588,7 @@ def _flash_bwd(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "block_q", "block_k", "interpret"),
+    static_argnames=("causal", "block_q", "block_k", "interpret", "window"),
 )
 def flash_attention(
     q: jax.Array,
@@ -575,6 +599,7 @@ def flash_attention(
     block_q: int = None,
     block_k: int = None,
     interpret: bool = False,
+    window: int = None,
 ) -> jax.Array:
     """Exact attention without materializing the score matrix.
 
@@ -585,6 +610,10 @@ def flash_attention(
     padding), the same convention as ``parallel.sequence``.
     block_q/block_k: the score tile of both kernels; ``None`` is
     ``tile_edge(T)``.
+    window: sliding-window attention, causal by definition: key j is
+    visible to query i iff ``0 <= i - j < window``. Key blocks wholly
+    outside the band are skipped by the kernels' loop bounds. A window that
+    covers the sequence is ``causal=True``, the same program.
     Differentiable (custom VJP, blockwise backward). Returns (B, T, H, D)
     in q's dtype.
     """
@@ -602,6 +631,10 @@ def flash_attention(
         " sequence (and mask the pads) first"
     )
     scale = 1.0 / float(d) ** 0.5
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"window={window}: a query sees at least itself")
+        causal, window = True, (window if window < t else None)
 
     heads = heads_per_block(h, hkv, d)
     if heads is None:
@@ -626,7 +659,9 @@ def flash_attention(
     if missing:
         mask = lax.pcast(mask, missing, to="varying")
 
-    static = (scale, causal, heads * d, heads, block_q, block_k, interpret)
+    static = (
+        scale, causal, window, heads * d, heads, block_q, block_k, interpret
+    )
 
     @jax.custom_vjp
     def attn(q, k, v, mask):

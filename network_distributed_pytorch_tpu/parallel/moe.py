@@ -14,7 +14,9 @@ Which layer is which:
   of the result with grouped products (every block of sorted rows belongs to
   one expert: batched matmuls over blocks). No capacity and no drops, no
   array of size tokens x experts x capacity; what absent experts would add
-  is left out (another rank's part). Used by ``models/nemotron_h``.
+  is left out (another rank's part). The experts are relu² (two stacked
+  leaves) or gated silu (three), told apart by the operands. Used by
+  ``models/nemotron_h`` (ungated, top 6) and ``models/afmoe`` (gated, top 8).
 
 ``switch_moe``, in detail (beyond-parity capability, SURVEY §2.3: EP/MoE
 absent from the reference). TPU-native design:
@@ -194,6 +196,7 @@ def held_experts_moe(
     scaling: float = 1.0,
     axis_name: Optional[str] = None,
     block_rows: int = 512,
+    w_gate: Optional[jax.Array] = None,  # (len(held), D, F): the experts are gated
 ) -> tuple[jax.Array, Dict[str, jax.Array]]:
     """This rank's part of a dropless top-k expert layer.
 
@@ -202,11 +205,14 @@ def held_experts_moe(
     experts); each token takes the ``top_k`` largest of ``score +
     select_bias`` and weights them ``scaling * score_i / sum_topk score``.
     Of those T*top_k assignments the ones on a ``held`` expert are sorted by
-    expert and computed as grouped products, ``w_out_e . relu(w_in_e . x)^2``:
-    each expert's rows are laid out from a multiple of ``block_rows``, so
-    every block of rows belongs to one expert and the two products are
-    batched matmuls over blocks, each block with its expert's weights; the
-    weighted rows are added back into their tokens.
+    expert and computed as grouped products. The expert's form follows its
+    operands: without ``w_gate`` it is ``w_out_e . relu(w_in_e . x)^2``
+    (Nemotron-H), with it ``w_out_e . (silu(w_gate_e . x) * (w_in_e . x))``
+    (afmoe's gate, up and down projections). Each expert's rows are laid out
+    from a multiple of ``block_rows``, so every block of rows belongs to one
+    expert and the two (or three) products are batched matmuls over blocks,
+    each block with its expert's weights; the weighted rows are added back
+    into their tokens.
 
     The work follows the assignments that landed here. They are taken in
     chunks of T rows (the expected load is T*top_k*len(held)/E): the first
@@ -222,8 +228,9 @@ def held_experts_moe(
     assignments on held experts that were not computed (always 0; counted
     from the rows the chunks covered, not assumed).
 
-    ``axis_name`` is where the exchange between ranks would ride; only the
-    one-rank layer (``None``) exists.
+    ``axis_name`` is where the exchange between ranks would ride. Only the
+    one-rank layer (``None``) exists, and both models that use it run it so:
+    one rank of a deployment without its exchange; any other value raises.
     """
     if axis_name is not None:
         raise NotImplementedError("held_experts_moe has no exchange over a mesh axis yet")
@@ -231,6 +238,7 @@ def held_experts_moe(
     e = router_kernel.shape[1]
     n_held = len(held)
     assert w_in.shape[0] == w_out.shape[0] == n_held and 1 <= top_k <= e
+    assert w_gate is None or w_gate.shape == w_in.shape
     f32, i32 = jnp.float32, jnp.int32
 
     with jax.named_scope("moe.route"):
@@ -264,7 +272,7 @@ def held_experts_moe(
     sorted_tokens = pad_to(order // top_k, 0)
     sorted_weights = pad_to(weights.reshape(-1)[order], 0.0)
 
-    def chunk(first, x, w_in, w_out, sorted_weights):
+    def chunk(first, x, w_in, w_out, sorted_weights, w_gate=None):
         """Rows [first, first + rows) of the sorted assignments -> their
         part of the output (T, D) and how many of them were live."""
         at = first + jnp.arange(rows)
@@ -291,7 +299,12 @@ def held_experts_moe(
         # an empty row's token is T, out of range: it reads zeros and adds nowhere
         rows_in = x.at[row_token].get(mode="fill", fill_value=0).reshape(n_blocks, block_rows, d)
         hidden = jnp.einsum("nbd,ndf->nbf", rows_in, block_in, preferred_element_type=f32)
-        hidden = relu_squared(hidden).astype(x.dtype)
+        if w_gate is None:
+            hidden = relu_squared(hidden).astype(x.dtype)
+        else:
+            block_gate = jnp.einsum("ne,edf->ndf", pick, w_gate)
+            gate = jnp.einsum("nbd,ndf->nbf", rows_in, block_gate, preferred_element_type=f32)
+            hidden = (jax.nn.silu(gate) * hidden).astype(x.dtype)
         part = jnp.einsum("nbf,nfd->nbd", hidden, block_out, preferred_element_type=f32)
         part = part.reshape(padded, d) * row_weight[:, None]
         out = jnp.zeros((t, d), f32).at[row_token].add(part, mode="drop")
@@ -320,6 +333,8 @@ def held_experts_moe(
 
     with jax.named_scope("moe.experts"):
         operands = (x, w_in.astype(x.dtype), w_out.astype(x.dtype), sorted_weights)
+        if w_gate is not None:
+            operands += (w_gate.astype(x.dtype),)
         # the first chunk nearly always holds every assignment: it runs outside any loop
         out, computed = chunk(0, *operands)
         if n_chunks > 1:

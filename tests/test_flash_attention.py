@@ -3,10 +3,12 @@ plain, padding-masked, causal, and causal+masked; bf16 inputs; the GPT
 attn_impl="flash" path; the forward kernel and the backward kernel's dq,
 dk, dv and dmask at the tiles the chip runs (``tile_edge``), alone and
 inside ``shard_map``; every lane block ``heads_per_block`` picks, the fold
-it falls back to and grouped key/value heads; and that nothing is turned or
-folded round the kernels where a lane block serves."""
+it falls back to and grouped key/value heads; sliding windows under, at and
+across the tile edge (kinds named ``window<N>``); and that nothing is turned
+or folded round the kernels where a lane block serves."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,17 +30,24 @@ def _qkv(seed, dtype=jnp.float32):
     return mk(ks[0]), mk(ks[1]), mk(ks[2])
 
 
-def _naive(q, k, v, mask=None, causal=False):
+def _window_of(kind: str):
+    """The window a case's kind names (``window96+soft_bias`` -> 96), or None."""
+    found = re.search(r"window(\d+)", kind)
+    return int(found.group(1)) if found else None
+
+
+def _naive(q, k, v, mask=None, causal=False, window=None):
     # fewer key/value heads: each serves a group of query heads
     k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2) for x in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
     s = s / jnp.sqrt(q.shape[-1])
     if mask is not None:
         s = s + mask[:, None, None, :]
-    if causal:
+    if causal or window:
         t = q.shape[1]
-        tril = jnp.tril(jnp.ones((t, t), bool))
-        s = jnp.where(tril[None, None], s, -jnp.inf)
+        behind = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]  # query - key
+        seen = (behind >= 0) & (behind < (window or t))
+        s = jnp.where(seen[None, None], s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", w, v.astype(jnp.float32))
 
@@ -181,6 +190,11 @@ FWD_CASES = [
     for t in (96, 128, 512, 640)  # 96: one block under 128; 640: 5x5 tiles of 128
     for kind in FWD_KINDS
     for dtype in (jnp.float32, jnp.bfloat16)
+] + [
+    # 5x5 tiles of 128: a window under the tile, the tile, and across two
+    pytest.param(640, kind, dtype, id=f"T640-{kind}-{dtype.__name__}")
+    for kind in ("window100", "window128", "window300")
+    for dtype in (jnp.float32, jnp.bfloat16)
 ]
 
 
@@ -200,8 +214,9 @@ def test_flash_forward_kernel_matches_naive(devices, t, kind, dtype):
         m[0, :] = np.finfo(np.float32).min  # what DistilBertEncoder emits
         m[1, t - t // 4 - 3:] = -1e30
         mask, live[0] = jnp.asarray(m), False
-    out = flash_attention(q, k, v, mask=mask, causal="causal" in kind, interpret=True)
-    want = _naive(q, k, v, mask=mask, causal="causal" in kind)
+    window = _window_of(kind)
+    out = flash_attention(q, k, v, mask=mask, causal="causal" in kind, window=window, interpret=True)
+    want = _naive(q, k, v, mask=mask, causal="causal" in kind, window=window)
     assert out.dtype == dtype and out.shape == q.shape
     got, want = np.asarray(out, np.float32), np.asarray(want)
     # naive softmax spreads an all-padded row evenly; the kernel gives nothing
@@ -270,12 +285,18 @@ BWD_CASES = [
     pytest.param(t, "both", dtype, id=f"T{t}-both-{dtype.__name__}")
     for t in (128, 384, 512, 64)  # 384: 3x3 tiles of 128; 64: tile = T < 128
     for dtype in (jnp.float32, jnp.bfloat16)
+] + [
+    # 3x3 tiles of 128: a window under the tile, the tile, and across two
+    pytest.param(384, kind, dtype, id=f"T384-{kind}-{dtype.__name__}")
+    for kind in ("window100", "window128+soft_bias", "window200+padding")
+    for dtype in (jnp.float32, jnp.bfloat16)
 ]
 
 
 def _bwd_inputs(t, kind, dtype, seed=5):
     """q, k, v, the weights of the scalar loss, the additive mask and
-    whether attention is causal, for one kind of mask."""
+    whether attention is causal, for one kind of mask (a ``window<N>`` in
+    the kind is read by the caller: ``_window_of``)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     q, k, v, w = (
         jax.random.normal(key, (BWD_B, t, BWD_H, BWD_D), dtype) for key in ks[:4]
@@ -283,10 +304,12 @@ def _bwd_inputs(t, kind, dtype, seed=5):
     m = np.zeros((BWD_B, t), np.float32)
     if kind in ("padding", "both"):
         m[1, t - t // 4 - 3:] = -1e30  # a padded tail that splits a block
+    elif "window" in kind and "padding" in kind:
+        m[1, 120:150] = -1e30  # padding inside the sequence: every window still sees a key
     elif kind == "padded_row":
         m[0, :] = np.finfo(np.float32).min  # what DistilBertEncoder emits
         m[1, t // 2:] = -1e30
-    elif kind == "soft_bias":
+    elif "soft_bias" in kind:
         m = np.asarray(jax.random.normal(ks[4], (BWD_B, t)), np.float32)
     return q, k, v, w, jnp.asarray(m), kind in ("causal", "both")
 
@@ -296,7 +319,7 @@ def _bwd_grads(t, kind, dtype):
 
     def loss(attend):
         def f(q, k, v, mask):
-            out = attend(q, k, v, mask=mask, causal=causal)
+            out = attend(q, k, v, mask=mask, causal=causal, window=_window_of(kind))
             return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32))
         return f
 
@@ -402,6 +425,13 @@ LANE_CASES = [
     if heads[0] <= 12 and heads != (1, 1, 96)
     for kind in ("padding", "causal", "both")
     for dtype in (jnp.float32, jnp.bfloat16)
+] + [
+    # sliding windows over every way heads are addressed, Trinity's 32 query
+    # heads over 4 key/value heads among them, at a narrow head (the fold)
+    # and at 128 (read in place): under the tile, the tile, across two tiles
+    pytest.param(heads, kind, jnp.float32, id=f"H{heads[0]}kv{heads[1]}D{heads[2]}-{kind}-float32")
+    for heads in ((4, 4, 64), (2, 2, 16), (4, 2, 128), (32, 4, 16), (32, 4, 128))
+    for kind in ("window96", "window128+padding", "window200+padding")
 ]
 
 
@@ -416,14 +446,16 @@ def test_flash_lane_blocks_match_naive(devices, heads, kind, dtype):
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
     q, w = (jax.random.normal(key, (BWD_B, LANE_T, h, d), dtype) for key in ks[:2])
     k, v = (jax.random.normal(key, (BWD_B, LANE_T, hkv, d), dtype) for key in ks[2:])
-    m = np.zeros((BWD_B, LANE_T), np.float32)
-    if kind != "causal":
+    m, window = np.zeros((BWD_B, LANE_T), np.float32), _window_of(kind)
+    if window and "padding" in kind:
+        m[1, 100:130] = -1e30  # padding inside the sequence: every window still sees a key
+    elif kind in ("padding", "both"):
         m[1, LANE_T - LANE_T // 4 - 3:] = -1e30  # a padded tail that splits a block
     mask, causal = jnp.asarray(m), kind != "padding"
 
     def run(attend):
         out, vjp = jax.vjp(
-            lambda q, k, v, mask: attend(q, k, v, mask=mask, causal=causal).astype(jnp.float32),
+            lambda q, k, v, mask: attend(q, k, v, mask=mask, causal=causal, window=window).astype(jnp.float32),
             q, k, v, mask,
         )
         return (out,) + vjp(w.astype(jnp.float32))
@@ -435,6 +467,46 @@ def test_flash_lane_blocks_match_naive(devices, heads, kind, dtype):
         a, e = np.asarray(a, np.float32), np.asarray(e, np.float32)
         assert np.all(np.isfinite(a)), name
         assert np.abs(a - e).max() <= tol * np.abs(e).max(), name
+
+
+def _jaxpr_text(**kwargs):
+    """The gradient program of one small causal call, as text."""
+    q = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, block_q=128, block_k=128, interpret=True, **kwargs).sum()
+
+    return str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
+
+
+@pytest.mark.parametrize("window", [256, 257, 4096], ids=lambda w: f"window{w}")
+def test_a_window_that_covers_the_sequence_is_the_causal_program(devices, window):
+    """``window >= T`` hides nothing ``causal=True`` shows: the same jaxpr
+    (no lower loop bound, no second compare), so the same bits, forward and
+    all three gradients."""
+    assert _jaxpr_text(window=window) == _jaxpr_text(causal=True)
+    ks = jax.random.split(jax.random.PRNGKey(8), 4)
+    q, k, v, w = (jax.random.normal(key, (2, 256, 2, 64)) for key in ks)
+
+    def run(**kwargs):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, interpret=True, **kwargs), q, k, v)
+        return (out,) + vjp(w)
+
+    for got, want in zip(run(window=window), run(causal=True)):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_no_window_leaves_both_kernels_as_they_were(devices):
+    """``window=None`` is not a code path: the program equals the one traced
+    with the argument left out, causal or not, and a real window changes it
+    (a lower bound on the forward's K loop, an upper on the backward's Q
+    loop, one more compare a tile)."""
+    assert _jaxpr_text(window=None) == _jaxpr_text()
+    assert _jaxpr_text(causal=True, window=None) == _jaxpr_text(causal=True)
+    assert _jaxpr_text(window=100) != _jaxpr_text(causal=True)
+    assert _jaxpr_text(window=100) == _jaxpr_text(causal=True, window=100)  # a window is causal
+    with pytest.raises(ValueError):
+        _jaxpr_text(window=0)
 
 
 @pytest.mark.parametrize(

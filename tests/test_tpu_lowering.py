@@ -25,13 +25,14 @@ from network_distributed_pytorch_tpu.ops.pallas_orthogonalize import (
     orthogonalize_pallas,
 )
 
-# (b, t, h, d[, key/value heads]), dtype, causal, padded mask — chip_smoke's
+# (b, t, h, d[, key/value heads[, window]]), dtype, causal, padded mask — chip_smoke's
 # DistilBERT-base attention (bf16 and fp32), GPT-2-small at its context, gpt_lm's full
 # preset (t=64), a serve prefill length that is no multiple of 128, and the
 # benchmark cells' own: imdb_psgd16_b16 (one 512x512 tile a head) and
 # nemotron_psgd16_t8k (512x512 tiles, K and V whole: the VMEM request; with
-# 32 key/value heads and with the model's 2, read in place), and a head
-# width no lane block serves (the fold)
+# 32 key/value heads and with the model's 2, read in place),
+# trinity_psgd16_t8k's sliding layers (32 heads over 4, a window of 2048:
+# both loop bounds), and a head width no lane block serves (the fold)
 FLASH_CASES = [
     pytest.param((16, 256, 12, 64), jnp.bfloat16, False, True, id="distilbert-bf16"),
     pytest.param((16, 256, 12, 64), jnp.float32, False, True, id="distilbert-fp32"),
@@ -41,6 +42,7 @@ FLASH_CASES = [
     pytest.param((48, 512, 12, 64), jnp.bfloat16, False, True, id="imdb-48x512"),
     pytest.param((1, 8192, 32, 128), jnp.bfloat16, True, False, id="nemotron-8192-causal"),
     pytest.param((1, 8192, 32, 128, 2), jnp.bfloat16, True, False, id="nemotron-8192-gqa"),
+    pytest.param((1, 8192, 32, 128, 4, 2048), jnp.bfloat16, True, False, id="trinity-8192-window-2048"),
     pytest.param((4, 512, 3, 64), jnp.bfloat16, False, True, id="fold-3x64"),
 ]
 # P-factor shapes: DistilBERT-base at rank 16 (chip_smoke), GPT-2 at rank 4
@@ -53,13 +55,14 @@ ORTHOGONALIZE_SHAPES = [
 def _flash_fns(shape, dtype, causal, masked):
     b, t, h, d = shape[:4]
     hkv = shape[4] if len(shape) > 4 else h
+    window = shape[5] if len(shape) > 5 else None
     kv = jax.ShapeDtypeStruct((b, t, hkv, d), dtype)
     args = [jax.ShapeDtypeStruct(shape[:4], dtype), kv, kv]
     if masked:
         args.append(jax.ShapeDtypeStruct((b, t), jnp.float32))
 
     def forward(q, k, v, mask=None):
-        return flash_attention(q, k, v, mask=mask, causal=causal)
+        return flash_attention(q, k, v, mask=mask, causal=causal, window=window)
 
     def loss(q, k, v, mask=None):
         return forward(q, k, v, mask).astype(jnp.float32).sum()
