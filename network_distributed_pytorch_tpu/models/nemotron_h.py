@@ -285,7 +285,9 @@ def zero_counters(config) -> Dict[str, Dict[str, jax.Array]]:
     or ``models/afmoe.py``'s)."""
     zero = lambda *shape: jnp.zeros(shape, jnp.int32)
     return {
-        f"layer_{i}": {"held": zero(len(config.held_experts)), "absent": zero(), "dropped": zero()}
+        f"layer_{i}": {
+            "held": zero(len(config.held_experts)), "absent": zero(), "dropped": zero(), "row_tiles": zero(),
+        }
         for i in config.expert_layers
     }
 

@@ -11,10 +11,11 @@ Which layer is which:
   larger deployment: it is told which expert ids it ``held``s, routes over
   ALL of the router's experts (sigmoid scores, top-k, renormalised, scaled),
   sorts the assignments that land on its own experts and computes their part
-  of the result with grouped products (every block of sorted rows belongs to
-  one expert: batched matmuls over blocks). No capacity and no drops, no
-  array of size tokens x experts x capacity; what absent experts would add
-  is left out (another rank's part). The experts are relu² (two stacked
+  of the result with grouped products over the sorted rows as they lie, each
+  expert's after the last's (``ops.grouped_matmul``: Pallas kernels on TPU,
+  ``lax.ragged_dot`` elsewhere). No capacity and no drops, no array of size
+  tokens x experts x capacity, no padding between experts; what absent
+  experts would add is left out (another rank's part). The experts are relu² (two stacked
   leaves) or gated silu (three), told apart by the operands. Used by
   ``models/nemotron_h`` (ungated, top 6) and ``models/afmoe`` (gated, top 8).
 
@@ -42,12 +43,15 @@ and expert shard axis).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from ..ops.grouped_matmul import grouped_matmul, row_tiles
 
 PyTree = Any
 
@@ -208,11 +212,19 @@ def held_experts_moe(
     expert and computed as grouped products. The expert's form follows its
     operands: without ``w_gate`` it is ``w_out_e . relu(w_in_e . x)^2``
     (Nemotron-H), with it ``w_out_e . (silu(w_gate_e . x) * (w_in_e . x))``
-    (afmoe's gate, up and down projections). Each expert's rows are laid out
-    from a multiple of ``block_rows``, so every block of rows belongs to one
-    expert and the two (or three) products are batched matmuls over blocks,
-    each block with its expert's weights; the weighted rows are added back
-    into their tokens.
+    (afmoe's gate, up and down projections). The sorted rows stay as they lie,
+    each expert's after the last's: the tokens are gathered in that order,
+    the two (or three) products are ``ops.grouped_matmul`` over them with the
+    experts' row counts as group sizes — row tiles of ``block_rows``, a tile
+    two experts share visited once for each, each visit reading its expert's
+    weights in place, a tile past the last assignment not visited at all —
+    and the weighted rows are added back into their tokens. (``lax.ragged_dot``
+    is the same product, and what this is on the CPU; on the v5e XLA's
+    lowering of it took 17 ms a call at these shapes, PR 27. The layout that
+    answered it then, every expert's rows padded to whole blocks and a
+    block's weights picked by a one-hot product, computed 12,288 rows for
+    3,000 to 4,400 and wrote a copy of the weights a block; the kernels
+    visit 13 to 15 tiles of 512 and copy nothing, PR 32.)
 
     The work follows the assignments that landed here. They are taken in
     chunks of T rows (the expected load is T*top_k*len(held)/E): the first
@@ -226,7 +238,9 @@ def held_experts_moe(
     int32 counters of this call — ``held`` (len(held),) assignments per held
     expert, ``absent`` assignments on experts that live elsewhere, ``dropped``
     assignments on held experts that were not computed (always 0; counted
-    from the rows the chunks covered, not assumed).
+    from the rows the chunks covered, not assumed), ``row_tiles`` the row
+    tiles one product of the first chunk visits (the row extent of its
+    kernel's grid: work that follows the load).
 
     ``axis_name`` is where the exchange between ranks would ride. Only the
     one-rank layer (``None``) exists, and both models that use it run it so:
@@ -259,56 +273,39 @@ def held_experts_moe(
         group_ends = jnp.cumsum(group_sizes)
         landed = group_ends[-1]
 
-    # chunks of the sorted assignments: T rows each, in whole blocks. The expected load is
+    # chunks of the sorted assignments: T rows each, in whole row tiles. The expected load is
     # T*top_k*len(held)/E, well under T for a rank that holds a share of the experts, but
     # skewed traffic (Zipf token ids route by id) was seen at 1.5x it in one run of nine
     most = t * min(top_k, n_held)
     rows = -(-min(t, most) // block_rows) * block_rows
     n_chunks = -(-most // rows)
-    n_blocks = rows // block_rows + n_held  # each expert may end on a part-filled block
-    padded = n_blocks * block_rows
     pad_to = lambda v, fill: jnp.pad(v[:most], (0, n_chunks * rows - most), constant_values=fill)
-    sorted_slots = pad_to(slots[order], n_held)
     sorted_tokens = pad_to(order // top_k, 0)
     sorted_weights = pad_to(weights.reshape(-1)[order], 0.0)
+
+    def sizes_in(first):
+        """How many of each held expert's rows lie in [first, first + rows)."""
+        clipped = lambda v: jnp.clip(v, first, first + rows)
+        return clipped(group_ends) - clipped(group_ends - group_sizes)
 
     def chunk(first, x, w_in, w_out, sorted_weights, w_gate=None):
         """Rows [first, first + rows) of the sorted assignments -> their
         part of the output (T, D) and how many of them were live."""
-        at = first + jnp.arange(rows)
-        live = at < landed
-        slot = jnp.where(live, lax.dynamic_slice(sorted_slots, (first,), (rows,)), 0)
-        starts = jnp.clip(group_ends - group_sizes, first, first + rows)
-        blocks_of = -(-(jnp.clip(group_ends, first, first + rows) - starts) // block_rows)
-        block_ends = jnp.cumsum(blocks_of)
-        # where each assignment's row goes: its expert's first block, then its rank there
-        to = jnp.where(live, (block_ends - blocks_of)[slot] * block_rows + at - starts[slot], padded)
-        row_token = jnp.full((padded,), t, i32).at[to].set(
-            lax.dynamic_slice(sorted_tokens, (first,), (rows,)), mode="drop"
+        sizes = sizes_in(first)
+        product = functools.partial(grouped_matmul, group_sizes=sizes, row_tile=block_rows)
+        # a row past the last assignment takes token T, out of range: it reads zeros and adds
+        # nowhere, no product visits it and each returns it as zeros
+        token = jnp.where(
+            first + jnp.arange(rows) < landed, lax.dynamic_slice(sorted_tokens, (first,), (rows,)), t
         )
-        row_weight = jnp.zeros((padded,), f32).at[to].set(
-            lax.dynamic_slice(sorted_weights, (first,), (rows,)), mode="drop"
-        )
-        block_expert = jnp.minimum(
-            jnp.searchsorted(block_ends, jnp.arange(n_blocks), side="right"), n_held - 1
-        )
-        # a block's weights by a one-hot product: a gather whose transpose is no scatter
-        pick = (block_expert[:, None] == jnp.arange(n_held)).astype(x.dtype)
-        block_in = jnp.einsum("ne,edf->ndf", pick, w_in)
-        block_out = jnp.einsum("ne,efd->nfd", pick, w_out)
-        # an empty row's token is T, out of range: it reads zeros and adds nowhere
-        rows_in = x.at[row_token].get(mode="fill", fill_value=0).reshape(n_blocks, block_rows, d)
-        hidden = jnp.einsum("nbd,ndf->nbf", rows_in, block_in, preferred_element_type=f32)
+        rows_in = x.at[token].get(mode="fill", fill_value=0)
+        hidden = product(rows_in, w_in)
         if w_gate is None:
             hidden = relu_squared(hidden).astype(x.dtype)
         else:
-            block_gate = jnp.einsum("ne,edf->ndf", pick, w_gate)
-            gate = jnp.einsum("nbd,ndf->nbf", rows_in, block_gate, preferred_element_type=f32)
-            hidden = (jax.nn.silu(gate) * hidden).astype(x.dtype)
-        part = jnp.einsum("nbf,nfd->nbd", hidden, block_out, preferred_element_type=f32)
-        part = part.reshape(padded, d) * row_weight[:, None]
-        out = jnp.zeros((t, d), f32).at[row_token].add(part, mode="drop")
-        return out, jnp.sum(live.astype(i32))
+            hidden = (jax.nn.silu(product(rows_in, w_gate)) * hidden).astype(x.dtype)
+        part = product(hidden, w_out) * lax.dynamic_slice(sorted_weights, (first,), (rows,))[:, None]
+        return jnp.zeros((t, d), f32).at[token].add(part, mode="drop"), jnp.sum(sizes)
 
     def later_chunks(*operands):
         """The rare, heavy load: chunk after chunk until the assignments end.
@@ -340,5 +337,8 @@ def held_experts_moe(
         if n_chunks > 1:
             more, more_computed = lax.cond(landed > rows, later_chunks, nothing, *operands)
             out, computed = out + more, computed + more_computed
-    counters = {"held": group_sizes, "absent": absent, "dropped": landed - computed}
+    counters = {
+        "held": group_sizes, "absent": absent, "dropped": landed - computed,
+        "row_tiles": row_tiles(sizes_in(0), block_rows),
+    }
     return out.astype(x.dtype), counters

@@ -129,8 +129,17 @@ def plain_gated_experts(x, router, gate, up, down, held):
 
 
 def routed(x, router, gate, up, down, held):
-    # blocks of 8 rows: a chunk is T = 48 rows, so a skewed load (3T) takes three
+    # row tiles of 8: a chunk is T = 48 rows in six of them, so a skewed load (3T) takes three chunks
     return held_experts_moe(x, x, router, jnp.zeros((E,)), up, down, held, K, 2.826, block_rows=8, w_gate=gate)
+
+
+def row_tiles_of(held_counts, rows=T, tile=8):
+    """The row tiles one product of the first chunk visits, by hand: every
+    (tile, expert) pair with a row in common among the first ``rows`` sorted
+    assignments."""
+    ends = np.minimum(np.cumsum(np.asarray(held_counts)), rows)
+    starts = np.concatenate([[0], ends[:-1]])
+    return int(sum(-(-e // tile) - s // tile for s, e in zip(starts, ends) if e > s))
 
 
 @pytest.mark.parametrize("skew", [0.0, 4.0], ids=["even", "most_tokens_on_three_experts"])
@@ -143,8 +152,10 @@ def test_gated_expert_layer_matches_a_plain_loop_and_drops_nothing(skew):
     np.testing.assert_allclose(got, plain_gated_experts(x, router, *mine, held), rtol=2e-4, atol=2e-5)
     assert int(counters["dropped"]) == 0
     assert int(counters["held"].sum() + counters["absent"]) == T * K
+    assert int(counters["row_tiles"]) == row_tiles_of(counters["held"])
     if skew:  # 3T = 144 assignments landed against chunks of 48 rows: all three chunks ran
         assert int(counters["held"][:3].sum()) == 3 * T
+        assert int(counters["row_tiles"]) == 6  # the first chunk is full, and all of it the first expert's
     grads = jax.grad(lambda *a: jnp.sum(jnp.sin(routed(*a, held)[0])), argnums=range(5))(x, router, *mine)
     plain = jax.grad(lambda *a: jnp.sum(jnp.sin(plain_gated_experts(*a, held))), argnums=range(5))(x, router, *mine)
     assert worst_relative(grads, plain) < 1e-4
@@ -168,6 +179,7 @@ def test_the_shares_of_sixteen_ranks_and_the_shared_expert_once_equal_the_uncut_
         part, counters = routed(x, router, gate[one], up[one], down[one], (rank,))
         parts, landed = parts + part, landed + int(counters["held"].sum())
         assert int(counters["absent"]) + int(counters["held"].sum()) == T * K
+        assert int(counters["row_tiles"]) == -(-int(counters["held"][0]) // 8)  # one expert: its rows in whole tiles
     assert landed == T * K  # every assignment landed on exactly one rank
     uncut = {"router": router, "experts_gate": gate, "experts_up": up, "experts_down": down, "shared": shared}
     cfg = {"num_experts_per_tok": K, "route_scale": 2.826, "held_experts": list(range(E))}
@@ -190,7 +202,7 @@ def test_a_router_score_rounded_to_bfloat16_picks_other_experts():
     assert np.any(full != low, axis=-1).mean() > 0.05
 
 
-# seed, held experts, assignments that land (a chunk is T = 48 rows in 6 blocks of 8): none past the first chunk;
+# seed, held experts, assignments that land (a chunk is T = 48 rows in 6 row tiles of 8): none past the first chunk;
 # 5, 12, 15 and 36 past it, in the scan's second chunk; 53, in its second and third
 SECOND_CHUNK_CASES = [
     (1, (0, 1, 2, 7, 9), 39), (1, (0, 1, 2, 7, 9, 11), 53), (2, (0, 1, 2, 3, 7, 9, 11), 60),
@@ -207,6 +219,8 @@ def test_a_load_past_the_first_chunk_goes_on_in_the_scan(seed, held, landed):
     mine = (gate[slots], up[slots], down[slots])
     got, counters = jax.jit(lambda *a: routed(*a, held))(x, router, *mine)
     assert int(counters["held"].sum()) == landed and int(counters["dropped"]) == 0
+    # the counter is the first chunk's: what of each expert's rows lies in its 48, whatever the scan takes on
+    assert int(counters["row_tiles"]) == row_tiles_of(counters["held"])
     np.testing.assert_allclose(got, plain_gated_experts(x, router, *mine, held), rtol=2e-4, atol=2e-5)
     grads = jax.grad(lambda *a: jnp.sum(jnp.sin(routed(*a, held)[0])), argnums=range(5))(x, router, *mine)
     plain = jax.grad(lambda *a: jnp.sum(jnp.sin(plain_gated_experts(*a, held))), argnums=range(5))(x, router, *mine)
@@ -216,7 +230,8 @@ def test_a_load_past_the_first_chunk_goes_on_in_the_scan(seed, held, landed):
 def test_the_ungated_layer_takes_the_same_path_without_its_gate():
     """Nemotron-H's relu² experts run the function the gated ones run, less
     one product: leaving ``w_gate`` out and passing None trace one program,
-    and it has two grouped products a chunk where the gated one has three."""
+    and it has two grouped products a chunk where the gated one has three
+    (a chunk is traced twice: the first, and the scan's)."""
     x, router, gate, w_in, w_out = expert_layer()
     held = (0, 1, 2, 7, 9)
     slots = jnp.asarray(held)
@@ -225,6 +240,7 @@ def test_the_ungated_layer_takes_the_same_path_without_its_gate():
     assert program() == program(w_gate=None)
     gated = program(w_gate=gate[slots])
     assert gated != program() and gated.count("logistic") > program().count("logistic")
+    assert 0 < program().count("ragged_dot") * 3 == gated.count("ragged_dot") * 2
 
 
 # ---- expert_bias: a buffer, balanced for weights that come from a seed --------

@@ -119,8 +119,17 @@ def plain_experts(x, router, w_in, w_out, held):
 
 
 def routed(x, router, w_in, w_out, held):
-    # blocks of 8 rows: a chunk is T = 48 rows, so a skewed load (3T) takes three
+    # row tiles of 8: a chunk is T = 48 rows in six of them, so a skewed load (3T) takes three chunks
     return held_experts_moe(x, x, router, jnp.zeros((E,)), w_in, w_out, held, K, 2.5, block_rows=8)
+
+
+def row_tiles_of(held_counts, rows=T, tile=8):
+    """The row tiles one product of the first chunk visits, by hand: every
+    (tile, expert) pair with a row in common among the first ``rows`` sorted
+    assignments."""
+    ends = np.minimum(np.cumsum(np.asarray(held_counts)), rows)
+    starts = np.concatenate([[0], ends[:-1]])
+    return int(sum(-(-e // tile) - s // tile for s, e in zip(starts, ends) if e > s))
 
 
 @pytest.mark.parametrize("skew", [0.0, 4.0], ids=["even", "most_tokens_on_three_experts"])
@@ -133,9 +142,12 @@ def test_expert_layer_matches_a_plain_loop_and_drops_nothing(skew):
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
     assert int(counters["dropped"]) == 0
     assert int(counters["held"].sum() + counters["absent"]) == T * K
+    # the first chunk's products visit the tiles that hold rows, a tile two experts share once for each
+    assert int(counters["row_tiles"]) == row_tiles_of(counters["held"])
     if skew:  # 3T = 144 assignments landed against chunks of 48 rows: all three chunks ran
         assert int(counters["held"].sum()) == 3 * T
         assert int(counters["held"][:3].sum()) == 3 * T
+        assert int(counters["row_tiles"]) == 6  # the first chunk is full, and all of it the first expert's
     grads = jax.grad(lambda *a: jnp.sum(jnp.sin(routed(*a, held)[0])), argnums=(0, 1, 2, 3))(
         x, router, w_in[slots], w_out[slots]
     )
@@ -158,6 +170,7 @@ def test_the_shares_of_sixteen_ranks_and_the_shared_expert_once_equal_the_uncut_
         part, counters = routed(x, router, w_in[rank:rank + 1], w_out[rank:rank + 1], (rank,))
         parts, landed = parts + part, landed + int(counters["held"].sum())
         assert int(counters["absent"]) + int(counters["held"].sum()) == T * K
+        assert int(counters["row_tiles"]) == -(-int(counters["held"][0]) // 8)  # one expert: its rows in whole tiles
     assert landed == T * K  # every assignment landed on exactly one rank
     shared = jnp.dot(jnp.square(jax.nn.relu(jnp.dot(x, shared_in, precision="highest"))), shared_out, precision="highest")
     uncut = {
@@ -185,14 +198,15 @@ def test_expert_layer_inside_shard_map_skips_and_runs_chunks_per_worker():
 
     def worker(x, router, w_in, w_out):
         out, counters = routed(x[0], router[0], w_in, w_out, held)
-        return out[None], counters["dropped"][None]
+        return out[None], counters["dropped"][None], counters["row_tiles"][None], counters["held"][None]
 
-    got, dropped = jax.jit(jax.shard_map(
-        worker, mesh=mesh, in_specs=(P("data"), P("data"), P(), P()), out_specs=(P("data"), P("data")),
+    got, dropped, row_tiles, landed = jax.jit(jax.shard_map(
+        worker, mesh=mesh, in_specs=(P("data"), P("data"), P(), P()), out_specs=P("data"),
     ))(x, router, w_in, w_out)
     for w in range(2):
         np.testing.assert_allclose(got[w], plain_experts(x[w], router[w], w_in, w_out, held), rtol=2e-4, atol=2e-5)
-    assert not dropped.any()
+        assert int(row_tiles[w]) == row_tiles_of(landed[w])  # each worker's own count
+    assert not dropped.any() and int(row_tiles[1]) == 6
 
 
 def test_the_layer_over_a_mesh_axis_is_not_built_yet():

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from network_distributed_pytorch_tpu.ops.flash_attention import flash_attention
+from network_distributed_pytorch_tpu.ops.grouped_matmul import grouped_matmul
 from network_distributed_pytorch_tpu.ops.pallas_orthogonalize import (
     orthogonalize_pallas,
 )
@@ -50,6 +51,10 @@ ORTHOGONALIZE_SHAPES = [
     (30522, 16), (3072, 16), (768, 16), (512, 16), (768, 2), (50257, 4),
     (2048, 4),
 ]
+# (k, n) of the routed experts' products over a chunk of 8192 sorted rows and
+# 8 held experts, bf16: nemotron_psgd16_t8k's two (1856 = 14.5 x 128 is one
+# tile, as the contraction and as the output) and trinity_psgd16_t8k's
+GROUPED_MATMUL_SHAPES = [(2688, 1856), (1856, 2688), (2048, 1024), (1024, 2048)]
 
 
 def _flash_fns(shape, dtype, causal, masked):
@@ -85,6 +90,39 @@ def test_pallas_orthogonalize_lowers_for_tpu(shape):
         lowering_platforms=("tpu",)
     )
     assert "tpu_custom_call" in lowered.as_text()
+
+
+def _grouped_matmul_fns(shape):
+    k, n = shape
+    args = [
+        jax.ShapeDtypeStruct((8192, k), jnp.bfloat16),
+        jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16),
+        jax.ShapeDtypeStruct((8,), jnp.int32),
+    ]
+    # interpret=False: the kernels, whatever backend traces them
+    forward = lambda lhs, rhs, sizes: grouped_matmul(lhs, rhs, sizes, interpret=False)
+    loss = lambda lhs, rhs, sizes: jnp.sum(jnp.sin(forward(lhs, rhs, sizes)))
+    return args, {"forward": forward, "grad": jax.grad(loss, argnums=(0, 1))}
+
+
+@pytest.mark.parametrize("shape", GROUPED_MATMUL_SHAPES, ids=str)
+def test_grouped_matmul_lowers_for_tpu_inside_shard_map(shape):
+    """Where every training step runs: the kernels' outputs declare how they
+    vary over the mesh (the library's own kernels do not, and are refused
+    here), rows and group sizes a worker's own, the matrices shared."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    args, fns = _grouped_matmul_fns(shape)
+    args = [jax.ShapeDtypeStruct((2,) + a.shape, a.dtype) for a in args]
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+
+    def worker(lhs, rhs, sizes):
+        d_lhs, d_rhs = fns["grad"](lhs[0], rhs[0], sizes[0])
+        return d_lhs[None], d_rhs[None]
+
+    sharded = jax.shard_map(worker, mesh=mesh, in_specs=P("data"), out_specs=P("data"))
+    text = jax.jit(sharded).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") >= 3
 
 
 # --- libtpu's Mosaic compiler, without a chip -------------------------------
@@ -165,6 +203,24 @@ def test_flash_backward_is_a_kernel_in_the_compiled_program(
     }
     too_large = {dims for dims, n in sizes.items() if n > b * h * t * d}
     assert not too_large, too_large
+
+
+@pytest.mark.parametrize("shape", GROUPED_MATMUL_SHAPES, ids=str)
+def test_grouped_matmul_compiles_with_mosaic_under_its_three_names(v5e_devices, shape):
+    """Forward and both cotangents at the expert layers' real shapes: what
+    Mosaic would refuse on the chip (a tile's VMEM, a 1856-wide block, a
+    select in bf16, the transposed products) it refuses here; each
+    ``pallas_call`` is in the compiled program under its own name."""
+    import re
+
+    args, fns = _grouped_matmul_fns(shape)
+    args = [_on(v5e_devices[0], a) for a in args]
+    jax.jit(fns["forward"]).lower(*args).compile()
+    hlo = jax.jit(fns["grad"]).lower(*args).compile().as_text()
+    kernels = re.findall(r"%([a-z_]+)[\w.]* = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    # jax wraps the name in what differentiated it: jvp_grouped_matmul_, transpose_jvp_grouped_matmul_nt__
+    kernels = sorted(re.sub(r"^(transpose_|jvp_)+|_+$", "", kernel) for kernel in kernels)
+    assert kernels == ["grouped_matmul", "grouped_matmul_nt", "grouped_matmul_tn"], kernels
 
 
 @pytest.mark.parametrize("shape", ORTHOGONALIZE_SHAPES, ids=str)
