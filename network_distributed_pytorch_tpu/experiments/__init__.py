@@ -17,4 +17,5 @@ from . import (  # noqa: F401
     powersgd_cifar10,
     powersgd_imdb,
     powersgd_nemotron,
+    powersgd_qwen3_next,
 )

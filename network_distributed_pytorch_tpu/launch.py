@@ -35,6 +35,7 @@ from .experiments import (
     powersgd_cifar10,
     powersgd_imdb,
     powersgd_nemotron,
+    powersgd_qwen3_next,
     serve_gpt,
 )
 from .observe import RawEvent, StreamJsonSink, Telemetry
@@ -49,6 +50,7 @@ EXPERIMENTS = {
     "powersgd_imdb": powersgd_imdb.run,
     "powersgd_nemotron": powersgd_nemotron.run,
     "powersgd_afmoe": powersgd_afmoe.run,
+    "powersgd_qwen3_next": powersgd_qwen3_next.run,
     "imdb_baseline": imdb_baseline.run,
     "bandwidth_study": bandwidth_study.run,
     "gpt_lm": gpt_lm.run,
@@ -786,7 +788,7 @@ def main(argv=None) -> dict:
                       spec_k=args.spec_k if args.spec_k is not None else 0)
     elif args.experiment == "bandwidth_study":
         kwargs.update(preset=args.preset)
-    elif args.experiment in ("powersgd_nemotron", "powersgd_afmoe"):
+    elif args.experiment in ("powersgd_nemotron", "powersgd_afmoe", "powersgd_qwen3_next"):
         kwargs.update(preset=args.preset,
                       max_steps_per_epoch=args.max_steps_per_epoch)
     elif args.experiment in ("gpt_lm", "gpt_pp", "gpt_sp", "gpt_tp", "gpt_moe"):

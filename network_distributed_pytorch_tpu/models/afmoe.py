@@ -44,7 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -96,10 +96,14 @@ class AfmoeConfig:
         return tuple(range(self.num_dense_layers, len(self.layer_types)))
 
 
-def rotary(x: jax.Array, theta: float) -> jax.Array:
+def rotary(x: jax.Array, theta: float, rotary_dim: Optional[int] = None) -> jax.Array:
     """``x`` (B, T, H, D) in fp32, position t turned by the angles ``t *
     theta^(-2i/D)``: ``x cos + rotate_half(x) sin``, the halves paired as
-    HuggingFace pairs them (i with i + D/2)."""
+    HuggingFace pairs them (i with i + D/2). With ``rotary_dim`` only the
+    head's first ``rotary_dim`` dims turn, as a head of that size would
+    (``partial_rotary_factor``); the others pass as they came."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        return jnp.concatenate([rotary(x[..., :rotary_dim], theta), x[..., rotary_dim:]], axis=-1)
     t, d = x.shape[1], x.shape[-1]
     inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]  # (T, D/2)

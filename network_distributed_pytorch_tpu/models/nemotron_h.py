@@ -108,11 +108,15 @@ def _dense(cfg, width: int, std: float, name: str) -> nn.Dense:
 
 class RMSNorm(nn.Module):
     eps: float
+    zero_centred: bool = False  # the learned scale is 1 + w, w from zero (``models/qwen3_next.py``)
 
     @nn.compact
     def __call__(self, x):
         """fp32 in, fp32 out: callers cast to what their products take."""
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        init = nn.initializers.zeros if self.zero_centred else nn.initializers.ones
+        scale = self.param("scale", init, (x.shape[-1],))
+        if self.zero_centred:
+            scale = 1.0 + scale
         x = x.astype(jnp.float32)
         return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps) * scale
 

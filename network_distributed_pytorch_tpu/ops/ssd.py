@@ -18,20 +18,24 @@ the recurrence as written is ``benchmark/reference/nemotron_h.py``'s.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 
-def causal_conv1d(x: jax.Array, kernel: jax.Array, bias: jax.Array) -> jax.Array:
+def causal_conv1d(x: jax.Array, kernel: jax.Array, bias: Optional[jax.Array]) -> jax.Array:
     """Depthwise causal convolution over time: ``y_t = sum_j kernel[j] *
-    x_{t-K+1+j} + bias``; ``x`` (B, T, C), ``kernel`` (K, C), ``bias`` (C,).
+    x_{t-K+1+j} + bias``; ``x`` (B, T, C), ``kernel`` (K, C), ``bias`` (C,)
+    or None for a convolution without one (a Gated-DeltaNet mixer's).
     K shifted multiply-adds (K is 4): no convolution op, no (T, K) window."""
     k, t = kernel.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    out = bias.astype(x.dtype)
+    out = None if bias is None else bias.astype(x.dtype)
     for j in range(k):
-        out = out + padded[:, j:j + t] * kernel[j].astype(x.dtype)
+        tap = padded[:, j:j + t] * kernel[j].astype(x.dtype)
+        out = tap if out is None else out + tap
     return out
 
 

@@ -9,7 +9,7 @@ Which layer is which:
   is exactly as wide as the experts the mesh holds. Used by ``gpt_moe``.
 - :func:`held_experts_moe` — the layer of one expert-parallel rank of a
   larger deployment: it is told which expert ids it ``held``s, routes over
-  ALL of the router's experts (sigmoid scores, top-k, renormalised, scaled),
+  ALL of the router's experts (sigmoid or softmax scores, top-k, renormalised, scaled),
   sorts the assignments that land on its own experts and computes their part
   of the result with grouped products over the sorted rows as they lie, each
   expert's after the last's (``ops.grouped_matmul``: Pallas kernels on TPU,
@@ -17,7 +17,8 @@ Which layer is which:
   tokens x experts x capacity, no padding between experts; what absent
   experts would add is left out (another rank's part). The experts are relu² (two stacked
   leaves) or gated silu (three), told apart by the operands. Used by
-  ``models/nemotron_h`` (ungated, top 6) and ``models/afmoe`` (gated, top 8).
+  ``models/nemotron_h`` (ungated, top 6), ``models/afmoe`` (gated, top 8) and
+  ``models/qwen3_next`` (gated, softmax scores, top 10 of 512).
 
 ``switch_moe``, in detail (beyond-parity capability, SURVEY §2.3: EP/MoE
 absent from the reference). TPU-native design:
@@ -201,10 +202,13 @@ def held_experts_moe(
     axis_name: Optional[str] = None,
     block_rows: int = 512,
     w_gate: Optional[jax.Array] = None,  # (len(held), D, F): the experts are gated
+    score: str = "sigmoid",     # "sigmoid": each expert scored alone | "softmax": over all E
 ) -> tuple[jax.Array, Dict[str, jax.Array]]:
     """This rank's part of a dropless top-k expert layer.
 
-    Scores are ``sigmoid(router_in @ router_kernel)`` in fp32 at full
+    Scores are ``sigmoid(router_in @ router_kernel)``, or with
+    ``score="softmax"`` the softmax of those logits over the router's whole
+    width, in fp32 at full
     precision (a top-k is discrete: a score rounded to bf16 picks other
     experts); each token takes the ``top_k`` largest of ``score +
     select_bias`` and weights them ``scaling * score_i / sum_topk score``.
@@ -253,13 +257,14 @@ def held_experts_moe(
     n_held = len(held)
     assert w_in.shape[0] == w_out.shape[0] == n_held and 1 <= top_k <= e
     assert w_gate is None or w_gate.shape == w_in.shape
+    assert score in ("sigmoid", "softmax"), score
     f32, i32 = jnp.float32, jnp.int32
 
     with jax.named_scope("moe.route"):
         logits = jnp.dot(
             router_in.astype(f32), router_kernel.astype(f32), precision=lax.Precision.HIGHEST
         )
-        scores = jax.nn.sigmoid(logits)  # (T, E)
+        scores = jax.nn.sigmoid(logits) if score == "sigmoid" else jax.nn.softmax(logits, axis=-1)  # (T, E)
         _, chosen = lax.top_k(scores + select_bias.astype(f32), top_k)  # (T, K)
         picked = jnp.take_along_axis(scores, chosen, axis=-1)
         weights = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)

@@ -33,7 +33,9 @@ from network_distributed_pytorch_tpu.ops.pallas_orthogonalize import (
 # nemotron_psgd16_t8k (512x512 tiles, K and V whole: the VMEM request; with
 # 32 key/value heads and with the model's 2, read in place),
 # trinity_psgd16_t8k's sliding layers (32 heads over 4, a window of 2048:
-# both loop bounds), and a head width no lane block serves (the fold)
+# both loop bounds), qwen3next_psgd16_t8k's full layer (16 heads of 256 over
+# 2: two lane tiles a head, the VMEM of T = 16,384 at 128), and a head width
+# no lane block serves (the fold)
 FLASH_CASES = [
     pytest.param((16, 256, 12, 64), jnp.bfloat16, False, True, id="distilbert-bf16"),
     pytest.param((16, 256, 12, 64), jnp.float32, False, True, id="distilbert-fp32"),
@@ -44,6 +46,7 @@ FLASH_CASES = [
     pytest.param((1, 8192, 32, 128), jnp.bfloat16, True, False, id="nemotron-8192-causal"),
     pytest.param((1, 8192, 32, 128, 2), jnp.bfloat16, True, False, id="nemotron-8192-gqa"),
     pytest.param((1, 8192, 32, 128, 4, 2048), jnp.bfloat16, True, False, id="trinity-8192-window-2048"),
+    pytest.param((1, 8192, 16, 256, 2), jnp.bfloat16, True, False, id="qwen3next-8192-head-256-gqa"),
     pytest.param((4, 512, 3, 64), jnp.bfloat16, False, True, id="fold-3x64"),
 ]
 # P-factor shapes: DistilBERT-base at rank 16 (chip_smoke), GPT-2 at rank 4
@@ -53,8 +56,9 @@ ORTHOGONALIZE_SHAPES = [
 ]
 # (k, n) of the routed experts' products over a chunk of 8192 sorted rows and
 # 8 held experts, bf16: nemotron_psgd16_t8k's two (1856 = 14.5 x 128 is one
-# tile, as the contraction and as the output) and trinity_psgd16_t8k's
-GROUPED_MATMUL_SHAPES = [(2688, 1856), (1856, 2688), (2048, 1024), (1024, 2048)]
+# tile, as the contraction and as the output) and trinity_psgd16_t8k's; with
+# a third number, that many held experts: qwen3next_psgd16_t8k's 16 of width 512
+GROUPED_MATMUL_SHAPES = [(2688, 1856), (1856, 2688), (2048, 1024), (1024, 2048), (2048, 512, 16), (512, 2048, 16)]
 
 
 def _flash_fns(shape, dtype, causal, masked):
@@ -93,11 +97,11 @@ def test_pallas_orthogonalize_lowers_for_tpu(shape):
 
 
 def _grouped_matmul_fns(shape):
-    k, n = shape
+    k, n, held = (*shape, 8)[:3]
     args = [
         jax.ShapeDtypeStruct((8192, k), jnp.bfloat16),
-        jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16),
-        jax.ShapeDtypeStruct((8,), jnp.int32),
+        jax.ShapeDtypeStruct((held, k, n), jnp.bfloat16),
+        jax.ShapeDtypeStruct((held,), jnp.int32),
     ]
     # interpret=False: the kernels, whatever backend traces them
     forward = lambda lhs, rhs, sizes: grouped_matmul(lhs, rhs, sizes, interpret=False)
