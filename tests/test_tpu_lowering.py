@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from network_distributed_pytorch_tpu.ops import gated_delta
 from network_distributed_pytorch_tpu.ops.flash_attention import flash_attention
 from network_distributed_pytorch_tpu.ops.grouped_matmul import grouped_matmul
 from network_distributed_pytorch_tpu.ops.pallas_orthogonalize import (
@@ -129,6 +130,35 @@ def test_grouped_matmul_lowers_for_tpu_inside_shard_map(shape):
     assert text.count("tpu_custom_call") >= 3
 
 
+def _chunk_local_fns(bsz=1, t=8192, hk=16, hv=32, d=128, chunk=64, dtype=jnp.bfloat16):
+    """The gated delta rule's chunk-local stage at qwen3next_psgd16_t8k's
+    shapes: one sequence of 8192, 16 key and 32 value heads of 128, chunks of
+    64, bf16; and the whole rule on a chip (``default_backend`` steered)."""
+    step_values = jax.ShapeDtypeStruct((bsz, t // chunk, hk, hv // hk, chunk), jnp.float32)
+    k, v = jax.ShapeDtypeStruct((bsz, t, hk, d), dtype), jax.ShapeDtypeStruct((bsz, t, hv, d), dtype)
+    # interpret=False: the kernels, whatever backend traces them
+    stage = lambda q, k, v, gamma, beta: gated_delta.chunk_local(q, k, v, gamma, beta, chunk, interpret=False)
+    loss = lambda *a: sum(jnp.sum(jnp.sin(x.astype(jnp.float32))) for x in stage(*a))
+    per_step = jax.ShapeDtypeStruct((bsz, t, hv), jnp.float32)
+    rule = lambda *a: jnp.sum(jnp.sin(gated_delta.gated_delta_rule(*a, chunk=chunk).astype(jnp.float32)))
+    return (
+        [k, k, v, step_values, step_values], {"forward": stage, "grad": jax.grad(loss, argnums=range(5))},
+        [k, k, v, per_step, per_step], jax.grad(rule, argnums=range(5)),
+    )
+
+
+def test_gated_delta_chunk_local_lowers_for_tpu_inside_shard_map():
+    """Both kernels' outputs declare how they vary over the mesh, as every
+    training step's ``shard_map(check_vma=True)`` asks."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    args, fns, _, _ = _chunk_local_fns(bsz=2)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    sharded = jax.shard_map(fns["grad"], mesh=mesh, in_specs=P("data"), out_specs=P("data"))
+    text = jax.jit(sharded).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 2
+
+
 # --- libtpu's Mosaic compiler, without a chip -------------------------------
 
 
@@ -225,6 +255,26 @@ def test_grouped_matmul_compiles_with_mosaic_under_its_three_names(v5e_devices, 
     # jax wraps the name in what differentiated it: jvp_grouped_matmul_, transpose_jvp_grouped_matmul_nt__
     kernels = sorted(re.sub(r"^(transpose_|jvp_)+|_+$", "", kernel) for kernel in kernels)
     assert kernels == ["grouped_matmul", "grouped_matmul_nt", "grouped_matmul_tn"], kernels
+
+
+def test_gated_delta_chunk_local_compiles_with_mosaic_under_its_two_names(v5e_devices, monkeypatch):
+    """At the cell's shapes: what Mosaic would refuse on the chip (the lane
+    concatenations of the inverse's state, the fp32 products at full
+    precision, the transposed bf16 ones, a step's VMEM) it refuses here. And
+    the whole rule on a chip takes the kernels: in its compiled gradient each
+    is a ``tpu_custom_call`` under its own name, and none of the stage's
+    (chunks, heads, 64, 64) fp32 matrices is an array of the program."""
+    import re
+
+    args, fns, rule_args, rule_grad = _chunk_local_fns()
+    on_chip = lambda structs: [_on(v5e_devices[0], a) for a in structs]
+    jax.jit(fns["forward"]).lower(*on_chip(args)).compile()
+    jax.jit(fns["grad"]).lower(*on_chip(args)).compile()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the backend's own choice, as on the chip
+    hlo = jax.jit(rule_grad).lower(*on_chip(rule_args)).compile().as_text()
+    kernels = re.findall(r"%([a-z_]+)[\w.]* = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert sorted(kernels) == ["gated_delta_chunk_local", "gated_delta_chunk_local_bwd"], kernels
+    assert not re.search(r"f32\[1,128,16,2,64,64\]", hlo)  # A, its powers, the decay, Q K^T: none is an array
 
 
 @pytest.mark.parametrize("shape", ORTHOGONALIZE_SHAPES, ids=str)
