@@ -260,23 +260,32 @@ def held_experts_moe(
     assert score in ("sigmoid", "softmax"), score
     f32, i32 = jnp.float32, jnp.int32
 
+    # leaf scopes, one metric each (benchmark/layer_metrics/moe_<leaf>_ms.py): every op of the
+    # layer sits under exactly one of moe.score / moe.sort / moe.count (inside moe.route),
+    # moe.layout (whatever is inside neither), moe.gather / moe.products / moe.combine (inside
+    # moe.experts), so the leaves of moe.route and of moe.experts add up to what those read.
+    # moe.overflow wraps the later chunks: their leaves nest under it, and its own bookkeeping
+    # (cond, scan, zeros, the carry's adds) is the one thing under moe.experts with no leaf
     with jax.named_scope("moe.route"):
-        logits = jnp.dot(
-            router_in.astype(f32), router_kernel.astype(f32), precision=lax.Precision.HIGHEST
-        )
-        scores = jax.nn.sigmoid(logits) if score == "sigmoid" else jax.nn.softmax(logits, axis=-1)  # (T, E)
-        _, chosen = lax.top_k(scores + select_bias.astype(f32), top_k)  # (T, K)
-        picked = jnp.take_along_axis(scores, chosen, axis=-1)
-        weights = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
-        # expert id -> its slot here; every absent expert shares the slot past the last
-        slot_of = np.full((e,), n_held, np.int32)
-        slot_of[list(held)] = np.arange(n_held)
-        slots = jnp.asarray(slot_of)[chosen].reshape(-1)  # (T*K,)
-        order = jnp.argsort(slots, stable=True)  # held experts' assignments first, by expert
-        counts = jnp.zeros((n_held + 1,), i32).at[slots].add(1)
-        group_sizes, absent = counts[:n_held], counts[n_held]
-        group_ends = jnp.cumsum(group_sizes)
-        landed = group_ends[-1]
+        with jax.named_scope("moe.score"):
+            logits = jnp.dot(
+                router_in.astype(f32), router_kernel.astype(f32), precision=lax.Precision.HIGHEST
+            )
+            scores = jax.nn.sigmoid(logits) if score == "sigmoid" else jax.nn.softmax(logits, axis=-1)  # (T, E)
+            _, chosen = lax.top_k(scores + select_bias.astype(f32), top_k)  # (T, K)
+            picked = jnp.take_along_axis(scores, chosen, axis=-1)
+            weights = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
+        with jax.named_scope("moe.sort"):
+            # expert id -> its slot here; every absent expert shares the slot past the last
+            slot_of = np.full((e,), n_held, np.int32)
+            slot_of[list(held)] = np.arange(n_held)
+            slots = jnp.asarray(slot_of)[chosen].reshape(-1)  # (T*K,)
+            order = jnp.argsort(slots, stable=True)  # held experts' assignments first, by expert
+        with jax.named_scope("moe.count"):
+            counts = jnp.zeros((n_held + 1,), i32).at[slots].add(1)
+            group_sizes, absent = counts[:n_held], counts[n_held]
+            group_ends = jnp.cumsum(group_sizes)
+            landed = group_ends[-1]
 
     # chunks of the sorted assignments: T rows each, in whole row tiles. The expected load is
     # T*top_k*len(held)/E, well under T for a rank that holds a share of the experts, but
@@ -285,8 +294,9 @@ def held_experts_moe(
     rows = -(-min(t, most) // block_rows) * block_rows
     n_chunks = -(-most // rows)
     pad_to = lambda v, fill: jnp.pad(v[:most], (0, n_chunks * rows - most), constant_values=fill)
-    sorted_tokens = pad_to(order // top_k, 0)
-    sorted_weights = pad_to(weights.reshape(-1)[order], 0.0)
+    with jax.named_scope("moe.layout"):
+        sorted_tokens = pad_to(order // top_k, 0)
+        sorted_weights = pad_to(weights.reshape(-1)[order], 0.0)
 
     def sizes_in(first):
         """How many of each held expert's rows lie in [first, first + rows)."""
@@ -296,21 +306,25 @@ def held_experts_moe(
     def chunk(first, x, w_in, w_out, sorted_weights, w_gate=None):
         """Rows [first, first + rows) of the sorted assignments -> their
         part of the output (T, D) and how many of them were live."""
-        sizes = sizes_in(first)
-        product = functools.partial(grouped_matmul, group_sizes=sizes, row_tile=block_rows)
-        # a row past the last assignment takes token T, out of range: it reads zeros and adds
-        # nowhere, no product visits it and each returns it as zeros
-        token = jnp.where(
-            first + jnp.arange(rows) < landed, lax.dynamic_slice(sorted_tokens, (first,), (rows,)), t
-        )
-        rows_in = x.at[token].get(mode="fill", fill_value=0)
-        hidden = product(rows_in, w_in)
-        if w_gate is None:
-            hidden = relu_squared(hidden).astype(x.dtype)
-        else:
-            hidden = (jax.nn.silu(product(rows_in, w_gate)) * hidden).astype(x.dtype)
-        part = product(hidden, w_out) * lax.dynamic_slice(sorted_weights, (first,), (rows,))[:, None]
-        return jnp.zeros((t, d), f32).at[token].add(part, mode="drop"), jnp.sum(sizes)
+        with jax.named_scope("moe.gather"):
+            # a row past the last assignment takes token T, out of range: it reads zeros and adds
+            # nowhere, no product visits it and each returns it as zeros
+            token = jnp.where(
+                first + jnp.arange(rows) < landed, lax.dynamic_slice(sorted_tokens, (first,), (rows,)), t
+            )
+            rows_in = x.at[token].get(mode="fill", fill_value=0)
+        with jax.named_scope("moe.products"):
+            sizes = sizes_in(first)
+            product = functools.partial(grouped_matmul, group_sizes=sizes, row_tile=block_rows)
+            hidden = product(rows_in, w_in)
+            if w_gate is None:
+                hidden = relu_squared(hidden).astype(x.dtype)
+            else:
+                hidden = (jax.nn.silu(product(rows_in, w_gate)) * hidden).astype(x.dtype)
+            part, computed = product(hidden, w_out), jnp.sum(sizes)
+        with jax.named_scope("moe.combine"):
+            part = part * lax.dynamic_slice(sorted_weights, (first,), (rows,))[:, None]
+            return jnp.zeros((t, d), f32).at[token].add(part, mode="drop"), computed
 
     def later_chunks(*operands):
         """The rare, heavy load: chunk after chunk until the assignments end.
@@ -334,16 +348,19 @@ def held_experts_moe(
         return tuple(lax.pcast(z, varying, to="varying") for z in zeros) if varying else zeros
 
     with jax.named_scope("moe.experts"):
-        operands = (x, w_in.astype(x.dtype), w_out.astype(x.dtype), sorted_weights)
-        if w_gate is not None:
-            operands += (w_gate.astype(x.dtype),)
+        with jax.named_scope("moe.products"):
+            operands = (x, w_in.astype(x.dtype), w_out.astype(x.dtype), sorted_weights)
+            if w_gate is not None:
+                operands += (w_gate.astype(x.dtype),)
         # the first chunk nearly always holds every assignment: it runs outside any loop
         out, computed = chunk(0, *operands)
         if n_chunks > 1:
-            more, more_computed = lax.cond(landed > rows, later_chunks, nothing, *operands)
-            out, computed = out + more, computed + more_computed
-    counters = {
-        "held": group_sizes, "absent": absent, "dropped": landed - computed,
-        "row_tiles": row_tiles(sizes_in(0), block_rows),
-    }
-    return out.astype(x.dtype), counters
+            with jax.named_scope("moe.overflow"):
+                more, more_computed = lax.cond(landed > rows, later_chunks, nothing, *operands)
+                out, computed = out + more, computed + more_computed
+    with jax.named_scope("moe.layout"):
+        counters = {
+            "held": group_sizes, "absent": absent, "dropped": landed - computed,
+            "row_tiles": row_tiles(sizes_in(0), block_rows),
+        }
+        return out.astype(x.dtype), counters
