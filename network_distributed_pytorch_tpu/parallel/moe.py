@@ -49,7 +49,6 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from ..ops.grouped_matmul import grouped_matmul, row_tiles
@@ -189,6 +188,69 @@ def relu_squared(h: jax.Array) -> jax.Array:
     return jnp.square(jax.nn.relu(h))
 
 
+# The router indexes T*top_k scalars by indices it has just computed. On the TPU a gather or a
+# scatter of that many scalars takes 0.2-0.85 ms a call where its bytes need 0.01-0.1, and a sort
+# of them 0.05-0.08 (PERF.md section 6, PR 39 and 40): so each such index is a compare against a
+# small static range, or rides the one sort the layer makes anyway. Same values, to the bit
+# (inside one jit XLA may add a token's k picks in another order: an ulp of a weight).
+
+
+def _picked(scores: jax.Array, chosen: jax.Array) -> jax.Array:
+    """``take_along_axis(scores, chosen, -1)``: (T, E), (T, K) -> (T, K).
+
+    Of each sum over E one term is not zero, so it is the gather's value; XLA
+    fuses the (T, K, E) compare and select into the reduce. jax's cotangent
+    of it is the same compare summed over K (a token's choices are distinct:
+    again one term a sum) where the gather's is a scatter into (T, E)."""
+    is_chosen = chosen[:, :, None] == lax.iota(chosen.dtype, scores.shape[1])
+    return jnp.sum(jnp.where(is_chosen, scores[:, None, :], 0.0), axis=-1)
+
+
+def _slots(chosen: jax.Array, held: Sequence[int]) -> jax.Array:
+    """Expert id -> its slot here, ``held.index(id)``, for (N,) ids; every
+    absent expert shares the slot past the last. A compare a held id and a
+    ``min`` over them, no (E,) table to look up."""
+    ids, at = jnp.asarray(held, jnp.int32)[:, None], lax.iota(jnp.int32, len(held))[:, None]
+    return jnp.min(jnp.where(chosen == ids, at, len(held)), axis=0)
+
+
+def _counts(slots: jax.Array, n: int) -> jax.Array:
+    """How many of ``slots`` are 0, 1 ... n - 1: ``zeros(n).at[slots].add(1)``
+    without the scatter-add, whose every slot is a collision."""
+    return jnp.sum(slots == lax.iota(jnp.int32, n)[:, None], axis=1, dtype=jnp.int32)
+
+
+@jax.custom_vjp
+def _sorted_by_slot(slots: jax.Array, weights: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``order = argsort(slots, stable=True)`` and ``weights[order]`` from one
+    sort. Its key is slot-major with the position below it, a different key
+    a position: sorted, it IS the stable order by slot, the positions are its
+    remainders, and the sort has two operands where a stable sort of slots,
+    positions and weights has three and a tie-break (``slots.max() *
+    slots.size`` has to fit an int32: the caller's to see). The weights ride
+    it as the payload.
+
+    jax differentiates a sort's payload through a gather, whose cotangent is
+    a scatter-add; here the cotangent rides a second sort, keyed on ``order``:
+    a permutation, so sorting by it puts each cotangent back at its assignment."""
+    n = slots.size
+    key, weights = lax.sort((slots * n + lax.iota(jnp.int32, n), weights), num_keys=1, is_stable=False)
+    return lax.rem(key, n), weights
+
+
+def _sorted_by_slot_fwd(slots, weights):
+    order, weights = _sorted_by_slot(slots, weights)
+    return (order, weights), order
+
+
+def _sorted_by_slot_bwd(order, cotangents):
+    _, d_weights = lax.sort((order, cotangents[1]), num_keys=1)
+    return None, d_weights
+
+
+_sorted_by_slot.defvjp(_sorted_by_slot_fwd, _sorted_by_slot_bwd)
+
+
 def held_experts_moe(
     x: jax.Array,               # (T, D) tokens, compute dtype
     router_in: jax.Array,       # (T, D) what the router scores (fp32 where the caller has it)
@@ -213,7 +275,11 @@ def held_experts_moe(
     experts); each token takes the ``top_k`` largest of ``score +
     select_bias`` and weights them ``scaling * score_i / sum_topk score``.
     Of those T*top_k assignments the ones on a ``held`` expert are sorted by
-    expert and computed as grouped products. The expert's form follows its
+    expert and computed as grouped products. The router indexes nothing by
+    data (``_picked``, ``_slots``, ``_counts``, ``_sorted_by_slot`` above it:
+    compares against static ranges and the payload of the one sort, the
+    values of the gathers and scatter-adds they stand for); the only gather
+    and scatter-add left move (rows, D). The expert's form follows its
     operands: without ``w_gate`` it is ``w_out_e . relu(w_in_e . x)^2``
     (Nemotron-H), with it ``w_out_e . (silu(w_gate_e . x) * (w_in_e . x))``
     (afmoe's gate, up and down projections). The sorted rows stay as they lie,
@@ -256,6 +322,7 @@ def held_experts_moe(
     e = router_kernel.shape[1]
     n_held = len(held)
     assert w_in.shape[0] == w_out.shape[0] == n_held and 1 <= top_k <= e
+    assert (n_held + 1) * t * top_k < 2**31  # _sorted_by_slot's key: a slot above each position
     assert w_gate is None or w_gate.shape == w_in.shape
     assert score in ("sigmoid", "softmax"), score
     f32, i32 = jnp.float32, jnp.int32
@@ -273,16 +340,14 @@ def held_experts_moe(
             )
             scores = jax.nn.sigmoid(logits) if score == "sigmoid" else jax.nn.softmax(logits, axis=-1)  # (T, E)
             _, chosen = lax.top_k(scores + select_bias.astype(f32), top_k)  # (T, K)
-            picked = jnp.take_along_axis(scores, chosen, axis=-1)
+            picked = _picked(scores, chosen)
             weights = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
         with jax.named_scope("moe.sort"):
-            # expert id -> its slot here; every absent expert shares the slot past the last
-            slot_of = np.full((e,), n_held, np.int32)
-            slot_of[list(held)] = np.arange(n_held)
-            slots = jnp.asarray(slot_of)[chosen].reshape(-1)  # (T*K,)
-            order = jnp.argsort(slots, stable=True)  # held experts' assignments first, by expert
+            slots = _slots(chosen.reshape(-1), held)  # (T*K,)
+            # held experts' assignments first, by expert; the weights ride the same sort
+            order, sorted_weights = _sorted_by_slot(slots, weights.reshape(-1))
         with jax.named_scope("moe.count"):
-            counts = jnp.zeros((n_held + 1,), i32).at[slots].add(1)
+            counts = _counts(slots, n_held + 1)
             group_sizes, absent = counts[:n_held], counts[n_held]
             group_ends = jnp.cumsum(group_sizes)
             landed = group_ends[-1]
@@ -296,7 +361,7 @@ def held_experts_moe(
     pad_to = lambda v, fill: jnp.pad(v[:most], (0, n_chunks * rows - most), constant_values=fill)
     with jax.named_scope("moe.layout"):
         sorted_tokens = pad_to(order // top_k, 0)
-        sorted_weights = pad_to(weights.reshape(-1)[order], 0.0)
+        sorted_weights = pad_to(sorted_weights, 0.0)
 
     def sizes_in(first):
         """How many of each held expert's rows lie in [first, first + rows)."""

@@ -39,9 +39,8 @@ CASES = [
 ]
 
 
-@functools.lru_cache(maxsize=None)
-def op_names(gated, score, top_k):
-    """The ``op_name`` of every instruction of the compiled program."""
+def step_like(gated, score, top_k):
+    """Value and gradient of the ``jax.checkpoint``ed layer, and its operands."""
 
     @jax.checkpoint
     def layer(x, router, w_in, w_out, w_gate):
@@ -57,8 +56,24 @@ def op_names(gated, score, top_k):
     keys = jax.random.split(jax.random.PRNGKey(0), 5)
     shapes = [(T, D), (D, E), (len(HELD), D, F), (len(HELD), F, D), (len(HELD), D, F)]
     args = [jax.random.normal(k, s) for k, s in zip(keys, shapes)]
-    program = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(*args).compile()
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3)), args
+
+
+@functools.lru_cache(maxsize=None)
+def op_names(gated, score, top_k):
+    """The ``op_name`` of every instruction of the compiled program."""
+    step, args = step_like(gated, score, top_k)
+    program = jax.jit(step).lower(*args).compile()
     return re.findall(r'op_name="([^"]*)"', hlo_text_of_compiled(program))
+
+
+def equations(jaxpr, outer=""):
+    """(primitive, name stack from the top) of every equation, inner jaxprs too."""
+    for eqn in jaxpr.eqns:
+        path = f"{outer}/{eqn.source_info.name_stack}"
+        yield eqn.primitive.name, path
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from equations(inner, path)
 
 
 def named(path, names):
@@ -108,6 +123,25 @@ def test_the_layout_is_in_neither_and_the_overflow_only_in_the_experts(gated, sc
             assert named(path, LEAVES), path
 
 
+@pytest.mark.parametrize("gated, score, top_k", CASES)
+def test_the_router_indexes_without_a_gather_or_a_scatter(gated, score, top_k):
+    """The router's index work is compares and a sort: the only equations
+    that index by data in the layer's value and gradient move (rows, D) data,
+    under ``moe.gather`` and ``moe.combine`` (forward, recomputation, and
+    each one's cotangent under the other's name)."""
+    step, args = step_like(gated, score, top_k)
+    eqns = list(equations(jax.make_jaxpr(step)(*args).jaxpr))
+    indexed = [(primitive, path) for primitive, path in eqns if "gather" in primitive or "scatter" in primitive]
+    assert {primitive for primitive, _ in indexed} == {"gather", "scatter-add"}
+    for primitive, path in indexed:
+        leaves = named(path, ROUTE + CHUNK + ("moe.layout", "moe.route"))
+        assert leaves in (["moe.gather"], ["moe.combine"]), (primitive, path)
+    # what a gather fetched rides the sort the layer makes anyway: forward, recomputed, and one
+    # more for the cotangent
+    sorts = [path for primitive, path in eqns if primitive == "sort"]
+    assert len(sorts) == 3 and all("moe.sort" in path for path in sorts), sorts
+
+
 def test_no_leaf_name_is_part_of_another():
     names = LEAVES + ("moe.route", "moe.experts", "moe.shared")
     for a in names:
@@ -126,8 +160,12 @@ def test_the_three_passes_are_on_the_paths(gated, score, top_k):
         if path.startswith("jit("):
             by_pass[pass_of(path)].append(path)
     assert all(by_pass.values())
-    last = lambda which: {p.rsplit("/", 1)[-1] for p in by_pass[which]}
-    assert "sort" in last("fwd") and "sort" in last("remat") and "sort" not in last("bwd")
+    last = lambda which, under="": {p.rsplit("/", 1)[-1] for p in by_pass[which] if under in p}
+    # every pass sorts (the sorted weights' cotangent rides a sort of its own, keyed on the
+    # order); only the forward's sort, and its recomputation, reads positions back off its keys
+    assert all("sort" in last(which, "moe.sort") for which in by_pass)
+    assert "rem" in last("fwd", "moe.sort") and "rem" in last("remat", "moe.sort")
+    assert "rem" not in last("bwd", "moe.sort")
     # the loss is outside the checkpoint
     assert "tanh" in last("fwd") and "tanh" not in last("remat") and "tanh" not in last("bwd")
     assert any("moe.combine" in p for p in by_pass["bwd"])
