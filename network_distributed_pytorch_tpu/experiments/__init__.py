@@ -16,6 +16,7 @@ from . import (  # noqa: F401
     powersgd_afmoe,
     powersgd_cifar10,
     powersgd_imdb,
+    powersgd_lfm2,
     powersgd_nemotron,
     powersgd_qwen3_next,
 )

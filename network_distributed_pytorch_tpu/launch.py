@@ -34,6 +34,7 @@ from .experiments import (
     powersgd_afmoe,
     powersgd_cifar10,
     powersgd_imdb,
+    powersgd_lfm2,
     powersgd_nemotron,
     powersgd_qwen3_next,
     serve_gpt,
@@ -51,6 +52,7 @@ EXPERIMENTS = {
     "powersgd_nemotron": powersgd_nemotron.run,
     "powersgd_afmoe": powersgd_afmoe.run,
     "powersgd_qwen3_next": powersgd_qwen3_next.run,
+    "powersgd_lfm2": powersgd_lfm2.run,
     "imdb_baseline": imdb_baseline.run,
     "bandwidth_study": bandwidth_study.run,
     "gpt_lm": gpt_lm.run,
@@ -788,7 +790,9 @@ def main(argv=None) -> dict:
                       spec_k=args.spec_k if args.spec_k is not None else 0)
     elif args.experiment == "bandwidth_study":
         kwargs.update(preset=args.preset)
-    elif args.experiment in ("powersgd_nemotron", "powersgd_afmoe", "powersgd_qwen3_next"):
+    elif args.experiment in (
+        "powersgd_nemotron", "powersgd_afmoe", "powersgd_qwen3_next", "powersgd_lfm2",
+    ):
         kwargs.update(preset=args.preset,
                       max_steps_per_epoch=args.max_steps_per_epoch)
     elif args.experiment in ("gpt_lm", "gpt_pp", "gpt_sp", "gpt_tp", "gpt_moe"):

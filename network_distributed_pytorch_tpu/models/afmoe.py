@@ -139,6 +139,21 @@ def _balancing_bias(tokens32, router, top_k):
     return -jnp.sort(scores, axis=0)[n_tokens - share]
 
 
+def expert_bias_of(layer: nn.Module, tokens32, router, top_k: int):
+    """An expert layer's ``expert_bias``, the (experts,) selection bias in
+    its ``buffers`` collection: zeros (and no variable) where the caller
+    brings none; under ``balanced_expert_bias``'s pass, where the collection
+    is writable, found from this layer's own scores and written back."""
+    expert_bias = jnp.zeros((router.shape[1],), jnp.float32)
+    writable = layer.is_mutable_collection(BUFFERS)  # init, or balanced_expert_bias's pass
+    if writable or layer.has_variable(BUFFERS, "expert_bias"):
+        buffer = layer.variable(BUFFERS, "expert_bias", lambda: expert_bias)
+        if writable and not layer.is_initializing():
+            buffer.value = _balancing_bias(tokens32, router, top_k)
+        expert_bias = buffer.value
+    return expert_bias
+
+
 class AfmoeExperts(nn.Module):
     config: AfmoeConfig
     out_std: float
@@ -156,13 +171,7 @@ class AfmoeExperts(nn.Module):
         u = u32.astype(cfg.dtype)
         bsz, t, _ = u.shape
         tokens32 = u32.reshape(bsz * t, d)
-        expert_bias = jnp.zeros((cfg.n_routed_experts,), jnp.float32)
-        writable = self.is_mutable_collection(BUFFERS)  # init, or balanced_expert_bias's pass
-        if writable or self.has_variable(BUFFERS, "expert_bias"):
-            buffer = self.variable(BUFFERS, "expert_bias", lambda: expert_bias)
-            if writable and not self.is_initializing():  # found from this layer's own scores
-                buffer.value = _balancing_bias(tokens32, router, cfg.experts_per_token)
-            expert_bias = buffer.value
+        expert_bias = expert_bias_of(self, tokens32, router, cfg.experts_per_token)
         routed, counters = held_experts_moe(
             u.reshape(bsz * t, d), tokens32, router, expert_bias,
             up, down, held, cfg.experts_per_token, cfg.route_scale, w_gate=gate,
@@ -258,12 +267,14 @@ class AfmoeLM(nn.Module):
         return logits, counters
 
 
-def balanced_expert_bias(model: AfmoeLM, params, input_ids) -> Dict:
+def balanced_expert_bias(model, params, input_ids) -> Dict:
     """The ``buffers`` collection that balances ``params``' routing on
     ``input_ids`` (B, T): one forward pass in which every expert layer takes
     its ``expert_bias`` from its own scores (``_balancing_bias``) and routes
-    by it, so the layers behind it see what they will see in training."""
-    model = AfmoeLM(dataclasses.replace(model.config, remat=False))  # nothing to recompute
+    by it, so the layers behind it see what they will see in training.
+    ``model`` is this module's or any whose expert layers keep the buffer so
+    (``models/lfm2.py``)."""
+    model = type(model)(dataclasses.replace(model.config, remat=False))  # nothing to recompute
     # the buffers alone leave the program: the head and its logits are never computed
     return jax.jit(lambda p, ids: model.apply({"params": p}, ids, mutable=[BUFFERS])[1][BUFFERS])(
         params, input_ids

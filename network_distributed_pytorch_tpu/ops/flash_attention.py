@@ -27,7 +27,10 @@ summed over each group outside. Shapes no lane block serves (D = 96; three
 heads of 64; grouped heads narrower than 128) take the one fallback: heads
 folded into the batch, ``(B·H, T, D)``, one head the whole last axis, the
 same kernels, a shared K/V row and the mask found by integer division of the
-grid row.
+grid row. One benchmark cell runs the fallback: ``lfm2_psgd16_t8k``'s attention
+layer, (H, Hkv, D) = (32, 8, 64) at T = 8192, where ``heads_per_block`` is None
+(grouped heads of 64 lanes), q, k, v and the output are transposed round the
+kernels in each pass and a head fills half the lanes.
 
 The forward (``_flash_kernel``) feeds the MXU as the backward does. Its two
 products, S = K·Qᵀ and Oᵀ = Vᵀ·P, take their operands in the input dtype and

@@ -27,9 +27,12 @@ from jax import lax
 
 def causal_conv1d(x: jax.Array, kernel: jax.Array, bias: Optional[jax.Array]) -> jax.Array:
     """Depthwise causal convolution over time: ``y_t = sum_j kernel[j] *
-    x_{t-K+1+j} + bias``; ``x`` (B, T, C), ``kernel`` (K, C), ``bias`` (C,)
-    or None for a convolution without one (a Gated-DeltaNet mixer's).
-    K shifted multiply-adds (K is 4): no convolution op, no (T, K) window."""
+    x_{t-K+1+j} + bias``, zeros before the sequence; ``x`` (B, T, C),
+    ``kernel`` (K, C) for any K, ``bias`` (C,) or None for a convolution
+    without one. Three mixers call it: Mamba-2's (K 4, a bias) and Gated
+    DeltaNet's (K 4, none) wrap it in a silu; LFM2's short convolution (K 3,
+    none) takes it as it is, between its two gates. K shifted multiply-adds
+    in ``x``'s dtype: no convolution op, no (T, K) window."""
     k, t = kernel.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     out = None if bias is None else bias.astype(x.dtype)

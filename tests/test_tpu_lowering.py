@@ -35,8 +35,10 @@ from network_distributed_pytorch_tpu.ops.pallas_orthogonalize import (
 # 32 key/value heads and with the model's 2, read in place),
 # trinity_psgd16_t8k's sliding layers (32 heads over 4, a window of 2048:
 # both loop bounds), qwen3next_psgd16_t8k's full layer (16 heads of 256 over
-# 2: two lane tiles a head, the VMEM of T = 16,384 at 128), and a head width
-# no lane block serves (the fold)
+# 2: two lane tiles a head, the VMEM of T = 16,384 at 128), lfm2_psgd16_t8k's
+# attention layer (32 heads of 64 over 8: grouped heads narrower than a lane
+# block, so the fold at T = 8192, one 64-lane head's K and V whole in VMEM),
+# and a head width no lane block serves (the fold)
 FLASH_CASES = [
     pytest.param((16, 256, 12, 64), jnp.bfloat16, False, True, id="distilbert-bf16"),
     pytest.param((16, 256, 12, 64), jnp.float32, False, True, id="distilbert-fp32"),
@@ -48,6 +50,7 @@ FLASH_CASES = [
     pytest.param((1, 8192, 32, 128, 2), jnp.bfloat16, True, False, id="nemotron-8192-gqa"),
     pytest.param((1, 8192, 32, 128, 4, 2048), jnp.bfloat16, True, False, id="trinity-8192-window-2048"),
     pytest.param((1, 8192, 16, 256, 2), jnp.bfloat16, True, False, id="qwen3next-8192-head-256-gqa"),
+    pytest.param((1, 8192, 32, 64, 8), jnp.bfloat16, True, False, id="lfm2-8192-head-64-gqa-fold"),
     pytest.param((4, 512, 3, 64), jnp.bfloat16, False, True, id="fold-3x64"),
 ]
 # P-factor shapes: DistilBERT-base at rank 16 (chip_smoke), GPT-2 at rank 4
@@ -58,8 +61,12 @@ ORTHOGONALIZE_SHAPES = [
 # (k, n) of the routed experts' products over a chunk of 8192 sorted rows and
 # 8 held experts, bf16: nemotron_psgd16_t8k's two (1856 = 14.5 x 128 is one
 # tile, as the contraction and as the output) and trinity_psgd16_t8k's; with
-# a third number, that many held experts: qwen3next_psgd16_t8k's 16 of width 512
-GROUPED_MATMUL_SHAPES = [(2688, 1856), (1856, 2688), (2048, 1024), (1024, 2048), (2048, 512, 16), (512, 2048, 16)]
+# a third number, that many held experts: qwen3next_psgd16_t8k's 16 of width 512;
+# lfm2_psgd16_t8k's 8 of width 1536 (three 512-tiles)
+GROUPED_MATMUL_SHAPES = [
+    (2688, 1856), (1856, 2688), (2048, 1024), (1024, 2048), (2048, 512, 16), (512, 2048, 16),
+    (2048, 1536), (1536, 2048),
+]
 
 
 def _flash_fns(shape, dtype, causal, masked):
