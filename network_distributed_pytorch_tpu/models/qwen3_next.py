@@ -43,6 +43,7 @@ rotary turn ``models/afmoe.py``'s: ``__call__`` returns ``(logits, counters)``.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Tuple
 
@@ -117,7 +118,7 @@ class GatedDeltaNet(nn.Module):
     @nn.compact
     def __call__(self, u32):
         from ..ops.gated_delta import gated_delta_rule
-        from ..ops.ssd import causal_conv1d
+        from ..ops.gated_delta_frame import framed_rule
 
         cfg, f32 = self.config, jnp.float32
         hk, hv, dk, dv = cfg.linear_key_heads, cfg.linear_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
@@ -127,7 +128,6 @@ class GatedDeltaNet(nn.Module):
         # HuggingFace's grouped column order: a key head's q, k, then its r value heads' v and z
         qkvz = _dense(cfg, 2 * key_dim + 2 * value_dim, cfg.init_std, "in_proj_qkvz")(u)
         ba = _dense(cfg, 2 * hv, cfg.init_std, "in_proj_ba")(u)
-        q, k, v, z = jnp.split(qkvz.reshape(bsz, t, hk, -1), [dk, 2 * dk, 2 * dk + r * dv], axis=-1)
         b, a = jnp.split(ba.reshape(bsz, t, hk, 2 * r), 2, axis=-1)
         flat = lambda x: x.reshape(bsz, t, -1)
 
@@ -143,22 +143,13 @@ class GatedDeltaNet(nn.Module):
         )
         norm_scale = self.param("norm_scale", nn.initializers.ones, (dv,))
 
-        with jax.named_scope("gdn.conv"):
-            qkv = jax.nn.silu(causal_conv1d(jnp.concatenate([flat(q), flat(k), flat(v)], axis=-1), conv_kernel, None))
-        q, k, v = jnp.split(qkv, [key_dim, 2 * key_dim], axis=-1)
         with jax.named_scope("gdn.frame"):
             beta = jax.nn.sigmoid(flat(b).astype(f32))
             g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(flat(a).astype(f32) + dt_bias)
-            l2norm = lambda x: x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
-            q = (l2norm(q.reshape(bsz, t, hk, dk).astype(f32)) * dk ** -0.5).astype(cfg.dtype)
-            k = l2norm(k.reshape(bsz, t, hk, dk).astype(f32)).astype(cfg.dtype)
-        with jax.named_scope("gdn.rule"):
-            o = gated_delta_rule(q, k, v.reshape(bsz, t, hv, dv), g, beta, cfg.chunk_size)
-        with jax.named_scope("gdn.frame"):  # Qwen3NextRMSNormGated: the norm first, then the gate
-            o = o.astype(f32)
-            o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps) * norm_scale
-            o = (o * jax.nn.silu(z.reshape(bsz, t, hv, dv).astype(f32))).astype(cfg.dtype)
-        return _dense(cfg, cfg.hidden_size, self.out_std, "out_proj")(o.reshape(bsz, t, value_dim))
+        # conv, silu and the l2 norms, the rule, the gated norm: on TPU a Pallas pass each side of the rule
+        rule = functools.partial(gated_delta_rule, chunk=cfg.chunk_size)
+        o = framed_rule(rule, qkvz, conv_kernel, norm_scale, g, beta, cfg.norm_eps, hk, r, dk, dv)
+        return _dense(cfg, cfg.hidden_size, self.out_std, "out_proj")(o)
 
 
 class GatedAttention(nn.Module):

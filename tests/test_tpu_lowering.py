@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from network_distributed_pytorch_tpu.ops import gated_delta
+from network_distributed_pytorch_tpu.ops import gated_delta, gated_delta_frame
 from network_distributed_pytorch_tpu.ops.flash_attention import flash_attention
 from network_distributed_pytorch_tpu.ops.grouped_matmul import grouped_matmul
 from network_distributed_pytorch_tpu.ops.pallas_orthogonalize import (
@@ -166,6 +166,45 @@ def test_gated_delta_chunk_local_lowers_for_tpu_inside_shard_map():
     assert text.count("tpu_custom_call") == 2
 
 
+def _frame_fns(bsz=1, t=8192, hk=16, r=2, d=128, dtype=jnp.bfloat16):
+    """What frames the rule at qwen3next_psgd16_t8k's shapes: ``qkvz`` (1,
+    8192, 12288) bf16 in the grouped column order, the conv's four taps, g and
+    beta; both passes as kernels whatever traces them, round a stand-in for
+    the rule that reads every operand, and the gradient of every input."""
+    qkvz = jax.ShapeDtypeStruct((bsz, t, hk * (2 + 2 * r) * d), dtype)
+    conv_kernel = jax.ShapeDtypeStruct((4, hk * (2 + r) * d), jnp.float32)
+    norm_scale = jax.ShapeDtypeStruct((d,), jnp.float32)
+    per_step = jax.ShapeDtypeStruct((bsz, t, hk * r), jnp.float32)
+
+    def rule(q, k, v, g, beta):
+        by_key = v.reshape(v.shape[:2] + (hk, r, d)) * (q * k)[:, :, :, None]
+        return by_key.reshape(v.shape) * (beta * jnp.exp(g))[..., None].astype(v.dtype)
+
+    def forward(qkvz, conv_kernel, norm_scale, g, beta):
+        return gated_delta_frame.framed_rule(rule, qkvz, conv_kernel, norm_scale, g, beta, 1e-6, hk, r, d, d, interpret=False)
+
+    loss = lambda *a: jnp.sum(jnp.sin(forward(*a).astype(jnp.float32)))
+    return [qkvz, conv_kernel, norm_scale, per_step, per_step], {"forward": forward, "grad": jax.grad(loss, argnums=range(5))}
+
+
+def test_gated_delta_frame_lowers_for_tpu_inside_shard_map():
+    """The four kernels' outputs declare how they vary over the mesh, the
+    parameters cast to varying as the trainer casts them."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    args, fns = _frame_fns(bsz=2)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+
+    def worker(qkvz, conv_kernel, norm_scale, g, beta):
+        conv_kernel, norm_scale = (jax.lax.pcast(p, "data", to="varying") for p in (conv_kernel, norm_scale))
+        d_qkvz, d_conv, d_scale, d_g, d_beta = fns["grad"](qkvz, conv_kernel, norm_scale, g, beta)
+        return d_qkvz, d_conv[None], d_scale[None], d_g, d_beta
+
+    sharded = jax.shard_map(worker, mesh=mesh, in_specs=(P("data"), P(), P(), P("data"), P("data")), out_specs=P("data"))
+    text = jax.jit(sharded).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 4
+
+
 # --- libtpu's Mosaic compiler, without a chip -------------------------------
 
 
@@ -282,6 +321,65 @@ def test_gated_delta_chunk_local_compiles_with_mosaic_under_its_two_names(v5e_de
     kernels = re.findall(r"%([a-z_]+)[\w.]* = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
     assert sorted(kernels) == ["gated_delta_chunk_local", "gated_delta_chunk_local_bwd"], kernels
     assert not re.search(r"f32\[1,128,16,2,64,64\]", hlo)  # A, its powers, the decay, Q K^T: none is an array
+
+
+def _custom_calls(hlo):
+    import re
+
+    return sorted(re.findall(r"%([a-z_]+)[\w.]* = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo))
+
+
+FRAME_NAMES = ["gdn_frame_in", "gdn_frame_in_bwd", "gdn_frame_out", "gdn_frame_out_bwd"]
+
+
+# the cell's own shape; a T that ends inside a tile and inside a HALO block; fp32 at a T under one tile
+@pytest.mark.parametrize(
+    "t,dtype", [(8192, jnp.bfloat16), (8200, jnp.bfloat16), (1000, jnp.float32)], ids=["cell-8192-bf16", "ragged-8200-bf16", "1000-fp32"]
+)
+def test_gated_delta_frame_compiles_with_mosaic_under_its_four_names(v5e_devices, t, dtype):
+    """What Mosaic would refuse on the chip (the grouped lanes through the
+    index maps, the sublane rotations, the HALO blocks, the resident sums, a
+    step's VMEM) it refuses here; each ``pallas_call`` is in the compiled
+    program under its own name."""
+    args, fns = _frame_fns(t=t, dtype=dtype)
+    args = [_on(v5e_devices[0], a) for a in args]
+    assert _custom_calls(jax.jit(fns["forward"]).lower(*args).compile().as_text()) == FRAME_NAMES[::2]
+    assert _custom_calls(jax.jit(fns["grad"]).lower(*args).compile().as_text()) == FRAME_NAMES
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["kept", "recomputed"])
+def test_the_mixer_on_a_chip_takes_the_frames_kernels_once_a_pass(v5e_devices, monkeypatch, remat):
+    """The engagement check: one Gated-DeltaNet layer at the cell's shapes,
+    its value and gradient compiled for v5e with the backend's own choice, as
+    on the chip. The four calls are in the program once a layer-pass each
+    (forward, its recomputation where the block is rematerialised, backward),
+    and no copy that converts an array the size of q or larger is left under
+    ``gdn.frame`` (beta's and g's (T, 32) fp32 relayouts for the rule stay)."""
+    import re
+
+    from network_distributed_pytorch_tpu.models.qwen3_next import GatedDeltaNet, Qwen3NextConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = Qwen3NextConfig(dtype=jnp.bfloat16)
+    layer = GatedDeltaNet(cfg, 0.02)
+    u = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), jnp.float32)
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 64, cfg.hidden_size))))["params"]
+    apply = lambda p, u: layer.apply({"params": p}, u)
+    apply = jax.checkpoint(apply) if remat else apply
+    loss = lambda p, u: jnp.sum(jnp.sin(apply(p, u).astype(jnp.float32)))
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda x: _on(v5e_devices[0], x), tree)
+    hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(on_chip(params), on_chip(u)).compile().as_text()
+    forwards = 2 if remat else 1
+    assert _custom_calls(hlo) == sorted(
+        ["gated_delta_chunk_local"] * forwards + ["gated_delta_chunk_local_bwd"]
+        + FRAME_NAMES[::2] * forwards + FRAME_NAMES[1::2]
+    )
+    q_size = 8192 * cfg.linear_key_heads * cfg.linear_key_head_dim
+    for line in hlo.splitlines():
+        if " copy(" in line and "gdn.frame" in line and "convert_element_type" in line:
+            dims = re.search(r"= \w+\[([\d,]+)\]", line).group(1)
+            assert np.prod([int(n) for n in dims.split(",")]) < q_size, line
+    assert "gdn.conv" not in hlo  # the conv sits inside the ``in`` pass
 
 
 @pytest.mark.parametrize("shape", ORTHOGONALIZE_SHAPES, ids=str)
