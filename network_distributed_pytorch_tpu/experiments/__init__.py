@@ -17,6 +17,7 @@ from . import (  # noqa: F401
     powersgd_cifar10,
     powersgd_imdb,
     powersgd_lfm2,
+    powersgd_mellum,
     powersgd_nemotron,
     powersgd_qwen3_next,
 )

@@ -35,6 +35,7 @@ from .experiments import (
     powersgd_cifar10,
     powersgd_imdb,
     powersgd_lfm2,
+    powersgd_mellum,
     powersgd_nemotron,
     powersgd_qwen3_next,
     serve_gpt,
@@ -53,6 +54,7 @@ EXPERIMENTS = {
     "powersgd_afmoe": powersgd_afmoe.run,
     "powersgd_qwen3_next": powersgd_qwen3_next.run,
     "powersgd_lfm2": powersgd_lfm2.run,
+    "powersgd_mellum": powersgd_mellum.run,
     "imdb_baseline": imdb_baseline.run,
     "bandwidth_study": bandwidth_study.run,
     "gpt_lm": gpt_lm.run,
@@ -791,7 +793,7 @@ def main(argv=None) -> dict:
     elif args.experiment == "bandwidth_study":
         kwargs.update(preset=args.preset)
     elif args.experiment in (
-        "powersgd_nemotron", "powersgd_afmoe", "powersgd_qwen3_next", "powersgd_lfm2",
+        "powersgd_nemotron", "powersgd_afmoe", "powersgd_qwen3_next", "powersgd_lfm2", "powersgd_mellum",
     ):
         kwargs.update(preset=args.preset,
                       max_steps_per_epoch=args.max_steps_per_epoch)

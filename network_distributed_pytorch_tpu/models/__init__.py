@@ -35,6 +35,7 @@ from .gpt import (  # noqa: F401
 )
 from .afmoe import AfmoeConfig, AfmoeLM, afmoe_tiny  # noqa: F401
 from .lfm2 import Lfm2Config, Lfm2LM, lfm2_tiny  # noqa: F401
+from .mellum import MellumConfig, MellumLM, mellum_tiny  # noqa: F401
 from .qwen3_next import Qwen3NextConfig, Qwen3NextLM, qwen3_next_tiny  # noqa: F401
 from .nemotron_h import (  # noqa: F401
     NemotronHConfig,

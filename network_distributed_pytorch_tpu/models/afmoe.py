@@ -43,8 +43,9 @@ as that model does, so ``next_token_lm_loss`` and ``zero_counters`` serve both.
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Mapping, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -96,18 +97,73 @@ class AfmoeConfig:
         return tuple(range(self.num_dense_layers, len(self.layer_types)))
 
 
-def rotary(x: jax.Array, theta: float, rotary_dim: Optional[int] = None) -> jax.Array:
+class Rope(NamedTuple):
+    """One rotary embedding's numbers, hashable, so a config field: a theta
+    alone is ``rope_type: default``; with ``factor`` it is YaRN's."""
+
+    theta: float
+    factor: Optional[float] = None  # YaRN's scaling factor; None: the default embedding
+    original_positions: int = 0  # original_max_position_embeddings
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None  # None: 0.1 ln(factor) + 1
+
+    @classmethod
+    def of(cls, parameters: Mapping) -> "Rope":
+        """From HuggingFace's ``rope_parameters`` of one layer kind."""
+        kind = parameters.get("rope_type", "default")
+        if kind == "default":
+            return cls(float(parameters["rope_theta"]))
+        if kind != "yarn":
+            raise ValueError(f"rope_type {kind!r}: default or yarn")
+        return cls(
+            float(parameters["rope_theta"]), float(parameters["factor"]),
+            parameters["original_max_position_embeddings"], float(parameters.get("beta_fast", 32)),
+            float(parameters.get("beta_slow", 1)), parameters.get("attention_factor"),
+        )
+
+
+def rope_frequencies(rope: Rope, dim: int) -> Tuple[jax.Array, float]:
+    """``(inv_freq (dim/2,) in fp32, factor)`` of one rotary embedding over
+    ``dim`` dims: position t turns pair i by ``t * inv_freq[i]``, and cos and
+    sin are both multiplied by ``factor``.
+
+    - the default embedding: ``theta^(-2i/dim)``, factor 1.
+    - YaRN, as HuggingFace's ``_compute_yarn_parameters``: the default
+      frequencies (``extrap``) blended with the same divided by ``factor``
+      (``interp``), ``interp * ramp + extrap * (1 - ramp)`` with ``ramp_i =
+      clip((i - low) / (high - low), 0, 1)`` and ``low`` / ``high`` the pairs
+      that make ``beta_fast`` / ``beta_slow`` turns over the original
+      positions, ``c(n) = dim * ln(L / (2 pi n)) / (2 ln theta)``, floored
+      and ceiled (``truncate``, the default); the factor is
+      ``attention_factor``, ``0.1 ln(factor) + 1`` where none is given."""
+    inv_freq = rope.theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if rope.factor is None:
+        return inv_freq, 1.0
+    turns_at = lambda n: dim * math.log(rope.original_positions / (2 * math.pi * n)) / (2 * math.log(rope.theta))
+    low = max(math.floor(turns_at(rope.beta_fast)), 0)
+    high = min(math.ceil(turns_at(rope.beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    factor = rope.attention_factor or 0.1 * math.log(rope.factor) + 1.0
+    return inv_freq / rope.factor * ramp + inv_freq * (1.0 - ramp), float(factor)
+
+
+def rotary(x: jax.Array, rope: Rope, rotary_dim: Optional[int] = None) -> jax.Array:
     """``x`` (B, T, H, D) in fp32, position t turned by the angles ``t *
-    theta^(-2i/D)``: ``x cos + rotate_half(x) sin``, the halves paired as
-    HuggingFace pairs them (i with i + D/2). With ``rotary_dim`` only the
+    inv_freq`` of ``rope_frequencies(rope, D)`` (the default embedding: ``t
+    * theta^(-2i/D)``): ``x cos + rotate_half(x) sin``, the halves paired as
+    HuggingFace pairs them (i with i + D/2), cos and sin times the
+    embedding's factor where it has one (YaRN). With ``rotary_dim`` only the
     head's first ``rotary_dim`` dims turn, as a head of that size would
     (``partial_rotary_factor``); the others pass as they came."""
     if rotary_dim is not None and rotary_dim < x.shape[-1]:
-        return jnp.concatenate([rotary(x[..., :rotary_dim], theta), x[..., rotary_dim:]], axis=-1)
+        return jnp.concatenate([rotary(x[..., :rotary_dim], rope), x[..., rotary_dim:]], axis=-1)
     t, d = x.shape[1], x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    inv_freq, factor = rope_frequencies(rope, d)
     angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]  # (T, D/2)
     cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
@@ -203,7 +259,7 @@ class AfmoeAttention(nn.Module):
             q = RMSNorm(cfg.norm_eps, name="q_norm")(q)
             k = RMSNorm(cfg.norm_eps, name="k_norm")(k)
             if self.sliding:  # the full layers carry no positions
-                q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
+                q, k = rotary(q, Rope(cfg.rope_theta)), rotary(k, Rope(cfg.rope_theta))
             q, k = q.astype(cfg.dtype), k.astype(cfg.dtype)
         with jax.named_scope("attn.window" if self.sliding else "attn.full"):
             if resolve_attn_impl(cfg.attn_impl) == "flash":

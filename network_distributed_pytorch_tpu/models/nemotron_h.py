@@ -286,7 +286,7 @@ class NemotronHLM(nn.Module):
 def zero_counters(config) -> Dict[str, Dict[str, jax.Array]]:
     """The counters' tree before the first step: what ``init_state`` takes.
     ``config`` names its ``expert_layers`` and ``held_experts`` (this model's
-    or ``models/afmoe.py``'s)."""
+    or any that calls ``held_experts_moe``, whose counters these are)."""
     zero = lambda *shape: jnp.zeros(shape, jnp.int32)
     return {
         f"layer_{i}": {

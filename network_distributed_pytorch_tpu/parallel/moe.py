@@ -17,8 +17,11 @@ Which layer is which:
   tokens x experts x capacity, no padding between experts; what absent
   experts would add is left out (another rank's part). The experts are relu² (two stacked
   leaves) or gated silu (three), told apart by the operands. Used by
-  ``models/nemotron_h`` (ungated, top 6), ``models/afmoe`` (gated, top 8) and
-  ``models/qwen3_next`` (gated, softmax scores, top 10 of 512).
+  ``models/nemotron_h`` (ungated, top 6 of 128), ``models/afmoe`` (gated, top
+  8 of 128), ``models/qwen3_next`` (gated, softmax scores, top 10 of 512),
+  ``models/lfm2`` (gated, top 4 of 64) and ``models/mellum`` (gated, softmax
+  scores, top 8 of 64: one assignment in eight a held expert's, the heaviest
+  load a rank's share sees, which is what sizes the layer's first chunk).
 
 ``switch_moe``, in detail (beyond-parity capability, SURVEY §2.3: EP/MoE
 absent from the reference). TPU-native design:
@@ -251,6 +254,31 @@ def _sorted_by_slot_bwd(order, cotangents):
 _sorted_by_slot.defvjp(_sorted_by_slot_fwd, _sorted_by_slot_bwd)
 
 
+def chunk_rows(t: int, top_k: int, n_held: int, e: int, block_rows: int = 512) -> int:
+    """Rows of one chunk of ``held_experts_moe``'s sorted assignments: the
+    larger of ``t`` and 3/2 of the load an even router lands here,
+    ``t*top_k*n_held/e``, in whole row tiles, never more than every
+    assignment there can be. A rule over the layer's own shapes: a rank that
+    holds a small share (0.31-0.5 t expected in the four models before
+    mellum) keeps ``t`` rows, one that holds a large one (mellum's 16 of 64
+    at top 8: 2 t) gets a first chunk its load fits, so that the later
+    chunks stay the rare heavy load and not every step's path (an entered
+    ``moe.overflow`` costs ~24 ms a layer, 17 of them bookkeeping over (T, D)
+    and the weights' shapes: PERF.md section 6, PR 39). Why 3/2: Zipf token
+    ids route by id, and the load was seen at 1.5x the expected in one
+    trinity run of nine (a post-norm block under a balanced bias); where
+    tokens route by their own ids it stays closer (lfm2 0.93-1.11x, PR 41;
+    mellum 0.86-1.15x, 1.71-2.30 T where 2 T is expected, over 3 seeds x 3
+    sequences x 4 layers on the chip at the published widths, and 72.7% of
+    the chunk in a traced run's worst layer, PR 44). The rule answers an
+    expected load, not a collapsed router: where every token picks the same
+    experts (mellum with its embedding at 0.02: 0.14-2.97 T by seed) only
+    the later chunks do."""
+    most = t * min(top_k, n_held)
+    expected = -(-3 * t * top_k * n_held // (2 * e))
+    return -(-min(max(t, expected), most) // block_rows) * block_rows
+
+
 def held_experts_moe(
     x: jax.Array,               # (T, D) tokens, compute dtype
     router_in: jax.Array,       # (T, D) what the router scores (fp32 where the caller has it)
@@ -297,12 +325,15 @@ def held_experts_moe(
     visit 13 to 15 tiles of 512 and copy nothing, PR 32.)
 
     The work follows the assignments that landed here. They are taken in
-    chunks of T rows (the expected load is T*top_k*len(held)/E): the first
-    chunk runs outside any loop and nearly always holds them all; a heavier
-    load goes on chunk after chunk (``lax.cond`` into a ``lax.scan``, a chunk
-    past the last assignment skipped) up to T*min(top_k, len(held)) rows —
-    every assignment there can be — so none is ever dropped, and no array
-    has a (tokens, experts, capacity) shape.
+    chunks whose size follows the expected load, T*top_k*len(held)/E, read
+    from the shapes: ``chunk_rows`` gives a chunk the larger of T rows and
+    1.5 times that load (0.31-0.5 T expected in nemotron_h, afmoe, qwen3_next
+    and lfm2 as the benchmark cuts them, so T rows; 2 T in mellum's four-chip
+    share, so 3 T). The first chunk runs outside any loop and is sized to
+    hold them all; a heavier load goes on chunk after chunk (``lax.cond``
+    into a ``lax.scan``, a chunk past the last assignment skipped) up to
+    T*min(top_k, len(held)) rows — every assignment there can be — so none
+    is ever dropped, and no array has a (tokens, experts, capacity) shape.
 
     Returns ``(out, counters)``: ``out`` (T, D) in ``x``'s dtype, and
     int32 counters of this call — ``held`` (len(held),) assignments per held
@@ -310,10 +341,11 @@ def held_experts_moe(
     assignments on held experts that were not computed (always 0; counted
     from the rows the chunks covered, not assumed), ``row_tiles`` the row
     tiles one product of the first chunk visits (the row extent of its
-    kernel's grid: work that follows the load).
+    kernel's grid: work that follows the load). How many chunks held live
+    rows is ``max(ceil(sum(held) / chunk_rows(...)), 1)``: a reader has both.
 
     ``axis_name`` is where the exchange between ranks would ride. Only the
-    one-rank layer (``None``) exists, and both models that use it run it so:
+    one-rank layer (``None``) exists, and every model that uses it runs it so:
     one rank of a deployment without its exchange; any other value raises.
     """
     if axis_name is not None:
@@ -352,11 +384,10 @@ def held_experts_moe(
             group_ends = jnp.cumsum(group_sizes)
             landed = group_ends[-1]
 
-    # chunks of the sorted assignments: T rows each, in whole row tiles. The expected load is
-    # T*top_k*len(held)/E, well under T for a rank that holds a share of the experts, but
-    # skewed traffic (Zipf token ids route by id) was seen at 1.5x it in one run of nine
+    # chunks of the sorted assignments, in whole row tiles: the first is sized by the expected
+    # load (chunk_rows), the later ones take whatever a heavier load leaves
     most = t * min(top_k, n_held)
-    rows = -(-min(t, most) // block_rows) * block_rows
+    rows = chunk_rows(t, top_k, n_held, e, block_rows)
     n_chunks = -(-most // rows)
     pad_to = lambda v, fill: jnp.pad(v[:most], (0, n_chunks * rows - most), constant_values=fill)
     with jax.named_scope("moe.layout"):

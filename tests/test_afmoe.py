@@ -11,8 +11,9 @@ import numpy as np
 import pytest
 
 from benchmark.reference import afmoe as reference
-from network_distributed_pytorch_tpu.models.afmoe import AfmoeAttention, AfmoeConfig, rotary
-from network_distributed_pytorch_tpu.parallel.moe import held_experts_moe
+from network_distributed_pytorch_tpu.models.afmoe import AfmoeAttention, AfmoeConfig, Rope, rotary
+from network_distributed_pytorch_tpu.parallel import moe
+from network_distributed_pytorch_tpu.parallel.moe import chunk_rows, held_experts_moe
 
 
 def worst_relative(got, want) -> float:
@@ -83,7 +84,7 @@ def test_rotary_positions_are_in_the_sliding_layers_and_not_in_the_full_ones():
 
 def test_rotary_turns_pairs_by_fp32_angles_and_keeps_their_length():
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 40, 2, 8))
-    turned = rotary(x, 10000.0)
+    turned = rotary(x, Rope(10000.0))
     assert turned.dtype == jnp.float32
     np.testing.assert_allclose(turned[:, 0], x[:, 0], rtol=1e-6)  # position 0: no turn
     pair = lambda v, i: v[..., i] ** 2 + v[..., i + 4] ** 2  # i pairs with i + D/2
@@ -91,7 +92,7 @@ def test_rotary_turns_pairs_by_fp32_angles_and_keeps_their_length():
         np.testing.assert_allclose(pair(turned, i), pair(x, i), rtol=1e-5)
     # the dot product of a turned q and k depends on their distance alone
     q, k = x[:, :, :1], x[:, :, 1:]
-    same = lambda at: (rotary(jnp.roll(q, at, 1), 1e4)[:, 5 + at] * rotary(jnp.roll(k, at, 1), 1e4)[:, 2 + at]).sum()
+    same = lambda at: (rotary(jnp.roll(q, at, 1), Rope(1e4))[:, 5 + at] * rotary(jnp.roll(k, at, 1), Rope(1e4))[:, 2 + at]).sum()
     np.testing.assert_allclose(same(0), same(11), rtol=1e-4)
 
 
@@ -129,7 +130,8 @@ def plain_gated_experts(x, router, gate, up, down, held):
 
 
 def routed(x, router, gate, up, down, held):
-    # row tiles of 8: a chunk is T = 48 rows in six of them, so a skewed load (3T) takes three chunks
+    # row tiles of 8; five held of 16 at top 3 expect 45 of T = 48 rows, so parallel.moe.chunk_rows gives a
+    # chunk 3/2 of that, 72 rows in nine tiles, and a skewed load (3T) takes two chunks
     return held_experts_moe(x, x, router, jnp.zeros((E,)), up, down, held, K, 2.826, block_rows=8, w_gate=gate)
 
 
@@ -152,10 +154,12 @@ def test_gated_expert_layer_matches_a_plain_loop_and_drops_nothing(skew):
     np.testing.assert_allclose(got, plain_gated_experts(x, router, *mine, held), rtol=2e-4, atol=2e-5)
     assert int(counters["dropped"]) == 0
     assert int(counters["held"].sum() + counters["absent"]) == T * K
-    assert int(counters["row_tiles"]) == row_tiles_of(counters["held"])
-    if skew:  # 3T = 144 assignments landed against chunks of 48 rows: all three chunks ran
+    assert chunk_rows(T, K, len(held), E, 8) == 72
+    assert int(counters["row_tiles"]) == row_tiles_of(counters["held"], rows=72)
+    assert -(-int(counters["held"].sum()) // 72) == (2 if skew else 1)  # the chunks that held live rows
+    if skew:  # 3T = 144 assignments landed against chunks of 72 rows: both chunks ran
         assert int(counters["held"][:3].sum()) == 3 * T
-        assert int(counters["row_tiles"]) == 6  # the first chunk is full, and all of it the first expert's
+        assert int(counters["row_tiles"]) == 9  # the first chunk is full: the first expert's 48 rows and 24 of the second's
     grads = jax.grad(lambda *a: jnp.sum(jnp.sin(routed(*a, held)[0])), argnums=range(5))(x, router, *mine)
     plain = jax.grad(lambda *a: jnp.sum(jnp.sin(plain_gated_experts(*a, held))), argnums=range(5))(x, router, *mine)
     assert worst_relative(grads, plain) < 1e-4
@@ -202,8 +206,8 @@ def test_a_router_score_rounded_to_bfloat16_picks_other_experts():
     assert np.any(full != low, axis=-1).mean() > 0.05
 
 
-# seed, held experts, assignments that land (a chunk is T = 48 rows in 6 row tiles of 8): none past the first chunk;
-# 5, 12, 15 and 36 past it, in the scan's second chunk; 53, in its second and third
+# seed, held experts, assignments that land, the chunk HELD TO T = 48 rows in 6 row tiles of 8 (below): none past
+# the first chunk; 5, 12, 15 and 36 past it, in the scan's second chunk; 53, in its second and third
 SECOND_CHUNK_CASES = [
     (1, (0, 1, 2, 7, 9), 39), (1, (0, 1, 2, 7, 9, 11), 53), (2, (0, 1, 2, 3, 7, 9, 11), 60),
     (1, (0, 1, 2, 3, 7, 9, 11), 63), (0, (0, 1, 2, 3, 4, 7, 9, 11), 84), (0, tuple(range(10)), 101),
@@ -211,9 +215,14 @@ SECOND_CHUNK_CASES = [
 
 
 @pytest.mark.parametrize("seed,held,landed", SECOND_CHUNK_CASES, ids=lambda v: str(v) if isinstance(v, int) else f"{len(v)}held")
-def test_a_load_past_the_first_chunk_goes_on_in_the_scan(seed, held, landed):
+def test_a_load_past_the_first_chunk_goes_on_in_the_scan(seed, held, landed, monkeypatch):
     """Whatever lands past the first chunk, a few rows or two chunks more, is
-    the scan's: output and gradients as the plain loop, nothing dropped."""
+    the scan's: output and gradients as the plain loop, nothing dropped. The
+    chunk is held to T rows here, as a rank with a small share has it: at
+    these toy shares (5 to 10 of 16 held) ``chunk_rows`` would size the first
+    chunk past every load below, which is its purpose and
+    ``test_moe_chunks.py``'s subject; this test is the later chunks' own."""
+    monkeypatch.setattr(moe, "chunk_rows", lambda t, top_k, n_held, e, block_rows: -(-t // block_rows) * block_rows)
     x, router, gate, up, down = expert_layer(seed=seed)
     slots = jnp.asarray(held)
     mine = (gate[slots], up[slots], down[slots])

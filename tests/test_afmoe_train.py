@@ -229,5 +229,7 @@ def test_the_cells_rehearsal_ends_correct():
     assert done.returncode == 0, done.stderr[-2000:]
     last = json.loads(done.stdout.strip().splitlines()[-1])
     assert last["correct"] is True and last["failed"] == 0
-    assert set(last["metrics"]) == {"expert_load_max_over_mean"}  # the one count; no device metric from a CPU
+    # the three counts (the chunk's two since PR 44); no device metric from a CPU
+    assert set(last["metrics"]) == {"expert_load_max_over_mean", "moe_chunks", "moe_chunk_fill_pct"}
+    assert last["metrics"]["moe_chunks"]["value"] == 1.0
     assert "0 dropped" in done.stdout

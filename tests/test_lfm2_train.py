@@ -354,10 +354,10 @@ def test_the_full_preset_is_the_cells_cut():
     assert shapes["embed"]["embedding"].shape == (16384, 2048)
 
 
-def bf16_angles(x, theta, rotary_dim=None):
+def bf16_angles(x, rope, rotary_dim=None):
     """``models/afmoe.rotary`` with its angles, cos and sin computed in bf16:
     what the configuration says is fp32, a precision lower."""
-    t, d = x.shape[1], x.shape[-1]
+    t, d, theta = x.shape[1], x.shape[-1], rope.theta
     low = jnp.bfloat16
     inv_freq = (theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)).astype(low)
     angles = jnp.arange(t, dtype=jnp.float32).astype(low)[:, None] * inv_freq[None, :]
@@ -404,5 +404,7 @@ def test_the_cells_rehearsal_ends_correct():
     assert done.returncode == 0, done.stderr[-2000:]
     last = json.loads(done.stdout.strip().splitlines()[-1])
     assert last["correct"] is True and last["failed"] == 0
-    assert set(last["metrics"]) == {"expert_load_max_over_mean"}  # the one count; no device metric from a CPU
+    # the three counts (the chunk's two since PR 44); no device metric from a CPU
+    assert set(last["metrics"]) == {"expert_load_max_over_mean", "moe_chunks", "moe_chunk_fill_pct"}
+    assert last["metrics"]["moe_chunks"]["value"] == 1.0
     assert "0 dropped" in done.stdout

@@ -15,7 +15,7 @@ from network_distributed_pytorch_tpu.models.nemotron_h import (
     NemotronHConfig,
 )
 from network_distributed_pytorch_tpu.ops.ssd import causal_conv1d, gated_group_rms_norm, ssd_scan
-from network_distributed_pytorch_tpu.parallel.moe import held_experts_moe
+from network_distributed_pytorch_tpu.parallel.moe import chunk_rows, held_experts_moe
 
 def worst_relative(got, want) -> float:
     off = jax.tree_util.tree_map(
@@ -118,12 +118,16 @@ def plain_experts(x, router, w_in, w_out, held):
     return out
 
 
+# five held of 16 at top 3 expect 45 of T = 48 rows: parallel.moe.chunk_rows gives a chunk 3/2 of that in
+# row tiles of 8, 72 rows in nine of them, so a skewed load (3T = 144) takes two chunks
+ROWS = 72
+
+
 def routed(x, router, w_in, w_out, held):
-    # row tiles of 8: a chunk is T = 48 rows in six of them, so a skewed load (3T) takes three chunks
     return held_experts_moe(x, x, router, jnp.zeros((E,)), w_in, w_out, held, K, 2.5, block_rows=8)
 
 
-def row_tiles_of(held_counts, rows=T, tile=8):
+def row_tiles_of(held_counts, rows=ROWS, tile=8):
     """The row tiles one product of the first chunk visits, by hand: every
     (tile, expert) pair with a row in common among the first ``rows`` sorted
     assignments."""
@@ -143,11 +147,13 @@ def test_expert_layer_matches_a_plain_loop_and_drops_nothing(skew):
     assert int(counters["dropped"]) == 0
     assert int(counters["held"].sum() + counters["absent"]) == T * K
     # the first chunk's products visit the tiles that hold rows, a tile two experts share once for each
+    assert chunk_rows(T, K, len(held), E, 8) == ROWS
     assert int(counters["row_tiles"]) == row_tiles_of(counters["held"])
-    if skew:  # 3T = 144 assignments landed against chunks of 48 rows: all three chunks ran
+    assert -(-int(counters["held"].sum()) // ROWS) == (2 if skew else 1)  # the chunks that held live rows
+    if skew:  # 3T = 144 assignments landed against chunks of 72 rows: both chunks ran
         assert int(counters["held"].sum()) == 3 * T
         assert int(counters["held"][:3].sum()) == 3 * T
-        assert int(counters["row_tiles"]) == 6  # the first chunk is full, and all of it the first expert's
+        assert int(counters["row_tiles"]) == 9  # the first chunk is full: the first expert's 48 rows and 24 of the second's
     grads = jax.grad(lambda *a: jnp.sum(jnp.sin(routed(*a, held)[0])), argnums=(0, 1, 2, 3))(
         x, router, w_in[slots], w_out[slots]
     )
@@ -171,6 +177,7 @@ def test_the_shares_of_sixteen_ranks_and_the_shared_expert_once_equal_the_uncut_
         parts, landed = parts + part, landed + int(counters["held"].sum())
         assert int(counters["absent"]) + int(counters["held"].sum()) == T * K
         assert int(counters["row_tiles"]) == -(-int(counters["held"][0]) // 8)  # one expert: its rows in whole tiles
+        assert chunk_rows(T, K, 1, E, 8) == T >= int(counters["held"].sum())  # a sixteenth held: T rows, one chunk
     assert landed == T * K  # every assignment landed on exactly one rank
     shared = jnp.dot(jnp.square(jax.nn.relu(jnp.dot(x, shared_in, precision="highest"))), shared_out, precision="highest")
     uncut = {
@@ -206,7 +213,7 @@ def test_expert_layer_inside_shard_map_skips_and_runs_chunks_per_worker():
     for w in range(2):
         np.testing.assert_allclose(got[w], plain_experts(x[w], router[w], w_in, w_out, held), rtol=2e-4, atol=2e-5)
         assert int(row_tiles[w]) == row_tiles_of(landed[w])  # each worker's own count
-    assert not dropped.any() and int(row_tiles[1]) == 6
+    assert not dropped.any() and int(row_tiles[1]) == 9
 
 
 def test_the_layer_over_a_mesh_axis_is_not_built_yet():

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import qwen3_next as reference
-from network_distributed_pytorch_tpu.models.afmoe import rotary
+from network_distributed_pytorch_tpu.models.afmoe import Rope, rotary
 from network_distributed_pytorch_tpu.models.nemotron_h import RMSNorm
 from network_distributed_pytorch_tpu.models.qwen3_next import (
     GatedAttention, GatedDeltaNet, Qwen3NextConfig, Qwen3NextExperts,
@@ -113,9 +113,9 @@ def test_gated_attention_layer_matches_the_plain_reference(attn_impl):
 
 def test_partial_rotary_turns_the_first_dims_and_leaves_the_rest_untouched():
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 40, 2, 32))
-    turned = rotary(x, 1e7, rotary_dim=8)
+    turned = rotary(x, Rope(1e7), rotary_dim=8)
     np.testing.assert_array_equal(turned[..., 8:], x[..., 8:])  # dims 8..31 pass as they came
-    np.testing.assert_array_equal(turned[..., :8], rotary(x[..., :8], 1e7))  # as a head of 8 dims would turn
+    np.testing.assert_array_equal(turned[..., :8], rotary(x[..., :8], Rope(1e7)))  # as a head of 8 dims would turn
     assert float(jnp.abs(turned[:, 1:, :, :8] - x[:, 1:, :, :8]).max()) > 1e-2
     pair = lambda v, i: v[..., i] ** 2 + v[..., i + 4] ** 2  # inside the rotary part i pairs with i + 4
     for i in range(4):
@@ -127,10 +127,10 @@ def test_partial_rotary_turns_the_first_dims_and_leaves_the_rest_untouched():
     cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
     x1, x2 = jnp.split(x, 2, axis=-1)
     whole = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    np.testing.assert_array_equal(rotary(x, 1e7), whole)
-    np.testing.assert_array_equal(rotary(x, 1e7, rotary_dim=None), whole)
-    np.testing.assert_array_equal(rotary(x, 1e7, rotary_dim=32), whole)
-    same_program = lambda **kw: str(jax.make_jaxpr(lambda x: rotary(x, 1e7, **kw))(x))
+    np.testing.assert_array_equal(rotary(x, Rope(1e7)), whole)
+    np.testing.assert_array_equal(rotary(x, Rope(1e7), rotary_dim=None), whole)
+    np.testing.assert_array_equal(rotary(x, Rope(1e7), rotary_dim=32), whole)
+    same_program = lambda **kw: str(jax.make_jaxpr(lambda x: rotary(x, Rope(1e7), **kw))(x))
     assert same_program() == same_program(rotary_dim=None) == same_program(rotary_dim=32)
 
 

@@ -38,7 +38,9 @@ from network_distributed_pytorch_tpu.ops.pallas_orthogonalize import (
 # 2: two lane tiles a head, the VMEM of T = 16,384 at 128), lfm2_psgd16_t8k's
 # attention layer (32 heads of 64 over 8: grouped heads narrower than a lane
 # block, so the fold at T = 8192, one 64-lane head's K and V whole in VMEM),
-# and a head width no lane block serves (the fold)
+# mellum2_psgd16_t8k's sliding layers (trinity's heads, a window of 1024: 8
+# windows, a band two 512-tiles wide), and a head width no lane block serves
+# (the fold)
 FLASH_CASES = [
     pytest.param((16, 256, 12, 64), jnp.bfloat16, False, True, id="distilbert-bf16"),
     pytest.param((16, 256, 12, 64), jnp.float32, False, True, id="distilbert-fp32"),
@@ -51,6 +53,7 @@ FLASH_CASES = [
     pytest.param((1, 8192, 32, 128, 4, 2048), jnp.bfloat16, True, False, id="trinity-8192-window-2048"),
     pytest.param((1, 8192, 16, 256, 2), jnp.bfloat16, True, False, id="qwen3next-8192-head-256-gqa"),
     pytest.param((1, 8192, 32, 64, 8), jnp.bfloat16, True, False, id="lfm2-8192-head-64-gqa-fold"),
+    pytest.param((1, 8192, 32, 128, 4, 1024), jnp.bfloat16, True, False, id="mellum-8192-window-1024"),
     pytest.param((4, 512, 3, 64), jnp.bfloat16, False, True, id="fold-3x64"),
 ]
 # P-factor shapes: DistilBERT-base at rank 16 (chip_smoke), GPT-2 at rank 4
@@ -62,10 +65,13 @@ ORTHOGONALIZE_SHAPES = [
 # 8 held experts, bf16: nemotron_psgd16_t8k's two (1856 = 14.5 x 128 is one
 # tile, as the contraction and as the output) and trinity_psgd16_t8k's; with
 # a third number, that many held experts: qwen3next_psgd16_t8k's 16 of width 512;
-# lfm2_psgd16_t8k's 8 of width 1536 (three 512-tiles)
+# lfm2_psgd16_t8k's 8 of width 1536 (three 512-tiles); with a fourth, that many
+# rows a chunk: mellum2_psgd16_t8k's 16 of width 896 = 7 x 128 over 2304 = 18 x
+# 128 (one 896-tile, three 768-tiles) at the 24,576 rows parallel/moe.chunk_rows
+# gives its load
 GROUPED_MATMUL_SHAPES = [
     (2688, 1856), (1856, 2688), (2048, 1024), (1024, 2048), (2048, 512, 16), (512, 2048, 16),
-    (2048, 1536), (1536, 2048),
+    (2048, 1536), (1536, 2048), (2304, 896, 16, 24576), (896, 2304, 16, 24576),
 ]
 
 
@@ -105,9 +111,9 @@ def test_pallas_orthogonalize_lowers_for_tpu(shape):
 
 
 def _grouped_matmul_fns(shape):
-    k, n, held = (*shape, 8)[:3]
+    k, n, held, rows = (*shape, *(8, 8192)[len(shape) - 2:])
     args = [
-        jax.ShapeDtypeStruct((8192, k), jnp.bfloat16),
+        jax.ShapeDtypeStruct((rows, k), jnp.bfloat16),
         jax.ShapeDtypeStruct((held, k, n), jnp.bfloat16),
         jax.ShapeDtypeStruct((held,), jnp.int32),
     ]
