@@ -257,6 +257,19 @@ def _product_bwd(tm, interpret, residuals, cotangent):
 _product.defvjp(_product_fwd, _product_bwd)
 
 
+def _as_all(*operands):
+    """``operands``, each varying over the mesh as all of them together do.
+    A custom_vjp's cotangents have their primals' types: inside shard_map
+    every operand has to vary as the cotangents, made from all of them, will."""
+    varying = _vma(*operands)
+
+    def as_all(operand):
+        missing = tuple(varying - jax.typeof(operand).vma)
+        return lax.pcast(operand, missing, to="varying") if missing else operand
+
+    return tuple(as_all(operand) for operand in operands)
+
+
 def grouped_matmul(
     lhs: jax.Array,          # (m, k) rows, each group's after the last's
     rhs: jax.Array,          # (g, k, n) one matrix a group, lhs's dtype
@@ -280,12 +293,4 @@ def grouped_matmul(
         return _live(lax.ragged_dot(lhs, rhs, group_sizes, preferred_element_type=jnp.float32), group_sizes)
     tm = min(row_tile, lhs.shape[0])
     assert lhs.shape[0] % tm == 0, (lhs.shape, row_tile)
-    # a custom_vjp's cotangents have their primals' types: inside shard_map every operand
-    # has to vary over the mesh as the cotangents, made from all of them, will
-    varying = _vma(lhs, rhs, group_sizes)
-
-    def as_all(operand):
-        missing = tuple(varying - jax.typeof(operand).vma)
-        return lax.pcast(operand, missing, to="varying") if missing else operand
-
-    return _product(tm, bool(interpret), as_all(lhs), as_all(rhs), as_all(group_sizes))
+    return _product(tm, bool(interpret), *_as_all(lhs, rhs, group_sizes))

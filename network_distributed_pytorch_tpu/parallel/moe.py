@@ -55,6 +55,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.grouped_matmul import grouped_matmul, row_tiles
+from ..ops.rows_to_tokens import rows_of_tokens, tokens_from_rows
 
 PyTree = Any
 
@@ -306,8 +307,15 @@ def held_experts_moe(
     expert and computed as grouped products. The router indexes nothing by
     data (``_picked``, ``_slots``, ``_counts``, ``_sorted_by_slot`` above it:
     compares against static ranges and the payload of the one sort, the
-    values of the gathers and scatter-adds they stand for); the only gather
-    and scatter-add left move (rows, D). The expert's form follows its
+    values of the gathers and scatter-adds they stand for); what is left
+    indexed by data moves (rows, D), and is each other's transpose:
+    ``ops.rows_to_tokens.rows_of_tokens`` brings the tokens to their rows (a
+    gather, forward and as the combine's cotangent) and
+    ``tokens_from_rows`` adds the weighted rows back into their tokens
+    (forward and as the gather's cotangent: on TPU a Pallas kernel that
+    holds a tile of tokens in VMEM and reads the rows that belong to it from
+    where they lie, fp32 sums; ``.at[token].add`` elsewhere, which on the
+    v5e is a loop over the rows, PR 45). The expert's form follows its
     operands: without ``w_gate`` it is ``w_out_e . relu(w_in_e . x)^2``
     (Nemotron-H), with it ``w_out_e . (silu(w_gate_e . x) * (w_in_e . x))``
     (afmoe's gate, up and down projections). The sorted rows stay as they lie,
@@ -408,9 +416,9 @@ def held_experts_moe(
             token = jnp.where(
                 first + jnp.arange(rows) < landed, lax.dynamic_slice(sorted_tokens, (first,), (rows,)), t
             )
-            rows_in = x.at[token].get(mode="fill", fill_value=0)
-        with jax.named_scope("moe.products"):
             sizes = sizes_in(first)
+            rows_in = rows_of_tokens(x, token, sizes)
+        with jax.named_scope("moe.products"):
             product = functools.partial(grouped_matmul, group_sizes=sizes, row_tile=block_rows)
             hidden = product(rows_in, w_in)
             if w_gate is None:
@@ -420,7 +428,7 @@ def held_experts_moe(
             part, computed = product(hidden, w_out), jnp.sum(sizes)
         with jax.named_scope("moe.combine"):
             part = part * lax.dynamic_slice(sorted_weights, (first,), (rows,))[:, None]
-            return jnp.zeros((t, d), f32).at[token].add(part, mode="drop"), computed
+            return tokens_from_rows(part, token, sizes, t), computed
 
     def later_chunks(*operands):
         """The rare, heavy load: chunk after chunk until the assignments end.

@@ -6,11 +6,15 @@ held live rows follows from ``held`` and the rule (the benchmark's
 ``moe_chunks`` reads it so). The later chunks stay what they were: the rare
 heavy load, never a drop."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from network_distributed_pytorch_tpu.ops.rows_to_tokens import rows_of_tokens, tokens_from_rows
+from network_distributed_pytorch_tpu.parallel import moe
 from network_distributed_pytorch_tpu.parallel.moe import chunk_rows, held_experts_moe
 
 T, D, F = 64, 16, 8
@@ -113,6 +117,44 @@ def test_a_load_pushed_past_the_chunk_goes_on_in_later_chunks_and_drops_nothing(
     want = jax.grad(loss(lambda x, p: dense(x, p, held, top_k)), argnums=(0, 1))(x, p)
     for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-4)
+
+
+# (e, held, top_k, every token alike) -> chunks that hold live rows
+KERNEL_CASES = [
+    pytest.param(16, (0, 1, 2, 3), 2, False, 1, id="a_quarter_of_t_in_a_chunk_of_t"),
+    pytest.param(16, (0, 1, 2, 3), 8, False, 1, id="two_t_in_a_chunk_of_3t"),
+    pytest.param(16, (0, 1, 2, 3), 4, True, 3, id="four_t_pushed_into_later_chunks"),
+]
+
+
+@pytest.mark.parametrize("e, held, top_k, alike, chunks", KERNEL_CASES)
+def test_with_the_kernel_the_layer_computes_what_the_indexed_add_computes(monkeypatch, e, held, top_k, alike, chunks):
+    """The layer as the chip runs it but for the compiler (the add of rows
+    into tokens, forward and as the gather's cotangent, is the Pallas kernel,
+    here in the interpreter) against the layer as the CPU runs it
+    (``.at[token].add``): output, counters and gradients to fp32 rounding —
+    a token's few terms may be summed in another order, nothing else."""
+    x, p = layer(e, held, seed=3)
+    if alike:  # every token picks the four held experts: 4 T rows where the chunk holds 1.5 T
+        x = jnp.broadcast_to(x[:1], x.shape) + 1e-3 * x
+        p["router"] = p["router"].at[:, :4].set(jnp.outer(x[0], jnp.ones(4)) * 0.5)
+
+    def value_counters_and_gradients():
+        value = lambda x, p: (lambda out, counters: (jnp.sum(jnp.sin(out)), (out, counters)))(*routed(x, p, held, top_k))
+        return jax.value_and_grad(value, argnums=(0, 1), has_aux=True)(x, p)
+
+    (_, (want, want_counters)), want_grads = value_counters_and_gradients()
+    monkeypatch.setattr(moe, "rows_of_tokens", functools.partial(rows_of_tokens, interpret=True))
+    monkeypatch.setattr(moe, "tokens_from_rows", functools.partial(tokens_from_rows, interpret=True))
+    (_, (got, got_counters)), got_grads = value_counters_and_gradients()
+
+    rows = chunk_rows(T, top_k, len(held), e, 8)
+    assert max(-(-int(got_counters["held"].sum()) // rows), 1) == chunks and int(got_counters["dropped"]) == 0
+    for name in want_counters:
+        np.testing.assert_array_equal(got_counters[name], want_counters[name])
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    for g, w in zip(jax.tree_util.tree_leaves(got_grads), jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
 
 
 def test_the_counters_tree_is_the_layers_own():

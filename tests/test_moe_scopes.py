@@ -22,6 +22,8 @@ import jax.numpy as jnp
 import pytest
 
 from benchmark.layer_metrics.passes import pass_of
+from network_distributed_pytorch_tpu.ops.rows_to_tokens import rows_of_tokens, tokens_from_rows
+from network_distributed_pytorch_tpu.parallel import moe
 from network_distributed_pytorch_tpu.parallel.moe import held_experts_moe
 from network_distributed_pytorch_tpu.utils.hlo_audit import hlo_text_of_compiled
 
@@ -68,10 +70,11 @@ def op_names(gated, score, top_k):
 
 
 def equations(jaxpr, outer=""):
-    """(primitive, name stack from the top) of every equation, inner jaxprs too."""
+    """(primitive, name stack from the top) of every equation, inner jaxprs
+    too; a ``pallas_call`` as ``pallas_call:<its name=>``."""
     for eqn in jaxpr.eqns:
         path = f"{outer}/{eqn.source_info.name_stack}"
-        yield eqn.primitive.name, path
+        yield eqn.primitive.name + (f":{eqn.params['name']}" if eqn.primitive.name == "pallas_call" else ""), path
         for inner in jax.core.jaxprs_in_params(eqn.params):
             yield from equations(inner, path)
 
@@ -127,8 +130,11 @@ def test_the_layout_is_in_neither_and_the_overflow_only_in_the_experts(gated, sc
 def test_the_router_indexes_without_a_gather_or_a_scatter(gated, score, top_k):
     """The router's index work is compares and a sort: the only equations
     that index by data in the layer's value and gradient move (rows, D) data,
-    under ``moe.gather`` and ``moe.combine`` (forward, recomputation, and
-    each one's cotangent under the other's name)."""
+    under ``moe.gather`` and ``moe.combine``. Off the chip (this test: the
+    CPU path, what the layer was before PR 45) those are the gather of the
+    tokens to their rows and the scatter-add of the rows back, forward and
+    recomputed, each one's cotangent the other primitive under the same
+    name; on the chip the next test."""
     step, args = step_like(gated, score, top_k)
     eqns = list(equations(jax.make_jaxpr(step)(*args).jaxpr))
     indexed = [(primitive, path) for primitive, path in eqns if "gather" in primitive or "scatter" in primitive]
@@ -140,6 +146,56 @@ def test_the_router_indexes_without_a_gather_or_a_scatter(gated, score, top_k):
     # more for the cotangent
     sorts = [path for primitive, path in eqns if primitive == "sort"]
     assert len(sorts) == 3 and all("moe.sort" in path for path in sorts), sorts
+
+
+@pytest.mark.parametrize("gated, score, top_k", CASES)
+def test_on_the_chip_only_gathers_index_by_data_and_the_adds_are_the_kernel(gated, score, top_k, monkeypatch):
+    """What the backend selects on TPU, traced here (nothing is lowered):
+    the tokens to their rows and the combine's cotangent are gathers, as off
+    the chip (and the grouped products' kernels look their few visits up);
+    both adds of rows into tokens (``moe.combine`` forward and recomputed,
+    ``moe.gather``'s cotangent) are the ``tokens_from_rows`` kernel, so the layer has no scatter at all; the kernel's ranges are
+    compares and a cumulative sum, so the sorts stay three; and the kernel
+    with everything it is made from names exactly one leaf."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step, args = step_like(gated, score, top_k)
+    eqns = list(equations(jax.make_jaxpr(step)(*args).jaxpr))
+    indexed = [(primitive, path) for primitive, path in eqns if "gather" in primitive or "scatter" in primitive]
+    assert {primitive for primitive, _ in indexed} == {"gather"}
+    for primitive, path in indexed:
+        # under moe.products: the grouped products' visit tables (ops.grouped_matmul._visits), (tiles + held) scalars
+        leaves = named(path, ROUTE + CHUNK + ("moe.layout", "moe.route"))
+        assert leaves in (["moe.gather"], ["moe.combine"], ["moe.products"]), path
+    adds = [path for primitive, path in eqns if primitive == "pallas_call:tokens_from_rows"]
+    assert {tuple(named(path, CHUNK)) for path in adds} == {("moe.gather",), ("moe.combine",)}, adds
+    assert all("transpose(" in path for path in adds if "moe.gather" in path)  # the gather's is a cotangent
+    assert any("transpose(" not in path for path in adds if "moe.combine" in path)  # the combine's runs forward
+    sorts = [path for primitive, path in eqns if primitive == "sort"]
+    assert len(sorts) == 3 and all("moe.sort" in path for path in sorts), sorts
+    for primitive, path in eqns:
+        if "moe.experts" in path:
+            leaves = named(path, CHUNK)
+            assert not named(path, ROUTE + ("moe.layout",)), (primitive, path)
+            assert len(leaves) == 1 or (not leaves and "moe.overflow" in path), (primitive, path)
+
+
+@pytest.mark.parametrize("gated, score, top_k", CASES)
+def test_an_op_of_the_interpreted_kernel_names_exactly_one_leaf(gated, score, top_k, monkeypatch):
+    """The compiled program with the kernel in the Pallas interpreter (its
+    copies, loops and row adds as XLA instructions of their own): every
+    instruction under ``moe.experts`` still names one leaf, and the kernel's
+    are under the two that add rows into tokens."""
+    monkeypatch.setattr(moe, "rows_of_tokens", functools.partial(rows_of_tokens, interpret=True))
+    monkeypatch.setattr(moe, "tokens_from_rows", functools.partial(tokens_from_rows, interpret=True))
+    step, args = step_like(gated, score, top_k)
+    program = jax.jit(step).lower(*args).compile()
+    paths = [p for p in re.findall(r'op_name="([^"]*)"', hlo_text_of_compiled(program)) if "moe.experts" in p]
+    for path in paths:
+        assert not named(path, ROUTE + ("moe.layout",)), path
+        leaves = named(path, CHUNK)
+        assert len(leaves) == 1 or (not leaves and "moe.overflow" in path), path
+    kernel = [p for p in paths if "tokens_from_rows" in p]
+    assert {tuple(named(p, CHUNK)) for p in kernel} == {("moe.gather",), ("moe.combine",)}, kernel
 
 
 def test_no_leaf_name_is_part_of_another():

@@ -15,6 +15,8 @@ serving experiments use:
   stepped on the CPU mesh in interpret mode.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,6 +28,7 @@ from network_distributed_pytorch_tpu.ops.grouped_matmul import grouped_matmul
 from network_distributed_pytorch_tpu.ops.pallas_orthogonalize import (
     orthogonalize_pallas,
 )
+from network_distributed_pytorch_tpu.ops.rows_to_tokens import rows_of_tokens, tokens_from_rows
 
 # (b, t, h, d[, key/value heads[, window]]), dtype, causal, padded mask — chip_smoke's
 # DistilBERT-base attention (bf16 and fp32), GPT-2-small at its context, gpt_lm's full
@@ -73,6 +76,10 @@ GROUPED_MATMUL_SHAPES = [
     (2688, 1856), (1856, 2688), (2048, 1024), (1024, 2048), (2048, 512, 16), (512, 2048, 16),
     (2048, 1536), (1536, 2048), (2304, 896, 16, 24576), (896, 2304, 16, 24576),
 ]
+# (rows, d, runs) of the expert layers' adds of rows into 8192 tokens: mellum2_psgd16_t8k's
+# 24,576-row chunk over 16 held experts and nemotron_psgd16_t8k's 8,192 over 8 (its width,
+# 21 x 128, the widest of the five cells)
+ROWS_TO_TOKENS_SHAPES = [(24576, 2304, 16), (8192, 2688, 8)]
 
 
 def _flash_fns(shape, dtype, causal, masked):
@@ -141,6 +148,44 @@ def test_grouped_matmul_lowers_for_tpu_inside_shard_map(shape):
     sharded = jax.shard_map(worker, mesh=mesh, in_specs=P("data"), out_specs=P("data"))
     text = jax.jit(sharded).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") >= 3
+
+
+def _rows_to_tokens_fns(shape, t=8192):
+    """The layer's two uses: fp32 rows added into their tokens (the kernel
+    forward, its cotangent the gather) and bf16 tokens gathered to their rows
+    (the kernel backward, on a bf16 cotangent)."""
+    rows, d, runs = shape
+    args = [
+        jax.ShapeDtypeStruct((rows, d), jnp.float32), jax.ShapeDtypeStruct((t, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((rows,), jnp.int32), jax.ShapeDtypeStruct((runs,), jnp.int32),
+    ]
+    # interpret=False: the kernel, whatever backend traces it
+    forward = lambda part, x, token, sizes: tokens_from_rows(part, token, sizes, t, interpret=False)
+
+    def loss(part, x, token, sizes):
+        rows_in = rows_of_tokens(x, token, sizes, interpret=False).astype(jnp.float32)
+        return jnp.sum(jnp.sin(forward(part * rows_in, x, token, sizes)))
+
+    return args, {"forward": forward, "grad": jax.grad(loss, argnums=(0, 1))}
+
+
+@pytest.mark.parametrize("shape", ROWS_TO_TOKENS_SHAPES, ids=str)
+def test_tokens_from_rows_lowers_for_tpu_inside_shard_map(shape):
+    """Where every training step runs: the kernel's output declares how it
+    varies over the mesh; rows, tokens and sizes a worker's own."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    args, fns = _rows_to_tokens_fns(shape)
+    args = [jax.ShapeDtypeStruct((2,) + a.shape, a.dtype) for a in args]
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+
+    def worker(*operands):
+        d_part, d_x = fns["grad"](*(operand[0] for operand in operands))
+        return d_part[None], d_x[None]
+
+    sharded = jax.shard_map(worker, mesh=mesh, in_specs=P("data"), out_specs=P("data"))
+    text = jax.jit(sharded).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") >= 2  # forward, and the gather's cotangent
 
 
 def _chunk_local_fns(bsz=1, t=8192, hk=16, hv=32, d=128, chunk=64, dtype=jnp.bfloat16):
@@ -270,7 +315,6 @@ def test_flash_backward_is_a_kernel_in_the_compiled_program(
     after it), beside the forward's; no ``while`` is left of the K-block
     scan it replaced, and no array is larger than q — the scan's
     (B*H, T, block_k) score tensors are what the kernel keeps in VMEM."""
-    import re
 
     b, t, h, d = shape
     args, fns = _flash_fns(shape, jnp.bfloat16, causal, masked)
@@ -297,16 +341,27 @@ def test_grouped_matmul_compiles_with_mosaic_under_its_three_names(v5e_devices, 
     Mosaic would refuse on the chip (a tile's VMEM, a 1856-wide block, a
     select in bf16, the transposed products) it refuses here; each
     ``pallas_call`` is in the compiled program under its own name."""
-    import re
-
     args, fns = _grouped_matmul_fns(shape)
     args = [_on(v5e_devices[0], a) for a in args]
     jax.jit(fns["forward"]).lower(*args).compile()
     hlo = jax.jit(fns["grad"]).lower(*args).compile().as_text()
-    kernels = re.findall(r"%([a-z_]+)[\w.]* = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
-    # jax wraps the name in what differentiated it: jvp_grouped_matmul_, transpose_jvp_grouped_matmul_nt__
-    kernels = sorted(re.sub(r"^(transpose_|jvp_)+|_+$", "", kernel) for kernel in kernels)
-    assert kernels == ["grouped_matmul", "grouped_matmul_nt", "grouped_matmul_tn"], kernels
+    assert _bare(_custom_calls(hlo)) == ["grouped_matmul", "grouped_matmul_nt", "grouped_matmul_tn"]
+
+
+@pytest.mark.parametrize("shape", ROWS_TO_TOKENS_SHAPES, ids=str)
+def test_tokens_from_rows_compiles_with_mosaic_under_its_name(v5e_devices, shape):
+    """At the expert layers' real shapes, fp32 rows forward and a bf16
+    cotangent backward: what Mosaic would refuse on the chip (the tokens of
+    24,576 rows in SMEM, a copy that starts inside a tile of the rows'
+    layout, a row read alone from a packed dtype, the tile's VMEM) it
+    refuses here; the call is in the compiled program under its name, and no
+    scatter is."""
+    args, fns = _rows_to_tokens_fns(shape)
+    args = [_on(v5e_devices[0], a) for a in args]
+    assert _custom_calls(jax.jit(fns["forward"]).lower(*args).compile().as_text()) == ["tokens_from_rows"]
+    hlo = jax.jit(fns["grad"]).lower(*args).compile().as_text()
+    assert _bare(_custom_calls(hlo)) == ["tokens_from_rows"] * 2  # the gather's cotangent is the second call
+    assert " scatter(" not in hlo and " gather(" in hlo
 
 
 def test_gated_delta_chunk_local_compiles_with_mosaic_under_its_two_names(v5e_devices, monkeypatch):
@@ -316,8 +371,6 @@ def test_gated_delta_chunk_local_compiles_with_mosaic_under_its_two_names(v5e_de
     the whole rule on a chip takes the kernels: in its compiled gradient each
     is a ``tpu_custom_call`` under its own name, and none of the stage's
     (chunks, heads, 64, 64) fp32 matrices is an array of the program."""
-    import re
-
     args, fns, rule_args, rule_grad = _chunk_local_fns()
     on_chip = lambda structs: [_on(v5e_devices[0], a) for a in structs]
     jax.jit(fns["forward"]).lower(*on_chip(args)).compile()
@@ -330,9 +383,12 @@ def test_gated_delta_chunk_local_compiles_with_mosaic_under_its_two_names(v5e_de
 
 
 def _custom_calls(hlo):
-    import re
-
     return sorted(re.findall(r"%([a-z_]+)[\w.]* = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo))
+
+
+def _bare(kernels):
+    """jax wraps a kernel's name in what differentiated it: jvp_grouped_matmul_, transpose_jvp_grouped_matmul_nt__"""
+    return sorted(re.sub(r"^(transpose_|jvp_)+|_+$", "", kernel) for kernel in kernels)
 
 
 FRAME_NAMES = ["gdn_frame_in", "gdn_frame_in_bwd", "gdn_frame_out", "gdn_frame_out_bwd"]
@@ -361,8 +417,6 @@ def test_the_mixer_on_a_chip_takes_the_frames_kernels_once_a_pass(v5e_devices, m
     (forward, its recomputation where the block is rematerialised, backward),
     and no copy that converts an array the size of q or larger is left under
     ``gdn.frame`` (beta's and g's (T, 32) fp32 relayouts for the rule stay)."""
-    import re
-
     from network_distributed_pytorch_tpu.models.qwen3_next import GatedDeltaNet, Qwen3NextConfig
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
