@@ -158,14 +158,53 @@ def rotary(x: jax.Array, rope: Rope, rotary_dim: Optional[int] = None) -> jax.Ar
     (``partial_rotary_factor``); the others pass as they came."""
     if rotary_dim is not None and rotary_dim < x.shape[-1]:
         return jnp.concatenate([rotary(x[..., :rotary_dim], rope), x[..., rotary_dim:]], axis=-1)
-    t, d = x.shape[1], x.shape[-1]
-    inv_freq, factor = rope_frequencies(rope, d)
-    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]  # (T, D/2)
+    angles, factor = _angles(rope, x.shape[1], x.shape[-1])
     cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
     if factor != 1.0:
         cos, sin = cos * factor, sin * factor
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def _angles(rope: Rope, t: int, dim: int) -> Tuple[jax.Array, float]:
+    """``(t * inv_freq`` (T, dim/2) in fp32``, factor)`` of ``rope_frequencies(rope, dim)``."""
+    inv_freq, factor = rope_frequencies(rope, dim)
+    return jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :], factor
+
+
+def rope_tables(rope: Rope, t: int, dim: int) -> Tuple[jax.Array, jax.Array]:
+    """What :func:`rotary` turns ``dim`` dims by, as tables: cos and sin of
+    its angles times its factor, (T, dim/2) in fp32 each."""
+    angles, factor = _angles(rope, t, dim)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    return (cos * factor, sin * factor) if factor != 1.0 else (cos, sin)
+
+
+def normed_and_turned(
+    q_norm: RMSNorm, k_norm: RMSNorm, q, k, rope: Optional[Rope], dtype, rotary_dim: Optional[int] = None,
+    interpret: Optional[bool] = None,
+):
+    """What an attention layer does to q (B, T, H, D) and k (B, T, H_kv, D)
+    between their projections and the attention itself, under its scope
+    ``attn.rope``: each head through its norm, then turned by ``rope`` as
+    :func:`rotary` turns it (``None``: the layer carries no positions), then
+    cast to ``dtype``.
+
+    ``interpret=None`` lets the backend decide: on TPU one Pallas pass forward
+    and one backward (``ops/qk_rope.py``) where its tiles serve the shape,
+    elsewhere and otherwise these XLA lines; ``True`` runs the kernels in the
+    Pallas interpreter, ``False`` the kernels whatever traces them."""
+    from ..ops import pallas_interpret, qk_rope
+
+    t, d = q.shape[1], q.shape[-1]
+    turning = 0 if rope is None else d if rotary_dim is None else min(rotary_dim, d)
+    served = qk_rope.serves(t, q.shape[2], k.shape[2], d, turning)
+    if not served or (interpret is None and pallas_interpret()):
+        turned = lambda x: x if rope is None else rotary(x, rope, rotary_dim)
+        return turned(q_norm(q)).astype(dtype), turned(k_norm(k)).astype(dtype)
+    cos, sin = (None, None) if rope is None else rope_tables(rope, t, turning)
+    scales = q_norm(q, scale_alone=True), k_norm(k, scale_alone=True)
+    return qk_rope.normed_and_turned(q, k, *scales, cos, sin, q_norm.eps, dtype, bool(interpret))
 
 
 class GatedMLP(nn.Module):
@@ -256,11 +295,9 @@ class AfmoeAttention(nn.Module):
         v = _dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
         gate = _dense(cfg, hq * hd, cfg.init_std, "gate_proj")(u)
         with jax.named_scope("attn.rope"):
-            q = RMSNorm(cfg.norm_eps, name="q_norm")(q)
-            k = RMSNorm(cfg.norm_eps, name="k_norm")(k)
-            if self.sliding:  # the full layers carry no positions
-                q, k = rotary(q, Rope(cfg.rope_theta)), rotary(k, Rope(cfg.rope_theta))
-            q, k = q.astype(cfg.dtype), k.astype(cfg.dtype)
+            rope = Rope(cfg.rope_theta) if self.sliding else None  # the full layers carry no positions
+            norms = RMSNorm(cfg.norm_eps, name="q_norm"), RMSNorm(cfg.norm_eps, name="k_norm")
+            q, k = normed_and_turned(*norms, q, k, rope, cfg.dtype)
         with jax.named_scope("attn.window" if self.sliding else "attn.full"):
             if resolve_attn_impl(cfg.attn_impl) == "flash":
                 from ..ops import flash_attention, pallas_interpret

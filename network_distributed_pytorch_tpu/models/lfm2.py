@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .afmoe import GatedMLP, Rope, expert_bias_of, rotary
+from .afmoe import GatedMLP, Rope, expert_bias_of, normed_and_turned
 from .nemotron_h import RMSNorm, _dense, _kernel, einsum_attention
 
 CONV, FULL = "conv", "full_attention"
@@ -144,8 +144,8 @@ class Lfm2Attention(nn.Module):
         k = _dense(cfg, hkv * hd, cfg.init_std, "k_proj")(u).reshape(bsz, t, hkv, hd)
         v = _dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
         with jax.named_scope("attn.rope"):
-            q = rotary(RMSNorm(cfg.norm_eps, name="q_norm")(q), Rope(cfg.rope_theta)).astype(cfg.dtype)
-            k = rotary(RMSNorm(cfg.norm_eps, name="k_norm")(k), Rope(cfg.rope_theta)).astype(cfg.dtype)
+            norms = RMSNorm(cfg.norm_eps, name="q_norm"), RMSNorm(cfg.norm_eps, name="k_norm")
+            q, k = normed_and_turned(*norms, q, k, Rope(cfg.rope_theta), cfg.dtype)
         with jax.named_scope("attn.full"):
             if resolve_attn_impl(cfg.attn_impl) == "flash":
                 from ..ops import flash_attention, pallas_interpret

@@ -57,7 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .afmoe import FULL, SLIDING, Rope, rotary
+from .afmoe import FULL, SLIDING, Rope, normed_and_turned
 from .nemotron_h import RMSNorm, _dense, _kernel, einsum_attention
 
 
@@ -130,8 +130,8 @@ class MellumAttention(nn.Module):
         v = _dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
         with jax.named_scope("attn.rope"):
             rope = cfg.rope_sliding if sliding else cfg.rope_full  # both kinds turn, each by its own frequencies
-            q = rotary(RMSNorm(cfg.norm_eps, name="q_norm")(q), rope).astype(cfg.dtype)
-            k = rotary(RMSNorm(cfg.norm_eps, name="k_norm")(k), rope).astype(cfg.dtype)
+            norms = RMSNorm(cfg.norm_eps, name="q_norm"), RMSNorm(cfg.norm_eps, name="k_norm")
+            q, k = normed_and_turned(*norms, q, k, rope, cfg.dtype)
         with jax.named_scope("attn.window" if sliding else "attn.full"):
             if resolve_attn_impl(cfg.attn_impl) == "flash":
                 from ..ops import flash_attention, pallas_interpret

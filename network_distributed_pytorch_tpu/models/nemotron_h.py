@@ -111,12 +111,16 @@ class RMSNorm(nn.Module):
     zero_centred: bool = False  # the learned scale is 1 + w, w from zero (``models/qwen3_next.py``)
 
     @nn.compact
-    def __call__(self, x):
-        """fp32 in, fp32 out: callers cast to what their products take."""
+    def __call__(self, x, scale_alone: bool = False):
+        """fp32 in, fp32 out: callers cast to what their products take. With
+        ``scale_alone`` what multiplies the normed ``x`` and no arithmetic on
+        it, for a caller whose kernel norms (``models/afmoe.normed_and_turned``)."""
         init = nn.initializers.zeros if self.zero_centred else nn.initializers.ones
         scale = self.param("scale", init, (x.shape[-1],))
         if self.zero_centred:
             scale = 1.0 + scale
+        if scale_alone:
+            return scale
         x = x.astype(jnp.float32)
         return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps) * scale
 

@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .afmoe import GatedMLP, Rope, rotary
+from .afmoe import GatedMLP, Rope, normed_and_turned
 from .nemotron_h import RMSNorm, _dense, _kernel, einsum_attention
 
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -169,8 +169,8 @@ class GatedAttention(nn.Module):
         k = _dense(cfg, hkv * hd, cfg.init_std, "k_proj")(u).reshape(bsz, t, hkv, hd)
         v = _dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
         with jax.named_scope("attn.rope"):
-            q = rotary(_norm(cfg, "q_norm")(q), Rope(cfg.rope_theta), cfg.rotary_dim).astype(cfg.dtype)
-            k = rotary(_norm(cfg, "k_norm")(k), Rope(cfg.rope_theta), cfg.rotary_dim).astype(cfg.dtype)
+            norms = _norm(cfg, "q_norm"), _norm(cfg, "k_norm")
+            q, k = normed_and_turned(*norms, q, k, Rope(cfg.rope_theta), cfg.dtype, cfg.rotary_dim)
         with jax.named_scope("attn.full"):
             if resolve_attn_impl(cfg.attn_impl) == "flash":
                 from ..ops import flash_attention, pallas_interpret

@@ -377,7 +377,7 @@ def test_rotary_angles_in_bfloat16_show_in_attentions_gradients(monkeypatch):
     control turned the cell's ``correct`` to false by the memories' limit
     alone (q_proj 0.385 and k_proj 0.390 against 0.30; my chip run, PR 41)."""
     from benchmark.reference_check import TOLERANCES
-    from network_distributed_pytorch_tpu.models import lfm2
+    from network_distributed_pytorch_tpu.models import afmoe
 
     model = lfm2_tiny(layer_types=(CONV, FULL), rope_theta=1e6)
     ids = jax.random.randint(jax.random.PRNGKey(1), (1, 2049), 0, 256)
@@ -388,7 +388,7 @@ def test_rotary_angles_in_bfloat16_show_in_attentions_gradients(monkeypatch):
     loss = next_token_lm_loss(model)
     as_built = jax.grad(lambda p: loss(p, {}, batch)[0])(params)
     assert worst_relative(attention(as_built), attention(want)) < 1e-3
-    monkeypatch.setattr(lfm2, "rotary", bf16_angles)
+    monkeypatch.setattr(afmoe, "rotary", bf16_angles)  # where the layers' XLA lines look it up
     lowered = jax.grad(lambda p: loss(p, {}, batch)[0])(params)
     assert 0.1 < worst_relative(attention(lowered), attention(want)) < TOLERANCES["update_each"]
 

@@ -209,13 +209,13 @@ def test_rotary_angles_in_bfloat16_show_in_attentions_gradients(monkeypatch):
     in its configuration file, 0.10: between what its sound runs and what
     this control read on the chip at the published widths, PERF.md section 6,
     PR 44)."""
-    from network_distributed_pytorch_tpu.models import mellum
+    from network_distributed_pytorch_tpu.models import afmoe
 
     model, params, batch = long_model_and_batch()
     _, want, _ = reference.make_loss_and_grads(reference_cfg(model.config))(params, {}, batch)
     loss = next_token_lm_loss(model)
     as_built = jax.grad(lambda p: loss(p, {}, batch)[0])(params)
-    monkeypatch.setattr(mellum, "rotary", bf16_angles)
+    monkeypatch.setattr(afmoe, "rotary", bf16_angles)  # where the layers' XLA lines look it up
     lowered = jax.grad(lambda p: loss(p, {}, batch)[0])(params)
     for layer in ("layer_0", "layer_1"):
         assert worst_relative(attention_grads(as_built, layer), attention_grads(want, layer)) < 1e-3

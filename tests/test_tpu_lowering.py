@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from network_distributed_pytorch_tpu.ops import gated_delta, gated_delta_frame
+from network_distributed_pytorch_tpu.ops import gated_delta, gated_delta_frame, qk_rope
 from network_distributed_pytorch_tpu.ops.flash_attention import flash_attention
 from network_distributed_pytorch_tpu.ops.grouped_matmul import grouped_matmul
 from network_distributed_pytorch_tpu.ops.pallas_orthogonalize import (
@@ -256,6 +256,53 @@ def test_gated_delta_frame_lowers_for_tpu_inside_shard_map():
     assert text.count("tpu_custom_call") == 4
 
 
+# (query heads, key heads, head, theta or None, rotary_dim) of the four cells' passes under ``attn.rope`` at T = 8192,
+# bf16: trinity_psgd16_t8k's and mellum2_psgd16_t8k's 32 + 4 heads of 128 turned whole (two tables), and without
+# positions (trinity's full layer: the norm alone, no table); lfm2_psgd16_t8k's 32 + 8 of 64 (two heads a lane
+# block, three tables); qwen3next_psgd16_t8k's 16 + 2 of 256 of which the first 64 lanes turn
+QK_ROPE_SHAPES = [
+    pytest.param(32, 4, 128, 1e4, 128, id="32+4x128-turned"),
+    pytest.param(32, 4, 128, None, 0, id="32+4x128-norm-alone"),
+    pytest.param(32, 8, 64, 1e6, 64, id="32+8x64-turned"),
+    pytest.param(16, 2, 256, 1e7, 64, id="16+2x256-turning-64"),
+]
+QK_ROPE_NAMES = ["qk_rope", "qk_rope_bwd"]
+
+
+def _qk_rope_fns(hq, hk, d, theta, rotary_dim, bsz=1, t=8192, dtype=jnp.bfloat16):
+    """The pass as kernels whatever traces them, with its tables built as the
+    models build them, and the gradient of q, k and both scales."""
+    from network_distributed_pytorch_tpu.models.afmoe import Rope, rope_tables
+
+    args = [jax.ShapeDtypeStruct((bsz, t, h, d), dtype) for h in (hq, hk)] + [jax.ShapeDtypeStruct((d,), jnp.float32)] * 2
+
+    def forward(q, k, q_scale, k_scale):
+        cos, sin = (None, None) if theta is None else rope_tables(Rope(theta), t, rotary_dim)
+        return qk_rope.normed_and_turned(q, k, q_scale, k_scale, cos, sin, 1e-6, dtype, interpret=False)
+
+    loss = lambda *a: sum(jnp.sum(jnp.sin(y.astype(jnp.float32))) for y in forward(*a))
+    return args, {"forward": forward, "grad": jax.grad(loss, argnums=range(4))}
+
+
+@pytest.mark.parametrize("hq,hk,d,theta,rotary_dim", QK_ROPE_SHAPES)
+def test_qk_rope_lowers_for_tpu_inside_shard_map(hq, hk, d, theta, rotary_dim):
+    """Both kernels' outputs declare how they vary over the mesh, the scales
+    cast to varying as the trainer casts parameters."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    args, fns = _qk_rope_fns(hq, hk, d, theta, rotary_dim, bsz=2)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+
+    def worker(q, k, q_scale, k_scale):
+        q_scale, k_scale = (jax.lax.pcast(p, "data", to="varying") for p in (q_scale, k_scale))
+        dq, dk, d_q_scale, d_k_scale = fns["grad"](q, k, q_scale, k_scale)
+        return dq, dk, d_q_scale[None], d_k_scale[None]
+
+    sharded = jax.shard_map(worker, mesh=mesh, in_specs=(P("data"), P("data"), P(), P()), out_specs=P("data"))
+    text = jax.jit(sharded).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 2
+
+
 # --- libtpu's Mosaic compiler, without a chip -------------------------------
 
 
@@ -440,6 +487,64 @@ def test_the_mixer_on_a_chip_takes_the_frames_kernels_once_a_pass(v5e_devices, m
             dims = re.search(r"= \w+\[([\d,]+)\]", line).group(1)
             assert np.prod([int(n) for n in dims.split(",")]) < q_size, line
     assert "gdn.conv" not in hlo  # the conv sits inside the ``in`` pass
+
+
+@pytest.mark.parametrize("hq,hk,d,theta,rotary_dim", QK_ROPE_SHAPES)
+def test_qk_rope_compiles_with_mosaic_under_its_two_names(v5e_devices, hq, hk, d, theta, rotary_dim):
+    """What Mosaic would refuse on the chip (the lane rotations, the masked
+    sums over a lane block's two heads, a lane block cut out of a head of 256,
+    the resident sums, a step's VMEM) it refuses here; each ``pallas_call`` is
+    in the compiled program under its own name."""
+    args, fns = _qk_rope_fns(hq, hk, d, theta, rotary_dim)
+    args = [_on(v5e_devices[0], a) for a in args]
+    assert _custom_calls(jax.jit(fns["forward"]).lower(*args).compile().as_text()) == QK_ROPE_NAMES[:1]
+    assert _custom_calls(jax.jit(fns["grad"]).lower(*args).compile().as_text()) == QK_ROPE_NAMES
+
+
+def _attention_layers():
+    from network_distributed_pytorch_tpu.models import afmoe, lfm2, mellum, qwen3_next
+
+    bf16 = dict(dtype=jnp.bfloat16)
+    yield "trinity-sliding-kept", afmoe.AfmoeAttention(afmoe.AfmoeConfig(**bf16), True, 0.02), False
+    yield "trinity-sliding-recomputed", afmoe.AfmoeAttention(afmoe.AfmoeConfig(**bf16), True, 0.02), True
+    yield "trinity-full-recomputed", afmoe.AfmoeAttention(afmoe.AfmoeConfig(**bf16), False, 0.02), True
+    yield "mellum2-full-yarn-recomputed", mellum.MellumAttention(mellum.MellumConfig(**bf16), mellum.FULL, 0.02), True
+    yield "lfm2-recomputed", lfm2.Lfm2Attention(lfm2.Lfm2Config(**bf16), 0.02), True
+    yield "qwen3next-recomputed", qwen3_next.GatedAttention(qwen3_next.Qwen3NextConfig(**bf16), 0.02), True
+
+
+@pytest.mark.parametrize("layer,remat", [pytest.param(layer, remat, id=name) for name, layer, remat in _attention_layers()])
+def test_an_attention_layer_on_a_chip_takes_the_rope_kernels_once_a_pass(v5e_devices, monkeypatch, layer, remat):
+    """The engagement check: one attention layer of each model at its
+    published widths and T = 8192, its value and gradient compiled for v5e
+    with the backend's own choice, as on the chip. The forward kernel is in the
+    program once a pass (forward, its recomputation where the block is
+    rematerialised), the backward once, beside the flash kernels; and no
+    fusion under ``attn.rope`` reads or writes an array larger than k: what
+    XLA still fuses there is the tables, (T, 128) fp32, and the scales. (Plain
+    copies stay under the scope's reshapes where the kernels' flat layout is
+    not its neighbour's: lfm2's fold of its heads of 64, qwen3next's q cut
+    out of ``q_proj``'s [q | gate] columns.)"""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = layer.config
+    u = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), jnp.float32)
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 64, cfg.hidden_size))))["params"]
+    apply = lambda p, u: layer.apply({"params": p}, u)
+    apply = jax.checkpoint(apply) if remat else apply
+    loss = lambda p, u: jnp.sum(jnp.sin(apply(p, u).astype(jnp.float32)))
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda x: _on(v5e_devices[0], x), tree)
+    hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(on_chip(params), on_chip(u)).compile().as_text()
+    forwards = 2 if remat else 1
+    assert _custom_calls(hlo) == sorted(["flash_attention", "qk_rope"] * forwards + ["flash_attention_bwd", "qk_rope_bwd"])
+    k_size = 8192 * cfg.n_kv_heads * cfg.head_dim
+    instruction = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\((.*?)\)(?:, |$)")
+    elements = lambda types: [int(np.prod([int(n) for n in dims.split(",") if n])) for dims in re.findall(r"\w+\[([\d,]*)\]", types)]
+    parsed = [m.groups() + (line,) for line in hlo.splitlines() if (m := instruction.match(line))]
+    result_of = {name: types for name, types, _, _, _ in parsed}
+    under = [row for row in parsed if "attn.rope" in row[4] and row[2] == "fusion"]  # none where no table is built: trinity's full layer
+    for name, types, _, operands, line in under:
+        touched = elements(types) + [n for operand in re.findall(r"%([\w.\-]+)", operands) for n in elements(result_of.get(operand, ""))]
+        assert max(touched, default=0) <= k_size, line[:300]
 
 
 @pytest.mark.parametrize("shape", ORTHOGONALIZE_SHAPES, ids=str)
