@@ -3,8 +3,8 @@ sliding-window layers with rotary positions beside full layers without,
 gated attention, gated experts (``models/afmoe.py``), one expert-parallel
 rank's share of the experts.
 
-The experiment is ``powersgd_nemotron``'s with another model: the same
-``train_lm`` (``make_train_step`` with ``PowerSGDReducer``, ``train_loop``,
+The experiment is ``experiments/lm.py``'s ``train_lm`` with this model
+(``make_train_step`` with ``PowerSGDReducer``, ``train_loop``,
 packed Zipf ids, the expert layers' counters on every step's
 ``step/loss_sync`` span). Its weights come from a seed, so each expert
 layer's ``expert_bias`` is set where a run in training keeps it: every
@@ -21,11 +21,10 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
-from ..models.afmoe import (
-    BUFFERS, FULL, SLIDING, AfmoeConfig, AfmoeLM, afmoe_tiny, balanced_expert_bias,
-)
+from ..models.afmoe import AfmoeConfig, AfmoeLM, afmoe_tiny
+from ..models.layers import BUFFERS, FULL, SLIDING, balanced_expert_bias
 from ..utils.config import ExperimentConfig
-from .powersgd_nemotron import default_config, model_kwargs, train_lm
+from .lm import default_config, model_kwargs, train_lm
 
 
 def run(

@@ -3,8 +3,8 @@ gated short-convolution mixers, three to one grouped-query attention layer,
 sigmoid-routed gated experts, a head tied to the embedding
 (``models/lfm2.py``), one expert-parallel rank's share of the experts.
 
-The experiment is ``powersgd_nemotron``'s with another model: the same
-``train_lm`` (``make_train_step`` with ``PowerSGDReducer``, ``train_loop``,
+The experiment is ``experiments/lm.py``'s ``train_lm`` with this model
+(``make_train_step`` with ``PowerSGDReducer``, ``train_loop``,
 packed Zipf ids, the expert layers' counters on every step's
 ``step/loss_sync`` span). Each expert layer's ``expert_bias`` stays at its
 published initial value, zeros: with this model's pre-norm blocks weights
@@ -21,9 +21,10 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
-from ..models.lfm2 import CONV, FULL, Lfm2Config, Lfm2LM, lfm2_tiny
+from ..models.layers import FULL
+from ..models.lfm2 import CONV, Lfm2Config, Lfm2LM, lfm2_tiny
 from ..utils.config import ExperimentConfig
-from .powersgd_nemotron import default_config, model_kwargs, train_lm
+from .lm import default_config, model_kwargs, train_lm
 
 
 def run(

@@ -3,8 +3,8 @@ model: sliding-window and YaRN-scaled full attention layers three to one,
 both turned by a rotary embedding, softmax-routed gated experts in every
 layer (``models/mellum.py``), one expert-parallel rank's share of the experts.
 
-The experiment is ``powersgd_nemotron``'s with another model: the same
-``train_lm`` (``make_train_step`` with ``PowerSGDReducer``, ``train_loop``,
+The experiment is ``experiments/lm.py``'s ``train_lm`` with this model
+(``make_train_step`` with ``PowerSGDReducer``, ``train_loop``,
 packed Zipf ids, the expert layers' counters on every step's
 ``step/loss_sync`` span). The model publishes no selection bias and no
 buffer, so the state carries the counters alone and nothing is balanced:
@@ -22,9 +22,10 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
-from ..models.mellum import FULL, SLIDING, MellumConfig, MellumLM, mellum_tiny
+from ..models.layers import FULL, SLIDING
+from ..models.mellum import MellumConfig, MellumLM, mellum_tiny
 from ..utils.config import ExperimentConfig
-from .powersgd_nemotron import default_config, model_kwargs, train_lm
+from .lm import default_config, model_kwargs, train_lm
 
 
 def run(

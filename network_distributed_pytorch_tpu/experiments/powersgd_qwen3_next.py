@@ -3,8 +3,8 @@ Gated-DeltaNet linear-attention layers to one gated full-attention layer,
 softmax-routed experts with a gated shared expert in every layer
 (``models/qwen3_next.py``), one expert-parallel rank's share of the experts.
 
-The experiment is ``powersgd_nemotron``'s with another model: the same
-``train_lm`` (``make_train_step`` with ``PowerSGDReducer``, ``train_loop``,
+The experiment is ``experiments/lm.py``'s ``train_lm`` with this model
+(``make_train_step`` with ``PowerSGDReducer``, ``train_loop``,
 packed Zipf ids, the expert layers' counters on every step's
 ``step/loss_sync`` span). The model has no selection bias and no buffers.
 
@@ -17,9 +17,10 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
-from ..models.qwen3_next import FULL, LINEAR, Qwen3NextConfig, Qwen3NextLM, qwen3_next_tiny
+from ..models.layers import FULL
+from ..models.qwen3_next import LINEAR, Qwen3NextConfig, Qwen3NextLM, qwen3_next_tiny
 from ..utils.config import ExperimentConfig
-from .powersgd_nemotron import default_config, model_kwargs, train_lm
+from .lm import default_config, model_kwargs, train_lm
 
 
 def run(

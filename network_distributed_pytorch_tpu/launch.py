@@ -40,6 +40,7 @@ from .experiments import (
     powersgd_qwen3_next,
     serve_gpt,
 )
+from .experiments.lm import LM_EXPERIMENTS
 from .observe import RawEvent, StreamJsonSink, Telemetry
 from .parallel.mesh import DistributedConfig, initialize_distributed
 from .utils.config import ExperimentConfig
@@ -792,9 +793,7 @@ def main(argv=None) -> dict:
                       spec_k=args.spec_k if args.spec_k is not None else 0)
     elif args.experiment == "bandwidth_study":
         kwargs.update(preset=args.preset)
-    elif args.experiment in (
-        "powersgd_nemotron", "powersgd_afmoe", "powersgd_qwen3_next", "powersgd_lfm2", "powersgd_mellum",
-    ):
+    elif args.experiment in LM_EXPERIMENTS:
         kwargs.update(preset=args.preset,
                       max_steps_per_epoch=args.max_steps_per_epoch)
     elif args.experiment in ("gpt_lm", "gpt_pp", "gpt_sp", "gpt_tp", "gpt_moe"):

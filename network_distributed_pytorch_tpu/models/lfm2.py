@@ -19,11 +19,11 @@ norm is an RMSNorm with a learned scale; no bias anywhere:
 - ``full_attention`` (``ops.flash_attention``), ``n_heads`` query heads over
   ``n_kv_heads`` key/value heads: ``q = RMSNorm_head(W_q u)``, ``k =
   RMSNorm_head(W_k u)``, ``v = W_v u``; q and k turned by the rotary embedding
-  over the whole head in EVERY attention layer (``models/afmoe.rotary``,
+  over the whole head in EVERY attention layer (``models/layers.rotary``,
   theta ``rope_theta``, angles in fp32); causal ``softmax(q k^T /
   sqrt(head_dim)) v``; ``out = W_o o``. No gate, no window.
 - feed-forward: the first ``num_dense_layers`` layers a gated MLP of
-  ``dense_width``, ``W_2 (silu(W_1 u) * W_3 u)`` (``models/afmoe.GatedMLP``);
+  ``dense_width``, ``W_2 (silu(W_1 u) * W_3 u)`` (``models/layers.GatedMLP``);
   the others routed experts (``parallel.moe.held_experts_moe``): ``s =
   sigmoid(u W_r)`` in fp32 over all ``n_routed_experts``, the
   ``experts_per_token`` largest of ``s + expert_bias``, weights ``route_scale
@@ -32,7 +32,7 @@ norm is an RMSNorm with a learned scale; no bias anywhere:
   leaves out what the others would add. Nothing is dropped.
 - ``expert_bias`` is a buffer no gradient reaches (the ``buffers`` collection,
   as ``models/afmoe.py``'s; zeros, the published initial value, where the
-  caller brings none); ``afmoe.balanced_expert_bias`` balances it for weights
+  caller brings none); ``layers.balanced_expert_bias`` balances it for weights
   that come from a seed.
 
 Left out: any auxiliary loss, and the ``+ 1e-6`` HuggingFace adds to the
@@ -44,9 +44,9 @@ stream is carried in it. The router, every norm (q's and k's too) and the
 rotary angles compute in fp32; the two gates and the taps' sum of the short
 convolution compute in ``dtype``, as the depthwise convs of the other mixers
 do. ``remat`` recomputes each block in the backward pass. ``RMSNorm``, the
-projections, the loss and the counters' tree are ``models/nemotron_h.py``'s,
-the gated MLP, the rotary turn and the buffers ``models/afmoe.py``'s:
-``__call__`` returns ``(logits, counters)``.
+projections, the gated MLP, the rotary turn, the buffers, the loss and the
+counters' tree are ``models/layers.py``'s: ``__call__`` returns ``(logits,
+counters)``.
 """
 
 from __future__ import annotations
@@ -59,10 +59,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .afmoe import GatedMLP, Rope, expert_bias_of, normed_and_turned
-from .nemotron_h import RMSNorm, _dense, _kernel, einsum_attention
+from .layers import (
+    FULL, GatedMLP, RMSNorm, Rope, causal_attention, dense, kernel, normed_and_turned, routed_experts, run_layers,
+)
 
-CONV, FULL = "conv", "full_attention"
+CONV = "conv"
 
 
 @dataclass(frozen=True)
@@ -115,7 +116,7 @@ class ShortConv(nn.Module):
 
         cfg = self.config
         u = u32.astype(cfg.dtype)
-        bcz = _dense(cfg, 3 * cfg.hidden_size, cfg.init_std, "in_proj")(u)
+        bcz = dense(cfg, 3 * cfg.hidden_size, cfg.init_std, "in_proj")(u)
         bound = 1.0 / np.sqrt(cfg.conv_kernel)  # torch's Conv1d default for a depthwise kernel
         conv_kernel = self.param(
             "conv_kernel",
@@ -125,7 +126,7 @@ class ShortConv(nn.Module):
         with jax.named_scope("shortconv.mix"):
             b, c, z = jnp.split(bcz, 3, axis=-1)
             y = c * causal_conv1d(b * z, conv_kernel, None)
-        return _dense(cfg, cfg.hidden_size, self.out_std, "out_proj")(y)
+        return dense(cfg, cfg.hidden_size, self.out_std, "out_proj")(y)
 
 
 class Lfm2Attention(nn.Module):
@@ -134,26 +135,19 @@ class Lfm2Attention(nn.Module):
 
     @nn.compact
     def __call__(self, u32):
-        from ..ops.flash_attention import resolve_attn_impl
-
         cfg = self.config
         hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         u = u32.astype(cfg.dtype)
         bsz, t, _ = u.shape
-        q = _dense(cfg, hq * hd, cfg.init_std, "q_proj")(u).reshape(bsz, t, hq, hd)
-        k = _dense(cfg, hkv * hd, cfg.init_std, "k_proj")(u).reshape(bsz, t, hkv, hd)
-        v = _dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
+        q = dense(cfg, hq * hd, cfg.init_std, "q_proj")(u).reshape(bsz, t, hq, hd)
+        k = dense(cfg, hkv * hd, cfg.init_std, "k_proj")(u).reshape(bsz, t, hkv, hd)
+        v = dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
         with jax.named_scope("attn.rope"):
             norms = RMSNorm(cfg.norm_eps, name="q_norm"), RMSNorm(cfg.norm_eps, name="k_norm")
             q, k = normed_and_turned(*norms, q, k, Rope(cfg.rope_theta), cfg.dtype)
         with jax.named_scope("attn.full"):
-            if resolve_attn_impl(cfg.attn_impl) == "flash":
-                from ..ops import flash_attention, pallas_interpret
-
-                ctx = flash_attention(q, k, v, causal=True, interpret=pallas_interpret())
-            else:
-                ctx = einsum_attention(q, k, v)
-        return _dense(cfg, cfg.hidden_size, self.out_std, "o_proj")(ctx.reshape(bsz, t, hq * hd))
+            ctx = causal_attention(cfg, q, k, v)
+        return dense(cfg, cfg.hidden_size, self.out_std, "o_proj")(ctx.reshape(bsz, t, hq * hd))
 
 
 class Lfm2Experts(nn.Module):
@@ -162,23 +156,11 @@ class Lfm2Experts(nn.Module):
 
     @nn.compact
     def __call__(self, u32):
-        from ..parallel.moe import held_experts_moe
-
         cfg = self.config
-        d, f, held = cfg.hidden_size, cfg.expert_width, cfg.held_experts
-        router = self.param("router", _kernel(cfg.init_std), (d, cfg.n_routed_experts))
-        gate = self.param("experts_gate", _kernel(cfg.init_std), (len(held), d, f))
-        up = self.param("experts_up", _kernel(cfg.init_std), (len(held), d, f))
-        down = self.param("experts_down", _kernel(self.out_std), (len(held), f, d))
-        u = u32.astype(cfg.dtype)
-        bsz, t, _ = u.shape
-        tokens32 = u32.reshape(bsz * t, d)
-        expert_bias = expert_bias_of(self, tokens32, router, cfg.experts_per_token)
-        routed, counters = held_experts_moe(
-            u.reshape(bsz * t, d), tokens32, router, expert_bias,
-            up, down, held, cfg.experts_per_token, cfg.route_scale, w_gate=gate,
+        return routed_experts(
+            self, cfg, u32.astype(cfg.dtype), u32, self.out_std,
+            gated=True, score="sigmoid", route_scale=cfg.route_scale, biased=True,
         )
-        return routed.reshape(bsz, t, d), counters
 
 
 class Lfm2Block(nn.Module):
@@ -189,7 +171,7 @@ class Lfm2Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        # as nemotron_h's blocks: each output projection starts 1/sqrt(layers) smaller
+        # rescale_prenorm_residual: each block's output projection starts 1/sqrt(layers) smaller
         out_std = cfg.init_std / np.sqrt(len(cfg.layer_types))
         normed = RMSNorm(cfg.norm_eps, name="operator_norm")(x)
         if self.kind == CONV:
@@ -214,16 +196,12 @@ class Lfm2LM(nn.Module):
         layers' counters of this call."""
         cfg = self.config
         embed = nn.Embed(
-            cfg.vocab_size, cfg.hidden_size, embedding_init=_kernel(cfg.init_std),
+            cfg.vocab_size, cfg.hidden_size, embedding_init=kernel(cfg.init_std),
             dtype=cfg.dtype, name="embed",
         )
         x = embed(input_ids)
-        block = nn.remat(Lfm2Block) if cfg.remat else Lfm2Block
-        counters = {}
-        for i, kind in enumerate(cfg.layer_types):
-            x, layer_counters = block(cfg, kind, i < cfg.num_dense_layers, name=f"layer_{i}")(x)
-            if layer_counters:
-                counters[f"layer_{i}"] = layer_counters
+        kinds = [(kind, i < cfg.num_dense_layers) for i, kind in enumerate(cfg.layer_types)]
+        x, counters = run_layers(Lfm2Block, cfg, kinds, x)
         x = RMSNorm(cfg.norm_eps, name="embedding_norm")(x).astype(cfg.dtype)
         # the tied head: x E^T, the (V, h) leaf contracted over h as it lies
         logits = jax.lax.dot_general(

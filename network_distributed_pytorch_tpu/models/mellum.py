@@ -16,7 +16,7 @@ scale; no bias anywhere:
 - attention (``ops.flash_attention``), ``n_heads`` query heads over
   ``n_kv_heads`` key/value heads: ``q = RMSNorm_head(W_q u)``, ``k =
   RMSNorm_head(W_k u)``, ``v = W_v u``; q and k turned by the rotary embedding
-  over the whole head (``models/afmoe.rotary``, angles, cos and sin in fp32)
+  over the whole head (``models/layers.rotary``, angles, cos and sin in fp32)
   with the layer kind's own ``rope_parameters``: a ``sliding_attention`` layer
   the default frequencies ``theta^(-2i/D)`` and query i sees key j iff ``0 <=
   i - j < sliding_window``; a ``full_attention`` layer YaRN's blended
@@ -41,9 +41,9 @@ auxiliary loss (no ``router_aux_loss_coef``).
 Parameters are fp32; ``dtype`` is what the products run in, and the residual
 stream is carried in it. The router, every norm (q's and k's too) and the
 rotary frequencies, angles, cos and sin compute in fp32. ``remat`` recomputes
-each block in the backward pass. ``RMSNorm``, the projections, the loss and
-the counters' tree are ``models/nemotron_h.py``'s, the rotary turn
-``models/afmoe.py``'s: ``__call__`` returns ``(logits, counters)``. The model
+each block in the backward pass. ``RMSNorm``, the projections, the rotary
+turn, the loss and the counters' tree are ``models/layers.py``'s:
+``__call__`` returns ``(logits, counters)``. The model
 has no buffers: ``TrainState.model_state`` carries the counters only.
 """
 
@@ -57,8 +57,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .afmoe import FULL, SLIDING, Rope, normed_and_turned
-from .nemotron_h import RMSNorm, _dense, _kernel, einsum_attention
+from .layers import (
+    FULL, SLIDING, RMSNorm, Rope, causal_attention, dense, kernel, normed_and_turned, routed_experts, run_layers,
+)
 
 
 # The embedding starts at unit scale where every kernel starts at ``init_std``. With both at 0.02 a
@@ -117,29 +118,22 @@ class MellumAttention(nn.Module):
 
     @nn.compact
     def __call__(self, u32):
-        from ..ops.flash_attention import resolve_attn_impl
-
         cfg = self.config
         hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         sliding = self.kind == SLIDING
         window = cfg.sliding_window if sliding else None
         u = u32.astype(cfg.dtype)
         bsz, t, _ = u.shape
-        q = _dense(cfg, hq * hd, cfg.init_std, "q_proj")(u).reshape(bsz, t, hq, hd)
-        k = _dense(cfg, hkv * hd, cfg.init_std, "k_proj")(u).reshape(bsz, t, hkv, hd)
-        v = _dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
+        q = dense(cfg, hq * hd, cfg.init_std, "q_proj")(u).reshape(bsz, t, hq, hd)
+        k = dense(cfg, hkv * hd, cfg.init_std, "k_proj")(u).reshape(bsz, t, hkv, hd)
+        v = dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
         with jax.named_scope("attn.rope"):
             rope = cfg.rope_sliding if sliding else cfg.rope_full  # both kinds turn, each by its own frequencies
             norms = RMSNorm(cfg.norm_eps, name="q_norm"), RMSNorm(cfg.norm_eps, name="k_norm")
             q, k = normed_and_turned(*norms, q, k, rope, cfg.dtype)
         with jax.named_scope("attn.window" if sliding else "attn.full"):
-            if resolve_attn_impl(cfg.attn_impl) == "flash":
-                from ..ops import flash_attention, pallas_interpret
-
-                ctx = flash_attention(q, k, v, causal=True, window=window, interpret=pallas_interpret())
-            else:
-                ctx = einsum_attention(q, k, v, window)
-        return _dense(cfg, cfg.hidden_size, self.out_std, "o_proj")(ctx.reshape(bsz, t, hq * hd))
+            ctx = causal_attention(cfg, q, k, v, window)
+        return dense(cfg, cfg.hidden_size, self.out_std, "o_proj")(ctx.reshape(bsz, t, hq * hd))
 
 
 class MellumExperts(nn.Module):
@@ -148,22 +142,11 @@ class MellumExperts(nn.Module):
 
     @nn.compact
     def __call__(self, u32):
-        from ..parallel.moe import held_experts_moe
-
         cfg = self.config
-        d, f, held = cfg.hidden_size, cfg.expert_width, cfg.held_experts
-        router = self.param("router", _kernel(cfg.init_std), (d, cfg.n_routed_experts))
-        gate = self.param("experts_gate", _kernel(cfg.init_std), (len(held), d, f))
-        up = self.param("experts_up", _kernel(cfg.init_std), (len(held), d, f))
-        down = self.param("experts_down", _kernel(self.out_std), (len(held), f, d))
-        u = u32.astype(cfg.dtype)
-        bsz, t, _ = u.shape
-        routed, counters = held_experts_moe(
-            u.reshape(bsz * t, d), u32.reshape(bsz * t, d), router,
-            jnp.zeros((cfg.n_routed_experts,), jnp.float32),  # the model publishes no selection bias
-            up, down, held, cfg.experts_per_token, w_gate=gate, score="softmax",
+        # softmax scores, top k renormalised, no scaling factor; the model publishes no selection bias
+        return routed_experts(
+            self, cfg, u32.astype(cfg.dtype), u32, self.out_std, gated=True, score="softmax", route_scale=1.0, biased=False,
         )
-        return routed.reshape(bsz, t, d), counters
 
 
 class MellumBlock(nn.Module):
@@ -173,7 +156,7 @@ class MellumBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        # as nemotron_h's blocks: each output projection starts 1/sqrt(layers) smaller
+        # rescale_prenorm_residual: each block's output projection starts 1/sqrt(layers) smaller
         out_std = cfg.init_std / np.sqrt(len(cfg.layer_types))
         normed = RMSNorm(cfg.norm_eps, name="input_layernorm")(x)
         x = x + MellumAttention(cfg, self.kind, out_std, name="self_attn")(normed).astype(x.dtype)
@@ -191,15 +174,12 @@ class MellumLM(nn.Module):
         layers' counters of this call."""
         cfg = self.config
         x = nn.Embed(
-            cfg.vocab_size, cfg.hidden_size, embedding_init=_kernel(EMBED_STD),
+            cfg.vocab_size, cfg.hidden_size, embedding_init=kernel(EMBED_STD),
             dtype=cfg.dtype, name="embed",
         )(input_ids)
-        block = nn.remat(MellumBlock) if cfg.remat else MellumBlock
-        counters = {}
-        for i, kind in enumerate(cfg.layer_types):
-            x, counters[f"layer_{i}"] = block(cfg, kind, name=f"layer_{i}")(x)
+        x, counters = run_layers(MellumBlock, cfg, [(kind,) for kind in cfg.layer_types], x)
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x).astype(cfg.dtype)
-        head = self.param("head", _kernel(cfg.init_std), (cfg.hidden_size, cfg.vocab_size))
+        head = self.param("head", kernel(cfg.init_std), (cfg.hidden_size, cfg.vocab_size))
         logits = jnp.dot(x, head.astype(cfg.dtype), preferred_element_type=jnp.float32)
         return logits, counters
 

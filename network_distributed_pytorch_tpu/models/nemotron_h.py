@@ -47,6 +47,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .layers import RMSNorm, causal_attention, dense, kernel, routed_experts, run_layers
+from .layers import next_token_lm_loss, zero_counters  # noqa: F401  benchmark/builders and references read them here
+
 
 @dataclass(frozen=True)
 class NemotronHConfig:
@@ -96,48 +99,6 @@ class NemotronHConfig:
         return tuple(i for i, kind in enumerate(self.pattern) if kind == "E")
 
 
-def _kernel(std: float):
-    return nn.initializers.normal(stddev=std)
-
-
-def _dense(cfg, width: int, std: float, name: str) -> nn.Dense:
-    """Every projection of the model (and of ``models/afmoe.py``): no bias,
-    products in ``cfg.dtype``."""
-    return nn.Dense(width, use_bias=False, dtype=cfg.dtype, kernel_init=_kernel(std), name=name)
-
-
-class RMSNorm(nn.Module):
-    eps: float
-    zero_centred: bool = False  # the learned scale is 1 + w, w from zero (``models/qwen3_next.py``)
-
-    @nn.compact
-    def __call__(self, x, scale_alone: bool = False):
-        """fp32 in, fp32 out: callers cast to what their products take. With
-        ``scale_alone`` what multiplies the normed ``x`` and no arithmetic on
-        it, for a caller whose kernel norms (``models/afmoe.normed_and_turned``)."""
-        init = nn.initializers.zeros if self.zero_centred else nn.initializers.ones
-        scale = self.param("scale", init, (x.shape[-1],))
-        if self.zero_centred:
-            scale = 1.0 + scale
-        if scale_alone:
-            return scale
-        x = x.astype(jnp.float32)
-        return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps) * scale
-
-
-def einsum_attention(q, k, v, window: int = None):
-    """Causal grouped-query attention with the weights materialised, the
-    engine off the TPU: q (B, T, H, D), k and v (B, T, Hkv, D) repeated to H
-    heads; with ``window``, query i sees key j iff ``0 <= i - j < window``."""
-    t, hd = q.shape[1], q.shape[-1]
-    k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2) for x in (k, v))
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
-    behind = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]  # query - key
-    seen = (behind >= 0) & (behind < (window or t))
-    weights = jax.nn.softmax(jnp.where(seen, scores / np.sqrt(hd), -jnp.inf), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", weights.astype(q.dtype), v)
-
-
 class Mamba2Mixer(nn.Module):
     config: NemotronHConfig
     out_std: float
@@ -150,7 +111,7 @@ class Mamba2Mixer(nn.Module):
         h, p, g, n = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups, cfg.state_size
         d_inner, conv_dim = cfg.d_inner, cfg.d_inner + 2 * g * n
         u = u32.astype(cfg.dtype)
-        zxbcdt = _dense(cfg, d_inner + conv_dim + h, cfg.init_std, "in_proj")(u)
+        zxbcdt = dense(cfg, d_inner + conv_dim + h, cfg.init_std, "in_proj")(u)
         z, xbc, dt = jnp.split(zxbcdt, [d_inner, d_inner + conv_dim], axis=-1)
 
         def dt_bias_init(key, shape):
@@ -186,7 +147,7 @@ class Mamba2Mixer(nn.Module):
                 b.reshape(bsz, t, g, n), c.reshape(bsz, t, g, n), d, cfg.chunk_size,
             )
         y = gated_group_rms_norm(y.reshape(bsz, t, d_inner), z, norm_scale, g, cfg.norm_eps)
-        return _dense(cfg, cfg.hidden_size, self.out_std, "out_proj")(y)
+        return dense(cfg, cfg.hidden_size, self.out_std, "out_proj")(y)
 
 
 class ExpertsMixer(nn.Module):
@@ -195,25 +156,18 @@ class ExpertsMixer(nn.Module):
 
     @nn.compact
     def __call__(self, u32):
-        from ..parallel.moe import held_experts_moe, relu_squared
+        from ..parallel.moe import relu_squared
 
         cfg = self.config
-        d, f, held = cfg.hidden_size, cfg.expert_width, cfg.held_experts
-        router = self.param("router", _kernel(cfg.init_std), (d, cfg.n_routed_experts))
-        experts_in = self.param("experts_in", _kernel(cfg.init_std), (len(held), d, f))
-        experts_out = self.param("experts_out", _kernel(self.out_std), (len(held), f, d))
         u = u32.astype(cfg.dtype)
-        bsz, t, _ = u.shape
-        routed, counters = held_experts_moe(
-            u.reshape(bsz * t, d), u32.reshape(bsz * t, d), router,
-            # e_score_correction_bias: a buffer the optimizer never touches, zeros here
-            jnp.zeros((cfg.n_routed_experts,), jnp.float32),
-            experts_in, experts_out, held, cfg.experts_per_token, cfg.routed_scaling,
+        # e_score_correction_bias is a buffer the optimizer never touches, zeros here: not biased
+        routed, counters = routed_experts(
+            self, cfg, u, u32, self.out_std, gated=False, score="sigmoid", route_scale=cfg.routed_scaling, biased=False,
         )
         with jax.named_scope("moe.shared"):
-            hidden = relu_squared(_dense(cfg, cfg.shared_expert_width, cfg.init_std, "shared_in")(u))
-            shared = _dense(cfg, d, self.out_std, "shared_out")(hidden)
-        return routed.reshape(bsz, t, d) + shared, counters
+            hidden = relu_squared(dense(cfg, cfg.shared_expert_width, cfg.init_std, "shared_in")(u))
+            shared = dense(cfg, cfg.hidden_size, self.out_std, "shared_out")(hidden)
+        return routed + shared, counters
 
 
 class GroupedQueryAttention(nn.Module):
@@ -222,25 +176,16 @@ class GroupedQueryAttention(nn.Module):
 
     @nn.compact
     def __call__(self, u32):
-        from ..ops.flash_attention import resolve_attn_impl
-
         cfg = self.config
         hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         u = u32.astype(cfg.dtype)
         bsz, t, _ = u.shape
-        q = _dense(cfg, hq * hd, cfg.init_std, "q_proj")(u).reshape(bsz, t, hq, hd)
-        k = _dense(cfg, hkv * hd, cfg.init_std, "k_proj")(u).reshape(bsz, t, hkv, hd)
-        v = _dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
+        q = dense(cfg, hq * hd, cfg.init_std, "q_proj")(u).reshape(bsz, t, hq, hd)
+        k = dense(cfg, hkv * hd, cfg.init_std, "k_proj")(u).reshape(bsz, t, hkv, hd)
+        v = dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
         with jax.named_scope("attn.core"):
-            # each key/value head serves n_heads // n_kv_heads query heads
-            if resolve_attn_impl(cfg.attn_impl) == "flash":
-                from ..ops import flash_attention, pallas_interpret
-
-                # the kernels read the shared head in place
-                ctx = flash_attention(q, k, v, causal=True, interpret=pallas_interpret())
-            else:
-                ctx = einsum_attention(q, k, v)
-        return _dense(cfg, cfg.hidden_size, self.out_std, "o_proj")(ctx.reshape(bsz, t, hq * hd))
+            ctx = causal_attention(cfg, q, k, v)
+        return dense(cfg, cfg.hidden_size, self.out_std, "o_proj")(ctx.reshape(bsz, t, hq * hd))
 
 
 class NemotronHBlock(nn.Module):
@@ -272,51 +217,14 @@ class NemotronHLM(nn.Module):
         layers' counters of this call."""
         cfg = self.config
         x = nn.Embed(
-            cfg.vocab_size, cfg.hidden_size, embedding_init=_kernel(cfg.init_std),
+            cfg.vocab_size, cfg.hidden_size, embedding_init=kernel(cfg.init_std),
             dtype=cfg.dtype, name="embed",
         )(input_ids)
-        block = nn.remat(NemotronHBlock) if cfg.remat else NemotronHBlock
-        counters = {}
-        for i, kind in enumerate(cfg.pattern):
-            x, layer_counters = block(cfg, kind, name=f"layer_{i}")(x)
-            if layer_counters:
-                counters[f"layer_{i}"] = layer_counters
+        x, counters = run_layers(NemotronHBlock, cfg, [(kind,) for kind in cfg.pattern], x)
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x).astype(cfg.dtype)
-        head = self.param("head", _kernel(cfg.init_std), (cfg.hidden_size, cfg.vocab_size))
+        head = self.param("head", kernel(cfg.init_std), (cfg.hidden_size, cfg.vocab_size))
         logits = jnp.dot(x, head.astype(cfg.dtype), preferred_element_type=jnp.float32)
         return logits, counters
-
-
-def zero_counters(config) -> Dict[str, Dict[str, jax.Array]]:
-    """The counters' tree before the first step: what ``init_state`` takes.
-    ``config`` names its ``expert_layers`` and ``held_experts`` (this model's
-    or any that calls ``held_experts_moe``, whose counters these are)."""
-    zero = lambda *shape: jnp.zeros(shape, jnp.int32)
-    return {
-        f"layer_{i}": {
-            "held": zero(len(config.held_experts)), "absent": zero(), "dropped": zero(), "row_tiles": zero(),
-        }
-        for i in config.expert_layers
-    }
-
-
-def next_token_lm_loss(model):
-    """The trainer's loss function: mean next-token cross-entropy of fp32
-    logits (``labels`` already shifted by the data), the expert layers'
-    counters handed on as model state under ``STEP_COUNTERS``; whatever
-    else the model state holds is the model's other variable collections
-    (``models/afmoe.py``'s ``buffers``) and goes to it unchanged."""
-    from ..parallel.trainer import STEP_COUNTERS
-
-    def loss_fn(params, model_state, batch):
-        others = {k: v for k, v in model_state.items() if k != STEP_COUNTERS}
-        logits, counters = model.apply({"params": params, **others}, batch["input_ids"])
-        # logsumexp minus the label's logit: no (B, T, vocab) array of log-probabilities
-        picked = jnp.take_along_axis(logits, batch["labels"][..., None], axis=-1)[..., 0]
-        loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) - picked)
-        return loss, {**model_state, STEP_COUNTERS: counters}
-
-    return loss_fn
 
 
 def nemotron_h_tiny(**overrides) -> NemotronHLM:

@@ -22,7 +22,7 @@ from zero:
 - ``full_attention`` (``ops.flash_attention``): ``[q | gate] = u W_q`` (one
   leaf; per head its q then its gate), ``k``, ``v``; ``q`` and ``k`` normed per
   head, then the first ``partial_rotary_factor`` of each head turned by the
-  rotary embedding (``models/afmoe.rotary``); causal, ``n_heads`` query heads
+  rotary embedding (``models/layers.rotary``); causal, ``n_heads`` query heads
   over ``n_kv_heads`` key/value heads; ``out = W_o (o * sigmoid(gate))``.
 - experts (``parallel.moe.held_experts_moe``): ``p = softmax(u W_r)`` in fp32
   over all ``n_routed_experts``, the ``experts_per_token`` largest, weights
@@ -36,9 +36,9 @@ Left out: the multi-token-prediction head (HuggingFace's
 Parameters are fp32; ``dtype`` is what the products run in, and the residual
 stream is carried in it. The router, every norm, ``beta``, ``g``, the l2
 norms, the rotary angles and both sigmoid gates compute in fp32. ``remat``
-recomputes each block in the backward pass. The projections, the loss and
-the counters' tree are ``models/nemotron_h.py``'s, the gated MLP and the
-rotary turn ``models/afmoe.py``'s: ``__call__`` returns ``(logits, counters)``.
+recomputes each block in the backward pass. The projections, the norm, the
+gated MLP, the rotary turn, the loss and the counters' tree are
+``models/layers.py``'s: ``__call__`` returns ``(logits, counters)``.
 """
 
 from __future__ import annotations
@@ -52,10 +52,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .afmoe import GatedMLP, Rope, normed_and_turned
-from .nemotron_h import RMSNorm, _dense, _kernel, einsum_attention
+from .layers import (
+    FULL, GatedMLP, RMSNorm, Rope, causal_attention, dense, kernel, normed_and_turned, routed_experts, run_layers,
+)
 
-LINEAR, FULL = "linear_attention", "full_attention"
+LINEAR = "linear_attention"
 A_FLOOR = 1e-4  # A = max(U(0, 16), A_FLOOR): HuggingFace takes log U(0, 16), -inf at a draw of 0
 
 
@@ -126,8 +127,8 @@ class GatedDeltaNet(nn.Module):
         u = u32.astype(cfg.dtype)
         bsz, t, _ = u.shape
         # HuggingFace's grouped column order: a key head's q, k, then its r value heads' v and z
-        qkvz = _dense(cfg, 2 * key_dim + 2 * value_dim, cfg.init_std, "in_proj_qkvz")(u)
-        ba = _dense(cfg, 2 * hv, cfg.init_std, "in_proj_ba")(u)
+        qkvz = dense(cfg, 2 * key_dim + 2 * value_dim, cfg.init_std, "in_proj_qkvz")(u)
+        ba = dense(cfg, 2 * hv, cfg.init_std, "in_proj_ba")(u)
         b, a = jnp.split(ba.reshape(bsz, t, hk, 2 * r), 2, axis=-1)
         flat = lambda x: x.reshape(bsz, t, -1)
 
@@ -149,7 +150,7 @@ class GatedDeltaNet(nn.Module):
         # conv, silu and the l2 norms, the rule, the gated norm: on TPU a Pallas pass each side of the rule
         rule = functools.partial(gated_delta_rule, chunk=cfg.chunk_size)
         o = framed_rule(rule, qkvz, conv_kernel, norm_scale, g, beta, cfg.norm_eps, hk, r, dk, dv)
-        return _dense(cfg, cfg.hidden_size, self.out_std, "out_proj")(o)
+        return dense(cfg, cfg.hidden_size, self.out_std, "out_proj")(o)
 
 
 class GatedAttention(nn.Module):
@@ -158,28 +159,21 @@ class GatedAttention(nn.Module):
 
     @nn.compact
     def __call__(self, u32):
-        from ..ops.flash_attention import resolve_attn_impl
-
         cfg = self.config
         hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         u = u32.astype(cfg.dtype)
         bsz, t, _ = u.shape
         # the output gate lives inside q_proj: per head its q, then its gate
-        q, gate = jnp.split(_dense(cfg, 2 * hq * hd, cfg.init_std, "q_proj")(u).reshape(bsz, t, hq, 2 * hd), 2, axis=-1)
-        k = _dense(cfg, hkv * hd, cfg.init_std, "k_proj")(u).reshape(bsz, t, hkv, hd)
-        v = _dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
+        q, gate = jnp.split(dense(cfg, 2 * hq * hd, cfg.init_std, "q_proj")(u).reshape(bsz, t, hq, 2 * hd), 2, axis=-1)
+        k = dense(cfg, hkv * hd, cfg.init_std, "k_proj")(u).reshape(bsz, t, hkv, hd)
+        v = dense(cfg, hkv * hd, cfg.init_std, "v_proj")(u).reshape(bsz, t, hkv, hd)
         with jax.named_scope("attn.rope"):
             norms = _norm(cfg, "q_norm"), _norm(cfg, "k_norm")
             q, k = normed_and_turned(*norms, q, k, Rope(cfg.rope_theta), cfg.dtype, cfg.rotary_dim)
         with jax.named_scope("attn.full"):
-            if resolve_attn_impl(cfg.attn_impl) == "flash":
-                from ..ops import flash_attention, pallas_interpret
-
-                ctx = flash_attention(q, k, v, causal=True, interpret=pallas_interpret())
-            else:
-                ctx = einsum_attention(q, k, v)
+            ctx = causal_attention(cfg, q, k, v)
         gated = ctx.reshape(bsz, t, hq * hd) * jax.nn.sigmoid(gate.reshape(bsz, t, hq * hd).astype(jnp.float32))
-        return _dense(cfg, cfg.hidden_size, self.out_std, "o_proj")(gated.astype(cfg.dtype))
+        return dense(cfg, cfg.hidden_size, self.out_std, "o_proj")(gated.astype(cfg.dtype))
 
 
 class Qwen3NextExperts(nn.Module):
@@ -188,27 +182,18 @@ class Qwen3NextExperts(nn.Module):
 
     @nn.compact
     def __call__(self, u32):
-        from ..parallel.moe import held_experts_moe
-
         cfg = self.config
-        d, f, held = cfg.hidden_size, cfg.expert_width, cfg.held_experts
-        router = self.param("router", _kernel(cfg.init_std), (d, cfg.n_routed_experts))
-        gate = self.param("experts_gate", _kernel(cfg.init_std), (len(held), d, f))
-        up = self.param("experts_up", _kernel(cfg.init_std), (len(held), d, f))
-        down = self.param("experts_down", _kernel(self.out_std), (len(held), f, d))
-        shared_gate = self.param("shared_gate", _kernel(cfg.init_std), (d,))
         u = u32.astype(cfg.dtype)
-        bsz, t, _ = u.shape
-        routed, counters = held_experts_moe(
-            u.reshape(bsz * t, d), u32.reshape(bsz * t, d), router,
-            jnp.zeros((cfg.n_routed_experts,), jnp.float32),  # the model has no selection bias
-            up, down, held, cfg.experts_per_token, w_gate=gate, score="softmax",
+        # softmax scores, top k renormalised, no scaling factor; the model has no selection bias
+        routed, counters = routed_experts(
+            self, cfg, u, u32, self.out_std, gated=True, score="softmax", route_scale=1.0, biased=False,
         )
+        shared_gate = self.param("shared_gate", kernel(cfg.init_std), (cfg.hidden_size,))
         with jax.named_scope("moe.shared"):
             shared = GatedMLP(cfg, cfg.shared_expert_width, self.out_std, name="shared")(u)
             opened = jax.nn.sigmoid(jnp.dot(u32.astype(jnp.float32), shared_gate, precision=jax.lax.Precision.HIGHEST))
             shared = (shared * opened[..., None]).astype(cfg.dtype)
-        return routed.reshape(bsz, t, d) + shared, counters
+        return routed + shared, counters
 
 
 class Qwen3NextBlock(nn.Module):
@@ -218,7 +203,7 @@ class Qwen3NextBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        # as nemotron_h's blocks: each output projection starts 1/sqrt(layers) smaller
+        # rescale_prenorm_residual: each block's output projection starts 1/sqrt(layers) smaller
         out_std = cfg.init_std / np.sqrt(len(cfg.layer_types))
         normed = _norm(cfg, "input_layernorm")(x)
         if self.kind == LINEAR:
@@ -239,15 +224,12 @@ class Qwen3NextLM(nn.Module):
         layers' counters of this call."""
         cfg = self.config
         x = nn.Embed(
-            cfg.vocab_size, cfg.hidden_size, embedding_init=_kernel(cfg.init_std),
+            cfg.vocab_size, cfg.hidden_size, embedding_init=kernel(cfg.init_std),
             dtype=cfg.dtype, name="embed",
         )(input_ids)
-        block = nn.remat(Qwen3NextBlock) if cfg.remat else Qwen3NextBlock
-        counters = {}
-        for i, kind in enumerate(cfg.layer_types):
-            x, counters[f"layer_{i}"] = block(cfg, kind, name=f"layer_{i}")(x)
+        x, counters = run_layers(Qwen3NextBlock, cfg, [(kind,) for kind in cfg.layer_types], x)
         x = _norm(cfg, "final_norm")(x).astype(cfg.dtype)
-        head = self.param("head", _kernel(cfg.init_std), (cfg.hidden_size, cfg.vocab_size))
+        head = self.param("head", kernel(cfg.init_std), (cfg.hidden_size, cfg.vocab_size))
         logits = jnp.dot(x, head.astype(cfg.dtype), preferred_element_type=jnp.float32)
         return logits, counters
 

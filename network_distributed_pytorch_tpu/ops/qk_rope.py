@@ -1,7 +1,7 @@
 """The per-head RMSNorm of q and k and their rotary turn as one pass: on TPU
 one Pallas kernel forward and one backward under one ``jax.custom_vjp``, q and
 k in one call. The XLA lines it stands in for are the models' own
-(``models/afmoe.normed_and_turned``: ``RMSNorm``, ``rotary``, the cast), which
+(``models/layers.normed_and_turned``: ``RMSNorm``, ``rotary``, the cast), which
 run off the TPU and for shapes :func:`serves` declines.
 
 Why a kernel: the work is elementwise and a trinity layer's 151 MB of reads
@@ -17,7 +17,7 @@ a step), none of it arithmetic the chip is short of:
   its other half by a lane rotation in VMEM;
 - cos and sin were evaluated inside the fusions. Here they come as two fp32
   tables (T, rotary_dim / 2), built once a call by the caller from the
-  embedding's own frequencies (``models/afmoe.rope_tables``), and the kernel
+  embedding's own frequencies (``models/layers.rope_tables``), and the kernel
   reads a T tile of them laid over a lane block (:func:`_lane_tables`).
 
 Grid (batch, tile of T, group of heads): a step takes the key heads of one

@@ -16,12 +16,12 @@ Which layer is which:
   ``lax.ragged_dot`` elsewhere). No capacity and no drops, no array of size
   tokens x experts x capacity, no padding between experts; what absent
   experts would add is left out (another rank's part). The experts are relu² (two stacked
-  leaves) or gated silu (three), told apart by the operands. Used by
-  ``models/nemotron_h`` (ungated, top 6 of 128), ``models/afmoe`` (gated, top
-  8 of 128), ``models/qwen3_next`` (gated, softmax scores, top 10 of 512),
-  ``models/lfm2`` (gated, top 4 of 64) and ``models/mellum`` (gated, softmax
-  scores, top 8 of 64: one assignment in eight a held expert's, the heaviest
-  load a rank's share sees, which is what sizes the layer's first chunk).
+  leaves) or gated silu (three), told apart by the operands. Its one caller
+  among the models is ``models/layers.routed_experts``, for ``nemotron_h``
+  (ungated, top 6 of 128), ``afmoe`` (gated, top 8 of 128), ``qwen3_next``
+  (gated, softmax scores, top 10 of 512), ``lfm2`` (gated, top 4 of 64) and
+  ``mellum`` (gated, softmax scores, top 8 of 64: one assignment in eight a
+  held expert's, the heaviest load a rank sees, which sizes the first chunk).
 
 ``switch_moe``, in detail (beyond-parity capability, SURVEY §2.3: EP/MoE
 absent from the reference). TPU-native design:

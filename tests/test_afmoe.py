@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from benchmark.reference import afmoe as reference
-from network_distributed_pytorch_tpu.models.afmoe import AfmoeAttention, AfmoeConfig, Rope, rotary
+from network_distributed_pytorch_tpu.models.afmoe import AfmoeAttention, AfmoeConfig
+from network_distributed_pytorch_tpu.models.layers import Rope, rotary
 from network_distributed_pytorch_tpu.parallel import moe
 from network_distributed_pytorch_tpu.parallel.moe import chunk_rows, held_experts_moe
 
@@ -266,7 +267,8 @@ def test_balanced_expert_bias_gives_every_expert_its_share_and_zeros_are_no_buff
     zeros leave some expert over four times that; the buffers are one (experts,) leaf a layer that no
     gradient reaches; zeros for them and no ``buffers`` at all are one
     program's output."""
-    from network_distributed_pytorch_tpu.models.afmoe import BUFFERS, afmoe_tiny, balanced_expert_bias
+    from network_distributed_pytorch_tpu.models.afmoe import afmoe_tiny
+    from network_distributed_pytorch_tpu.models.layers import BUFFERS, balanced_expert_bias
 
     model = afmoe_tiny(held_experts=tuple(range(16)), remat=True)
     ids = zipf_ids(0, (2, 256))

@@ -17,10 +17,10 @@ import pytest
 from benchmark import cells, compose
 from benchmark.reference import afmoe as reference
 from benchmark.reference import ef_momentum
-from network_distributed_pytorch_tpu.models.afmoe import (
-    BUFFERS, FULL, SLIDING, AfmoeConfig, afmoe_tiny, balanced_expert_bias,
+from network_distributed_pytorch_tpu.models.afmoe import AfmoeConfig, afmoe_tiny
+from network_distributed_pytorch_tpu.models.layers import (
+    BUFFERS, FULL, SLIDING, balanced_expert_bias, next_token_lm_loss, zero_counters,
 )
-from network_distributed_pytorch_tpu.models.nemotron_h import next_token_lm_loss, zero_counters
 from network_distributed_pytorch_tpu.parallel.trainer import STEP_COUNTERS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

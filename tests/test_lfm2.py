@@ -13,9 +13,9 @@ import numpy as np
 import pytest
 
 from benchmark.reference import lfm2 as reference
-from network_distributed_pytorch_tpu.models.afmoe import BUFFERS, balanced_expert_bias
+from network_distributed_pytorch_tpu.models.layers import BUFFERS, FULL, balanced_expert_bias
 from network_distributed_pytorch_tpu.models.lfm2 import (
-    CONV, FULL, Lfm2Attention, Lfm2Config, Lfm2Experts, ShortConv, lfm2_tiny,
+    CONV, Lfm2Attention, Lfm2Config, Lfm2Experts, ShortConv, lfm2_tiny,
 )
 from network_distributed_pytorch_tpu.ops.ssd import causal_conv1d
 from network_distributed_pytorch_tpu.parallel.moe import held_experts_moe
@@ -250,7 +250,7 @@ def zipf_ids(seed, shape, vocab=256):
 
 
 def test_balanced_expert_bias_serves_this_model_as_it_serves_afmoe():
-    """``models/afmoe.balanced_expert_bias`` takes the model it is given: on
+    """``models/layers.balanced_expert_bias`` takes the model it is given: on
     the batch it was balanced on, every expert of every expert layer of an
     ``Lfm2LM`` takes between 0.4 of and twice its share where zeros leave 0.02 to 3.5; the buffers are one
     (experts,) leaf a layer that no gradient reaches; zeros for them (the

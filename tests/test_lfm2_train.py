@@ -19,9 +19,8 @@ import pytest
 from benchmark import cells, compose
 from benchmark.reference import ef_momentum
 from benchmark.reference import lfm2 as reference
-from network_distributed_pytorch_tpu.models.afmoe import BUFFERS, balanced_expert_bias
-from network_distributed_pytorch_tpu.models.lfm2 import CONV, FULL, Lfm2Config, lfm2_tiny
-from network_distributed_pytorch_tpu.models.nemotron_h import next_token_lm_loss, zero_counters
+from network_distributed_pytorch_tpu.models.layers import BUFFERS, FULL, balanced_expert_bias, next_token_lm_loss, zero_counters
+from network_distributed_pytorch_tpu.models.lfm2 import CONV, Lfm2Config, lfm2_tiny
 from network_distributed_pytorch_tpu.parallel.trainer import STEP_COUNTERS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -179,8 +178,8 @@ def _final_hidden(model, params, ids):
     """The model's last hidden states: its logits un-projected by the
     pseudo-inverse would lose precision, so the blocks are applied again here,
     by name, as ``Lfm2LM`` applies them."""
+    from network_distributed_pytorch_tpu.models.layers import RMSNorm
     from network_distributed_pytorch_tpu.models.lfm2 import Lfm2Block
-    from network_distributed_pytorch_tpu.models.nemotron_h import RMSNorm
 
     cfg = model.config
     x = params["embed"]["embedding"][ids]
@@ -203,10 +202,10 @@ def test_three_steps_of_the_experiment_match_algorithm_2_over_the_plain_referenc
     (no buffers: ``expert_bias`` stays zeros on both sides): the three losses (at a learning rate large enough
     that the second and third depend on the updates), and the parameters the
     reference holds after step 1 against the experiment's after its first."""
-    from network_distributed_pytorch_tpu.experiments import powersgd_lfm2, powersgd_nemotron
+    from network_distributed_pytorch_tpu.experiments import lm, powersgd_lfm2
 
     seen = {}
-    real = powersgd_nemotron.train_loop
+    real = lm.train_loop
 
     def spy(step, state, batches, epochs, **kw):
         seen["params0"] = jax.device_get(state.params)
@@ -226,8 +225,8 @@ def test_three_steps_of_the_experiment_match_algorithm_2_over_the_plain_referenc
         seen["losses"] = [r.loss for r in logger.records]
         return state, logger
 
-    monkeypatch.setattr(powersgd_nemotron, "train_loop", spy)
-    config = powersgd_nemotron.default_config()
+    monkeypatch.setattr(lm, "train_loop", spy)
+    config = lm.default_config()
     config.learning_rate, config.reducer_rank, config.log_every, config.seed = 0.05, 2, 0, 7
     out = powersgd_lfm2.run(config, preset="small", max_steps_per_epoch=3)
     assert out["experiment"] == "powersgd_lfm2" and out["steps"] == 3
@@ -326,7 +325,7 @@ def test_the_full_preset_is_the_cells_cut():
     config, and the parameter count the file states, from shapes (nothing is
     placed or run here)."""
     from benchmark.builders import lfm2 as builder
-    from network_distributed_pytorch_tpu.experiments import powersgd_lfm2, powersgd_nemotron
+    from network_distributed_pytorch_tpu.experiments import lm, powersgd_lfm2
 
     cell = cells.cell(CELL)
     cfg = compose.resolved(cell["config"], cell["workload"], rehearsal=False)
@@ -339,7 +338,7 @@ def test_the_full_preset_is_the_cells_cut():
 
     real, powersgd_lfm2.train_lm = powersgd_lfm2.train_lm, capture
     try:
-        config = powersgd_nemotron.default_config()
+        config = lm.default_config()
         config.compute_dtype = "bfloat16"
         powersgd_lfm2.run(config, preset="full")
     finally:
@@ -355,7 +354,7 @@ def test_the_full_preset_is_the_cells_cut():
 
 
 def bf16_angles(x, rope, rotary_dim=None):
-    """``models/afmoe.rotary`` with its angles, cos and sin computed in bf16:
+    """``models/layers.rotary`` with its angles, cos and sin computed in bf16:
     what the configuration says is fp32, a precision lower."""
     t, d, theta = x.shape[1], x.shape[-1], rope.theta
     low = jnp.bfloat16
@@ -377,7 +376,7 @@ def test_rotary_angles_in_bfloat16_show_in_attentions_gradients(monkeypatch):
     control turned the cell's ``correct`` to false by the memories' limit
     alone (q_proj 0.385 and k_proj 0.390 against 0.30; my chip run, PR 41)."""
     from benchmark.reference_check import TOLERANCES
-    from network_distributed_pytorch_tpu.models import afmoe
+    from network_distributed_pytorch_tpu.models import layers
 
     model = lfm2_tiny(layer_types=(CONV, FULL), rope_theta=1e6)
     ids = jax.random.randint(jax.random.PRNGKey(1), (1, 2049), 0, 256)
@@ -388,7 +387,7 @@ def test_rotary_angles_in_bfloat16_show_in_attentions_gradients(monkeypatch):
     loss = next_token_lm_loss(model)
     as_built = jax.grad(lambda p: loss(p, {}, batch)[0])(params)
     assert worst_relative(attention(as_built), attention(want)) < 1e-3
-    monkeypatch.setattr(afmoe, "rotary", bf16_angles)  # where the layers' XLA lines look it up
+    monkeypatch.setattr(layers, "rotary", bf16_angles)  # where the layers' XLA lines look it up
     lowered = jax.grad(lambda p: loss(p, {}, batch)[0])(params)
     assert 0.1 < worst_relative(attention(lowered), attention(want)) < TOLERANCES["update_each"]
 

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import mellum as reference
-from network_distributed_pytorch_tpu.models.afmoe import FULL, SLIDING, Rope, rope_frequencies, rotary
+from network_distributed_pytorch_tpu.models.layers import FULL, SLIDING, Rope, rope_frequencies, rotary
 from network_distributed_pytorch_tpu.models.mellum import MellumAttention, MellumConfig, MellumExperts
 from network_distributed_pytorch_tpu.parallel.moe import chunk_rows, held_experts_moe
 
@@ -80,7 +80,7 @@ def test_yarn_frequencies_at_a_toy_size():
 
 
 def rotary_before_pr_44(x, theta):
-    """``models/afmoe.rotary`` as afmoe, qwen3_next and lfm2 called it before
+    """``models/layers.rotary`` as afmoe, qwen3_next and lfm2 called it before
     it took a ``Rope``: the lines of the parent commit."""
     t, d = x.shape[1], x.shape[-1]
     inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)

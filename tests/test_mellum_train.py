@@ -20,9 +20,8 @@ import pytest
 from benchmark import cells, compose
 from benchmark.reference import ef_momentum
 from benchmark.reference import mellum as reference
-from network_distributed_pytorch_tpu.models.afmoe import Rope
-from network_distributed_pytorch_tpu.models.mellum import FULL, SLIDING, MellumConfig, mellum_tiny
-from network_distributed_pytorch_tpu.models.nemotron_h import next_token_lm_loss, zero_counters
+from network_distributed_pytorch_tpu.models.layers import FULL, SLIDING, Rope, next_token_lm_loss, zero_counters
+from network_distributed_pytorch_tpu.models.mellum import MellumConfig, mellum_tiny
 from network_distributed_pytorch_tpu.parallel.trainer import STEP_COUNTERS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -188,9 +187,9 @@ def test_plain_rotary_in_the_full_layer_shows_in_its_gradients():
 
 
 def bf16_angles(x, rope, rotary_dim=None):
-    """``models/afmoe.rotary`` with its frequencies, angles, cos and sin in
+    """``models/layers.rotary`` with its frequencies, angles, cos and sin in
     bf16: what the configuration says is fp32, a precision lower."""
-    from network_distributed_pytorch_tpu.models.afmoe import rope_frequencies
+    from network_distributed_pytorch_tpu.models.layers import rope_frequencies
 
     t, low = x.shape[1], jnp.bfloat16
     inv_freq, factor = rope_frequencies(rope, x.shape[-1])
@@ -209,13 +208,13 @@ def test_rotary_angles_in_bfloat16_show_in_attentions_gradients(monkeypatch):
     in its configuration file, 0.10: between what its sound runs and what
     this control read on the chip at the published widths, PERF.md section 6,
     PR 44)."""
-    from network_distributed_pytorch_tpu.models import afmoe
+    from network_distributed_pytorch_tpu.models import layers
 
     model, params, batch = long_model_and_batch()
     _, want, _ = reference.make_loss_and_grads(reference_cfg(model.config))(params, {}, batch)
     loss = next_token_lm_loss(model)
     as_built = jax.grad(lambda p: loss(p, {}, batch)[0])(params)
-    monkeypatch.setattr(afmoe, "rotary", bf16_angles)  # where the layers' XLA lines look it up
+    monkeypatch.setattr(layers, "rotary", bf16_angles)  # where the layers' XLA lines look it up
     lowered = jax.grad(lambda p: loss(p, {}, batch)[0])(params)
     for layer in ("layer_0", "layer_1"):
         assert worst_relative(attention_grads(as_built, layer), attention_grads(want, layer)) < 1e-3
@@ -266,10 +265,10 @@ def test_three_steps_of_the_experiment_match_algorithm_2_over_the_plain_referenc
     batches: the three losses (at a learning rate large enough that the second
     and third depend on the updates), and the parameters the reference holds
     after step 1 against the experiment's after its first."""
-    from network_distributed_pytorch_tpu.experiments import powersgd_mellum, powersgd_nemotron
+    from network_distributed_pytorch_tpu.experiments import lm, powersgd_mellum
 
     seen = {}
-    real = powersgd_nemotron.train_loop
+    real = lm.train_loop
 
     def spy(step, state, batches, epochs, **kw):
         seen["params0"] = jax.device_get(state.params)
@@ -289,8 +288,8 @@ def test_three_steps_of_the_experiment_match_algorithm_2_over_the_plain_referenc
         seen["losses"] = [r.loss for r in logger.records]
         return state, logger
 
-    monkeypatch.setattr(powersgd_nemotron, "train_loop", spy)
-    config = powersgd_nemotron.default_config()
+    monkeypatch.setattr(lm, "train_loop", spy)
+    config = lm.default_config()
     config.learning_rate, config.reducer_rank, config.log_every, config.seed = 0.05, 2, 0, 7
     out = powersgd_mellum.run(config, preset="small", max_steps_per_epoch=3)
     assert out["experiment"] == "powersgd_mellum" and out["steps"] == 3
@@ -396,7 +395,7 @@ def test_the_full_preset_is_the_cells_cut():
     config, and the parameter count the file states, from shapes (nothing is
     placed or run here); its expert layer's chunk is 3 T rows."""
     from benchmark.builders import mellum as builder
-    from network_distributed_pytorch_tpu.experiments import powersgd_mellum, powersgd_nemotron
+    from network_distributed_pytorch_tpu.experiments import lm, powersgd_mellum
     from network_distributed_pytorch_tpu.parallel.moe import chunk_rows
 
     cell = cells.cell(CELL)
@@ -410,7 +409,7 @@ def test_the_full_preset_is_the_cells_cut():
 
     real, powersgd_mellum.train_lm = powersgd_mellum.train_lm, capture
     try:
-        config = powersgd_nemotron.default_config()
+        config = lm.default_config()
         config.compute_dtype = "bfloat16"
         powersgd_mellum.run(config, preset="full")
     finally:

@@ -159,7 +159,7 @@ def test_with_the_kernel_the_layer_computes_what_the_indexed_add_computes(monkey
 
 def test_the_counters_tree_is_the_layers_own():
     from network_distributed_pytorch_tpu.models.mellum import mellum_tiny
-    from network_distributed_pytorch_tpu.models.nemotron_h import zero_counters
+    from network_distributed_pytorch_tpu.models.layers import zero_counters
 
     zeros = zero_counters(mellum_tiny().config)
     assert sorted(zeros) == [f"layer_{i}" for i in range(4)]

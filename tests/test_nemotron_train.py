@@ -16,11 +16,8 @@ import pytest
 from benchmark import cells, compose
 from benchmark.reference import ef_momentum
 from benchmark.reference import nemotron_h as reference
-from network_distributed_pytorch_tpu.models.nemotron_h import (
-    NemotronHConfig,
-    nemotron_h_tiny,
-    next_token_lm_loss,
-)
+from network_distributed_pytorch_tpu.models.layers import next_token_lm_loss
+from network_distributed_pytorch_tpu.models.nemotron_h import NemotronHConfig, nemotron_h_tiny
 from network_distributed_pytorch_tpu.parallel.trainer import STEP_COUNTERS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -1,5 +1,5 @@
 """The per-head norm of q and k and their rotary turn as one pass
-(``ops/qk_rope.py``, through ``models/afmoe.normed_and_turned``) on the CPU
+(``ops/qk_rope.py``, through ``models/layers.normed_and_turned``) on the CPU
 at small sizes: the two Pallas kernels in the interpreter against the XLA
 lines the four attention modules had (``RMSNorm``, ``rotary``, the cast) under
 jax's own differentiation of those lines — values and all four cotangents (q,
@@ -26,8 +26,7 @@ import pytest
 
 from benchmark.layer_metrics.passes import pass_of
 from network_distributed_pytorch_tpu.models import afmoe, lfm2, mellum, qwen3_next
-from network_distributed_pytorch_tpu.models.afmoe import Rope, normed_and_turned, rotary
-from network_distributed_pytorch_tpu.models.nemotron_h import RMSNorm
+from network_distributed_pytorch_tpu.models.layers import FULL, SLIDING, RMSNorm, Rope, normed_and_turned, rotary
 from network_distributed_pytorch_tpu.ops import qk_rope
 from network_distributed_pytorch_tpu.utils.hlo_audit import hlo_text_of_compiled
 
@@ -245,10 +244,10 @@ def attention_layers():
     yield "afmoe_sliding", afmoe, afmoe.AfmoeAttention(cfg, True, 0.02)
     yield "afmoe_full_no_positions", afmoe, afmoe.AfmoeAttention(cfg, False, 0.02)
     cfg = mellum.MellumConfig(**SMALL, head_dim=128, sliding_window=16, rope_sliding=Rope(5e5), rope_full=YARN)
-    yield "mellum_sliding", mellum, mellum.MellumAttention(cfg, mellum.SLIDING, 0.02)
-    yield "mellum_full_yarn", mellum, mellum.MellumAttention(cfg, mellum.FULL, 0.02)
+    yield "mellum_sliding", mellum, mellum.MellumAttention(cfg, SLIDING, 0.02)
+    yield "mellum_full_yarn", mellum, mellum.MellumAttention(cfg, FULL, 0.02)
     yield "lfm2_head_64", lfm2, lfm2.Lfm2Attention(lfm2.Lfm2Config(**SMALL, head_dim=64, dense_width=96), 0.02)
-    cfg = qwen3_next.Qwen3NextConfig(**SMALL, head_dim=256, shared_expert_width=32, layer_types=(qwen3_next.FULL,))
+    cfg = qwen3_next.Qwen3NextConfig(**SMALL, head_dim=256, shared_expert_width=32, layer_types=(FULL,))
     yield "qwen3next_quarter_of_256", qwen3_next, qwen3_next.GatedAttention(cfg, 0.02)
 
 
@@ -286,8 +285,8 @@ def test_a_layer_with_the_kernels_is_the_layer_without_them(module, layer, monke
 @pytest.mark.parametrize(
     "module,model",
     [
-        pytest.param(afmoe, afmoe.afmoe_tiny(head_dim=128, remat=True, layer_types=(afmoe.SLIDING, afmoe.FULL)), id="afmoe"),
-        pytest.param(mellum, mellum.mellum_tiny(head_dim=128, remat=True, layer_types=(mellum.SLIDING, mellum.FULL)), id="mellum"),
+        pytest.param(afmoe, afmoe.afmoe_tiny(head_dim=128, remat=True, layer_types=(SLIDING, FULL)), id="afmoe"),
+        pytest.param(mellum, mellum.mellum_tiny(head_dim=128, remat=True, layer_types=(SLIDING, FULL)), id="mellum"),
     ],
 )
 def test_the_kernels_three_passes_are_under_attn_rope(module, model, monkeypatch, tile_of_16):

@@ -13,8 +13,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import qwen3_next as reference
-from network_distributed_pytorch_tpu.models.afmoe import Rope, rotary
-from network_distributed_pytorch_tpu.models.nemotron_h import RMSNorm
+from network_distributed_pytorch_tpu.models.layers import RMSNorm, Rope, rotary
 from network_distributed_pytorch_tpu.models.qwen3_next import (
     GatedAttention, GatedDeltaNet, Qwen3NextConfig, Qwen3NextExperts,
 )

@@ -17,8 +17,8 @@ import pytest
 from benchmark import cells, compose
 from benchmark.reference import ef_momentum
 from benchmark.reference import qwen3_next as reference
-from network_distributed_pytorch_tpu.models.nemotron_h import next_token_lm_loss, zero_counters
-from network_distributed_pytorch_tpu.models.qwen3_next import FULL, LINEAR, Qwen3NextConfig, qwen3_next_tiny
+from network_distributed_pytorch_tpu.models.layers import FULL, next_token_lm_loss, zero_counters
+from network_distributed_pytorch_tpu.models.qwen3_next import LINEAR, Qwen3NextConfig, qwen3_next_tiny
 from network_distributed_pytorch_tpu.parallel.trainer import STEP_COUNTERS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -208,8 +208,7 @@ def test_the_full_preset_is_the_cells_cut():
     """``preset="full"`` builds the configuration file's model: the same
     parameter tree, shape for shape (nothing is placed or run here)."""
     from benchmark.builders import qwen3_next as builder
-    from network_distributed_pytorch_tpu.experiments import powersgd_qwen3_next
-    from network_distributed_pytorch_tpu.experiments import powersgd_nemotron
+    from network_distributed_pytorch_tpu.experiments import lm, powersgd_qwen3_next
 
     cell = cells.cell(CELL)
     cfg = compose.resolved(cell["config"], cell["workload"], rehearsal=False)
@@ -222,7 +221,7 @@ def test_the_full_preset_is_the_cells_cut():
 
     real, powersgd_qwen3_next.train_lm = powersgd_qwen3_next.train_lm, capture
     try:
-        config = powersgd_nemotron.default_config()
+        config = lm.default_config()
         config.compute_dtype = "bfloat16"
         powersgd_qwen3_next.run(config, preset="full")
     finally:

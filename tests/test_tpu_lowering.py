@@ -272,7 +272,7 @@ QK_ROPE_NAMES = ["qk_rope", "qk_rope_bwd"]
 def _qk_rope_fns(hq, hk, d, theta, rotary_dim, bsz=1, t=8192, dtype=jnp.bfloat16):
     """The pass as kernels whatever traces them, with its tables built as the
     models build them, and the gradient of q, k and both scales."""
-    from network_distributed_pytorch_tpu.models.afmoe import Rope, rope_tables
+    from network_distributed_pytorch_tpu.models.layers import Rope, rope_tables
 
     args = [jax.ShapeDtypeStruct((bsz, t, h, d), dtype) for h in (hq, hk)] + [jax.ShapeDtypeStruct((d,), jnp.float32)] * 2
 
@@ -503,12 +503,13 @@ def test_qk_rope_compiles_with_mosaic_under_its_two_names(v5e_devices, hq, hk, d
 
 def _attention_layers():
     from network_distributed_pytorch_tpu.models import afmoe, lfm2, mellum, qwen3_next
+    from network_distributed_pytorch_tpu.models.layers import FULL
 
     bf16 = dict(dtype=jnp.bfloat16)
     yield "trinity-sliding-kept", afmoe.AfmoeAttention(afmoe.AfmoeConfig(**bf16), True, 0.02), False
     yield "trinity-sliding-recomputed", afmoe.AfmoeAttention(afmoe.AfmoeConfig(**bf16), True, 0.02), True
     yield "trinity-full-recomputed", afmoe.AfmoeAttention(afmoe.AfmoeConfig(**bf16), False, 0.02), True
-    yield "mellum2-full-yarn-recomputed", mellum.MellumAttention(mellum.MellumConfig(**bf16), mellum.FULL, 0.02), True
+    yield "mellum2-full-yarn-recomputed", mellum.MellumAttention(mellum.MellumConfig(**bf16), FULL, 0.02), True
     yield "lfm2-recomputed", lfm2.Lfm2Attention(lfm2.Lfm2Config(**bf16), 0.02), True
     yield "qwen3next-recomputed", qwen3_next.GatedAttention(qwen3_next.Qwen3NextConfig(**bf16), 0.02), True
 
