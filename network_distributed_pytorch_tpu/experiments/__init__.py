@@ -19,5 +19,6 @@ from . import (  # noqa: F401
     powersgd_lfm2,
     powersgd_mellum,
     powersgd_nemotron,
+    powersgd_phi4flash,
     powersgd_qwen3_next,
 )

@@ -1,4 +1,4 @@
-"""What the five language-model experiments share (``LM_EXPERIMENTS``):
+"""What the six language-model experiments share (``LM_EXPERIMENTS``):
 ``train_lm``, the experiment without its model, and the config each starts
 from. An experiment's file builds its model and hands it here; none imports
 another.
@@ -27,7 +27,10 @@ from ..utils.config import ExperimentConfig
 from .common import accumulated_batches, powersgd_reducer_kwargs, summarize, train_loop
 
 # the experiments built on train_lm, by their names in launch.EXPERIMENTS
-LM_EXPERIMENTS = ("powersgd_nemotron", "powersgd_afmoe", "powersgd_qwen3_next", "powersgd_lfm2", "powersgd_mellum")
+LM_EXPERIMENTS = (
+    "powersgd_nemotron", "powersgd_afmoe", "powersgd_qwen3_next", "powersgd_lfm2", "powersgd_mellum",
+    "powersgd_phi4flash",
+)
 
 
 def default_config() -> ExperimentConfig:

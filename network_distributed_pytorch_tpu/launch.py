@@ -37,6 +37,7 @@ from .experiments import (
     powersgd_lfm2,
     powersgd_mellum,
     powersgd_nemotron,
+    powersgd_phi4flash,
     powersgd_qwen3_next,
     serve_gpt,
 )
@@ -56,6 +57,7 @@ EXPERIMENTS = {
     "powersgd_qwen3_next": powersgd_qwen3_next.run,
     "powersgd_lfm2": powersgd_lfm2.run,
     "powersgd_mellum": powersgd_mellum.run,
+    "powersgd_phi4flash": powersgd_phi4flash.run,
     "imdb_baseline": imdb_baseline.run,
     "bandwidth_study": bandwidth_study.run,
     "gpt_lm": gpt_lm.run,

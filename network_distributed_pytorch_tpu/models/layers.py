@@ -1,5 +1,5 @@
-"""What more than one of the five language models uses (``nemotron_h``,
-``afmoe``, ``qwen3_next``, ``lfm2``, ``mellum``), between ``parallel/moe.py``
+"""What more than one of the six language models uses (``nemotron_h``, ``afmoe``,
+``qwen3_next``, ``lfm2``, ``mellum``, ``phi4flash``), between ``parallel/moe.py``
 and ``ops/`` below and one file per model above: projections and norms, the
 rotary embedding, the one choice of attention engine, the one caller of
 ``held_experts_moe``, the layer stack, the counters' tree and the loss (every
@@ -281,10 +281,10 @@ def balanced_expert_bias(model, params, input_ids) -> Dict:
 
 
 def run_layers(block_cls, cfg, per_layer_args: Iterable[Sequence], x):
-    """The layer stack inside a model's ``__call__``: layer ``i`` is
-    ``block_cls(cfg, *per_layer_args[i], name="layer_<i>")``, recomputed in
-    the backward pass where ``cfg.remat`` (``jax.checkpoint`` per layer). ->
-    ``(x, {"layer_<i>": its counters})``, a layer without experts left out."""
+    """The layer stack inside a model's ``__call__``: layer ``i`` is ``block_cls(cfg, *per_layer_args[i],
+    name="layer_<i>")``, recomputed in the backward pass where ``cfg.remat`` (``jax.checkpoint`` per layer). ``x`` is
+    what a block takes and returns first: an array, or any pytree (``models/phi4flash.py``: ``(h, memory, cache)``,
+    ``None`` before a source ran). -> ``(x, {"layer_<i>": its counters})``, a layer without experts left out."""
     block = nn.remat(block_cls) if cfg.remat else block_cls
     counters = {}
     for i, args in enumerate(per_layer_args):

@@ -42,8 +42,11 @@ from network_distributed_pytorch_tpu.ops.rows_to_tokens import rows_of_tokens, t
 # attention layer (32 heads of 64 over 8: grouped heads narrower than a lane
 # block, so the fold at T = 8192, one 64-lane head's K and V whole in VMEM),
 # mellum2_psgd16_t8k's sliding layers (trinity's heads, a window of 1024: 8
-# windows, a band two 512-tiles wide), and a head width no lane block serves
-# (the fold)
+# windows, a band two 512-tiles wide), phi4flash_psgd16_t8k's differential
+# attention (the four attentions of a layer as one call: 80 stacked query heads
+# of 64 over 40, so the fold; causal in the full and cross layers, a window of
+# 512 in the sliding one: 16 windows, a band one or two 512-tiles wide), and a
+# head width no lane block serves (the fold)
 FLASH_CASES = [
     pytest.param((16, 256, 12, 64), jnp.bfloat16, False, True, id="distilbert-bf16"),
     pytest.param((16, 256, 12, 64), jnp.float32, False, True, id="distilbert-fp32"),
@@ -57,6 +60,8 @@ FLASH_CASES = [
     pytest.param((1, 8192, 16, 256, 2), jnp.bfloat16, True, False, id="qwen3next-8192-head-256-gqa"),
     pytest.param((1, 8192, 32, 64, 8), jnp.bfloat16, True, False, id="lfm2-8192-head-64-gqa-fold"),
     pytest.param((1, 8192, 32, 128, 4, 1024), jnp.bfloat16, True, False, id="mellum-8192-window-1024"),
+    pytest.param((1, 8192, 80, 64, 40), jnp.bfloat16, True, False, id="phi4flash-8192-stacked-64-fold"),
+    pytest.param((1, 8192, 80, 64, 40, 512), jnp.bfloat16, True, False, id="phi4flash-8192-stacked-64-window-512"),
     pytest.param((4, 512, 3, 64), jnp.bfloat16, False, True, id="fold-3x64"),
 ]
 # P-factor shapes: DistilBERT-base at rank 16 (chip_smoke), GPT-2 at rank 4
@@ -610,6 +615,59 @@ def test_smoke_train_step_compiles_for_four_v5e_chips(v5e_devices, monkeypatch):
     assert "tpu_custom_call" in hlo  # the kernels are in the program
     audit = step.ledger.reconcile(hlo)
     assert audit["exact"] and audit["hlo_collective_count"] > 0, audit
+
+
+def _selective_scan_fns(bsz=1, t=8192, ch=5120, n=16):
+    """phi4flash_psgd16_t8k's one scan: a sequence of 8192, 5120 channels, a state of 16."""
+    from network_distributed_pytorch_tpu.ops.selective_scan import selective_scan
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    struct = jax.ShapeDtypeStruct
+    args = (
+        struct((bsz, t, ch), bf16), struct((bsz, t, ch), f32), struct((ch, n), f32),
+        struct((bsz, t, n), bf16), struct((bsz, t, n), bf16), struct((ch,), f32),
+    )
+    loss = lambda *v: selective_scan(*v).astype(f32).sum()
+    return args, {"forward": selective_scan, "grad": jax.grad(loss, argnums=tuple(range(6)))}
+
+
+@pytest.mark.parametrize("which", ["forward", "grad"])
+def test_selective_scan_compiles_for_v5e_and_never_holds_the_states_of_a_sequence(v5e_devices, which):
+    """At the cell's shape the (T, C, N) states of a sequence are 2.7 GB in
+    fp32: the compiled program's temporaries stay under a third of that,
+    forward and with every cotangent (AOT for v5e read 0.21 and 0.51 GB)."""
+    args, fns = _selective_scan_fns()
+    compiled = jax.jit(fns[which]).lower(*[_on(v5e_devices[0], a) for a in args]).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8192 * 5120 * 16 * 4 / 3
+
+
+def test_the_phi4flash_cuts_step_lowers_for_tpu(monkeypatch):
+    """The benchmark's cut (layers 15-19 at the published widths, T = 8192,
+    bf16, ``remat``) through ``make_train_step`` under PowerSGD rank 16,
+    cross-lowered for TPU as the chip builds it ("auto" resolving to the flash
+    kernels and the Pallas Gram-Schmidt): the three attention layers' kernels
+    are in the program, forward, recomputed and backward, and the scan's
+    ``while``. (The whole compile for v5e is ``benchmark/tests/test_aot_v5e.py``'s.)"""
+    from network_distributed_pytorch_tpu.models.layers import next_token_lm_loss, zero_counters
+    from network_distributed_pytorch_tpu.models.phi4flash import Phi4FlashConfig, Phi4FlashLM
+    from network_distributed_pytorch_tpu.parallel import PowerSGDReducer, make_mesh
+    from network_distributed_pytorch_tpu.parallel.trainer import STEP_COUNTERS, make_train_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    seq_len = 8192
+    model = Phi4FlashLM(Phi4FlashConfig(
+        vocab_size=25008, layer_indices=(15, 16, 17, 18, 19), dtype=jnp.bfloat16, remat=True,
+    ))
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, seq_len), jnp.int32)))["params"]
+    step = make_train_step(
+        next_token_lm_loss(model), PowerSGDReducer(compression_rank=16, matricize="last"), params,
+        learning_rate=5e-5, algorithm="ef_momentum", mesh=make_mesh(devices=jax.devices()[:1]),
+    )
+    state = jax.eval_shape(lambda p: step.init_state(p, model_state={STEP_COUNTERS: zero_counters(model.config)}), params)
+    tokens = jax.ShapeDtypeStruct((1, seq_len), jnp.int32)
+    text = step.fn.trace(state, {"input_ids": tokens, "labels": tokens}).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") >= 9  # three layers' flash forward, recomputed forward and backward
+    assert "stablehlo.while" in text
 
 
 def test_flash_grad_without_a_mask_types_inside_shard_map(v5e_devices):
