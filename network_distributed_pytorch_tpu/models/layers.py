@@ -182,8 +182,9 @@ def normed_and_turned(
 
 def einsum_attention(q, k, v, window: int = None):
     """Causal grouped-query attention with the weights materialised, the
-    engine off the TPU: q (B, T, H, D), k and v (B, T, Hkv, D) repeated to H
-    heads; with ``window``, query i sees key j iff ``0 <= i - j < window``."""
+    engine off the TPU: q (B, T, H, D), k (B, T, Hkv, D) and v (B, T, Hkv, Dv)
+    repeated to H heads, -> (B, T, H, Dv); with ``window``, query i sees key j
+    iff ``0 <= i - j < window``."""
     t, hd = q.shape[1], q.shape[-1]
     k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2) for x in (k, v))
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)

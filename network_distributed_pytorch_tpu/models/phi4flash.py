@@ -46,9 +46,11 @@ Parameters are fp32; ``dtype`` is what the products run in, and the residual
 stream, the memory and the cache are carried in it. ``delta``, ``A``, the
 scan's state, every LayerNorm's and the subln's statistics, lambda, the
 difference ``O1 - lambda O2`` and the logits are fp32. The four attentions of
-a layer are ONE call of ``layers.causal_attention`` on heads stacked ``(q1, q1,
-q2, q2)`` over ``(k1, k1, k2, k2)`` and ``(v1, v2, v1, v2)``: flash on TPU
-(heads of 64 grouped two to one: the kernels' fold), einsum elsewhere.
+a layer are ONE call of ``layers.causal_attention`` and two softmaxes a pair:
+query heads stacked ``(q1, q2)`` over key heads ``(k1, k2)``, and both read the
+value heads ``[v1 | v2]``, twice as wide, so the call returns ``(O1, O2)``:
+flash on TPU (heads of 64 over value heads of 128, grouped two to one: the
+kernels' fold), einsum elsewhere.
 ``__call__`` returns ``(logits, {})``: the model has no expert layer, so
 ``layers.zero_counters`` of its config is empty.
 """
@@ -228,14 +230,14 @@ class DiffAttention(nn.Module):
                 cache = paired(k.reshape(bsz, t, hkv, hd)) + paired(v.reshape(bsz, t, hkv, hd))
             k1, k2, v1, v2 = cache
             heads = lambda *parts: jnp.concatenate(parts, axis=2)
-            stacked = heads(q1, q1, q2, q2), heads(k1, k1, k2, k2), heads(v1, v2, v1, v2)
+            values = jnp.concatenate([v1, v2], axis=-1)  # (B, T, hkv/2, 2 hd): what q1's softmax and q2's both multiply
+            stacked = heads(q1, q2), heads(k1, k2), heads(values, values)
         with jax.named_scope(scope):
-            a11, a12, a21, a22 = jnp.split(causal_attention(cfg, *stacked, self.window), 4, axis=2)
+            o1, o2 = jnp.split(causal_attention(cfg, *stacked, self.window), 2, axis=2)  # (B, T, hq/2, 2 hd) each
         with jax.named_scope("attn.diff"):
             lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + init
-            side = lambda first, second: jnp.concatenate([first, second], axis=-1)  # (B, T, hq/2, 2 hd)
             scale = self.param("subln", nn.initializers.ones, (2 * hd,))
-            o = difference(side(a11, a12), side(a21, a22), lam, scale, cfg.norm_eps, 1.0 - init, cfg.dtype)
+            o = difference(o1, o2, lam, scale, cfg.norm_eps, 1.0 - init, cfg.dtype)
         return biased(cfg, cfg.hidden_size, cfg.out_std, "out_proj")(o.reshape(bsz, t, hq * hd)), cache
 
 

@@ -27,10 +27,26 @@ summed over each group outside. Shapes no lane block serves (D = 96; three
 heads of 64; grouped heads narrower than 128) take the one fallback: heads
 folded into the batch, ``(B·H, T, D)``, one head the whole last axis, the
 same kernels, a shared K/V row and the mask found by integer division of the
-grid row. One benchmark cell runs the fallback: ``lfm2_psgd16_t8k``'s attention
-layer, (H, Hkv, D) = (32, 8, 64) at T = 8192, where ``heads_per_block`` is None
+grid row.
+
+A value head of its own width: ``v`` may be ``(B, T, Hkv, Dv)`` with Dv not D,
+and the output is then ``(B, T, H, Dv)``. The score tile, its mask, running
+max, exp, normaliser and lse are a (query head, key head)'s as ever and the
+scale stays 1/sqrt(D); the accumulator, Oᵀ = Vᵀ·P, D = rowsum(dO ∘ O), dP =
+V·dOᵀ and dV run over Dv lanes, and P is made (and in the backward recomputed)
+once however wide V is. q, k, dq and dk ride blocks of the query/key width
+and v, o, do and dv blocks of the value width. That is served one head a
+block: in place where D and Dv are both multiples of 128, else through the
+fold; a lane block of several heads has one width. Where Dv == D the traced
+program is the one this file traced before it knew of Dv.
+
+Two benchmark cells run the fallback: ``lfm2_psgd16_t8k``'s attention layer,
+(H, Hkv, D) = (32, 8, 64) at T = 8192, where ``heads_per_block`` is None
 (grouped heads of 64 lanes), q, k, v and the output are transposed round the
-kernels in each pass and a head fills half the lanes.
+kernels in each pass and a head fills half the lanes; and
+``phi4flash_psgd16_t8k``'s three differential-attention layers, (H, Hkv, D,
+Dv) = (40, 20, 64, 128): a pair's two softmaxes (q1·k1, q2·k2) each multiply
+the value heads [v1 | v2] side by side, so each softmax is computed once.
 
 The forward (``_flash_kernel``) feeds the MXU as the backward does. Its two
 products, S = K·Qᵀ and Oᵀ = Vᵀ·P, take their operands in the input dtype and
@@ -77,7 +93,7 @@ goes.
 
 Correctness is pinned against naive einsum attention (padding masks, causal,
 both, windows under, at and across the tile edge, grads, every lane block,
-the fold and grouped K/V) in
+the fold, grouped K/V and a value head wider and narrower than the query's) in
 ``tests/test_flash_attention.py``; on CPU the kernel runs in interpret mode
 (the test path), on TPU it compiles with Mosaic.
 """
@@ -131,7 +147,7 @@ def _keys_on_sublanes(row):
     return jnp.sum(jnp.where(diagonal, row, 0.0), axis=1, keepdims=True)
 
 
-def heads_per_block(h: int, hkv: int, d: int):
+def heads_per_block(h: int, hkv: int, d: int, dv: int = None):
     """How many heads of width ``d`` one lane block of the ``(B, T, H*d)``
     layout holds, or None where no lane block serves the shape and the
     heads are folded into the batch instead. A block's last dimension is a
@@ -139,7 +155,11 @@ def heads_per_block(h: int, hkv: int, d: int):
     multiple is a block of its own (and ``hkv < h`` key/value heads are then
     read in place, head ``i // (h // hkv)``); narrower heads that fill 128
     lanes exactly go 128 // d to a block; heads that all fit in 128 lanes
-    are the one block."""
+    are the one block. A value head of its own width ``dv`` is a block of
+    its own beside a query/key head that is one, both a multiple of 128
+    lanes; several heads a block share one width."""
+    if dv not in (None, d):
+        return 1 if d % 128 == 0 and dv % 128 == 0 else None
     if d % 128 == 0:
         return 1
     if hkv != h:
@@ -190,10 +210,12 @@ def _flash_kernel(
     A head is picked out of the block by zeroing the others' lanes of Q:
     S = K·Qᵀ over all the lanes is then that head's, at the passes of a
     128-deep MXU either way; Vᵀ·P comes out for every lane of the block and
-    each head keeps its own rows."""
+    each head keeps its own rows. V's block, the accumulator and the output
+    are as wide as the value head, which in a block of one head need not be
+    the query/key head's."""
     qi = pl.program_id(2)
     q = q_ref[0]  # (block_q, lanes)
-    lanes = q.shape[-1]
+    v_lanes = v_ref.shape[-1]
     q_heads = [_only_head(q, heads, i) for i in range(heads)]
     nt = (((1,), (1,)), ((), ()))  # A·Bᵀ
     tn = (((0,), (0,)), ((), ()))  # Aᵀ·B
@@ -228,7 +250,7 @@ def _flash_kernel(
                 valid = valid & (q_pos - k_pos < window)
 
         def one_head(q, m, l, acc):
-            # m, l: (1, block_q); acc: (lanes, block_q)
+            # m, l: (1, block_q); acc: (v_lanes, block_q)
             s = jax.lax.dot_general(
                 k_blk, q, nt, preferred_element_type=jnp.float32
             ) * scale + mask_col
@@ -254,7 +276,7 @@ def _flash_kernel(
 
     m0 = jnp.full((1, block_q), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((1, block_q), jnp.float32)
-    acc0 = jnp.zeros((lanes, block_q), jnp.float32)
+    acc0 = jnp.zeros((v_lanes, block_q), jnp.float32)
     done = lax.fori_loop(lo, hi, body, ((m0, l0, acc0),) * heads)
     for i, (m, l, _) in enumerate(done):
         lse_ref[0, i] = jnp.where(
@@ -307,12 +329,17 @@ def _flash_bwd_kernel(
     A head is picked out of the block by zeroing the others' lanes of its
     Q, K and dO: the two products over the lanes (S, dP) are then that
     head's, and the three that keep the lanes (dV, dK, dQᵀ) are zero
-    outside them, so the heads' add up in the block's accumulators."""
+    outside them, so the heads' add up in the block's accumulators.
+
+    q, k, dq, dk and the dQᵀ scratch are as wide as the query/key head; v, o,
+    do and dv as the value head, which in a block of one head need not be
+    the same: D and dP run over the value lanes, S over the query/key lanes,
+    and P is recomputed once for both."""
     n_q, n_k = t // block_q, t // block_k
     nt = (((1,), (1,)), ((), ()))  # A·Bᵀ
     tn = (((0,), (0,)), ((), ()))  # Aᵀ·B
     tile = (block_k, block_q)
-    lanes = q_ref.shape[-1]
+    lanes, v_lanes = q_ref.shape[-1], v_ref.shape[-1]
     dqt_acc[...] = jnp.zeros_like(dqt_acc)
 
     def row_terms(i, _):
@@ -384,7 +411,12 @@ def _flash_bwd_kernel(
                 dmask = dmask + jnp.sum(ds, axis=1, keepdims=True)
             return dk, dv, dmask
 
-        zero = jnp.zeros((block_k, lanes), jnp.float32)
+        # dK and dV start from zero: one array a width, so one where the
+        # value head is as wide as the key's
+        zero = {
+            width: jnp.zeros((block_k, width), jnp.float32)
+            for width in {lanes, v_lanes}
+        }
         # causal: Q blocks that end before this K block starts see none of it
         lo = lax.div(j * block_k, block_q) if causal else 0
         hi = n_q
@@ -393,7 +425,8 @@ def _flash_bwd_kernel(
             last = (j + 1) * block_k + window - 2  # the last row that sees it
             hi = jnp.minimum(lax.div(last, block_q) + 1, n_q)
         dk, dv, dmask = lax.fori_loop(
-            lo, hi, q_block, (zero, zero, jnp.zeros((block_k, 1), jnp.float32))
+            lo, hi, q_block,
+            (zero[lanes], zero[v_lanes], jnp.zeros((block_k, 1), jnp.float32)),
         )
         dk_ref[0, pl.ds(ks, block_k), :] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[0, pl.ds(ks, block_k), :] = dv.astype(dv_ref.dtype)
@@ -457,30 +490,37 @@ def _shares(q, k, mask):
     )
 
 
+def _value_lanes(k, v, lanes: int) -> int:
+    """The width of v's lane block beside k's of ``lanes``: v brings k's
+    heads, each its own width."""
+    return v.shape[2] * lanes // k.shape[2]
+
+
 def _flash_fwd(
     scale, causal, window, lanes, heads, block_q, block_k, interpret,
     q, k, v, mask,
 ):
     """The forward kernel over the layout both kernels address: q
-    (N, T, Hq·D), k and v (Nkv, T, Hkv·D), mask (B, T); N is a multiple of
-    Nkv and of B, and Hq of Hkv. The grid is (row of q, lane block, Q
-    block), a lane block being ``lanes`` wide and ``heads`` heads; the
-    key/value block and the mask row a query block shares are found by
-    integer division in the index maps. Returns the output, as q, and the
-    lse, (N, Hq, 1, T)."""
+    (N, T, Hq·D), k (Nkv, T, Hkv·D) and v (Nkv, T, Hkv·Dv), mask (B, T); N
+    is a multiple of Nkv and of B, and Hq of Hkv. The grid is (row of q,
+    lane block, Q block), a lane block being ``heads`` heads, ``lanes`` wide
+    in q and k and ``v_lanes`` in v and the output; the key/value block and
+    the mask row a query block shares are found by integer division in the
+    index maps. Returns the output, (N, T, Hq·Dv), and the lse,
+    (N, Hq, 1, T)."""
     n, t, width = q.shape
-    n_heads = width // lanes * heads
+    n_blocks = width // lanes
+    v_lanes = _value_lanes(k, v, lanes)
     per_row, per_block, per_mask = _shares(q, k, mask)
     vma = _vma(q, k, v, mask)
     # what the kernel keeps in VMEM: K and V whole and the Q and O blocks,
     # double-buffered and padded to 128 lanes, and a few fp32 tiles a head
     resident = (
-        4 * (t + block_q) * max(lanes, 128) * q.dtype.itemsize
+        2 * (t + block_q) * (max(lanes, 128) + max(v_lanes, 128))
+        * q.dtype.itemsize
         + heads * 8 * 4 * block_q * block_k
     )
-    kv_block = pl.BlockSpec(
-        (1, t, lanes), lambda i, hb, qi: (i // per_row, 0, hb // per_block)
-    )
+    kv_index = lambda i, hb, qi: (i // per_row, 0, hb // per_block)
     # TPU block shapes need their last two dims (8, 128)-divisible or equal
     # to the array's: the mask rides as (B, T/block_k, block_k) and the lse
     # as (N, Hq, 1, T), never as 2-D rows of width T
@@ -488,25 +528,27 @@ def _flash_fwd(
         functools.partial(
             _flash_kernel, block_q, block_k, t, causal, window, scale, heads
         ),
-        grid=(n, width // lanes, t // block_q),
+        grid=(n, n_blocks, t // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, lanes), lambda i, hb, qi: (i, qi, hb)),
-            kv_block,
-            kv_block,
+            pl.BlockSpec((1, t, lanes), kv_index),
+            pl.BlockSpec((1, t, v_lanes), kv_index),
             pl.BlockSpec(
                 (1, t // block_k, block_k),
                 lambda i, hb, qi: (i // per_mask, 0, 0),
             ),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, lanes), lambda i, hb, qi: (i, qi, hb)),
+            pl.BlockSpec((1, block_q, v_lanes), lambda i, hb, qi: (i, qi, hb)),
             pl.BlockSpec(
                 (1, heads, 1, block_q), lambda i, hb, qi: (i, hb, 0, qi)
             ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((n, n_heads, 1, t), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((n, t, n_blocks * v_lanes), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct(
+                (n, n_blocks * heads, 1, t), jnp.float32, vma=vma
+            ),
         ],
         compiler_params=_vmem_params(resident),
         interpret=interpret,
@@ -525,11 +567,14 @@ def _flash_bwd(
     mask's cotangent is over every head; returns (dq, dk, dv, dmask)."""
     n, t, width = q.shape
     n_blocks = width // lanes
+    v_lanes = _value_lanes(k, v, lanes)
     per_row, per_block, per_mask = _shares(q, k, mask)
     vma = _vma(q, k, v, mask, do)
-    whole = pl.BlockSpec((1, t, lanes), lambda i, hb: (i, 0, hb))
-    kv_whole = pl.BlockSpec(
-        (1, t, lanes), lambda i, hb: (i // per_row, 0, hb // per_block)
+    # q, k, dq and dk ride blocks of the query/key width, v, o, do and dv of
+    # the value width
+    whole = lambda wide: pl.BlockSpec((1, t, wide), lambda i, hb: (i, 0, hb))
+    kv_whole = lambda wide: pl.BlockSpec(
+        (1, t, wide), lambda i, hb: (i // per_row, 0, hb // per_block)
     )
     # per-row scalars ride as (blocks, block): block i is sublane row i of a
     # lane-dense array (no dynamic lane slicing on TPU) — the lse by Q
@@ -540,7 +585,7 @@ def _flash_bwd(
     # double-buffered and padded to 128 lanes, the dQᵀ scratch and a few
     # fp32 tiles a head
     resident = (
-        16 * t * max(lanes, 128) * q.dtype.itemsize
+        8 * t * (max(lanes, 128) + max(v_lanes, 128)) * q.dtype.itemsize
         + 4 * t * lanes
         + heads * 8 * 4 * block_q * block_k
     )
@@ -551,18 +596,19 @@ def _flash_bwd(
         ),
         grid=(n, n_blocks),
         in_specs=[
-            whole, kv_whole, kv_whole, whole, whole,
+            whole(lanes), kv_whole(lanes), kv_whole(v_lanes),
+            whole(v_lanes), whole(v_lanes),
             pl.BlockSpec((1, heads) + q_rows, lambda i, hb: (i, hb, 0, 0)),
             pl.BlockSpec((1,) + k_rows, lambda i, hb: (i // per_mask, 0, 0)),
         ],
         out_specs=[
-            whole, whole, whole,
+            whole(lanes), whole(lanes), whole(v_lanes),
             pl.BlockSpec((1, 1) + k_rows, lambda i, hb: (i, hb, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
             jax.ShapeDtypeStruct(q.shape, k.dtype, vma=vma),
-            jax.ShapeDtypeStruct(q.shape, v.dtype, vma=vma),
+            jax.ShapeDtypeStruct(out.shape, v.dtype, vma=vma),
             jax.ShapeDtypeStruct((n, n_blocks) + k_rows, jnp.float32, vma=vma),
         ],
         scratch_shapes=[
@@ -580,11 +626,12 @@ def _flash_bwd(
     if per_row * per_block > 1:
         # the query heads of a group each brought a dK and a dV of the one
         # key/value head they read
-        d = lanes // heads
-        shared = lambda x: x.reshape(
-            k.shape[0], per_row, t, k.shape[2] // d, per_block, d
-        ).sum(axis=(1, 4)).reshape(k.shape)
-        dk, dv = shared(dk), shared(dv)
+        kv_heads = k.shape[2] * heads // lanes
+        shared = lambda x, like: x.reshape(
+            like.shape[0], per_row, t, kv_heads, per_block,
+            like.shape[2] // kv_heads,
+        ).sum(axis=(1, 4)).reshape(like.shape)
+        dk, dv = shared(dk, k), shared(dv, v)
     dmask = dmask.reshape(mask.shape[0], per_mask * n_blocks, t).sum(axis=1)
     return dq, dk, dv, dmask
 
@@ -606,9 +653,10 @@ def flash_attention(
 ) -> jax.Array:
     """Exact attention without materializing the score matrix.
 
-    q: (B, T, H, D) — the package's layout everywhere else; k/v:
-    (B, T, Hkv, D) with H a multiple of Hkv: query head ``i`` reads
-    key/value head ``i // (H // Hkv)``.
+    q: (B, T, H, D) — the package's layout everywhere else; k:
+    (B, T, Hkv, D) and v: (B, T, Hkv, Dv) with H a multiple of Hkv: query
+    head ``i`` reads key/value head ``i // (H // Hkv)``. A value head may be
+    wider or narrower than the query/key head; the scale is ``1/sqrt(D)``.
     mask: optional (B, T) additive key mask (0 = attend, very negative =
     padding), the same convention as ``parallel.sequence``.
     block_q/block_k: the score tile of both kernels; ``None`` is
@@ -617,15 +665,15 @@ def flash_attention(
     visible to query i iff ``0 <= i - j < window``. Key blocks wholly
     outside the band are skipped by the kernels' loop bounds. A window that
     covers the sequence is ``causal=True``, the same program.
-    Differentiable (custom VJP, blockwise backward). Returns (B, T, H, D)
+    Differentiable (custom VJP, blockwise backward). Returns (B, T, H, Dv)
     in q's dtype.
     """
     b, t, h, d = q.shape
-    hkv = k.shape[2]
-    if h % hkv or v.shape != k.shape or k.shape != (b, t, hkv, d):
+    hkv, dv = k.shape[2], v.shape[-1]
+    if h % hkv or k.shape != (b, t, hkv, d) or v.shape != (b, t, hkv, dv):
         raise ValueError(
-            f"q {q.shape} needs k and v of one shape (B, T, Hkv, D) with H a"
-            f" multiple of Hkv; got {k.shape} and {v.shape}"
+            f"q {q.shape} needs k (B, T, Hkv, D) and v (B, T, Hkv, Dv) with H"
+            f" a multiple of Hkv; got {k.shape} and {v.shape}"
         )
     block_q = min(block_q or tile_edge(t), t)
     block_k = min(block_k or tile_edge(t), t)
@@ -639,18 +687,18 @@ def flash_attention(
             raise ValueError(f"window={window}: a query sees at least itself")
         causal, window = True, (window if window < t else None)
 
-    heads = heads_per_block(h, hkv, d)
+    heads = heads_per_block(h, hkv, d, dv)
     if heads is None:
         # no lane block serves these heads: (B, T, H, D) -> (B*H, T, D),
         # one row a (batch, head)
         heads = 1
-        rows = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, t, d)
-        unrows = lambda x: x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+        rows = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, t, x.shape[-1])
+        unrows = lambda x: x.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
     else:
         # the model's own layout: heads side by side on the last axis, as
         # the projections emit them
         rows = lambda x: x.reshape(b, t, -1)
-        unrows = lambda x: x.reshape(b, t, h, d)
+        unrows = lambda x: x.reshape(b, t, h, dv)
     if mask is None:
         mask = jnp.zeros((b, t), jnp.float32)
     mask = mask.astype(jnp.float32)

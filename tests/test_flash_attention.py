@@ -4,8 +4,10 @@ attn_impl="flash" path; the forward kernel and the backward kernel's dq,
 dk, dv and dmask at the tiles the chip runs (``tile_edge``), alone and
 inside ``shard_map``; every lane block ``heads_per_block`` picks, the fold
 it falls back to and grouped key/value heads; sliding windows under, at and
-across the tile edge (kinds named ``window<N>``); and that nothing is turned
-or folded round the kernels where a lane block serves."""
+across the tile edge (kinds named ``window<N>``); a value head wider or
+narrower than the query/key head (heads named ``(H, Hkv, D, Dv)``): one head
+a block where both widths are lane blocks, else the fold; and that nothing is
+turned or folded round the kernels where a lane block serves."""
 
 import functools
 import re
@@ -411,9 +413,17 @@ LANE_RULE = [
     ((4, 2, 128), 1), ((4, 1, 128), 1), ((32, 2, 128), 1), ((1, 1, 96), 1),
     ((3, 3, 64), None), ((2, 2, 96), None), ((4, 2, 16), None),
 ]
+# (H, Hkv, D, Dv), a value head of its own width: one head a block where both
+# widths are multiples of 128, else the fold — a pair of 64 a lane block
+# (4, 4, 64) among them, whose block has one width
+VALUE_WIDTH_RULE = [
+    ((2, 2, 128, 256), 1), ((4, 2, 128, 256), 1), ((4, 2, 256, 128), 1),
+    ((4, 2, 16, 32), None), ((8, 4, 64, 128), None), ((4, 4, 64, 128), None),
+    ((2, 2, 16, 8), None), ((2, 1, 128, 64), None), ((4, 4, 64, 64), 2),
+]
 
 
-@pytest.mark.parametrize("heads,per_block", LANE_RULE, ids=str)
+@pytest.mark.parametrize("heads,per_block", LANE_RULE + VALUE_WIDTH_RULE, ids=str)
 def test_heads_per_block(heads, per_block):
     assert heads_per_block(*heads) == per_block
 
@@ -432,6 +442,18 @@ LANE_CASES = [
     pytest.param(heads, kind, jnp.float32, id=f"H{heads[0]}kv{heads[1]}D{heads[2]}-{kind}-float32")
     for heads in ((4, 4, 64), (2, 2, 16), (4, 2, 128), (32, 4, 16), (32, 4, 128))
     for kind in ("window96", "window128+padding", "window200+padding")
+] + [
+    # a value head of its own width, through the fold and one head a block
+    pytest.param(heads, kind, dtype, id=f"H{heads[0]}kv{heads[1]}D{heads[2]}v{heads[3]}-{kind}-{dtype.__name__}")
+    for heads, _ in VALUE_WIDTH_RULE
+    if heads[2] != heads[3]
+    for kind in ("padding", "causal", "both")
+    for dtype in (jnp.float32, jnp.bfloat16)
+] + [
+    pytest.param(heads, kind, dtype, id=f"H{heads[0]}kv{heads[1]}D{heads[2]}v{heads[3]}-{kind}-{dtype.__name__}")
+    for heads in ((4, 2, 16, 32), (4, 2, 128, 256), (8, 4, 64, 128))
+    for kind in ("window96", "window128+padding", "window200+padding")
+    for dtype in (jnp.float32, jnp.bfloat16)
 ]
 
 
@@ -439,13 +461,17 @@ LANE_CASES = [
 def test_flash_lane_blocks_match_naive(devices, heads, kind, dtype):
     """The output and all four cotangents against naive fp32 attention for
     every way the kernels address heads: all in one lane block, pairs of 64,
-    one head of 128 a block, the fold where no lane block serves, and fewer
+    one head of 128 a block, the fold where no lane block serves, fewer
     key/value heads than query heads (naive attention on repeated K/V, so
-    its dK and dV are summed over each group)."""
-    h, hkv, d = heads
+    its dK and dV are summed over each group), and a value head of its own
+    width ``heads[3]``: the output and dV that wide, the scale the
+    query/key head's."""
+    h, hkv, d, dv = heads if len(heads) == 4 else heads + heads[2:]
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
-    q, w = (jax.random.normal(key, (BWD_B, LANE_T, h, d), dtype) for key in ks[:2])
-    k, v = (jax.random.normal(key, (BWD_B, LANE_T, hkv, d), dtype) for key in ks[2:])
+    q, w, k, v = (
+        jax.random.normal(key, (BWD_B, LANE_T) + head, dtype)
+        for key, head in zip(ks, ((h, d), (h, dv), (hkv, d), (hkv, dv)))
+    )
     m, window = np.zeros((BWD_B, LANE_T), np.float32), _window_of(kind)
     if window and "padding" in kind:
         m[1, 100:130] = -1e30  # padding inside the sequence: every window still sees a key
@@ -510,18 +536,18 @@ def test_no_window_leaves_both_kernels_as_they_were(devices):
 
 
 @pytest.mark.parametrize(
-    "b,t,h,hkv,d,causal",
-    [(48, 512, 12, 12, 64, False), (1, 8192, 32, 2, 128, True)],
-    ids=["imdb", "nemotron"],
+    "b,t,h,hkv,d,dv,causal",
+    [(48, 512, 12, 12, 64, 64, False), (1, 8192, 32, 2, 128, 128, True), (1, 4096, 8, 2, 128, 256, True)],
+    ids=["imdb", "nemotron", "value-256-over-128"],
 )
-def test_nothing_is_turned_or_folded_round_the_kernels(b, t, h, hkv, d, causal):
+def test_nothing_is_turned_or_folded_round_the_kernels(b, t, h, hkv, d, dv, causal):
     """At the cells' per-layer shapes the forward and the backward are the
     two kernels on the model's own layout: no ``transpose`` outside them, no
-    (B*H, T, D) array, and K and V go in with the heads they have."""
-    q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((b, t, hkv, d), jnp.bfloat16)
+    (B*H, T, D) array, and K and V go in with the heads they have, V at its
+    own width where that is a lane block too."""
+    q, k, v = (jax.ShapeDtypeStruct((b, t, n, width), jnp.bfloat16) for n, width in ((h, d), (hkv, d), (hkv, dv)))
     # imdb's batches bring their padding mask, nemotron's are packed
-    args = (q, kv, kv) if causal else (q, kv, kv, jax.ShapeDtypeStruct((b, t), jnp.float32))
+    args = (q, k, v) if causal else (q, k, v, jax.ShapeDtypeStruct((b, t), jnp.float32))
 
     def loss(q, k, v, mask=None):
         out = flash_attention(q, k, v, mask=mask, causal=causal)
@@ -536,5 +562,27 @@ def test_nothing_is_turned_or_folded_round_the_kernels(b, t, h, hkv, d, causal):
     assert (b * h, t, d) not in shapes
     for kernel in kernels:
         assert [v.aval.shape for v in kernel.invars[:3]] == [
-            (b, t, h * d), (b, t, hkv * d), (b, t, hkv * d)
+            (b, t, h * d), (b, t, hkv * d), (b, t, hkv * dv)
         ]
+    assert kernels[0].outvars[0].aval.shape == (b, t, h * dv)
+
+
+def test_a_value_width_of_its_own_folds_a_pair_of_64(devices):
+    """(H, Hkv, D) = (4, 4, 64) is a pair of heads a lane block; with a value
+    head of 128 beside it no lane block has one width, so the call takes the
+    fold, one head a row, q and k at 64 lanes and v at 128: the program of
+    ``phi4flash_psgd16_t8k``'s call, in small."""
+    q, v = (jax.ShapeDtypeStruct((2, 256, 4, width), jnp.float32) for width in (64, 128))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, v)
+    kernels = _kernel_blocks(jaxpr)
+    q_block, k_block, v_block, _, o_block, _ = kernels[None]
+    assert (q_block, k_block, v_block, o_block) == ((1, 256, 64), (1, 256, 64), (1, 256, 128), (1, 256, 128))
+    blocks = kernels["flash_attention_bwd"]
+    assert blocks[:5] == [(1, 256, 64), (1, 256, 64), (1, 256, 128), (1, 256, 128), (1, 256, 128)]  # q, k, v, o, do
+    assert blocks[7:10] == [(1, 256, 64), (1, 256, 64), (1, 256, 128)]  # dq, dk, dv
+    shapes = {x.aval.shape for e in _outside_the_kernels(jaxpr.jaxpr) for x in list(e.invars) + list(e.outvars)}
+    assert {(8, 256, 64), (8, 256, 128)} <= shapes

@@ -16,7 +16,7 @@ import pytest
 
 from benchmark.reference import phi4flash as reference
 from network_distributed_pytorch_tpu.models import phi4flash
-from network_distributed_pytorch_tpu.models.layers import next_token_lm_loss, zero_counters
+from network_distributed_pytorch_tpu.models.layers import einsum_attention, next_token_lm_loss, zero_counters
 from network_distributed_pytorch_tpu.models.phi4flash import (
     CROSS, FULL, GMU, MAMBA, SLIDING, DiffAttention, Mamba1Mixer, Phi4FlashBlock, Phi4FlashConfig, Phi4FlashLM,
     lambda_init, layer_kind, phi4flash_tiny,
@@ -287,10 +287,10 @@ def test_a_window_shorter_than_t_changes_the_sliding_layer_and_no_other():
         carry = stepped[0]
 
 
-def test_the_four_attentions_are_one_call_on_stacked_heads(monkeypatch):
-    """(q1, q1, q2, q2) over (k1, k1, k2, k2) and (v1, v2, v1, v2): 2 H / 2 =
-    twice the query heads over twice the key/value heads, one engine call a
-    layer, the sliding layer's with its window."""
+def test_the_four_attentions_are_one_call_and_two_softmaxes_a_pair(monkeypatch):
+    """(q1, q2) over (k1, k2) and the value heads [v1 | v2] twice: H query heads
+    over Hkv key/value heads, the value head 2 * head_dim wide, one engine call
+    a layer, the sliding layer's with its window."""
     calls = []
     real = phi4flash.causal_attention
 
@@ -302,5 +302,80 @@ def test_the_four_attentions_are_one_call_on_stacked_heads(monkeypatch):
     params, ids, _ = seeded(model)
     monkeypatch.setattr(phi4flash, "causal_attention", counted)
     model.apply({"params": params}, ids)
-    q, kv = (2, SEQ, 8, 16), (2, SEQ, 4, 16)
-    assert calls == [(q, kv, kv, 16), (q, kv, kv, None), (q, kv, kv, None)]
+    q, k, v = (2, SEQ, 4, 16), (2, SEQ, 2, 16), (2, SEQ, 2, 32)
+    assert calls == [(q, k, v, 16), (q, k, v, None), (q, k, v, None)]
+
+
+def four_attentions(cfg, index, window, p, u, cache=None):
+    """Differential attention as four attentions on heads stacked (q1, q1, q2,
+    q2) over (k1, k1, k2, k2) and (v1, v2, v1, v2), each softmax made once a
+    value half: the lines ``DiffAttention`` had before the value head took its
+    own width, on explicit parameters, in fp32."""
+    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    bsz, t, _ = u.shape
+    if cache is None:
+        q, k, v = jnp.split(u @ p["Wqkv"]["kernel"] + p["Wqkv"]["bias"], [hq * hd, (hq + hkv) * hd], axis=-1)
+        cache = phi4flash.paired(k.reshape(bsz, t, hkv, hd)) + phi4flash.paired(v.reshape(bsz, t, hkv, hd))
+    else:
+        q = u @ p["Wq"]["kernel"] + p["Wq"]["bias"]
+    q1, q2 = phi4flash.paired(q.reshape(bsz, t, hq, hd))
+    k1, k2, v1, v2 = cache
+    heads = lambda *parts: jnp.concatenate(parts, axis=2)
+    stacked = heads(q1, q1, q2, q2), heads(k1, k1, k2, k2), heads(v1, v2, v1, v2)
+    a11, a12, a21, a22 = jnp.split(einsum_attention(*stacked, window), 4, axis=2)
+    init = lambda_init(index)
+    lam = jnp.exp(p["lambda_q1"] @ p["lambda_k1"]) - jnp.exp(p["lambda_q2"] @ p["lambda_k2"]) + init
+    side = lambda first, second: jnp.concatenate([first, second], axis=-1)
+    o = phi4flash.difference(side(a11, a12), side(a21, a22), lam, p["subln"], cfg.norm_eps, 1.0 - init, jnp.float32)
+    return o.reshape(bsz, t, hq * hd) @ p["out_proj"]["kernel"] + p["out_proj"]["bias"], cache
+
+
+@pytest.mark.parametrize("engine", ["einsum", "flash"])
+@pytest.mark.parametrize("kind", [SLIDING, FULL, CROSS])
+def test_two_softmaxes_a_pair_are_the_four_attentions(kind, engine):
+    """``DiffAttention``'s output, the cache it hands on and the gradient of
+    every parameter, of its input and (a cross layer) of the cache it reads,
+    against :func:`four_attentions`, by either engine (flash: the kernels in
+    interpret mode, a value head of 32 over a head of 16 through the fold)."""
+    cfg = phi4flash_tiny(n_heads=8, n_kv_heads=4, attn_impl=engine).config
+    index, window = {SLIDING: (15, 16), FULL: (17, None), CROSS: (19, None)}[kind]
+    keys = jax.random.split(jax.random.PRNGKey(3), 7)
+    u = jax.random.normal(keys[0], (2, SEQ, 64))
+    cache = None
+    if kind == CROSS:
+        cache = tuple(jax.random.normal(key, (2, SEQ, 2, 16)) for key in keys[1:5])
+    module = DiffAttention(cfg, index, window)
+    p = module.init(keys[5], u, cache)["params"]
+    p = jax.tree_util.tree_map(
+        lambda x: x + 0.1 * jax.random.normal(jax.random.PRNGKey(x.size), x.shape) if x.ndim == 1 else x, p
+    )
+    w = jax.random.normal(keys[6], (2, SEQ, 64))
+
+    def loss(attend):
+        def f(p, u, cache):
+            out, kept = attend(p, u, cache)
+            return jnp.sum(out * w) + sum(jnp.sum(jnp.sin(x)) for x in kept), (out, kept)
+        return jax.value_and_grad(f, argnums=(0, 1, 2) if kind == CROSS else (0, 1), has_aux=True)
+
+    with jax.default_matmul_precision("highest"):
+        (_, got), got_grads = loss(lambda p, u, cache: module.apply({"params": p}, u, cache))(p, u, cache)
+        (_, want), want_grads = loss(lambda p, u, cache: four_attentions(cfg, index, window, p, u, cache))(p, u, cache)
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+    assert worst_relative(got, want) < 1e-5
+    assert jax.tree_util.tree_structure(got_grads) == jax.tree_util.tree_structure(want_grads)
+    assert worst_relative(got_grads, want_grads) < 2e-5
+    assert all(float(jnp.linalg.norm(g)) > 0 for g in jax.tree_util.tree_leaves(got_grads))
+
+
+def test_no_parameter_of_differential_attention_changes_name_or_shape():
+    """At the published widths the attention layers' leaves are what they
+    were when the four attentions were four softmaxes: a checkpoint of that
+    model loads."""
+    model = Phi4FlashLM(Phi4FlashConfig(vocab_size=256, layer_indices=(15, 17, 19)))
+    shapes = jax.eval_shape(lambda k: model.init(k, jnp.zeros((1, 64), jnp.int32))["params"], jax.random.PRNGKey(0))
+    shape_of = lambda tree: jax.tree_util.tree_map(lambda x: x.shape, tree)
+    shared = {f"lambda_{name}": (64,) for name in ("q1", "k1", "q2", "k2")}
+    shared.update(subln=(128,), out_proj={"kernel": (2560, 2560), "bias": (2560,)})
+    own = {"Wqkv": {"kernel": (2560, 5120), "bias": (5120,)}}
+    assert shape_of(shapes["layer_0"]["mixer"]) == shape_of(shapes["layer_1"]["mixer"]) == {**own, **shared}
+    assert shape_of(shapes["layer_2"]["mixer"]) == {"Wq": {"kernel": (2560, 2560), "bias": (2560,)}, **shared}

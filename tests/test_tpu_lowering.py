@@ -30,7 +30,7 @@ from network_distributed_pytorch_tpu.ops.pallas_orthogonalize import (
 )
 from network_distributed_pytorch_tpu.ops.rows_to_tokens import rows_of_tokens, tokens_from_rows
 
-# (b, t, h, d[, key/value heads[, window]]), dtype, causal, padded mask — chip_smoke's
+# (b, t, h, d[, key/value heads[, window[, value width]]]), dtype, causal, padded mask — chip_smoke's
 # DistilBERT-base attention (bf16 and fp32), GPT-2-small at its context, gpt_lm's full
 # preset (t=64), a serve prefill length that is no multiple of 128, and the
 # benchmark cells' own: imdb_psgd16_b16 (one 512x512 tile a head) and
@@ -43,10 +43,14 @@ from network_distributed_pytorch_tpu.ops.rows_to_tokens import rows_of_tokens, t
 # block, so the fold at T = 8192, one 64-lane head's K and V whole in VMEM),
 # mellum2_psgd16_t8k's sliding layers (trinity's heads, a window of 1024: 8
 # windows, a band two 512-tiles wide), phi4flash_psgd16_t8k's differential
-# attention (the four attentions of a layer as one call: 80 stacked query heads
-# of 64 over 40, so the fold; causal in the full and cross layers, a window of
-# 512 in the sliding one: 16 windows, a band one or two 512-tiles wide), and a
-# head width no lane block serves (the fold)
+# attention (the four attentions of a layer as one call, two softmaxes a pair:
+# 40 query heads of 64 over 20 key heads of 64 and 20 value heads of 128, so the
+# fold with a value block of its own width; causal in the full and cross layers,
+# a window of 512 in the sliding one: 16 windows, a band one or two 512-tiles
+# wide; and the call it was until PR 49, 80 stacked heads of 64 over 40, the
+# fold at one width), a value head of 256 beside a head of 128 read in place
+# (one head a block at two widths), and a head width no lane block serves (the
+# fold)
 FLASH_CASES = [
     pytest.param((16, 256, 12, 64), jnp.bfloat16, False, True, id="distilbert-bf16"),
     pytest.param((16, 256, 12, 64), jnp.float32, False, True, id="distilbert-fp32"),
@@ -62,6 +66,9 @@ FLASH_CASES = [
     pytest.param((1, 8192, 32, 128, 4, 1024), jnp.bfloat16, True, False, id="mellum-8192-window-1024"),
     pytest.param((1, 8192, 80, 64, 40), jnp.bfloat16, True, False, id="phi4flash-8192-stacked-64-fold"),
     pytest.param((1, 8192, 80, 64, 40, 512), jnp.bfloat16, True, False, id="phi4flash-8192-stacked-64-window-512"),
+    pytest.param((1, 8192, 40, 64, 20, None, 128), jnp.bfloat16, True, False, id="phi4flash-8192-64-over-value-128-fold"),
+    pytest.param((1, 8192, 40, 64, 20, 512, 128), jnp.bfloat16, True, False, id="phi4flash-8192-64-over-value-128-window-512"),
+    pytest.param((1, 4096, 8, 128, 2, None, 256), jnp.bfloat16, True, False, id="value-256-over-128-gqa"),
     pytest.param((4, 512, 3, 64), jnp.bfloat16, False, True, id="fold-3x64"),
 ]
 # P-factor shapes: DistilBERT-base at rank 16 (chip_smoke), GPT-2 at rank 4
@@ -89,10 +96,9 @@ ROWS_TO_TOKENS_SHAPES = [(24576, 2304, 16), (8192, 2688, 8)]
 
 def _flash_fns(shape, dtype, causal, masked):
     b, t, h, d = shape[:4]
-    hkv = shape[4] if len(shape) > 4 else h
-    window = shape[5] if len(shape) > 5 else None
-    kv = jax.ShapeDtypeStruct((b, t, hkv, d), dtype)
-    args = [jax.ShapeDtypeStruct(shape[:4], dtype), kv, kv]
+    hkv, window, dv = (*shape[4:], *(h, None, d)[len(shape) - 4:])
+    heads = lambda n, width: jax.ShapeDtypeStruct((b, t, n, width), dtype)
+    args = [heads(h, d), heads(hkv, d), heads(hkv, dv)]
     if masked:
         args.append(jax.ShapeDtypeStruct((b, t), jnp.float32))
 
@@ -647,7 +653,10 @@ def test_the_phi4flash_cuts_step_lowers_for_tpu(monkeypatch):
     cross-lowered for TPU as the chip builds it ("auto" resolving to the flash
     kernels and the Pallas Gram-Schmidt): the three attention layers' kernels
     are in the program, forward, recomputed and backward, and the scan's
-    ``while``. (The whole compile for v5e is ``benchmark/tests/test_aot_v5e.py``'s.)"""
+    ``while``. What says that each softmax is made once is the kernels' own
+    operands: 40 query rows of 64 lanes a sequence (not 80) over 20 key rows of
+    64 and 20 value rows of 128, in every forward and every backward. (The
+    whole compile for v5e is ``benchmark/tests/test_aot_v5e.py``'s.)"""
     from network_distributed_pytorch_tpu.models.layers import next_token_lm_loss, zero_counters
     from network_distributed_pytorch_tpu.models.phi4flash import Phi4FlashConfig, Phi4FlashLM
     from network_distributed_pytorch_tpu.parallel import PowerSGDReducer, make_mesh
@@ -668,6 +677,10 @@ def test_the_phi4flash_cuts_step_lowers_for_tpu(monkeypatch):
     text = step.fn.trace(state, {"input_ids": tokens, "labels": tokens}).lower(lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") >= 9  # three layers' flash forward, recomputed forward and backward
     assert "stablehlo.while" in text
+    operands = re.findall(r'kernel_name = "(_flash_kernel|flash_attention_bwd)"[^\n]*? : \(([^)]*)\) ->', text)
+    assert {name for name, _ in operands} == {"_flash_kernel", "flash_attention_bwd"}
+    qkv = "tensor<40x8192x64xbf16>, tensor<20x8192x64xbf16>, tensor<20x8192x128xbf16>, "
+    assert all(types.startswith(qkv) for _, types in operands), operands
 
 
 def test_flash_grad_without_a_mask_types_inside_shard_map(v5e_devices):
