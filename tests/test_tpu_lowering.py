@@ -624,7 +624,7 @@ def test_smoke_train_step_compiles_for_four_v5e_chips(v5e_devices, monkeypatch):
 
 
 def _selective_scan_fns(bsz=1, t=8192, ch=5120, n=16):
-    """phi4flash_psgd16_t8k's one scan: a sequence of 8192, 5120 channels, a state of 16."""
+    """phi4flash_psgd16_t8k's one scan: a sequence of 8192, 5120 channels, a state of 16; the kernels whatever traces them."""
     from network_distributed_pytorch_tpu.ops.selective_scan import selective_scan
 
     f32, bf16 = jnp.float32, jnp.bfloat16
@@ -633,17 +633,30 @@ def _selective_scan_fns(bsz=1, t=8192, ch=5120, n=16):
         struct((bsz, t, ch), bf16), struct((bsz, t, ch), f32), struct((ch, n), f32),
         struct((bsz, t, n), bf16), struct((bsz, t, n), bf16), struct((ch,), f32),
     )
-    loss = lambda *v: selective_scan(*v).astype(f32).sum()
-    return args, {"forward": selective_scan, "grad": jax.grad(loss, argnums=tuple(range(6)))}
+    scan = lambda *v: selective_scan(*v, interpret=False)
+    loss = lambda *v: scan(*v).astype(f32).sum()
+    return args, {"forward": scan, "grad": jax.grad(loss, argnums=tuple(range(6)))}
 
 
-@pytest.mark.parametrize("which", ["forward", "grad"])
-def test_selective_scan_compiles_for_v5e_and_never_holds_the_states_of_a_sequence(v5e_devices, which):
+@pytest.mark.parametrize("which,kernels", [("forward", ["selective_scan"]), ("grad", ["selective_scan", "selective_scan_bwd"])])
+def test_selective_scan_compiles_for_v5e_and_never_holds_the_states_of_a_sequence(v5e_devices, which, kernels):
     """At the cell's shape the (T, C, N) states of a sequence are 2.7 GB in
-    fp32: the compiled program's temporaries stay under a third of that,
-    forward and with every cotangent (AOT for v5e read 0.21 and 0.51 GB)."""
+    fp32: the two kernels are in the compiled program under their names, and
+    its temporaries stay under a third of that, forward and with every
+    cotangent (AOT for v5e read 0.021 GB forward, the (64, 16, 5120) states
+    the time blocks start from, and nothing beside the results with every
+    cotangent; the plain walk's program read 0.21 and 0.51). What Mosaic would
+    refuse on the chip (a row read alone, a gather along the lanes, the sums down the
+    sublanes and over the lanes, a step's VMEM) it refuses here, and what each
+    kernel asks of VMEM is under what any kernel of this file may."""
+    from network_distributed_pytorch_tpu.ops.flash_attention import _VMEM_MOST
+
     args, fns = _selective_scan_fns()
-    compiled = jax.jit(fns[which]).lower(*[_on(v5e_devices[0], a) for a in args]).compile()
+    lowered = jax.jit(fns[which]).lower(*[_on(v5e_devices[0], a) for a in args])
+    asks = re.findall(r'\\22size\\22: (\d+)}]}", kernel_name = "(\w+)"', lowered.as_text())
+    assert sorted(name for _, name in asks) == kernels and all(int(ask) <= _VMEM_MOST for ask, _ in asks), asks
+    compiled = lowered.compile()
+    assert _bare(_custom_calls(compiled.as_text())) == kernels
     assert compiled.memory_analysis().temp_size_in_bytes < 8192 * 5120 * 16 * 4 / 3
 
 
@@ -652,11 +665,13 @@ def test_the_phi4flash_cuts_step_lowers_for_tpu(monkeypatch):
     bf16, ``remat``) through ``make_train_step`` under PowerSGD rank 16,
     cross-lowered for TPU as the chip builds it ("auto" resolving to the flash
     kernels and the Pallas Gram-Schmidt): the three attention layers' kernels
-    are in the program, forward, recomputed and backward, and the scan's
-    ``while``. What says that each softmax is made once is the kernels' own
-    operands: 40 query rows of 64 lanes a sequence (not 80) over 20 key rows of
-    64 and 20 value rows of 128, in every forward and every backward. (The
-    whole compile for v5e is ``benchmark/tests/test_aot_v5e.py``'s.)"""
+    are in the program, forward, recomputed and backward, and the scan's two:
+    ``selective_scan`` forward and recomputed, ``selective_scan_bwd`` once, and
+    no ``while`` is left in the step. What says that each softmax is made once
+    is the kernels' own operands: 40 query rows of 64 lanes a sequence (not
+    80) over 20 key rows of 64 and 20 value rows of 128, in every forward and
+    every backward. (The whole compile for v5e is
+    ``benchmark/tests/test_aot_v5e.py``'s.)"""
     from network_distributed_pytorch_tpu.models.layers import next_token_lm_loss, zero_counters
     from network_distributed_pytorch_tpu.models.phi4flash import Phi4FlashConfig, Phi4FlashLM
     from network_distributed_pytorch_tpu.parallel import PowerSGDReducer, make_mesh
@@ -675,8 +690,10 @@ def test_the_phi4flash_cuts_step_lowers_for_tpu(monkeypatch):
     state = jax.eval_shape(lambda p: step.init_state(p, model_state={STEP_COUNTERS: zero_counters(model.config)}), params)
     tokens = jax.ShapeDtypeStruct((1, seq_len), jnp.int32)
     text = step.fn.trace(state, {"input_ids": tokens, "labels": tokens}).lower(lowering_platforms=("tpu",)).as_text()
-    assert text.count("tpu_custom_call") >= 9  # three layers' flash forward, recomputed forward and backward
-    assert "stablehlo.while" in text
+    assert text.count("tpu_custom_call") >= 12  # three layers' flash forward, recomputed forward and backward, and the scan's
+    names = re.findall(r'kernel_name = "(\w+)"', text)
+    assert (names.count("selective_scan"), names.count("selective_scan_bwd")) == (2, 1)
+    assert "stablehlo.while" not in text  # the scan's loops are inside the kernels
     operands = re.findall(r'kernel_name = "(_flash_kernel|flash_attention_bwd)"[^\n]*? : \(([^)]*)\) ->', text)
     assert {name for name, _ in operands} == {"_flash_kernel", "flash_attention_bwd"}
     qkv = "tensor<40x8192x64xbf16>, tensor<20x8192x64xbf16>, tensor<20x8192x128xbf16>, "
