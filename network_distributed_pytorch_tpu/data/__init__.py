@@ -14,6 +14,7 @@ from .partition import (  # noqa: F401
 from .loader import device_prefetch, epoch_order, iterate_batches, steps_per_epoch  # noqa: F401
 from .cifar10 import load_cifar10, load_cifar10_or_synthetic, synthetic_cifar10  # noqa: F401
 from .imdb import HashTokenizer, prepare_imdb, read_imdb_split, synthetic_imdb  # noqa: F401
+from .noising import block_noised  # noqa: F401
 from .wordpiece import (  # noqa: F401
     WordPieceTokenizer,
     build_vocab,

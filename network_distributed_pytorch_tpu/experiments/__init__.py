@@ -21,4 +21,5 @@ from . import (  # noqa: F401
     powersgd_nemotron,
     powersgd_phi4flash,
     powersgd_qwen3_next,
+    powersgd_sdar,
 )

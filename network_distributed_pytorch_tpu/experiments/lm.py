@@ -1,4 +1,4 @@
-"""What the six language-model experiments share (``LM_EXPERIMENTS``):
+"""What the seven language-model experiments share (``LM_EXPERIMENTS``):
 ``train_lm``, the experiment without its model, and the config each starts
 from. An experiment's file builds its model and hands it here; none imports
 another.
@@ -7,9 +7,11 @@ Composed like ``powersgd_imdb.run``: the same Algorithm-2 jitted step
 (``make_train_step`` with ``PowerSGDReducer``, rank 16, EF-SGD lr 5e-5 λ=.9,
 ``matricize="last"`` so a stacked ``(experts, in, out)`` leaf compresses as
 one ``(experts*in, out)`` matrix), the same ``train_loop``. Batches are
-dicts of packed token ids and their next-token labels; the expert layers'
-counters ride ``model_state`` (``parallel.trainer.STEP_COUNTERS``) and land
-on every step's ``step/loss_sync`` span.
+dicts of packed token ids and their next-token labels unless the caller
+brings its own loss and the batches it takes (``powersgd_sdar``: a noised
+copy and loss weights); the expert layers' counters ride ``model_state``
+(``parallel.trainer.STEP_COUNTERS``) and land on every step's
+``step/loss_sync`` span.
 """
 
 from __future__ import annotations
@@ -29,7 +31,7 @@ from .common import accumulated_batches, powersgd_reducer_kwargs, summarize, tra
 # the experiments built on train_lm, by their names in launch.EXPERIMENTS
 LM_EXPERIMENTS = (
     "powersgd_nemotron", "powersgd_afmoe", "powersgd_qwen3_next", "powersgd_lfm2", "powersgd_mellum",
-    "powersgd_phi4flash",
+    "powersgd_phi4flash", "powersgd_sdar",
 )
 
 
@@ -49,6 +51,12 @@ def model_kwargs(config: ExperimentConfig) -> Dict:
     return {"dtype": jnp.dtype(config.compute_dtype), **attn}
 
 
+def next_token_batches(ids: np.ndarray, rng: np.random.Generator) -> Dict[str, np.ndarray]:
+    """Sequences of ``seq_len + 1`` ids -> what ``next_token_lm_loss`` takes:
+    the first ``seq_len`` and, as labels, the ids one on."""
+    return {"input_ids": ids[:, :-1].copy(), "labels": ids[:, 1:].copy()}
+
+
 def train_lm(
     run_name: str,
     model,
@@ -59,17 +67,24 @@ def train_lm(
     max_steps_per_epoch: Optional[int],
     summary: Dict,
     collections_of: Optional[Callable] = None,
+    loss_of: Callable = next_token_lm_loss,
+    batches_of: Callable = next_token_batches,
+    drawn_ids: Optional[int] = None,
 ) -> Dict:
     """``model`` under PowerSGD through ``make_train_step`` and
     ``train_loop`` on synthetic packed sequences; ``summary`` is what the
     caller wants said of its model in the result. ``collections_of(params,
     ids)`` gives the model's variable collections beside its parameters
     (``powersgd_afmoe``'s balanced ``buffers``) from the ids of the pool's
-    first sequences; they ride ``model_state``."""
+    first sequences; they ride ``model_state``. ``loss_of(model)`` is the
+    trainer's loss function and ``batches_of(ids, rng)`` the pool of batches
+    it takes, as a dict of arrays a sample a row, made of the pool's ``seq_len
+    + 1`` ids a sample; the ids are the vocabulary's first ``drawn_ids`` (all
+    of them where ``None``)."""
     mesh = mesh or make_mesh()
     if not config.global_batch_size:
         config.global_batch_size = mesh.size
-    vocab = model.config.vocab_size
+    vocab = drawn_ids or model.config.vocab_size
 
     # synthetic packed sequences (no corpus ships with the repo): Zipf ids;
     # the pool holds at least two steps of whatever batch the caller set
@@ -89,7 +104,7 @@ def train_lm(
         **powersgd_reducer_kwargs(config),
     )
     step = make_train_step(
-        next_token_lm_loss(model),
+        loss_of(model),
         reducer,
         params,
         learning_rate=config.learning_rate,
@@ -99,11 +114,12 @@ def train_lm(
     )
     collections = collections_of(params, ids[:4, :-1]) if collections_of else {}
     state = step.init_state(
-        params, model_state={STEP_COUNTERS: zero_counters(model.config), **collections}
+        params,
+        model_state={STEP_COUNTERS: zero_counters(model.config, getattr(loss_of, "counters", ())), **collections},
     )
+    pool = batches_of(ids, rng)
     batches = accumulated_batches(
-        [ids[:, :-1].copy(), ids[:, 1:].copy()], config,
-        max_steps_per_epoch=max_steps_per_epoch, keys=("input_ids", "labels"),
+        list(pool.values()), config, max_steps_per_epoch=max_steps_per_epoch, keys=tuple(pool),
     )
     from ..observe import audit_from_config, telemetry_from_config
 

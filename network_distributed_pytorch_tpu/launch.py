@@ -39,6 +39,7 @@ from .experiments import (
     powersgd_nemotron,
     powersgd_phi4flash,
     powersgd_qwen3_next,
+    powersgd_sdar,
     serve_gpt,
 )
 from .experiments.lm import LM_EXPERIMENTS
@@ -58,6 +59,7 @@ EXPERIMENTS = {
     "powersgd_lfm2": powersgd_lfm2.run,
     "powersgd_mellum": powersgd_mellum.run,
     "powersgd_phi4flash": powersgd_phi4flash.run,
+    "powersgd_sdar": powersgd_sdar.run,
     "imdb_baseline": imdb_baseline.run,
     "bandwidth_study": bandwidth_study.run,
     "gpt_lm": gpt_lm.run,

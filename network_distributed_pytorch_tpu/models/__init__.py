@@ -37,6 +37,7 @@ from .afmoe import AfmoeConfig, AfmoeLM, afmoe_tiny  # noqa: F401
 from .lfm2 import Lfm2Config, Lfm2LM, lfm2_tiny  # noqa: F401
 from .mellum import MellumConfig, MellumLM, mellum_tiny  # noqa: F401
 from .phi4flash import Phi4FlashConfig, Phi4FlashLM, phi4flash_tiny  # noqa: F401
+from .sdar import SdarConfig, SdarLM, sdar_tiny  # noqa: F401
 from .qwen3_next import Qwen3NextConfig, Qwen3NextLM, qwen3_next_tiny  # noqa: F401
 from .nemotron_h import NemotronHConfig, NemotronHLM, nemotron_h_tiny  # noqa: F401
 from .layers import next_token_lm_loss  # noqa: F401

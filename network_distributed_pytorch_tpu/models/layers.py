@@ -1,9 +1,10 @@
-"""What more than one of the six language models uses (``nemotron_h``, ``afmoe``,
-``qwen3_next``, ``lfm2``, ``mellum``, ``phi4flash``), between ``parallel/moe.py``
+"""What more than one of the seven language models uses (``nemotron_h``, ``afmoe``,
+``qwen3_next``, ``lfm2``, ``mellum``, ``phi4flash``, ``sdar``), between ``parallel/moe.py``
 and ``ops/`` below and one file per model above: projections and norms, the
 rotary embedding, the one choice of attention engine, the one caller of
-``held_experts_moe``, the layer stack, the counters' tree and the loss (every
-model's ``__call__`` returns ``(logits, counters)``, so one loss serves all).
+``held_experts_moe``, the layer stack, the counters' tree and the losses (every
+model's ``__call__`` returns ``(logits, counters)``: next-token cross-entropy
+serves six, the masked-token loss the one trained by block diffusion).
 
 A model file imports from here and from no other model: a piece comes here
 when a second model needs it, never by an import from a sibling. What a model
@@ -121,17 +122,18 @@ def rope_frequencies(rope: Rope, dim: int) -> Tuple[jax.Array, float]:
     return inv_freq / rope.factor * ramp + inv_freq * (1.0 - ramp), float(factor)
 
 
-def rotary(x: jax.Array, rope: Rope, rotary_dim: Optional[int] = None) -> jax.Array:
+def rotary(x: jax.Array, rope: Rope, rotary_dim: Optional[int] = None, positions: Optional[jax.Array] = None) -> jax.Array:
     """``x`` (B, T, H, D) in fp32, position t turned by the angles ``t *
     inv_freq`` of ``rope_frequencies(rope, D)`` (the default embedding: ``t
     * theta^(-2i/D)``): ``x cos + rotate_half(x) sin``, the halves paired as
     HuggingFace pairs them (i with i + D/2), cos and sin times the
     embedding's factor where it has one (YaRN). With ``rotary_dim`` only the
     head's first ``rotary_dim`` dims turn, as a head of that size would
-    (``partial_rotary_factor``); the others pass as they came."""
+    (``partial_rotary_factor``); the others pass as they came. Row t stands
+    at ``positions[t]`` ((T,) integers; ``None``: at t)."""
     if rotary_dim is not None and rotary_dim < x.shape[-1]:
-        return jnp.concatenate([rotary(x[..., :rotary_dim], rope), x[..., rotary_dim:]], axis=-1)
-    angles, factor = _angles(rope, x.shape[1], x.shape[-1])
+        return jnp.concatenate([rotary(x[..., :rotary_dim], rope, None, positions), x[..., rotary_dim:]], axis=-1)
+    angles, factor = _angles(rope, x.shape[1], x.shape[-1], positions)
     cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
     if factor != 1.0:
         cos, sin = cos * factor, sin * factor
@@ -139,29 +141,31 @@ def rotary(x: jax.Array, rope: Rope, rotary_dim: Optional[int] = None) -> jax.Ar
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _angles(rope: Rope, t: int, dim: int) -> Tuple[jax.Array, float]:
-    """``(t * inv_freq`` (T, dim/2) in fp32``, factor)`` of ``rope_frequencies(rope, dim)``."""
+def _angles(rope: Rope, t: int, dim: int, positions: Optional[jax.Array] = None) -> Tuple[jax.Array, float]:
+    """``(position * inv_freq`` (T, dim/2) in fp32``, factor)`` of ``rope_frequencies(rope, dim)``; the positions
+    ``0..t-1`` where none are given."""
     inv_freq, factor = rope_frequencies(rope, dim)
-    return jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :], factor
+    at = jnp.arange(t, dtype=jnp.float32) if positions is None else positions.astype(jnp.float32)
+    return at[:, None] * inv_freq[None, :], factor
 
 
-def rope_tables(rope: Rope, t: int, dim: int) -> Tuple[jax.Array, jax.Array]:
+def rope_tables(rope: Rope, t: int, dim: int, positions: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
     """What :func:`rotary` turns ``dim`` dims by, as tables: cos and sin of
     its angles times its factor, (T, dim/2) in fp32 each."""
-    angles, factor = _angles(rope, t, dim)
+    angles, factor = _angles(rope, t, dim, positions)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     return (cos * factor, sin * factor) if factor != 1.0 else (cos, sin)
 
 
 def normed_and_turned(
     q_norm: RMSNorm, k_norm: RMSNorm, q, k, rope: Optional[Rope], dtype, rotary_dim: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    interpret: Optional[bool] = None, positions: Optional[jax.Array] = None,
 ):
     """What an attention layer does to q (B, T, H, D) and k (B, T, H_kv, D)
     between their projections and the attention itself, under its scope
     ``attn.rope``: each head through its norm, then turned by ``rope`` as
-    :func:`rotary` turns it (``None``: the layer carries no positions), then
-    cast to ``dtype``.
+    :func:`rotary` turns it (``None``: the layer carries no positions), row t
+    at ``positions[t]`` (``None``: at t), then cast to ``dtype``.
 
     ``interpret=None`` lets the backend decide: on TPU one Pallas pass forward
     and one backward (``ops/qk_rope.py``) where its tiles serve the shape,
@@ -172,39 +176,54 @@ def normed_and_turned(
     t, d = q.shape[1], q.shape[-1]
     turning = 0 if rope is None else d if rotary_dim is None else min(rotary_dim, d)
     served = qk_rope.serves(t, q.shape[2], k.shape[2], d, turning)
+    at = {} if positions is None else {"positions": positions}  # without positions: the calls as they were
     if not served or (interpret is None and pallas_interpret()):
-        turned = lambda x: x if rope is None else rotary(x, rope, rotary_dim)
+        turned = lambda x: x if rope is None else rotary(x, rope, rotary_dim, **at)
         return turned(q_norm(q)).astype(dtype), turned(k_norm(k)).astype(dtype)
-    cos, sin = (None, None) if rope is None else rope_tables(rope, t, turning)
+    cos, sin = (None, None) if rope is None else rope_tables(rope, t, turning, **at)
     scales = q_norm(q, scale_alone=True), k_norm(k, scale_alone=True)
     return qk_rope.normed_and_turned(q, k, *scales, cos, sin, q_norm.eps, dtype, bool(interpret))
 
 
-def einsum_attention(q, k, v, window: int = None):
+def blockwise_seen(half: int, block: int) -> jax.Array:
+    """The block-diffusion mask over ``[noised copy ; clean copy]`` rows, (2 half, 2 half) booleans, query by key: both
+    copies hold positions ``0..half-1`` in blocks of ``block``. A noised query sees the noised keys of its own block and
+    the clean keys of earlier blocks; a clean query the clean keys of its own block and earlier, and no noised key."""
+    row = jnp.arange(2 * half)
+    clean, blk = row >= half, (row % half) // block
+    (q_clean, q_blk), (k_clean, k_blk) = (clean[:, None], blk[:, None]), (clean[None, :], blk[None, :])
+    return jnp.where(k_clean, jnp.where(q_clean, k_blk <= q_blk, k_blk < q_blk), ~q_clean & (k_blk == q_blk))
+
+
+def einsum_attention(q, k, v, window: int = None, blockwise: Tuple[int, int] = None):
     """Causal grouped-query attention with the weights materialised, the
     engine off the TPU: q (B, T, H, D), k (B, T, Hkv, D) and v (B, T, Hkv, Dv)
     repeated to H heads, -> (B, T, H, Dv); with ``window``, query i sees key j
-    iff ``0 <= i - j < window``."""
+    iff ``0 <= i - j < window``; with ``blockwise=(half, block)`` iff
+    :func:`blockwise_seen` says so, and not causally."""
     t, hd = q.shape[1], q.shape[-1]
     k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2) for x in (k, v))
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
     behind = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]  # query - key
-    seen = (behind >= 0) & (behind < (window or t))
+    seen = (behind >= 0) & (behind < (window or t)) if blockwise is None else blockwise_seen(*blockwise)
     weights = jax.nn.softmax(jnp.where(seen, scores / np.sqrt(hd), -jnp.inf), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", weights.astype(q.dtype), v)
 
 
-def causal_attention(cfg, q, k, v, window: int = None):
+def causal_attention(cfg, q, k, v, window: int = None, blockwise: Tuple[int, int] = None):
     """:func:`einsum_attention`'s result by the engine ``cfg.attn_impl`` names
     ("auto": flash on TPU, einsum elsewhere); the flash kernels read the key/value
-    head a group shares in place."""
+    head a group shares in place, and skip by their loop bounds the tiles a
+    window or the block-wise rule hides."""
     from ..ops.flash_attention import resolve_attn_impl
 
     if resolve_attn_impl(cfg.attn_impl) == "flash":
         from ..ops import flash_attention, pallas_interpret
 
-        return flash_attention(q, k, v, causal=True, window=window, interpret=pallas_interpret())
-    return einsum_attention(q, k, v, window)
+        return flash_attention(
+            q, k, v, causal=blockwise is None, window=window, interpret=pallas_interpret(), blockwise=blockwise
+        )
+    return einsum_attention(q, k, v, window, blockwise)
 
 
 def _balancing_bias(tokens32, router, top_k):
@@ -295,14 +314,16 @@ def run_layers(block_cls, cfg, per_layer_args: Iterable[Sequence], x):
     return x, counters
 
 
-def zero_counters(config) -> Dict[str, Dict[str, jax.Array]]:
+def zero_counters(config, also: Sequence[str] = ()) -> Dict[str, Dict[str, jax.Array]]:
     """The counters' tree before the first step: what ``init_state`` takes.
     ``config`` names its ``expert_layers`` and ``held_experts`` (any model's
-    that calls ``held_experts_moe``, whose counters these are)."""
+    that calls ``held_experts_moe``, whose counters these are); ``also`` the
+    scalar counters the loss writes beside them (``masked_token_loss.counters``)."""
     zero = lambda *shape: jnp.zeros(shape, jnp.int32)
     return {
         f"layer_{i}": {
             "held": zero(len(config.held_experts)), "absent": zero(), "dropped": zero(), "row_tiles": zero(),
+            **{name: zero() for name in also},
         }
         for i in config.expert_layers
     }
@@ -325,3 +346,32 @@ def next_token_lm_loss(model):
         return loss, {**model_state, STEP_COUNTERS: counters}
 
     return loss_fn
+
+
+def masked_token_loss(model):
+    """The trainer's loss function under block diffusion: the batch brings a sample's ``input_ids`` (B, L), its
+    ``noisy_ids`` (some replaced by the ``[MASK]`` id, block by block) and ``loss_weight`` (``1 / t_b`` on a replaced
+    position, t_b its block's noise level; 0 elsewhere: ``data.noising.block_noised``). The model runs on ``[noisy ;
+    clean]`` rows (B, 2L) and returns logits for the L noised rows; a replaced position's OWN row predicts its token (no
+    shift), and the loss is ``sum(weight * -log p) / (B L)``, under the device scope ``denoise.loss``. The counters and
+    the other collections ride as :func:`next_token_lm_loss` hands them on, every expert layer's counters joined by
+    ``masked``, how many positions carried loss in the step (they enter the layer as one token id: a layer's load
+    moves with it)."""
+    from ..parallel.trainer import STEP_COUNTERS
+
+    def loss_fn(params, model_state, batch):
+        others = {k: v for k, v in model_state.items() if k != STEP_COUNTERS}
+        rows = jnp.concatenate([batch["noisy_ids"], batch["input_ids"]], axis=1)
+        logits, counters = model.apply({"params": params, **others}, rows)
+        with jax.named_scope("denoise.loss"):
+            picked = jnp.take_along_axis(logits, batch["input_ids"][..., None], axis=-1)[..., 0]
+            weight = batch["loss_weight"]
+            loss = jnp.mean(weight * (jax.nn.logsumexp(logits, axis=-1) - picked))
+        masked = jnp.sum(weight > 0, dtype=jnp.int32)
+        counters = {layer: {**counted, "masked": masked} for layer, counted in counters.items()}
+        return loss, {**model_state, STEP_COUNTERS: counters}
+
+    return loss_fn
+
+
+masked_token_loss.counters = ("masked",)  # what it adds to every expert layer's counters: ``zero_counters(config, also)``

@@ -75,6 +75,27 @@ the K block, and the tiles on the band's two edges carry the position
 compare. The bounds follow the shapes; ``window=None`` (or one that covers
 the sequence) is the causal program, unchanged.
 
+A third rule, the first that is not a band: ``blockwise=(half, block)``, the
+block-diffusion mask over T = 2 ``half`` rows, a noised copy of a sequence then
+its clean copy, both at positions 0..half-1 in blocks of ``block``
+(``models/sdar.py``). A noised query sees the noised keys of its own block and
+the clean keys of earlier blocks; a clean query the clean keys of its own
+block and earlier; no clean query sees a noised key. It is walked by loop
+bounds too (``blockwise_key_tiles``, ``blockwise_query_tiles``), a tile lying
+in one copy (the tile edge divides ``half``). Forward, query tile q of a copy:
+first the clean key tiles every query of the tile sees whole, ``[0, q)`` where
+blocks divide tiles, in a loop whose body carries no position compare; then
+the tiles on the diagonals, with it: clean tile q, and for a noised query tile
+its own noised tile. Backward, the same walk transposed: a clean key tile is
+read by the later query tiles of BOTH copies whole and by tile q of both with
+the compare, a noised key tile by its own noised query tile and no other. At
+half = 8,192 and tiles of 512 that is 288 of the 1,024 tile pairs for 256
+tiles' worth of visible pairs, 48 of them compared (three diagonals of 16). A
+block that straddles a tile edge or spans tiles widens the diagonals by the
+bounds' own floors and ceilings. The compare is two per pair against a row of
+block starts (one division a query, not a pair). ``blockwise=None`` traces
+the program this file traced before it knew the rule.
+
 Training: the kernel is wrapped in a ``custom_vjp``. The forward also emits
 the per-row log-sum-exp; the backward is a second Pallas kernel
 (``_flash_bwd_kernel``, named ``flash_attention_bwd`` in the compiled
@@ -94,7 +115,8 @@ goes.
 Correctness is pinned against naive einsum attention (padding masks, causal,
 both, windows under, at and across the tile edge, grads, every lane block,
 the fold, grouped K/V and a value head wider and narrower than the query's) in
-``tests/test_flash_attention.py``; on CPU the kernel runs in interpret mode
+``tests/test_flash_attention.py``, the block-wise rule and its walk in
+``tests/test_flash_blockwise.py``; on CPU the kernel runs in interpret mode
 (the test path), on TPU it compiles with Mosaic.
 """
 
@@ -182,12 +204,92 @@ def _only_head(x, heads: int, i: int, axis: int = -1):
     return jnp.where((at >= i * d) & (at < (i + 1) * d), x, jnp.zeros_like(x))
 
 
+def _blockwise_seen(blockwise, q_row, k_row, block_q: int, block_k: int):
+    """The block-wise rule on one tile, (block_k, block_q) booleans: the tile's
+    queries start at row ``q_row`` and its keys at row ``k_row`` of the 2 x
+    ``half`` rows (scalars; a tile lies in one copy, so its first row says
+    which). A noised query sees the noised keys of its own block and the
+    clean keys of earlier blocks, a clean one the clean keys of its own block
+    and earlier; no tile of clean queries against noised keys is ever
+    visited. One division a query, two compares a pair."""
+    half, block = blockwise
+    clean_q, clean_k = q_row >= half, k_row >= half
+    q_pos = q_row - jnp.where(clean_q, half, 0) + lax.broadcasted_iota(jnp.int32, (1, block_q), 1)
+    k_pos = k_row - jnp.where(clean_k, half, 0) + lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+    first = lax.div(q_pos, block) * block  # where each query's block starts
+    lower = jnp.where(clean_k, 0, first)
+    upper = jnp.where(clean_k & jnp.logical_not(clean_q), first, first + block)
+    return (k_pos >= lower) & (k_pos < upper)
+
+
+def _scalar_ops(x):
+    """(floor division, select) for the tile walks below: on a traced scalar
+    the kernels' own, on a Python int plain arithmetic (the tests read the
+    walk off the same functions the kernels call)."""
+    if isinstance(x, int):
+        return (lambda a, b: a // b), (lambda c, a, b: a if c else b)
+    return lax.div, jnp.where
+
+
+def blockwise_key_tiles(blockwise, qi, block_q: int, block_k: int):
+    """The key tiles the forward visits for query tile ``qi`` of ``[noised ;
+    clean]`` rows under ``blockwise=(half, block)``, by loop bounds:
+    ``(whole, edge, own_lo, own_hi)`` = the clean key tiles ``[0, whole)``
+    every query of the tile sees all of (no compare), the clean tiles
+    ``[whole, edge)`` on the diagonal (the position compare), and for a noised
+    query tile its own blocks' noised tiles ``[own_lo, own_hi)`` (compared
+    too; empty for a clean tile). Tile indices count inside a copy; works on
+    Python ints and on traced scalars alike."""
+    half, block = blockwise
+    div, pick = _scalar_ops(qi)
+    n_half = half // block_q
+    clean = qi >= n_half
+    start = (qi - pick(clean, n_half, 0)) * block_q  # the tile's first position
+    first = div(start, block) * block  # its first query's block starts here
+    last = (div(start + block_q - 1, block) + 1) * block  # its last query's block ends here
+    # clean keys below `all_see` are seen by every query, below `some_see` by some
+    all_see = pick(clean, first + block, first)
+    some_see = pick(clean, last, last - block)
+    whole = div(all_see, block_k)
+    edge = div(some_see + block_k - 1, block_k)
+    own_lo = div(first, block_k)
+    own_hi = pick(clean, own_lo, div(last + block_k - 1, block_k))
+    return whole, edge, own_lo, own_hi
+
+
+def blockwise_query_tiles(blockwise, j, block_q: int, block_k: int):
+    """The query tiles the backward visits for key tile ``j``, the transpose
+    of :func:`blockwise_key_tiles`: ``(part_n, full_n, whole_n, part_c,
+    full_c)``, tile indices inside a copy. The noised query tiles ``[part_n,
+    full_n)`` and the clean ones ``[part_c, full_c)`` carry the compare; the
+    noised ``[whole_n, half)`` and the clean ``[full_c, half)`` see the whole
+    key tile. A noised key tile is read by the noised query tiles of its own
+    blocks and no other: the other three ranges come back empty."""
+    half, block = blockwise
+    div, pick = _scalar_ops(j)
+    n_half = half // block_q
+    clean = j >= half // block_k
+    start = (j - pick(clean, half // block_k, 0)) * block_k  # the tile's first position
+    first = div(start, block) * block  # its first key's block starts here
+    last = (div(start + block_k - 1, block) + 1) * block  # its last key's block ends here
+    up = lambda a: div(a + block_q - 1, block_q)
+    # a noised query sees a clean key from the block after the key's on, a
+    # clean query from the key's own block on
+    part_n = div(pick(clean, first + block, first), block_q)
+    full_n = up(last)
+    return (
+        part_n, full_n, pick(clean, full_n, n_half),
+        pick(clean, div(first, block_q), n_half), pick(clean, up(last - block), n_half),
+    )
+
+
 def _flash_kernel(
     block_q: int,
     block_k: int,
     t: int,
     causal: bool,
     window: int,  # None: no window
+    blockwise,  # None, or (half, block): the block-wise rule over [noised ; clean] rows
     scale: float,
     heads: int,
     q_ref,
@@ -233,7 +335,7 @@ def _flash_kernel(
         # K blocks that end before this Q block's first row's window starts
         lo = lax.div(jnp.maximum(qi * block_q - (window - 1), 0), block_k)
 
-    def body(j, carry):
+    def body(j, carry, compared: bool = True):
         ks = pl.multiple_of(j * block_k, block_k)
         k_blk = k_ref[0, pl.ds(ks, block_k), :]
         v_blk = v_ref[0, pl.ds(ks, block_k), :]
@@ -248,6 +350,10 @@ def _flash_kernel(
             valid = valid & (q_pos >= k_pos)
             if window:
                 valid = valid & (q_pos - k_pos < window)
+        if blockwise and compared:
+            valid = valid & _blockwise_seen(
+                blockwise, qi * block_q, ks, block_q, block_k
+            )
 
         def one_head(q, m, l, acc):
             # m, l: (1, block_q); acc: (v_lanes, block_q)
@@ -277,7 +383,27 @@ def _flash_kernel(
     m0 = jnp.full((1, block_q), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((1, block_q), jnp.float32)
     acc0 = jnp.zeros((v_lanes, block_q), jnp.float32)
-    done = lax.fori_loop(lo, hi, body, ((m0, l0, acc0),) * heads)
+    start = ((m0, l0, acc0),) * heads
+    if blockwise:
+        # two loops, by bounds: the clean key tiles every query of this tile
+        # sees whole, then the tiles on the diagonals, the only ones that
+        # carry the position compare: the clean ones at the edge of what the
+        # tile sees and, for a noised tile, its own blocks' noised ones
+        n_half_k = blockwise[0] // block_k
+        whole, edge, own_lo, own_hi = blockwise_key_tiles(blockwise, qi, block_q, block_k)
+        seen_whole = lax.fori_loop(
+            0, whole, lambda n, c: body(n_half_k + n, c, compared=False), start
+        )
+        on_edge = edge - whole
+        done = lax.fori_loop(
+            0, on_edge + own_hi - own_lo,
+            lambda n, c: body(
+                jnp.where(n < on_edge, n_half_k + whole + n, own_lo + n - on_edge), c
+            ),
+            seen_whole,
+        )
+    else:
+        done = lax.fori_loop(lo, hi, body, start)
     for i, (m, l, _) in enumerate(done):
         lse_ref[0, i] = jnp.where(
             l > 0, m + jnp.log(jnp.maximum(l, 1e-37)), _LSE_EMPTY
@@ -296,6 +422,7 @@ def _flash_bwd_kernel(
     t: int,
     causal: bool,
     window: int,  # None: no window
+    blockwise,  # None, or (half, block): the block-wise rule over [noised ; clean] rows
     scale: float,
     heads: int,
     q_ref,
@@ -340,6 +467,8 @@ def _flash_bwd_kernel(
     tn = (((0,), (0,)), ((), ()))  # Aᵀ·B
     tile = (block_k, block_q)
     lanes, v_lanes = q_ref.shape[-1], v_ref.shape[-1]
+    if blockwise:
+        n_half_q = blockwise[0] // block_q
     dqt_acc[...] = jnp.zeros_like(dqt_acc)
 
     def row_terms(i, _):
@@ -367,7 +496,7 @@ def _flash_bwd_kernel(
         mask_col = _keys_on_sublanes(mask_ref[0, pl.ds(j, 1), :])
         key_ok = jnp.broadcast_to(mask_col > _MASK_PAD, tile)
 
-        def q_block(i, carry):
+        def q_block(i, carry, compared: bool = True):
             dk, dv, dmask = carry
             qs = pl.multiple_of(i * block_q, block_q)
             q_blk = q_ref[0, pl.ds(qs, block_q), :]
@@ -379,6 +508,10 @@ def _flash_bwd_kernel(
                 valid = valid & (q_pos >= k_pos)
                 if window:
                     valid = valid & (q_pos - k_pos < window)
+            if blockwise and compared:
+                valid = valid & _blockwise_seen(
+                    blockwise, qs, ks, block_q, block_k
+                )
             for h in range(heads):
                 q_h = _only_head(q_blk, heads, h)
                 do_h = _only_head(do_blk, heads, h)
@@ -424,10 +557,35 @@ def _flash_bwd_kernel(
             # Q blocks that start after this K block's last key's window ends
             last = (j + 1) * block_k + window - 2  # the last row that sees it
             hi = jnp.minimum(lax.div(last, block_q) + 1, n_q)
-        dk, dv, dmask = lax.fori_loop(
-            lo, hi, q_block,
-            (zero[lanes], zero[v_lanes], jnp.zeros((block_k, 1), jnp.float32)),
-        )
+        start = (zero[lanes], zero[v_lanes], jnp.zeros((block_k, 1), jnp.float32))
+        if blockwise:
+            # two loops, by bounds, the forward's walk transposed: the query
+            # tiles that see this whole key tile (for a clean one: the later
+            # tiles of both copies), then the tiles on the diagonals with the
+            # position compare (for a noised one: its own blocks' noised
+            # tiles, and no other)
+            part_n, full_n, whole_n, part_c, full_c = blockwise_query_tiles(
+                blockwise, j, block_q, block_k
+            )
+            noised_whole = n_half_q - whole_n
+            seen_whole = lax.fori_loop(
+                0, noised_whole + n_half_q - full_c,
+                lambda n, c: q_block(
+                    jnp.where(n < noised_whole, whole_n + n, n_half_q + full_c + n - noised_whole),
+                    c, compared=False,
+                ),
+                start,
+            )
+            noised_edge = full_n - part_n
+            dk, dv, dmask = lax.fori_loop(
+                0, noised_edge + full_c - part_c,
+                lambda n, c: q_block(
+                    jnp.where(n < noised_edge, part_n + n, n_half_q + part_c + n - noised_edge), c
+                ),
+                seen_whole,
+            )
+        else:
+            dk, dv, dmask = lax.fori_loop(lo, hi, q_block, start)
         dk_ref[0, pl.ds(ks, block_k), :] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[0, pl.ds(ks, block_k), :] = dv.astype(dv_ref.dtype)
         dmask_ref[0, 0, pl.ds(j, 1), :] = dmask.reshape(1, block_k)
@@ -497,7 +655,7 @@ def _value_lanes(k, v, lanes: int) -> int:
 
 
 def _flash_fwd(
-    scale, causal, window, lanes, heads, block_q, block_k, interpret,
+    scale, causal, window, blockwise, lanes, heads, block_q, block_k, interpret,
     q, k, v, mask,
 ):
     """The forward kernel over the layout both kernels address: q
@@ -526,7 +684,7 @@ def _flash_fwd(
     # as (N, Hq, 1, T), never as 2-D rows of width T
     return pl.pallas_call(
         functools.partial(
-            _flash_kernel, block_q, block_k, t, causal, window, scale, heads
+            _flash_kernel, block_q, block_k, t, causal, window, blockwise, scale, heads
         ),
         grid=(n, n_blocks, t // block_q),
         in_specs=[
@@ -556,7 +714,7 @@ def _flash_fwd(
 
 
 def _flash_bwd(
-    scale, causal, window, lanes, heads, block_q, block_k, interpret,
+    scale, causal, window, blockwise, lanes, heads, block_q, block_k, interpret,
     q, k, v, mask, out, lse, do,
 ):
     """Flash backward as one Pallas kernel (``_flash_bwd_kernel``) over the
@@ -591,8 +749,8 @@ def _flash_bwd(
     )
     dq, dk, dv, dmask = pl.pallas_call(
         functools.partial(
-            _flash_bwd_kernel, block_q, block_k, t, causal, window, scale,
-            heads,
+            _flash_bwd_kernel, block_q, block_k, t, causal, window, blockwise,
+            scale, heads,
         ),
         grid=(n, n_blocks),
         in_specs=[
@@ -638,7 +796,9 @@ def _flash_bwd(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "block_q", "block_k", "interpret", "window"),
+    static_argnames=(
+        "causal", "block_q", "block_k", "interpret", "window", "blockwise"
+    ),
 )
 def flash_attention(
     q: jax.Array,
@@ -650,6 +810,7 @@ def flash_attention(
     block_k: int = None,
     interpret: bool = False,
     window: int = None,
+    blockwise: tuple = None,
 ) -> jax.Array:
     """Exact attention without materializing the score matrix.
 
@@ -665,6 +826,9 @@ def flash_attention(
     visible to query i iff ``0 <= i - j < window``. Key blocks wholly
     outside the band are skipped by the kernels' loop bounds. A window that
     covers the sequence is ``causal=True``, the same program.
+    blockwise: ``(half, block)``, the block-diffusion rule over T = 2 * half
+    rows ``[noised copy ; clean copy]`` (the module's text); neither causal
+    nor a window; hidden tiles are skipped by the kernels' loop bounds.
     Differentiable (custom VJP, blockwise backward). Returns (B, T, H, Dv)
     in q's dtype.
     """
@@ -675,10 +839,20 @@ def flash_attention(
             f"q {q.shape} needs k (B, T, Hkv, D) and v (B, T, Hkv, Dv) with H"
             f" a multiple of Hkv; got {k.shape} and {v.shape}"
         )
-    block_q = min(block_q or tile_edge(t), t)
-    block_k = min(block_k or tile_edge(t), t)
-    assert t % block_q == 0 and t % block_k == 0, (
-        f"T={t} must divide into blocks ({block_q}, {block_k}); pad the"
+    if blockwise is not None:
+        half, block = blockwise
+        if causal or window is not None or t != 2 * half or block < 1 or half % block:
+            raise ValueError(
+                f"blockwise={blockwise}: (half, block) over T = 2 * half rows,"
+                f" whole blocks, and neither causal nor a window; got T={t},"
+                f" causal={causal}, window={window}"
+            )
+    # under the block-wise rule a tile lies in one copy: it divides the half
+    whole = t if blockwise is None else blockwise[0]
+    block_q = min(block_q or tile_edge(whole), whole)
+    block_k = min(block_k or tile_edge(whole), whole)
+    assert whole % block_q == 0 and whole % block_k == 0, (
+        f"T={whole} must divide into blocks ({block_q}, {block_k}); pad the"
         " sequence (and mask the pads) first"
     )
     scale = 1.0 / float(d) ** 0.5
@@ -711,7 +885,8 @@ def flash_attention(
         mask = lax.pcast(mask, missing, to="varying")
 
     static = (
-        scale, causal, window, heads * d, heads, block_q, block_k, interpret
+        scale, causal, window, blockwise, heads * d, heads, block_q, block_k,
+        interpret,
     )
 
     @jax.custom_vjp

@@ -1,7 +1,7 @@
 """The one-way picture of the language models, held by their sources (read
 with ``ast``; nothing is imported, no jax): ``models/layers.py`` <- one file
 per model <- ``experiments/lm.py`` <- one file per experiment. No model
-imports another of the six, none takes an underscore name from any module,
+imports another of the seven, none takes an underscore name from any module,
 and no experiment imports another: what two of them need lives in
 ``models/layers.py`` or ``experiments/lm.py``."""
 
@@ -11,8 +11,8 @@ import os
 import pytest
 
 PACKAGE = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "network_distributed_pytorch_tpu")
-MODELS = ("nemotron_h", "afmoe", "qwen3_next", "lfm2", "mellum", "phi4flash")
-EXPERIMENTS = tuple(f"powersgd_{name}" for name in ("nemotron", "afmoe", "qwen3_next", "lfm2", "mellum", "phi4flash"))
+MODELS = ("nemotron_h", "afmoe", "qwen3_next", "lfm2", "mellum", "phi4flash", "sdar")
+EXPERIMENTS = tuple(f"powersgd_{name}" for name in ("nemotron", "afmoe", "qwen3_next", "lfm2", "mellum", "phi4flash", "sdar"))
 
 
 def imports_of(folder: str, module: str):

@@ -559,6 +559,36 @@ def test_an_attention_layer_on_a_chip_takes_the_rope_kernels_once_a_pass(v5e_dev
         assert max(touched, default=0) <= k_size, line[:300]
 
 
+def test_the_sdar_attention_layer_on_a_chip_walks_the_rule_inside_the_flash_kernels(v5e_devices, monkeypatch):
+    """SDAR's attention layer at its published widths over the cell's 16,384
+    rows (a noised copy of 8,192 tokens beside the clean one), rematerialised,
+    value and gradient compiled for v5e with the backend's own choice: the
+    block-wise rule is the flash kernels' loop bounds (forward, recomputed,
+    backward: K, V and eight operands of 16,384 rows whole in VMEM), the
+    positions 0..8191 twice reach ``qk_rope`` as its tables, XLA is left no
+    ``while`` and no score-sized array, and under ``attn.blockwise`` it
+    multiplies nothing: the three kernels, and the sums of a key/value group's
+    dK and dV."""
+    from network_distributed_pytorch_tpu.models.sdar import SdarAttention, SdarConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = SdarAttention(SdarConfig(dtype=jnp.bfloat16), 0.02)
+    rows, cfg = 16384, layer.config
+    u = jax.ShapeDtypeStruct((1, rows, cfg.hidden_size), jnp.float32)
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 64, cfg.hidden_size))))["params"]
+    apply = jax.checkpoint(lambda p, u: layer.apply({"params": p}, u))
+    loss = lambda p, u: jnp.sum(jnp.sin(apply(p, u).astype(jnp.float32)))
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda x: _on(v5e_devices[0], x), tree)
+    hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(on_chip(params), on_chip(u)).compile().as_text()
+    assert _custom_calls(hlo) == sorted(["flash_attention", "qk_rope"] * 2 + ["flash_attention_bwd", "qk_rope_bwd"])
+    assert not re.search(r"\bwhile\(", hlo)
+    sizes = [int(np.prod([int(n) for n in dims.split(",")])) for dims in re.findall(r"\b(?:bf16|f32|s32|pred)\[([\d,]+)\]", hlo)]
+    assert max(sizes) <= rows * cfg.n_heads * cfg.head_dim  # q's size: no (rows, rows) mask or score reaches HBM
+    under = [line for line in hlo.splitlines() if "attn.blockwise" in line and " = " in line]
+    assert sum("custom-call(" in line for line in under) == 3
+    assert not any(re.search(r" (while|dot|convolution)\(", line) for line in under)  # XLA multiplies nothing there
+
+
 @pytest.mark.parametrize("shape", ORTHOGONALIZE_SHAPES, ids=str)
 def test_pallas_orthogonalize_compiles_with_mosaic(v5e_devices, shape):
     arg = _on(v5e_devices[0], jax.ShapeDtypeStruct(shape, jnp.float32))
@@ -697,6 +727,44 @@ def test_the_phi4flash_cuts_step_lowers_for_tpu(monkeypatch):
     operands = re.findall(r'kernel_name = "(_flash_kernel|flash_attention_bwd)"[^\n]*? : \(([^)]*)\) ->', text)
     assert {name for name, _ in operands} == {"_flash_kernel", "flash_attention_bwd"}
     qkv = "tensor<40x8192x64xbf16>, tensor<20x8192x64xbf16>, tensor<20x8192x128xbf16>, "
+    assert all(types.startswith(qkv) for _, types in operands), operands
+
+
+def test_the_sdar_cuts_step_lowers_for_tpu(monkeypatch):
+    """The benchmark's cut (four layers at the published widths, 16 of 128
+    experts, 8,192 tokens as 16,384 rows, bf16, ``remat``) through
+    ``make_train_step`` under PowerSGD rank 16 with the masked-token loss,
+    cross-lowered for TPU as the chip builds it: every layer's flash kernels
+    are in the program, forward, recomputed and backward, over 16,384 rows of
+    32 query heads and 4 key/value heads of 128 read in place. (The whole
+    compile for v5e is ``benchmark/tests/test_aot_v5e.py``'s.)"""
+    from network_distributed_pytorch_tpu.models.layers import masked_token_loss, zero_counters
+    from network_distributed_pytorch_tpu.models.sdar import SdarConfig, SdarLM
+    from network_distributed_pytorch_tpu.parallel import PowerSGDReducer, make_mesh
+    from network_distributed_pytorch_tpu.parallel.trainer import STEP_COUNTERS, make_train_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    length = 8192
+    model = SdarLM(SdarConfig(
+        vocab_size=18992, n_layers=4, held_experts=tuple(range(16)), dtype=jnp.bfloat16, remat=True,
+    ))
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 64), jnp.int32)))["params"]
+    assert sum(int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(params)) == 456_346_624
+    step = make_train_step(
+        masked_token_loss(model), PowerSGDReducer(compression_rank=16, matricize="last"), params,
+        learning_rate=5e-5, algorithm="ef_momentum", mesh=make_mesh(devices=jax.devices()[:1]),
+    )
+    counters = zero_counters(model.config, masked_token_loss.counters)
+    state = jax.eval_shape(lambda p: step.init_state(p, model_state={STEP_COUNTERS: counters}), params)
+    tokens = jax.ShapeDtypeStruct((1, length), jnp.int32)
+    batch = {"input_ids": tokens, "noisy_ids": tokens, "loss_weight": jax.ShapeDtypeStruct((1, length), jnp.float32)}
+    text = step.fn.trace(state, batch).lower(lowering_platforms=("tpu",)).as_text()
+    names = re.findall(r'kernel_name = "(\w+)"', text)
+    # four alike layers share one lowered block: its forward, its recomputation and its backward
+    assert (names.count("_flash_kernel"), names.count("flash_attention_bwd")) == (2, 1)
+    assert (names.count("qk_rope"), names.count("qk_rope_bwd")) == (2, 1)
+    operands = re.findall(r'kernel_name = "(_flash_kernel|flash_attention_bwd)"[^\n]*? : \(([^)]*)\) ->', text)
+    qkv = "tensor<1x16384x4096xbf16>, tensor<1x16384x512xbf16>, tensor<1x16384x512xbf16>, "
     assert all(types.startswith(qkv) for _, types in operands), operands
 
 
